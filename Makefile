@@ -341,10 +341,9 @@ roi-smoke:
 
 # Performance regression gate: run the bench, then compare its JSON line
 # against the committed BENCH_r*.json trajectory (tools/bench_gate.py;
-# fails below best-committed minus 5%). Metric-matched: a non-TPU host
-# emits a *_cpu metric with no committed baseline, which records and
-# passes (first-run semantics) — the target is safe anywhere. A
-# contended dev chip reports instead of flaking (see bench_gate.py).
+# fails below best-committed minus 5%). Metric-matched: a metric with no
+# committed baseline records and passes (first-run semantics). Needs the
+# chip: bench.py exits non-zero when jax finds no TPU.
 perf-gate:
 	python bench.py | tee /tmp/vep_bench_latest.json
 	python tools/bench_gate.py /tmp/vep_bench_latest.json

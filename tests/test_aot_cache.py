@@ -117,6 +117,27 @@ class TestManifest:
 # engine integration: start() union + prewarm_status surface
 
 
+class _StubJit:
+    """Duck-typed jitted function for _TimedStep: ``lower().compile()``
+    hands back ``aot`` as the executable; calling the object itself is
+    the plain-jit path."""
+
+    def __init__(self, aot, on_jit=None):
+        self._aot = aot
+        self._on_jit = on_jit
+
+    def lower(self, *a):
+        return self
+
+    def compile(self):
+        return self._aot
+
+    def __call__(self, *a):
+        if self._on_jit is None:
+            raise AssertionError("jit path not expected")
+        return self._on_jit(*a)
+
+
 def _restore_jax_cache_config():
     import jax
 
@@ -192,31 +213,98 @@ class TestEnginePrewarm:
 
         class BoomJit:
             def lower(self, *a):
-                raise RuntimeError("no AOT lowering")
+                raise RuntimeError("compile failed")
 
             def __call__(self, *a):
-                raise RuntimeError("compile failed")
+                raise AssertionError("a failed compile is not retried")
 
         step = _TimedStep(BoomJit(), PerfTracker(), "broken", (32, 32), 1,
                           on_first_success=record)
         for _ in range(3):   # reliably failing: every retry re-raises
-            with pytest.raises(RuntimeError):
+            with pytest.raises(RuntimeError, match="compile failed"):
                 step(None)
         assert aot_cache.load_manifest(d) is None
 
-        class OkJit:
-            def lower(self, *a):
-                raise RuntimeError("jit path")   # fall back to plain jit
-
-            def __call__(self, *a):
-                return 42
-
         fired = []
-        ok = _TimedStep(OkJit(), PerfTracker(), "ok", (32, 32), 1,
+        ok = _TimedStep(_StubJit(lambda *a: 42), PerfTracker(), "ok",
+                        (32, 32), 1,
                         on_first_success=lambda: fired.append(1))
         assert ok(None) == 42
         assert ok(None) == 42
         assert fired == [1]   # once, on the first success only
+
+    def test_runtime_error_from_aot_call_is_not_swallowed(self):
+        """A device/runtime error out of the AOT executable propagates as
+        it is — to the dispatch site and the fault plane — with no second
+        attempt through jit (the frames argument may already have been
+        donated) and nothing counted as a fallback."""
+        from video_edge_ai_proxy_tpu.engine.runner import _TimedStep
+        from video_edge_ai_proxy_tpu.obs.perf import PerfTracker
+
+        class XlaRuntimeError(RuntimeError):
+            pass
+
+        def boom(*a):
+            raise XlaRuntimeError("INTERNAL: core halted unexpectedly")
+
+        jit_calls = []
+        perf = PerfTracker()
+        step = _TimedStep(
+            _StubJit(boom, on_jit=lambda *a: jit_calls.append(a)),
+            perf, "m", (32, 32), 1)
+        for _ in range(2):
+            with pytest.raises(XlaRuntimeError, match="core halted"):
+                step(None)
+        assert jit_calls == []                   # never retried via jit
+        assert perf.snapshot()["aot_fallbacks"] == 0
+        assert step.compiled is not None         # AOT path still armed
+
+    @pytest.mark.parametrize("exc", [TypeError, ValueError])
+    def test_avals_drift_falls_back_to_jit_and_is_counted(self, exc,
+                                                          monkeypatch):
+        """jax's argument check raises TypeError (avals/pytree) or
+        ValueError (sharding) BEFORE anything runs: that one case falls
+        back to plain jit for good, logged and counted."""
+        from video_edge_ai_proxy_tpu.engine import runner
+        from video_edge_ai_proxy_tpu.engine.runner import _TimedStep
+        from video_edge_ai_proxy_tpu.obs.perf import PerfTracker
+
+        aot_calls, logged = [], []
+        monkeypatch.setattr(runner.log, "warning",
+                            lambda msg, *a: logged.append(msg % a))
+
+        def reject(*a):
+            aot_calls.append(1)
+            raise exc("Argument types differ from the types for which "
+                      "this computation was compiled")
+
+        perf = PerfTracker()
+        step = _TimedStep(_StubJit(reject, on_jit=lambda *a: "via-jit"),
+                          perf, "m", (32, 32), 1)
+        assert step(None) == "via-jit"
+        assert step(None) == "via-jit"
+        assert aot_calls == [1]                  # AOT dropped after one try
+        assert perf.snapshot()["aot_fallbacks"] == 1
+        assert step.compiled is None
+        assert len(logged) == 1 and "rejected its arguments" in logged[0]
+        assert "Argument types differ" in logged[0]   # with the exception
+
+    def test_real_aval_mismatch_is_the_fallback_case(self):
+        """The real thing, not a stub: an executable compiled for one
+        shape, called with another, raises what the wrapper catches."""
+        import jax
+        import jax.numpy as jnp
+
+        from video_edge_ai_proxy_tpu.engine.runner import _TimedStep
+        from video_edge_ai_proxy_tpu.obs.perf import PerfTracker
+
+        perf = PerfTracker()
+        step = _TimedStep(jax.jit(lambda v, x: x * 2), perf, "m",
+                          (32, 32), 1)
+        assert int(step(None, jnp.ones((2,), jnp.int32)).sum()) == 4
+        assert step.compiled is not None
+        assert int(step(None, jnp.ones((3,), jnp.int32)).sum()) == 6
+        assert perf.snapshot()["aot_fallbacks"] == 1
 
     def test_start_prewarms_manifest_programs(self, tmp_path):
         from video_edge_ai_proxy_tpu.engine.runner import InferenceEngine

@@ -5,6 +5,7 @@ import time
 
 import numpy as np
 import pytest
+from conftest import xfail_on_failure
 
 from video_edge_ai_proxy_tpu.bus.interface import FrameMeta
 from video_edge_ai_proxy_tpu.bus.memory_bus import MemoryFrameBus
@@ -1575,13 +1576,11 @@ class TestModelParallelServing:
     # max/sum in a different order than the dense reference, and under
     # bf16 activations on the 8-virtual-device CPU backend the top-prob
     # drift occasionally exceeds the 2e-2 band (top_ids can flip between
-    # near-tied classes). strict=False so an environment where the
-    # numerics line up keeps passing.
-    @pytest.mark.xfail(
-        strict=False,
-        reason="bf16 ring-attention vs dense top-prob drift exceeds the "
-        "tolerance band on the CPU test backend (pre-existing)",
-    )
+    # near-tied classes). Tolerated, not required, so an environment
+    # where the numerics line up keeps passing — and counts as a pass.
+    @xfail_on_failure(
+        "bf16 ring-attention vs dense top-prob drift exceeds the "
+        "tolerance band on the CPU test backend (pre-existing)")
     def test_sp_ring_attention_serving(self, bus):
         """Long-context serving: a mesh with a sequence axis re-wires
         transformer models onto ring attention (the serving twin of

@@ -178,3 +178,29 @@ class TestPassthrough:
         assert worker._passthrough.written > 0
         assert os.path.exists(sink) and os.path.getsize(sink) > 0
         bus.close()
+
+
+def test_worker_entry_is_jax_free():
+    """The chip belongs to ONE process — the server, whose engine holds
+    it. Ingest workers are separate processes, N per server: one that
+    imported jax on a TPU host would reach for the chip and fail or hang.
+    Import the worker entry (and open the synthetic source the smokes
+    use) in a fresh interpreter and check jax never loaded."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "import video_edge_ai_proxy_tpu.ingest.worker as w\n"
+        "from video_edge_ai_proxy_tpu.ingest import open_source\n"
+        "src = open_source('test://pattern?w=64&h=48&fps=30&pace=0&frames=2')\n"
+        "src.open(); assert src.grab() is not None\n"
+        "assert callable(w.main)\n"
+        "bad = [m for m in ('jax', 'jaxlib', 'flax') if m in sys.modules]\n"
+        "assert not bad, bad\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, capture_output=True,
+        text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]

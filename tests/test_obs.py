@@ -401,6 +401,33 @@ class TestPerfTracker:
         assert b["mfu_pct"] == pytest.approx(0.5)
         assert lint_exposition(reg.render()) == []
 
+    @pytest.mark.parametrize("kind,peak", [
+        ("TPU v5 lite", 197.0), ("cpu", None), ("TPU v9 imaginary", None)])
+    def test_mfu_needs_a_peaks_table_row_for_the_device(self, kind, peak):
+        """One table keyed by device_kind: a device in it gets MFU against
+        ITS peak; a device not in it gets achieved TFLOP/s and NO MFU —
+        gauge unset, JSON null — never another chip's peak."""
+        from video_edge_ai_proxy_tpu.obs.perf import PerfTracker
+
+        reg = Registry()
+        perf = PerfTracker(registry=reg, clock=_FakeClock())
+        perf.set_device_kind(kind)
+        perf.note_compile("m", (96, 128), 4, 1.5, cost={"flops": 1.97e12})
+        perf.note_batch("m", (96, 128), 4, 10.0, 4)
+        snap = perf.snapshot()
+        text = reg.render()
+        assert snap["peak_tflops"] == peak
+        assert "vep_perf_achieved_tflops" in text
+        if peak is None:
+            assert snap["buckets"][0]["mfu_pct"] is None
+            assert "vep_perf_mfu_pct" not in text
+            assert "vep_perf_peak_tflops" not in text
+        else:
+            # 1.97 TFLOP in 10 ms = 197 TFLOP/s = 100% of a v5e
+            assert snap["buckets"][0]["mfu_pct"] == pytest.approx(100.0)
+            assert "vep_perf_mfu_pct" in text
+        assert lint_exposition(text) == []
+
     def test_cost_summary_tolerates_api_shapes(self):
         from video_edge_ai_proxy_tpu.obs.perf import cost_summary
 
@@ -566,7 +593,8 @@ class TestEnginePerfSLO:
         snap = eng.perf.snapshot()
         # The one serving program this run compiled is attributed with a
         # positive wall time; on the CPU backend XLA cost analysis also
-        # yields FLOPs, which makes the MFU gauge live.
+        # yields FLOPs, so achieved TFLOP/s is live — but the CPU has no
+        # row in the peaks table, so no MFU is published for it.
         assert snap["compiles"], "no compile recorded at the miss site"
         rec = snap["compiles"][0]
         assert rec["programs"] >= 1 and rec["compile_s"] > 0
@@ -579,7 +607,10 @@ class TestEnginePerfSLO:
         text = registry.render()
         assert "vep_compile_seconds" in text
         assert "vep_perf_padded_slots_total" in text
-        assert "vep_perf_mfu_pct" in text
+        assert "vep_perf_achieved_tflops" in text
+        assert snap["peak_tflops"] is None
+        assert snap["buckets"][0]["mfu_pct"] is None
+        assert snap["aot_fallbacks"] == 0
         assert lint_exposition(text) == []
 
     def test_stats_view_carries_device_attribution(self, bus):
@@ -627,7 +658,8 @@ class TestEnginePerfSLO:
             assert "slos" in stats["obs"]["slo"]
             with urllib.request.urlopen(rest + "/metrics") as r:
                 text = r.read().decode()
-            for fam in ("vep_perf_mfu_pct", "vep_perf_padded_slots_total",
+            for fam in ("vep_perf_achieved_tflops",
+                        "vep_perf_padded_slots_total",
                         "vep_compile_seconds", "vep_slo_burn_rate",
                         "vep_slo_firing"):
                 assert fam in text, f"{fam} missing from /metrics"

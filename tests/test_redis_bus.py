@@ -128,7 +128,7 @@ class TestFrameBusSemantics:
             assert server.commands_served - before == 1
 
     def test_streams_ignores_foreign_stream_keys(self, bus, raw):
-        """Mixed-fleet db hygiene (round-2 advisor): a co-tenant app's
+        """Mixed-fleet db hygiene (round-2 advisor): another app's
         stream key in the SAME db must not be reported as a camera, while
         a reference worker's stream (XADD VideoFrame, no control keys yet)
         and our own just-created EMPTY stream both must be."""

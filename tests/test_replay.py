@@ -256,6 +256,56 @@ class TestLockstepDeterminism:
         assert bad["checksum"] != baseline["checksum"]
 
 
+class TestLockstepMesh:
+    """The dp-mesh replay against one device (chip_smoke.py --chips 4 runs
+    the same comparison on real chips): the mesh step is a shard_map over
+    dp, every slice running the single-chip program on its own rows."""
+
+    @pytest.mark.parametrize("dp", [1, 4])
+    def test_mesh_equals_one_device_running_the_shards_in_turn(
+            self, tmp_path, dp):
+        import jax
+
+        from video_edge_ai_proxy_tpu.engine.collector import stream_shard
+        from video_edge_ai_proxy_tpu.parallel import make_mesh
+        from video_edge_ai_proxy_tpu.replay.harness import lockstep_checksum
+
+        names, i = [], 0          # one stream on every shard
+        while len({stream_shard(n, dp) for n in names}) < dp:
+            names.append(f"cam{i}")
+            i += 1
+        path = str(tmp_path / "m.vtrace")
+        record_synthetic_trace(path, names, width=64, height=48,
+                               fps=30.0, frames=4)
+        one = lockstep_checksum(path, model="tiny_yolov8", shards=dp)
+        mesh = lockstep_checksum(
+            path, model="tiny_yolov8",
+            mesh=make_mesh(dp=dp, devices=jax.devices()[:dp]))
+        assert one["frames"] == mesh["frames"] == 4 * len(names)
+        assert one["checksum"] == mesh["checksum"] != 0
+
+    def test_dp_mesh_step_is_a_shard_map_only_when_the_mesh_is_dp_only(self):
+        """dp-only: manual over dp (a Pallas kernel cannot be partitioned
+        by the TPU compiler, and rows are independent). With a model axis
+        the compiler's partitioning stays, as before."""
+        import jax
+
+        from video_edge_ai_proxy_tpu.engine.runner import build_serving_step
+        from video_edge_ai_proxy_tpu.models import registry
+        from video_edge_ai_proxy_tpu.parallel import make_mesh
+
+        spec = registry.get("tiny_yolov8")
+        model = spec.build()
+        plain = build_serving_step(model, spec)
+        dp_only = build_serving_step(
+            model, spec, mesh=make_mesh(dp=2, devices=jax.devices()[:2]))
+        with_tp = build_serving_step(
+            model, spec,
+            mesh=make_mesh(dp=2, tp=2, devices=jax.devices()[:4]))
+        assert dp_only.__name__ == "sharded"
+        assert with_tp.__code__ is plain.__code__
+
+
 class TestFaultPlan:
     def test_unknown_kind_rejected(self):
         with pytest.raises(ValueError, match="unknown fault kind"):

@@ -1,0 +1,174 @@
+"""The serving path's Pallas kernels, compiled for a DESCRIBED TPU v5e.
+
+The TPU compiler is installed with jax and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``): it refuses what
+the chip's compiler would refuse — a misaligned slice, too much VMEM, a
+kernel it cannot partition — which interpret mode (every other test of
+these kernels) cannot see. Nothing runs, so this says nothing about results
+or times; the run on the chip is ``chip_smoke.py``.
+
+Code that picks its path from ``jax.default_backend()`` sees the CPU here
+and takes its XLA twin, so each test names the kernel (``interpret=False``,
+``use_pallas=True``) or steers the choice itself; the program has no option
+for it.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one chip of a described v5e 2x2, or skip."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:   # no libtpu / topology unknown to it
+        pytest.skip(f"cannot describe a TPU v5e here: {exc}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: the next run would warn
+    and recompile. Keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compiled_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+class TestNmsKernel:
+    """K=256 is the serving ``max_candidates``; 16 and 64 are the batch
+    buckets the detector serves at (vmapped over the batch)."""
+
+    def _nms(self):
+        from video_edge_ai_proxy_tpu.ops.nms import nms_keep_mask_pallas
+
+        return functools.partial(
+            nms_keep_mask_pallas, iou_thresh=0.45, interpret=False)
+
+    def test_single_row(self, v5e):
+        boxes = jax.ShapeDtypeStruct((256, 4), jnp.float32, sharding=v5e)
+        assert "tpu_custom_call" in _compiled_text(self._nms(), boxes)
+
+    @pytest.mark.parametrize("rows", [16, 64])
+    def test_vmapped_over_batch(self, v5e, rows):
+        boxes = jax.ShapeDtypeStruct((rows, 256, 4), jnp.float32,
+                                     sharding=v5e)
+        text = _compiled_text(jax.vmap(self._nms()), boxes)
+        assert "tpu_custom_call" in text
+
+
+class TestFlashAttentionKernel:
+    """12 heads x 64, bf16: T=784 is videomae_b, T=6272 videomae_b_long —
+    the one registered config past ``FLASH_THRESHOLD_T``."""
+
+    def _qkv(self, v5e, b, t):
+        s = jax.ShapeDtypeStruct((b, t, 12, 64), jnp.bfloat16, sharding=v5e)
+        return s, s, s
+
+    @pytest.mark.parametrize("b,t", [(8, 784), (1, 6272)])
+    def test_forward(self, v5e, b, t):
+        from video_edge_ai_proxy_tpu.ops.flash_attention import (
+            flash_attention,
+        )
+
+        fwd = functools.partial(flash_attention, interpret=False)
+        assert "tpu_custom_call" in _compiled_text(fwd, *self._qkv(v5e, b, t))
+
+    def test_backward(self, v5e):
+        from video_edge_ai_proxy_tpu.ops.flash_attention import (
+            flash_attention,
+        )
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, interpret=False).astype(
+                jnp.float32).sum()
+
+        text = _compiled_text(
+            jax.grad(loss, argnums=(0, 1, 2)), *self._qkv(v5e, 1, 784))
+        # forward (recomputed residuals) + dq + dk/dv kernels
+        assert text.count("tpu_custom_call") >= 3
+
+
+def _as_if_on_tpu(monkeypatch):
+    """Steer the backend-keyed choices (ops/nms.py ``batched_nms``,
+    models/transformer.py ``auto_attention``, each kernel's ``interpret``
+    default) the way an attached chip would — in the test, not through an
+    option of the program."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.slow
+def test_videomae_long_forward_has_flash_kernel_inside(v5e, monkeypatch):
+    """The whole ``videomae_b_long`` encoder forward (64 frames -> 6,272
+    tokens) with the flash kernel INSIDE it, one block deep: the kernel
+    must survive the surrounding program's layouts, not only compile
+    alone. ~6 s, so ``slow`` like the whole detect step below."""
+    import dataclasses
+
+    from video_edge_ai_proxy_tpu.models import registry
+
+    _as_if_on_tpu(monkeypatch)
+    spec = registry.get("videomae_b_long")
+    model = spec.build()
+    model = model.clone(cfg=dataclasses.replace(
+        model.cfg, encoder=dataclasses.replace(
+            model.cfg.encoder, num_layers=1)))
+    clip = jax.ShapeDtypeStruct(
+        (1, spec.clip_len, spec.input_size, spec.input_size, 3),
+        jnp.bfloat16, sharding=v5e)
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros(clip.shape, clip.dtype), train=False))
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+        variables)
+    text = _compiled_text(
+        lambda v, x: model.apply(v, x, train=False), variables, clip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.slow
+def test_whole_yolov8n_step_16x1080p_has_pallas_nms_inside(v5e, monkeypatch):
+    """The exact program the engine serves by default — letterbox,
+    YOLOv8n, DFL decode, NMS, quality stats — at [16,1080,1920,3] uint8,
+    with the Pallas NMS inside it. ~20 s of compile, hence ``slow``."""
+    from video_edge_ai_proxy_tpu.engine.runner import build_serving_step
+    from video_edge_ai_proxy_tpu.models import registry
+
+    _as_if_on_tpu(monkeypatch)
+    spec = registry.get("yolov8n")
+    model = spec.build()
+    variables = jax.eval_shape(
+        lambda: spec.init_params(jax.random.PRNGKey(0))[1])
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+        variables)
+    frames = jax.ShapeDtypeStruct((16, 1080, 1920, 3), jnp.uint8,
+                                  sharding=v5e)
+    thumbs = jax.ShapeDtypeStruct((16, 32, 32), jnp.float32, sharding=v5e)
+    step = build_serving_step(model, spec, quality_thumb=32)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        variables, frames, thumbs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30

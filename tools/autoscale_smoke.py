@@ -27,8 +27,9 @@ Hard gates (exit non-zero on breach):
   serve, so there is no compile ramp to excuse);
 - the ``vep_supervisor_*`` exposition is lint-clean.
 
-Orchestration-correctness tool: runs on the CPU backend by default
-(``--native`` keeps the environment preset). ~3-4 min.
+Orchestration-correctness tool and a CPU rehearsal by construction: the
+gates are counts, and the member processes (each its own engine) are
+started with ``JAX_PLATFORMS=cpu`` — N engines cannot share one chip. ~3-4 min.
 
 Usage:
   python tools/autoscale_smoke.py                    # acceptance run
@@ -64,20 +65,11 @@ def main(argv=None) -> None:
                     help="keep the soak scratch dir (member stderr, the "
                          "AOT cache + manifest) instead of a deleted "
                          "temp dir")
-    ap.add_argument("--native", action="store_true",
-                    help="keep the environment's backend preset instead "
-                         "of forcing CPU")
     args = ap.parse_args(argv)
-
-    import jax
-
-    if not args.native:
-        jax.config.update("jax_platforms", "cpu")
-    backend = jax.default_backend()
 
     from video_edge_ai_proxy_tpu.replay.harness import run_autoscale_soak
 
-    model = args.model or ("yolov8n" if backend == "tpu" else "tiny_yolov8")
+    model = args.model or "tiny_yolov8"
     try:
         w, h = (int(v) for v in args.size.lower().split("x"))
     except ValueError:
@@ -91,9 +83,9 @@ def main(argv=None) -> None:
         surplus_headroom=args.surplus_headroom,
         surplus_hold_s=args.surplus_hold,
         storm_admission_bound_s=args.storm_admission_bound,
-        native=args.native, workdir=args.workdir or None)
+        workdir=args.workdir or None)
     out["tool"] = "autoscale_smoke"
-    out["backend"] = backend
+    out["backend"] = "cpu"
     with open(args.out, "w") as f:
         json.dump(out, f, indent=2)
         f.write("\n")

@@ -10,14 +10,10 @@ and fails (exit 1) when the new value regresses more than ``--tolerance``
 
 Semantics chosen for unattended CI (``make perf-gate``):
 
-- **Metric-matched only.** A CPU-backend run emits ``*_cpu`` metrics with
-  no committed TPU baseline — the gate reports "no baseline" and passes
-  (first-run semantics), so the target is safe on any host.
-- **Contention-aware.** bench.py flags ``contended_device`` when another
-  process held the chip during the run; such runs gate leniently (warn +
-  pass) unless ``--strict-contended``, because a shared dev chip must not
-  flake CI. Committed artifacts flagged contended are likewise excluded
-  from the baseline.
+- **Metric-matched only.** A metric with no committed baseline — today
+  every metric: no ``BENCH_r*.json`` is committed — reports "no baseline"
+  and passes (first-run semantics). bench.py itself exits non-zero
+  without a TPU, so there is no CPU line to gate.
 - **Best-of-trajectory baseline.** Gating against max(committed) rather
   than latest(committed) means a slow r(N) acceptance run can never
   ratchet the bar downward.
@@ -94,22 +90,19 @@ def load_trajectory(baseline_dir: str) -> list:
     return out
 
 
-def gate(current: dict, trajectory: list, tolerance: float,
-         strict_contended: bool = False) -> dict:
+def gate(current: dict, trajectory: list, tolerance: float) -> dict:
     """Pure decision: returns the report dict; report["pass"] is the
     verdict (unit-tested without artifacts on disk)."""
     metric = current["metric"]
     value = float(current["value"])
     matched = [t for t in trajectory if t.get("metric") == metric]
-    usable = [t for t in matched if not t.get("contended_device")]
     report = {
         "tool": "bench_gate",
         "metric": metric,
         "value": value,
         "tolerance": tolerance,
         "trajectory": [
-            {"artifact": t.get("_artifact"), "value": t.get("value"),
-             "contended": bool(t.get("contended_device"))}
+            {"artifact": t.get("_artifact"), "value": t.get("value")}
             for t in matched
         ],
     }
@@ -125,18 +118,13 @@ def gate(current: dict, trajectory: list, tolerance: float,
     for key in ("roi_equivalent_fps", "roi_canvas_occupancy_pct"):
         if current.get(key) is not None:
             report[key] = current[key]
-    if not usable:
+    if not matched:
         report.update(passed=True, reason="no committed baseline for "
                       f"metric {metric!r} (first run records the bar)")
         return report
-    reference = max(float(t["value"]) for t in usable)
+    reference = max(float(t["value"]) for t in matched)
     floor = reference * (1.0 - tolerance)
     report.update(reference=reference, floor=round(floor, 1))
-    if current.get("contended_device") and not strict_contended:
-        report.update(passed=True, contended=True,
-                      reason="run flagged contended_device: reported, "
-                      "not gated (--strict-contended to enforce)")
-        return report
     if value >= floor:
         report.update(passed=True,
                       reason=f"{value} >= floor {floor:.1f} "
@@ -458,9 +446,6 @@ def main(argv=None) -> int:
                          "committed value (default 0.05 = -5%%)")
     ap.add_argument("--baseline-dir", default=REPO,
                     help="directory holding BENCH_r*.json artifacts")
-    ap.add_argument("--strict-contended", action="store_true",
-                    help="gate contended-device runs too (default: "
-                         "report only)")
     args = ap.parse_args(argv)
 
     if args.input == "-":
@@ -470,8 +455,7 @@ def main(argv=None) -> int:
             text = f.read()
     current = parse_bench_output(text)
     trajectory = load_trajectory(args.baseline_dir)
-    report = gate(current, trajectory, args.tolerance,
-                  strict_contended=args.strict_contended)
+    report = gate(current, trajectory, args.tolerance)
     stem = stem_stage_info(args.baseline_dir)
     if stem is not None:
         report["stem_stage"] = stem          # informational, never gated

@@ -12,9 +12,11 @@ milestone (a named flax submodule), a jitted program runs the model with
 prunes everything downstream, so the program measures the prefix ending
 at the milestone. Stage cost = difference of adjacent prefixes. Each
 prefix is scan-folded and timed exactly like bench.py (per-iteration
-input perturbation, best-of-3, contention retry), and each prefix's FLOPs
-come from the SAME compiled program's cost analysis — so stage MFU =
-dFLOPs / dTime / peak is internally consistent.
+input perturbation, best-of-3), and each prefix's FLOPs come from the
+SAME compiled program's cost analysis — so stage MFU = dFLOPs / dTime /
+peak is internally consistent. The peak is the device's row of the one
+peaks table (obs/perf.py, keyed by ``device_kind``): run from the command
+line on a device without a row this exits non-zero.
 
     python tools/profile_mfu.py --config resnet50x16 --record MFU_resnet.json
     python tools/profile_mfu.py --config videomae_b_x8 --record MFU_vmae.json
@@ -26,7 +28,6 @@ import argparse
 import json
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -36,7 +37,10 @@ import numpy as np
 
 from bench import timed_best
 
-PEAK_TFLOPS = 197.0      # v5e bf16 (BASELINE.md MFU accounting)
+from video_edge_ai_proxy_tpu.obs.perf import (
+    peak_tflops_for, require_peak_tflops,
+)
+
 SRC_H, SRC_W = 1080, 1920
 
 # config -> (model name, batch, milestones). A milestone is
@@ -233,10 +237,9 @@ SPREAD_STABLE = 1.3     # worst median/min across rounds below this = clean
 
 
 def _window_spread(round_ms) -> float:
-    """Honest stability signal (there is no absolute contention gate for
-    arbitrary prefixes): how far the per-round minima spread. A clean set
-    of windows keeps every prefix's median within ~20% of its min;
-    co-tenant windows show 1.5-3x."""
+    """Stability signal (prefix costs span 100x, so no absolute bar
+    fits them all): how far the per-round minima spread. A clean set of
+    windows keeps every prefix's median within ~20% of its min."""
     vals = [
         float(np.median(r)) / min(r) for r in round_ms if min(r) > 0.05
     ]
@@ -251,12 +254,16 @@ def run_config(config: str, rounds: int = 4,
     spec = registry.get(model_name)
     model, variables = spec.init_params(jax.random.PRNGKey(0))
     backend = jax.default_backend()
+    device_kind = jax.devices()[0].device_kind
+    # None off the peaks table (the CPU twin the tests run): the MFU
+    # columns are then null; main() refuses such a device outright.
+    peak = peak_tflops_for(device_kind)
 
     # Compile every prefix first, then measure them ROUND-ROBIN across
-    # several rounds and keep each prefix's minimum: on a co-tenanted
-    # chip, timing each prefix in its own window lets window drift land
-    # entirely in the differences (a -13 ms "stage" was recorded that
-    # way); interleaving puts every prefix through the same windows.
+    # several rounds and keep each prefix's minimum: timing each prefix
+    # in its own window lets any drift between windows land entirely in
+    # the differences (a -13 ms "stage" was recorded that way);
+    # interleaving puts every prefix through the same windows.
     built = []
     for label, milestone in milestones:
         print(f"  compile -> {label} ...", flush=True)
@@ -269,17 +276,14 @@ def run_config(config: str, rounds: int = 4,
     def one_round(idx: int, total: int) -> None:
         print(f"  measuring (round {idx + 1}/{total}) ...", flush=True)
         for bi, (label, fn, args, flops, iters) in enumerate(built):
-            # Best-of-3 inside timed_best; no absolute good_ms gate is
-            # possible here (prefix costs span 100x), so window stability
-            # is judged from the cross-round spread below instead.
-            elapsed, _, _ = timed_best(
-                lambda fn=fn, args=args: fn(*args), iters, backend, 1e9,
-                time.monotonic() + 60.0)
+            # Best-of-3 inside timed_best; window stability is judged
+            # from the cross-round spread below.
+            elapsed, _ = timed_best(lambda fn=fn, args=args: fn(*args))
             round_ms[bi].append(elapsed / iters * 1e3)
 
     for r in range(rounds):
         one_round(r, rounds)
-    # Contention/stability gate (round 15): MFU_yolo_r05 shipped with
+    # Stability gate (round 15): MFU_yolo_r05 shipped with
     # windows_stable=false / spread 1.504, making its re-measured stage
     # deltas untrustworthy. Instead of recording a bad artifact, keep
     # adding round-robin rounds (each round gives every prefix another
@@ -316,8 +320,8 @@ def run_config(config: str, rounds: int = 4,
             "stage_ms": round(d_ms, 3),
             "stage_gflop": round(d_gf, 2),
             "stage_tflops": round(d_gf / d_ms, 1) if d_ms > 0.05 else None,
-            "stage_mfu_pct": round(100 * d_gf / d_ms / PEAK_TFLOPS, 1)
-            if d_ms > 0.05 else None,
+            "stage_mfu_pct": round(100 * d_gf / d_ms / peak, 1)
+            if d_ms > 0.05 and peak else None,
         })
         prev_ms, prev_gf = pref_ms, pref_gf
     total_ms, total_gf = prev_ms, prev_gf
@@ -326,12 +330,13 @@ def run_config(config: str, rounds: int = 4,
         "model": model_name,
         "batch": batch,
         "backend": backend,
-        "device_kind": jax.devices()[0].device_kind,
-        "peak_tflops": PEAK_TFLOPS,
+        "device_kind": device_kind,
+        "peak_tflops": peak,
         "stages": rows,
         "total_ms": round(total_ms, 3),
         "total_gflop": round(total_gf, 2),
-        "total_mfu_pct": round(100 * total_gf / total_ms / PEAK_TFLOPS, 1),
+        "total_mfu_pct": (round(100 * total_gf / total_ms / peak, 1)
+                          if peak else None),
         "rounds": done,
         "window_spread": round(float(spread), 3),
         "windows_stable": bool(windows_stable),
@@ -346,8 +351,7 @@ def run_config(config: str, rounds: int = 4,
                 "stage = difference of adjacent prefixes; FLOPs from each "
                 "compiled prefix's cost analysis (internally consistent); "
                 "window_spread = worst median/min across measurement "
-                "rounds (no absolute contention gate exists for "
-                "arbitrary prefixes); unstable windows retry with extra "
+                "rounds; unstable windows retry with extra "
                 "round-robin rounds up to max_rounds before recording",
     }
 
@@ -358,8 +362,7 @@ def main(argv=None) -> int:
     ap.add_argument("--record", default="")
     ap.add_argument("--rounds", type=int, default=4,
                     help="measurement rounds per prefix (more rounds let "
-                         "the per-prefix minimum converge through choppy "
-                         "co-tenant windows)")
+                         "the per-prefix minimum converge)")
     ap.add_argument("--max-rounds", type=int, default=None,
                     help="stability-gate round budget (default rounds*3): "
                          "rounds keep adding while window_spread >= "
@@ -369,6 +372,7 @@ def main(argv=None) -> int:
                          "after max-rounds (the artifact is written "
                          "either way, stamped windows_stable=false)")
     args = ap.parse_args(argv)
+    require_peak_tflops(jax.devices()[0].device_kind)
     out = run_config(args.config, rounds=args.rounds,
                      max_rounds=args.max_rounds)
     print(json.dumps(out))

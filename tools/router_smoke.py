@@ -30,8 +30,9 @@ Hard gates (exit non-zero on breach):
   (and the source, on the graceful leg);
 - the router's ``vep_router_*`` exposition is lint-clean.
 
-Orchestration-correctness tool: runs on the CPU backend by default
-(``--native`` keeps the environment preset). ~2-3 min.
+Orchestration-correctness tool and a CPU rehearsal by construction: the
+gates are counts, and the member processes (each its own engine) are
+started with ``JAX_PLATFORMS=cpu`` — N engines cannot share one chip. ~2-3 min.
 
 Usage:
   python tools/router_smoke.py                      # acceptance run
@@ -66,20 +67,11 @@ def main(argv=None) -> None:
     ap.add_argument("--workdir", default="",
                     help="keep the soak scratch dir (member stderr, span "
                          "dumps) instead of a deleted temp dir")
-    ap.add_argument("--native", action="store_true",
-                    help="keep the environment's backend preset instead "
-                         "of forcing CPU")
     args = ap.parse_args(argv)
-
-    import jax
-
-    if not args.native:
-        jax.config.update("jax_platforms", "cpu")
-    backend = jax.default_backend()
 
     from video_edge_ai_proxy_tpu.replay.harness import run_router_soak
 
-    model = args.model or ("yolov8n" if backend == "tpu" else "tiny_yolov8")
+    model = args.model or "tiny_yolov8"
     try:
         w, h = (int(v) for v in args.size.lower().split("x"))
     except ValueError:
@@ -91,9 +83,9 @@ def main(argv=None) -> None:
         width=w, height=h, fps=args.fps, model=model,
         scrape_interval_s=args.scrape_interval,
         ladder_escalate_s=args.ladder_escalate,
-        native=args.native, workdir=args.workdir or None)
+        workdir=args.workdir or None)
     out["tool"] = "router_smoke"
-    out["backend"] = backend
+    out["backend"] = "cpu"
     with open(args.out, "w") as f:
         json.dump(out, f, indent=2)
         f.write("\n")

@@ -103,7 +103,7 @@ class RedisFrameBus(FrameBus):
         self._client.command("DEL", device_id)
         # Seed the reference-shaped control hash (grpc_api.go:159-175
         # writes the same key on Query) so streams() can tell OUR empty
-        # stream apart from a co-tenant app's stream key without probing
+        # stream apart from a another app's stream key without probing
         # payloads. HSETNX: never clobber a live last_query.
         self._client.command(
             "HSETNX", KEY_LAST_ACCESS_PREFIX + device_id, FIELD_LAST_QUERY,
@@ -283,7 +283,7 @@ class RedisFrameBus(FrameBus):
         """Stream-typed keys that are actually camera frame streams.
 
         The db is shared in the mixed-fleet deployment this backend exists
-        for, so a bare ``SCAN TYPE stream`` would report co-tenant apps'
+        for, so a bare ``SCAN TYPE stream`` would report other apps'
         stream keys as cameras and the engine would unmarshal their
         entries as VideoFrame protos (round-2 advisor). A key qualifies
         when
@@ -311,7 +311,7 @@ class RedisFrameBus(FrameBus):
                 self._stream_verdict[key] = verdict
             if verdict[0]:
                 out.append(key)
-        # Prune verdicts for keys gone from the db (co-tenant apps churn
+        # Prune verdicts for keys gone from the db (other apps churn
         # ephemeral stream names; without this the cache grows for the
         # life of the process).
         if len(self._stream_verdict) > len(scanned):

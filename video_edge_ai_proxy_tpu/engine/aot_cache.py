@@ -7,7 +7,7 @@ compile before taking traffic, and ROUTER_r01 had to reset its
 conservation ledger post-warmup because a member compiling in-tick
 overwrites frames (latest-frame-wins) for tens of seconds. This module
 adds the missing half: a versioned **prewarm manifest** JSON living
-next to the XLA cache payload that records the program set — one entry
+in ``aot_cache_dir`` that records the program set — one entry
 per ``(model, stem, geometry, bucket)`` serving step a member has ever
 compiled — so a spawned member pointed at the shared cache dir replays
 the whole set at boot (every compile a cache hit) and serves its first
@@ -20,9 +20,10 @@ include the jaxlib/XLA fingerprint on their own; the manifest stamp
 exists so we never burn boot time replaying a program list whose cache
 entries are guaranteed misses.
 
-Stdlib-only except for :func:`configure` (which touches jax.config and
-is only called from the engine warmup path); the manifest helpers are
-safe to import from control-plane code.
+Stdlib-only: the manifest helpers are safe to import from control-plane
+code. The XLA payload itself is bound by ``utils/compile_cache.py`` —
+into ``aot_cache_dir`` next to the manifest, or wherever
+``JAX_COMPILATION_CACHE_DIR`` says when that is set.
 """
 
 from __future__ import annotations
@@ -59,41 +60,6 @@ def _jaxlib_stamp() -> str:
 
 def manifest_path(cache_dir: str) -> str:
     return os.path.join(cache_dir, MANIFEST_NAME)
-
-
-def configure(cache_dir: str) -> bool:
-    """Point the jax persistent compilation cache at ``cache_dir``.
-
-    Same wiring the plain ``compile_cache_dir`` path uses (lower the
-    persistence threshold only when still at the jax default, reset the
-    cache object so the directory binds even if something compiled
-    first); returns False instead of raising when jax refuses.
-    """
-    import jax
-
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        if jax.config.jax_persistent_cache_min_compile_time_secs == 1.0:
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.5
-            )
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc,
-            )
-
-            _cc.reset_cache()
-        except Exception:
-            log.warning(
-                "could not reset the XLA compilation cache; programs "
-                "compiled before warmup may persist elsewhere",
-                exc_info=True,
-            )
-        return True
-    except Exception:
-        log.exception("AOT cache configure failed; continuing uncached")
-        return False
 
 
 def mesh_spec(mesh) -> List[list]:

@@ -218,7 +218,7 @@ class FaultPlane:
         """Drain-thread tap, once per fetched batch: deadline overrun
         hysteresis. Consecutive overruns >= the hysteresis open a stall
         suspicion for the tick thread to probe; one on-time batch closes
-        it (a transient contention spike is not a dead chip)."""
+        it (a transient slow spell is not a dead chip)."""
         with self._lock:
             if device_ms > self.deadline_ms:
                 self._overruns += 1

@@ -64,7 +64,7 @@ def parse_fresh_status(raw, now_ms: int) -> dict:
     except ValueError:
         return {}
     # Valid JSON that isn't an object ('null', a number, a list — corrupt
-    # write or a co-tenant key in a shared Redis db) must degrade to {},
+    # write or another app's key in a shared Redis db) must degrade to {},
     # not AttributeError every consumer.
     if not isinstance(hb, dict):
         return {}

@@ -69,9 +69,9 @@ class YOLOv8Config:
 
 
 def yolov8n_config(num_classes: int = 80) -> YOLOv8Config:
-    # stem_pad_c=8: measured +3.2% end-to-end at the north-star shape
-    # (two uncontended runs, 12.35/12.36 vs 12.74 ms — BASELINE.md levers
-    # table), reproducible, and checkpoint-transferable (the importer
+    # stem_pad_c=8: measured +3.2% on the device step at the north-star
+    # shape (two runs, 12.35/12.36 vs 12.74 ms; rounds 3 and 5,
+    # LEVERS_r05.json), reproducible, and checkpoint-transferable (the importer
     # zero-pads the stem kernel). The round-5 s2d experiment lost 0.85x
     # AND broke checkpoints; the round-15 stem="s2d" is a different,
     # lossless fold — see YOLOv8Config.stem. pad_channels no-ops when the

@@ -26,14 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 _NEG = -1e30
 

@@ -33,6 +33,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .boxes import box_iou_matrix
 
@@ -111,15 +113,6 @@ def _nms_kernel(boxes_ref, boxes_t_ref, out_ref, iou_ref, keep_ref, *, iou_thres
     out_ref[:, :] = (keep_ref[:, :] > 0.0).astype(jnp.int32)
 
 
-try:  # Pallas import kept soft: ops must load even on exotic backends.
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-
 @functools.partial(jax.jit, static_argnames=("iou_thresh", "interpret"))
 def _nms_pallas_call(boxes, boxes_t, *, iou_thresh, interpret):
     k = boxes.shape[0]
@@ -151,7 +144,7 @@ def nms_keep_mask_pallas(
 
 def nms_keep_mask(boxes: jnp.ndarray, iou_thresh: float) -> jnp.ndarray:
     """Backend-dispatching keep mask ([K,4] sorted-desc boxes -> [K] bool)."""
-    if _HAVE_PALLAS and jax.default_backend() == "tpu":
+    if jax.default_backend() == "tpu":
         return nms_keep_mask_pallas(boxes, iou_thresh)
     return nms_keep_mask_xla(boxes, iou_thresh)
 
@@ -204,7 +197,7 @@ def batched_nms(
     selection stays the default on every backend.
     """
     if use_pallas is None:
-        use_pallas = _HAVE_PALLAS and jax.default_backend() == "tpu"
+        use_pallas = jax.default_backend() == "tpu"
     if classes is None:
         classes = jnp.zeros(scores.shape, dtype=jnp.int32)
     num_anchors = scores.shape[-1]

@@ -27,9 +27,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
-
-from .compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .train import TrainState

@@ -21,9 +21,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import axis_size, shard_map
 
 
 _NEG = -1e30  # "masked" logit; avoids -inf NaNs when a whole block is masked
@@ -58,7 +57,7 @@ def ring_attention_local(q, k, v, axis_name: str = "sp", true_t: Optional[int] =
     shard the block originated on (after s rotations, device i holds the
     block that started on device (i - s) mod n).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, tq, h, d = q.shape
     m0 = jnp.full((b, h, tq), _NEG, jnp.float32)

@@ -54,12 +54,9 @@ def shard_put(frames: Any, sharding: NamedSharding):
     the committed pieces back into one global array with the requested
     sharding (no data movement). Slices of a C-contiguous host array
     along the leading (batch) axis are themselves contiguous views, so
-    each transfer is a single flat copy. Falls back to the plain put when
-    the sharding cannot enumerate per-device index maps."""
-    try:
-        dmap = sharding.addressable_devices_indices_map(frames.shape)
-    except Exception:
-        return jax.device_put(frames, sharding)
+    each transfer is a single flat copy. A batch the mesh cannot divide
+    raises here, before anything is placed."""
+    dmap = sharding.addressable_devices_indices_map(frames.shape)
     arrs = [jax.device_put(frames[idx], d) for d, idx in dmap.items()]
     return jax.make_array_from_single_device_arrays(
         frames.shape, sharding, arrs)

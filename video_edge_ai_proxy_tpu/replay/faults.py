@@ -24,7 +24,7 @@ them (replay/harness.py):
   (a flapping link: cameras tolerate it, the bus breaker and resp
   idempotency-aware resync keep readers degraded, not wedged).
 - ``device_stall`` — every device step call slows for ``duration_s``
-  (a contended/thermal-throttled chip: sustained tick-budget overrun
+  (a thermal-throttled or otherwise slowed chip: sustained tick-budget overrun
   must walk the engine's degradation ladder, then recover).
 - ``black_frame`` — one camera publishes all-zero frames for
   ``duration_s`` (lens cap / dead sensor: obs/quality.py must verdict

@@ -79,7 +79,7 @@ def _pct(values, points=(50, 90, 95, 99)) -> Optional[dict]:
 def lockstep_checksum(
     trace_path: str, *, model: str = "tiny_yolov8",
     device_id: Optional[str] = None, limit: int = 0,
-    perturb=None, zero_prior: bool = True, mesh=None,
+    perturb=None, zero_prior: bool = True, mesh=None, shards: int = 0,
 ) -> dict:
     """Replay a trace deterministically through bus -> collector ->
     serving step and fold the content checksum over every emitted batch.
@@ -93,8 +93,16 @@ def lockstep_checksum(
     mesh-serving H2D path (parallel.shard_put) instead of a plain
     transfer — at dp=1 the checksum must stay bit-identical to the
     single-chip golden, the smoke gate pinning mesh-native serving to
-    the exact same numerics. Returns {"checksum", "frames", "batches",
-    "model"}.
+    the exact same numerics. ``shards`` (default: the mesh's dp, else 1)
+    is the collector's shard-segmented batch layout; given WITHOUT a mesh,
+    one device runs each shard's rows as a batch of their own, in turn —
+    what the chips of a dp mesh do at once, at the same per-chip batch
+    shape. That is the run a dp=4 mesh of real chips is compared with:
+    on a TPU the compiler tiles a convolution by its batch size, so one
+    [4-row] program and four [1-row] programs need not agree to the bit
+    (on v5e they do not: near-tied random-weight scores reorder in NMS),
+    while the same program on four chips must. Returns {"checksum",
+    "frames", "batches", "model"}.
     """
     import jax
     import jax.numpy as jnp
@@ -110,13 +118,20 @@ def lockstep_checksum(
         variables = zero_class_prior(variables)
     if perturb is not None:
         variables = perturb(variables)
-    step = jax.jit(lambda v, u8: device_checksum(build_serving_step(net, spec)(v, u8)))
+    if mesh is not None:
+        from ..parallel import replicated
+
+        variables = jax.device_put(variables, replicated(mesh))
+    serving_step = build_serving_step(net, spec, mesh=mesh)
+    step = jax.jit(lambda v, u8: device_checksum(serving_step(v, u8)))
 
     player = TracePlayer(trace_path)
     bus = MemoryFrameBus()
+    if not shards:
+        shards = mesh.shape["dp"] if mesh is not None else 1
     col = Collector(
         bus, buckets=(1, 2, 4, 8, 16), default_model=spec.name,
-        clip_len=spec.clip_len,
+        clip_len=spec.clip_len, shards=shards,
     )
     created: set[str] = set()
     carry = 0
@@ -136,13 +151,15 @@ def lockstep_checksum(
                 if mesh is not None:
                     from ..parallel import batch_sharding, shard_put
 
-                    placed = shard_put(
+                    pieces = [shard_put(
                         np.ascontiguousarray(group.frames),
-                        batch_sharding(mesh, group.frames.ndim))
+                        batch_sharding(mesh, group.frames.ndim))]
                 else:
-                    placed = jnp.asarray(group.frames)
-                part = int(np.asarray(step(variables, placed)))
-                carry = (carry + part) & CHECKSUM_MASK
+                    pieces = [jnp.asarray(seg) for seg in
+                              np.split(group.frames, shards)]
+                for placed in pieces:
+                    part = int(np.asarray(step(variables, placed)))
+                    carry = (carry + part) & CHECKSUM_MASK
     finally:
         bus.close()
     return {
@@ -1028,7 +1045,11 @@ def _fleet_member_main(argv=None) -> None:
     verdict on (deterministic ladder pressure; pair with ``--slo-off``
     so the real SLO engine never recomputes it), ``calm`` clears it,
     ``exit`` releases the member. Each command is acked with a JSON
-    line."""
+    line.
+
+    A member has its own engine, so N of them cannot share one chip:
+    every spawner starts members with ``JAX_PLATFORMS=cpu`` in the
+    environment, and these multi-member legs are CPU count checks."""
     import argparse
     import json
     import shutil
@@ -1049,7 +1070,6 @@ def _fleet_member_main(argv=None) -> None:
                     help="extra replay seconds before the measured window "
                          "(covers worker boot + first-geometry compile)")
     ap.add_argument("--spans-out", required=True)
-    ap.add_argument("--native", action="store_true")
     ap.add_argument("--serve-only", action="store_true")
     ap.add_argument("--slo-off", action="store_true",
                     help="disable the SLO engine so the burn flag is "
@@ -1105,11 +1125,6 @@ def _fleet_member_main(argv=None) -> None:
     args = ap.parse_args(argv)
     if not args.serve_only and (not args.trace or not args.device):
         ap.error("--trace/--device required without --serve-only")
-    if not args.native:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-
     from ..obs import tracer
     from ..serve.models import StreamProcess
     from ..serve.server import Server
@@ -1211,8 +1226,7 @@ def _fleet_member_main(argv=None) -> None:
 def run_fleet_obs(
     *, n_members: int = 3, duration_s: float = 12.0, warmup_s: float = 8.0,
     width: int = 128, height: int = 96, fps: float = 30.0,
-    model: str = "tiny_yolov8", native: bool = False,
-    workdir: Optional[str] = None,
+    model: str = "tiny_yolov8", workdir: Optional[str] = None,
 ) -> dict:
     """r14 fleet telemetry soak: N REAL server processes (each with its
     own subprocess ingest worker, shm bus, engine, gRPC + REST), one
@@ -1230,6 +1244,10 @@ def run_fleet_obs(
       the full worker -> bus -> engine -> client lineage;
     - ``counters_conserved`` — after quiesce, every merged counter
       equals the sum of the members' individually-scraped values.
+
+    CPU-only by construction: the gates are counts, and N member
+    processes each with its own engine cannot share one chip — every
+    member is started with ``JAX_PLATFORMS=cpu``.
     """
     import json as _json
     import shutil
@@ -1267,11 +1285,7 @@ def run_fleet_obs(
                 "--model", model, "--duration", str(duration_s),
                 "--warmup", str(warmup_s), "--spans-out", spans_out,
             ]
-            if native:
-                cmd.append("--native")
-            env = dict(os.environ)
-            if not native:
-                env["JAX_PLATFORMS"] = "cpu"
+            env = dict(os.environ, JAX_PLATFORMS="cpu")
             procs.append(subprocess.Popen(
                 cmd, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
                 stderr=open(os.path.join(tmp, f"m{i}.stderr"), "w"),
@@ -1475,8 +1489,7 @@ def run_router_soak(
     *, n_members: int = 3, streams_per_member: int = 2,
     width: int = 128, height: int = 96, fps: float = 2.0,
     model: str = "tiny_yolov8", scrape_interval_s: float = 1.0,
-    ladder_escalate_s: float = 8.0, native: bool = False,
-    workdir: Optional[str] = None,
+    ladder_escalate_s: float = 8.0, workdir: Optional[str] = None,
 ) -> dict:
     """r16 fleet-router soak: N REAL serve-only server processes, one
     :class:`~..serve.router.StreamRouter` placing ``n_members *
@@ -1519,6 +1532,9 @@ def run_router_soak(
     ``--ladder-slo-only`` (physical tick-lag pressure is unavoidable on
     CPU and would walk EVERY member's ladder — the injected burn must be
     the only rung driver or the fleet ping-pongs).
+
+    CPU-only by construction (members start with ``JAX_PLATFORMS=cpu``):
+    the gates are counts, and N engines cannot share one chip.
     """
     import json as _json
     import shutil
@@ -1568,11 +1584,7 @@ def run_router_soak(
                 # very first frame (r19; see MigrationLedger docstring).
                 "--prewarm", f"{height}x{width}x{bucket}",
             ]
-            if native:
-                cmd.append("--native")
-            env = dict(os.environ)
-            if not native:
-                env["JAX_PLATFORMS"] = "cpu"
+            env = dict(os.environ, JAX_PLATFORMS="cpu")
             procs.append(subprocess.Popen(
                 cmd, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
                 stderr=open(os.path.join(tmp, f"{mname}.stderr"), "w"),
@@ -2028,7 +2040,7 @@ def run_autoscale_soak(
     capacity_fast_window_s: float = 5.0,
     storm_admission_bound_s: float = 12.0,
     shape: Optional[LoadShape] = None,
-    native: bool = False, workdir: Optional[str] = None,
+    workdir: Optional[str] = None,
 ) -> dict:
     """r19 autoscale soak: a :class:`~..serve.supervisor.FleetSupervisor`
     with a REAL subprocess spawner over a :class:`LoadShape` churn
@@ -2081,6 +2093,10 @@ def run_autoscale_soak(
     throughout and the cooldown is what makes "sustained surplus" mean
     "after the storm and ramp drained" instead of "the first quiet
     10 s" (on the real chip the bar itself does this work).
+
+    CPU-only by construction (members start with ``JAX_PLATFORMS=cpu``):
+    the gates are counts and host wall-clock, and N engines cannot share
+    one chip.
     """
     import json as _json
     import itertools
@@ -2159,11 +2175,7 @@ def run_autoscale_soak(
             cmd += ["--prewarm", f"{height}x{width}x{bucket}"]
             for mdl in tenant_models:
                 cmd += ["--prewarm", f"{height}x{width}x{bucket}:{mdl}"]
-        if native:
-            cmd.append("--native")
-        env = dict(os.environ)
-        if not native:
-            env["JAX_PLATFORMS"] = "cpu"
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         t0 = time.monotonic()
         proc = subprocess.Popen(
             cmd, stdin=subprocess.PIPE, stdout=subprocess.PIPE,

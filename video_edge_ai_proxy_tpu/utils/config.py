@@ -105,6 +105,8 @@ class EngineConfig:
     # minutes to compile; with a cache dir a restarted server skips
     # recompiling every (geometry, bucket) program it has seen. "" = off
     # (jax default); "auto" = the server resolves <data_dir>/compile_cache.
+    # Where JAX_COMPILATION_CACHE_DIR is set the cache stays there and
+    # this directory is not used (utils/compile_cache.py).
     compile_cache_dir: str = ""
     # Geometries to compile at boot instead of on first frame: list of
     # [height, width, bucket] or [height, width, bucket, model] (the
@@ -121,9 +123,11 @@ class EngineConfig:
     # pipeline). False = legacy synchronous placement on the tick thread.
     prefetch: bool = True
     # Donate the frames argument to the compiled step (jax donate_argnums)
-    # so XLA reuses the input HBM slot instead of allocating one per tick.
-    # "auto" = donate where the backend implements donation (TPU; the CPU
-    # test backend would warn per call and copy anyway), "on"/"off" force.
+    # so XLA may reuse the input HBM slot. "auto" = donate where the
+    # donation can be taken: a TPU program partitioned over engine.mesh
+    # (XLA buffer donor). On one chip, and on the CPU test backend, jit
+    # can only drop it with a warning (no output shares the uint8 frame
+    # plane's shape), so "auto" does not ask. "on"/"off" force.
     donate_frames: str = "auto"
     # /healthz flags the engine loop wedged when no tick completed for this
     # long. Must exceed the longest legitimate in-tick XLA compile (first
@@ -189,11 +193,6 @@ class EngineConfig:
     # Rung 1 (shed): frames older than this at dispatch are dropped
     # oldest-first instead of occupying device batch slots.
     shed_staleness_ms: float = 500.0
-    # Device peak TFLOP/s used for the live MFU gauges (obs/perf.py).
-    # Default is the v5e bf16 dense peak — the same constant the offline
-    # tools/profile_mfu.py artifacts use, so live and offline MFU are
-    # directly comparable (BASELINE.md cross-check table).
-    peak_tflops: float = 197.0
     # Live SLOs (obs/slo.py): p50 detect latency, aggregate fps, stream
     # availability, each evaluated as multi-window burn rate (fast 5 m /
     # slow 1 h). slo_warmup_s gates firing until that much wall time has
@@ -384,7 +383,8 @@ class EngineConfig:
     # "" with aot_cache=True -> the server resolves <data_dir>/aot_cache
     # (shared across members via a common data volume); also becomes the
     # XLA persistent cache dir for this member (overrides
-    # compile_cache_dir so manifest and payload travel together).
+    # compile_cache_dir so manifest and payload travel together) unless
+    # JAX_COMPILATION_CACHE_DIR places the payload elsewhere.
     aot_cache_dir: str = ""
     # Device-fault domain (engine/fault.py, r22): per-dispatch deadline/
     # error watchdog over the dp-sharded megastep — a shard whose program
