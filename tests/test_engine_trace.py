@@ -1,0 +1,367 @@
+"""The engine's batch trace (ISSUE 25): one measurement per tick and per
+batch, taken where the work happens, handed to three sinks — stage records
+(``cfg.stage_trace``), ``engine.*`` tracer events, registry counters.
+
+CPU backend, tiny models: counts and orderings only, never a time as a
+rate.
+"""
+
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from video_edge_ai_proxy_tpu.bus.interface import FrameMeta
+from video_edge_ai_proxy_tpu.bus.memory_bus import MemoryFrameBus
+from video_edge_ai_proxy_tpu.engine import Collector, InferenceEngine
+from video_edge_ai_proxy_tpu.obs import registry, tracer
+from video_edge_ai_proxy_tpu.obs.spans import (
+    ENGINE_STREAMS, STAGES, stage_breakdown, to_chrome_trace,
+    validate_chrome_trace,
+)
+from video_edge_ai_proxy_tpu.uplink.queue import AnnotationQueue
+from video_edge_ai_proxy_tpu.utils.config import EngineConfig
+
+H, W = 48, 64
+F = H * W * 3                     # bytes of one frame
+L = 4                             # tiny_videomae's clip length
+STAMPS = ("t_tick0", "t_collect0", "t_collect", "t_place_q", "t_place0",
+          "t_placed", "t_place_got", "t_step0", "t_step1", "t_submit",
+          "t_deq", "t_drain0", "t_drained", "t_emitted")
+PHASES = ("pre_collect", "read", "clip", "fill", "collect_other",
+          "place_wait", "step_call", "idle")
+
+
+def _publish(bus, device_id, value=128):
+    meta = FrameMeta(width=W, height=H, channels=3,
+                     timestamp_ms=int(time.time() * 1000), is_keyframe=True)
+    return bus.publish(device_id, np.full((H, W, 3), value, np.uint8), meta)
+
+
+def _model_of(device_id):
+    return ("tiny_videomae", L) if device_id.startswith("clip") \
+        else ("tiny_vit", 0)
+
+
+@pytest.fixture()
+def bus():
+    b = MemoryFrameBus()
+    yield b
+    b.close()
+
+
+@pytest.fixture()
+def spans_on():
+    prev = (tracer.enabled, tracer.sample_every)
+    tracer.clear()
+    tracer.configure(enabled=True, sample_every=1)
+    yield tracer
+    tracer.configure(enabled=prev[0], sample_every=prev[1])
+    tracer.clear()
+
+
+def _phase_seconds():
+    fam = {f.name: f for f in registry.families()}
+    return {p: fam["vep_tick_phase_seconds_total"].labels(p).value
+            for p in PHASES}
+
+
+def _collect_bytes():
+    fam = {f.name: f for f in registry.families()}
+    return {k: fam["vep_collect_bytes_total"].labels(k).value
+            for k in ("read", "copied", "fresh")}
+
+
+class _Fleet:
+    """An engine serving tag cameras (tiny_vit, the collector's pooled fast
+    path) and clip cameras (tiny_videomae, the generic path) by resolver.
+    ``round()`` publishes one frame from every camera while the collector
+    is held, so one tick reads the whole round."""
+
+    def __init__(self, bus, tags=1, clips=2, **cfg_kw):
+        self.bus = bus
+        self.cams = [f"tag{i}" for i in range(tags)] \
+            + [f"clip{i}" for i in range(clips)]
+        for cam in self.cams:
+            bus.create_stream(cam, F)
+        cfg = EngineConfig(model="tiny_vit", batch_buckets=(1, 2, 4),
+                           tick_ms=5, stage_trace=True, **cfg_kw)
+        self.eng = InferenceEngine(
+            bus, cfg, annotations=AnnotationQueue(handler=lambda b: True),
+            model_resolver=lambda d: _model_of(d)[0])
+        self.eng.warmup()
+        self._gate = threading.Lock()
+        real = self.eng._collector.collect
+
+        def gated(*a, **kw):
+            with self._gate:
+                return real(*a, **kw)
+
+        self.eng._collector.collect = gated
+        self.rounds = 0
+
+    def round(self):
+        with self._gate:
+            self.rounds += 1
+            for cam in self.cams:
+                _publish(self.bus, cam, value=self.rounds)
+
+    def run(self, rounds):
+        """Publish ``rounds`` rounds, each after the last was answered;
+        returns the stage records once the engine has stopped."""
+        self.eng.start()
+        try:
+            for _ in range(rounds):
+                self.round()
+                clips_full = self.rounds >= L
+                want = sum(1 for c in self.cams
+                           if clips_full or c.startswith("tag"))
+                deadline = time.time() + 60
+                have = len(self.eng.stage_records)
+                while len(self.eng.stage_records) < have + want \
+                        and time.time() < deadline:
+                    time.sleep(0.005)
+                assert len(self.eng.stage_records) >= have + want, \
+                    "a round went unanswered"
+        finally:
+            self.eng.stop()
+        return list(self.eng.stage_records)
+
+
+def _by_batch(records):
+    out = {}
+    for r in records:
+        out.setdefault(r["batch"], []).append(r)
+    return out
+
+
+class TestCollectorTrace:
+    """Exact counts on a fleet the test controls, collector alone."""
+
+    def _collector(self, bus):
+        return Collector(bus, buckets=(1, 2, 4), model_of=_model_of)
+
+    def test_clip_cameras_copy_each_frame_1_plus_2L_times(self, bus):
+        n = 2
+        for i in range(n):
+            bus.create_stream(f"clip{i}", F)
+        col = self._collector(bus)
+        for k in range(L):
+            for i in range(n):
+                _publish(bus, f"clip{i}", value=k)
+            groups = col.collect()
+            tr = col.last_trace
+            assert tr["frames_read"] == n and tr["bytes_read"] == n * F
+            if k < L - 1:          # windows filling: frames read, no clip
+                assert groups == []
+                assert tr["bytes_copied"] == tr["bytes_fresh"] == n * F
+                assert tr["clip_s"] == 0.0
+        assert len(groups) == 1 and groups[0].frames.shape[:2] == (n, L)
+        # ring -> a fresh array, L frames stacked into a fresh clip, the
+        # clip copied into a fresh batch: F + L*F + L*F a camera, all of
+        # it into memory handed out once
+        assert tr["bytes_copied"] == n * F * (1 + 2 * L)
+        assert tr["bytes_fresh"] == tr["bytes_copied"]
+        assert tr["read_s"] > 0 and tr["clip_s"] > 0 and tr["fill_s"] > 0
+        assert tr["read_ahead_s"] == 0.0
+
+    def test_padding_rows_count_as_copied(self, bus):
+        for i in range(3):
+            bus.create_stream(f"clip{i}", F)
+        col = self._collector(bus)
+        for k in range(L):
+            for i in range(3):
+                _publish(bus, f"clip{i}", value=k)
+            groups = col.collect()
+        assert groups[0].bucket == 4
+        # three clips in a four-row batch: the fill writes the fourth row too
+        assert col.last_trace["bytes_copied"] == 3 * F * (1 + L) + 4 * L * F
+
+    def test_fast_path_tag_camera_copies_once_into_the_pool(self, bus):
+        bus.create_stream("tag0", F)
+        col = self._collector(bus)
+        _publish(bus, "tag0")
+        col.collect()               # first sight: generic path, fresh
+        first = col.last_trace
+        assert (first["bytes_read"], first["bytes_copied"],
+                first["bytes_fresh"]) == (F, 2 * F, 2 * F)
+        _publish(bus, "tag0")
+        groups = col.collect()      # geometry known: ring -> pooled slot
+        tr = col.last_trace
+        assert len(groups) == 1
+        assert (tr["frames_read"], tr["bytes_read"], tr["bytes_copied"],
+                tr["bytes_fresh"]) == (1, F, F, 0)
+        assert tr["clip_s"] == 0.0
+
+    def test_an_empty_collect_reads_nothing(self, bus):
+        bus.create_stream("tag0", F)
+        col = self._collector(bus)
+        assert col.collect() == []
+        tr = col.last_trace
+        assert tr["frames_read"] == tr["bytes_copied"] == 0
+
+    def test_reads_between_ticks_count_in_the_tick_that_dispatches(self, bus):
+        bus.create_stream("tag0", F)
+        col = self._collector(bus)
+        _publish(bus, "tag0")
+        col.collect()               # learns the geometry
+        col.plan_assembly()
+        _publish(bus, "tag0")
+        assert col.assemble_step() == 1     # read ahead of the tick
+        assert col.last_trace["bytes_read"] == F    # the previous tick's
+        groups = col.collect()
+        tr = col.last_trace
+        assert len(groups) == 1
+        assert (tr["frames_read"], tr["bytes_read"], tr["bytes_fresh"]) \
+            == (1, F, 0)
+        assert 0 < tr["read_ahead_s"] <= tr["read_s"]
+
+
+class TestStageRecords:
+    @pytest.mark.parametrize("prefetch", [True, False])
+    def test_every_record_carries_the_ordered_batch_trace(self, bus,
+                                                          prefetch):
+        records = _Fleet(bus, tags=1, clips=2, prefetch=prefetch).run(L + 2)
+        assert records
+        for r in records:
+            assert isinstance(r["tick"], int)
+            assert r["batch"][0] == r["tick"]
+            stamps = [r[k] for k in STAMPS]
+            assert stamps == sorted(stamps), dict(zip(STAMPS, stamps))
+            in_collect = (r["read_s"] - r["read_ahead_s"] + r["clip_s"]
+                          + r["fill_s"])
+            # perf_counter durations against time.time() stamps
+            assert in_collect <= r["t_collect"] - r["t_collect0"] + 1e-3
+            assert r["collect_other_s"] >= 0 and r["pre_collect_s"] >= 0
+            assert r["place_wait_s"] >= 0 and r["step_call_s"] > 0
+        for recs in _by_batch(records).values():
+            first = {k: v for k, v in recs[0].items()
+                     if k not in ("device_id", "ts_pub_ms", "t_emitted")}
+            for r in recs[1:]:      # one trace a batch, shared
+                assert {k: r[k] for k in first} == first
+
+    def test_two_groups_of_one_tick_share_tick_and_differ_in_batch(self, bus):
+        records = _Fleet(bus, tags=1, clips=2).run(L + 2)
+        by_tick = {}
+        for r in records:
+            by_tick.setdefault(r["tick"], set()).add(r["batch"])
+        double = [t for t, bs in by_tick.items() if len(bs) == 2]
+        # rounds L, L+1, L+2: a tag batch and a clip batch in one tick
+        assert len(double) == 3
+        for t in double:
+            assert sorted(b[1] for b in by_tick[t]) == [0, 1]
+            recs = [r for r in records if r["tick"] == t]
+            assert {r["device_id"] for r in recs} \
+                == {"tag0", "clip0", "clip1"}
+            # both batches carry the one tick's collector counts: the tag
+            # frame ring -> pooled slot once, two clips the generic way
+            for r in recs:
+                assert r["bytes_read"] == 3 * F
+                assert r["bytes_copied"] == F + 2 * F * (1 + 2 * L)
+                assert r["bytes_fresh"] == 2 * F * (1 + 2 * L)
+                assert r["frames_read"] == 3
+
+    def test_equal_floats_never_merge_batches(self, bus):
+        """Batches are told apart by identifier: forcing every stamp of a
+        kind equal changes nothing about how records group."""
+        records = _Fleet(bus, tags=1, clips=2).run(L + 1)
+        n_batches = len(_by_batch(records))
+        for r in records:
+            r["t_submit"] = 1.0
+        assert len(_by_batch(records)) == n_batches >= 3
+
+
+class TestSinksAgree:
+    def test_idle_ticks_leave_no_event_and_no_record(self, bus, spans_on):
+        fleet = _Fleet(bus, tags=1, clips=0)
+        before = _phase_seconds()
+        fleet.eng.start()
+        try:
+            deadline = time.time() + 30
+            while fleet.eng.ticks < 20 and time.time() < deadline:
+                time.sleep(0.01)
+        finally:
+            fleet.eng.stop()
+        assert fleet.eng.ticks >= 20
+        assert not fleet.eng.stage_records
+        assert not [s for s in spans_on.streams() if s in ENGINE_STREAMS]
+        after = _phase_seconds()
+        assert after["idle"] > before["idle"]
+        for p in PHASES:
+            if p != "idle":
+                assert after[p] == before[p], p
+
+    def test_tracks_records_and_counters_tell_one_story(self, bus, spans_on):
+        fleet = _Fleet(bus, tags=1, clips=2)
+        bytes0, phase0 = _collect_bytes(), _phase_seconds()
+        records = fleet.run(L + 2)
+        bytes1, phase1 = _collect_bytes(), _phase_seconds()
+        events = spans_on.events()
+        engine = [e for e in events if e["stream"] in ENGINE_STREAMS]
+        assert {e["stream"] for e in engine} == set(ENGINE_STREAMS)
+        assert {e["stage"] for e in engine} <= set(STAGES)
+        assert all(e["frame"] == e["tick"] for e in engine)
+        # every tick that read a frame left one "tick" event; counters rose
+        # by the bytes those events name
+        ticks = [e for e in engine if e["stage"] == "tick"]
+        assert len(ticks) == L + 2
+        for kind in ("read", "copied", "fresh"):
+            assert bytes1[kind] - bytes0[kind] \
+                == sum(e["bytes_" + kind] for e in ticks)
+        assert bytes1["read"] - bytes0["read"] == (L + 2) * 3 * F
+        for p in PHASES:
+            assert phase1[p] >= phase0[p]
+        for p in ("read", "clip", "fill", "step_call"):
+            assert phase1[p] > phase0[p], p
+        # the records' batches are the events' batches
+        batches = {tuple(e["batch"]) for e in engine if "batch" in e}
+        assert batches == set(_by_batch(records))
+        for stage in ("place_wait", "step_call", "place", "drain_wake",
+                      "fetch", "emit_batch"):
+            assert {tuple(e["batch"]) for e in engine
+                    if e["stage"] == stage} == batches, stage
+        # the tick's spans nest inside its "tick" event
+        for t in ticks:
+            inner = [e for e in engine if e["stream"] == "engine.tick"
+                     and e["tick"] == t["tick"] and e is not t]
+            assert {"pre_collect", "collect_tick"} \
+                <= {e["stage"] for e in inner}
+            for e in inner:
+                assert e["ts"] <= t["ts"] + 1e-3
+                assert e["ts"] - e["dur_ms"] / 1e3 \
+                    >= t["ts"] - t["dur_ms"] / 1e3 - 1e-3
+        # the export draws the three threads beside the cameras, and the
+        # per-camera lineage table is what it was
+        trace = to_chrome_trace(events)
+        assert validate_chrome_trace(trace) == []
+        names = {e["args"]["name"] for e in trace["traceEvents"]
+                 if e.get("name") == "thread_name"}
+        assert {f"stream {s}" for s in ENGINE_STREAMS} <= names
+        assert {f"stream {c}" for c in fleet.cams} <= names
+        cameras = [e for e in events if e["stream"] not in ENGINE_STREAMS]
+        assert stage_breakdown(events) == stage_breakdown(cameras)
+
+
+STEP_SCOPES = ("pre_cast_scale", "pre_resize", "pre_normalize", "embed",
+               "encoder_block", "head", "softmax_topk")
+
+
+@pytest.mark.parametrize("model,shape", [
+    ("tiny_vit", (2, H, W, 3)), ("tiny_videomae", (2, L, H, W, 3))])
+def test_the_compiled_step_names_its_stages(model, shape):
+    """``jax.named_scope`` around the stages of the serving step: the
+    names an operator finds in a device trace (PERF.md lists them)."""
+    import jax
+    import jax.numpy as jnp
+
+    from video_edge_ai_proxy_tpu.engine.runner import build_serving_step
+    from video_edge_ai_proxy_tpu.models import registry as models
+
+    spec = models.get(model)
+    module, variables = spec.init_params(jax.random.PRNGKey(0))
+    lowered = jax.jit(build_serving_step(module, spec)).lower(
+        variables, jax.ShapeDtypeStruct(shape, jnp.uint8))
+    text = lowered.as_text(debug_info=True)
+    for scope in STEP_SCOPES:
+        assert f"/{scope}/" in text, scope

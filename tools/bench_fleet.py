@@ -206,7 +206,7 @@ def engine_leg(fleet: dict, src_hw, duration_s: float, tick_ms: int) -> dict:
     bus.close()
     groups = {}
     for r in records:
-        groups.setdefault(r["t_submit"], r["bucket"])
+        groups.setdefault(r["batch"], r["bucket"])   # (tick, group)
     padded_frames = sum(groups.values())
     return {
         "streams": len(assignment),

@@ -82,6 +82,29 @@ def make_repin(base_shard_of, shards: int, dead):
     return repin
 
 
+def _new_trace() -> dict:
+    """One collect() call's measurement, accumulated over cameras and
+    groups and named by purpose, not by today's numpy call:
+
+    - ``read_s``: bus ring -> host memory (``read_latest``,
+      ``read_latest_into``, the window's ``assemble_step``);
+      ``read_ahead_s`` is the part of it spent between ticks, before
+      collect() was entered.
+    - ``clip_s``: making one stream's clip window one contiguous sample.
+    - ``fill_s``: samples -> the batch buffer, with its allocation and
+      zero-padding.
+    - ``frames_read`` / ``bytes_read``: new frames taken off the rings.
+    - ``bytes_copied``: every byte written into host memory (reads, clip
+      assembly, fill, padding); ``bytes_fresh``: those written into a
+      buffer that is handed out once (a new allocation, the bus's
+      per-read destination) and so first-touched, as against a pooled
+      buffer reused from tick to tick.
+    """
+    return {"read_s": 0.0, "read_ahead_s": 0.0, "clip_s": 0.0, "fill_s": 0.0,
+            "frames_read": 0, "bytes_read": 0, "bytes_copied": 0,
+            "bytes_fresh": 0}
+
+
 @dataclass
 class BatchGroup:
     """One shape-homogeneous device batch (before padding)."""
@@ -368,6 +391,12 @@ class Collector:
         # active (plain collect path).
         self._window: Optional[dict] = None
         self._only: Optional[set] = None   # restrict to these ids (None = all)
+        # What one collect() cost, by purpose (see _new_trace). Reads that
+        # assemble_step makes between ticks land in the accumulator of the
+        # collect() that dispatches them; collect() hands it out as
+        # ``last_trace`` and starts the next. Engine thread only.
+        self._acc = _new_trace()
+        self.last_trace = _new_trace()
         # Latest-wins supersessions are BY DESIGN, but invisible drops are
         # not: a cursor that jumps k>1 sequence numbers means k-1 frames
         # were published and never read (camera outrunning the tick rate).
@@ -426,6 +455,47 @@ class Collector:
                 device_id, "collect", meta.packet, pub_ms=meta.timestamp_ms,
                 trace_id=trace_id_of(meta, device_id),
             )
+
+    def _read_into(self, device_id: str, dst: np.ndarray, min_seq: int,
+                   pooled: bool = True):
+        """``bus.read_latest_into``, timed and counted into the trace."""
+        acc = self._acc
+        t = time.perf_counter()
+        res = self._bus.read_latest_into(device_id, dst, min_seq=min_seq)
+        acc["read_s"] += time.perf_counter() - t
+        if res is not None:
+            drifted = isinstance(res, Frame)   # the bus allocated instead
+            self._count_frame(res.data.nbytes if drifted else dst.nbytes,
+                              fresh=drifted or not pooled)
+        return res
+
+    def _read(self, device_id: str, min_seq: int) -> Optional[Frame]:
+        """``bus.read_latest`` (a fresh array per frame), timed and
+        counted into the trace."""
+        acc = self._acc
+        t = time.perf_counter()
+        frame = self._bus.read_latest(device_id, min_seq=min_seq)
+        acc["read_s"] += time.perf_counter() - t
+        if frame is not None:
+            self._count_frame(frame.data.nbytes, fresh=True)
+        return frame
+
+    def _count_frame(self, nbytes: int, fresh: bool) -> None:
+        """One new frame off a ring, copied once into host memory."""
+        acc = self._acc
+        acc["frames_read"] += 1
+        acc["bytes_read"] += int(nbytes)
+        acc["bytes_copied"] += int(nbytes)
+        if fresh:
+            acc["bytes_fresh"] += int(nbytes)
+
+    def _note_fill(self, t0: float, nbytes: int, fresh: bool) -> None:
+        """Close a fill span opened at perf_counter ``t0``."""
+        acc = self._acc
+        acc["fill_s"] += time.perf_counter() - t0
+        acc["bytes_copied"] += int(nbytes)
+        if fresh:
+            acc["bytes_fresh"] += int(nbytes)
 
     def _stream_model(self, device_id: str):
         """(model name, clip_len) for one stream — per-stream override via
@@ -528,6 +598,13 @@ class Collector:
         leased to an in-flight batch is reused until release(). The pool
         grows to the observed high-water mark — steady state 2 buffers
         for the common synchronous one-group case."""
+        t0 = time.perf_counter()
+        try:
+            return self._pooled_locked(shape)
+        finally:
+            self._acc["fill_s"] += time.perf_counter() - t0
+
+    def _pooled_locked(self, shape: tuple):
         with self._pool_lock:
             slot = self._pool.get(shape)
             if slot is None:
@@ -639,7 +716,9 @@ class Collector:
                 dirty = max(fill.get(idx, 0), touched)
                 fill[idx] = n
         if dirty > n:
+            t0 = time.perf_counter()
             buf[n:dirty] = 0
+            self._note_fill(t0, (dirty - n) * buf[0].nbytes, fresh=False)
 
     def _zero_pad_rows_sharded(self, buf: np.ndarray, shape: tuple, idx,
                                real: set, bucket: int, touched: int) -> None:
@@ -660,9 +739,13 @@ class Collector:
                     fill = slot["fill"]
                     dirty = max(fill.get(idx, 0), touched)
                     fill[idx] = bucket
+        t0 = time.perf_counter()
+        zeroed = 0
         for r in range(dirty):
             if r not in real:
                 buf[r] = 0
+                zeroed += 1
+        self._note_fill(t0, zeroed * buf[0].nbytes, fresh=idx is None)
 
     def _finish_sharded(self, buf: np.ndarray, shape: tuple, idx,
                         per: List[list], seg_src: int, bucket: int,
@@ -681,16 +764,20 @@ class Collector:
         metas: List[FrameMeta] = []
         rows: List[int] = []
         real: set = set()
+        t0 = time.perf_counter()
+        moved = 0
         for s, entries in enumerate(per):
             for i, (device_id, meta) in enumerate(entries):
                 old = s * seg_src + i
                 new = s * seg + i
                 if new != old:
                     buf[new] = buf[old]
+                    moved += 1
                 ids.append(device_id)
                 metas.append(meta)
                 rows.append(new)
                 real.add(new)
+        self._note_fill(t0, moved * buf[0].nbytes, fresh=idx is None)
         self._zero_pad_rows_sharded(buf, shape, idx, real, bucket, touched)
         group = BatchGroup(
             src_hw=src_hw, device_ids=ids, frames=buf[:bucket],
@@ -872,9 +959,8 @@ class Collector:
             else:
                 t = slot if slot is not None else len(g["ids"])
             g["hw"] = max(g["hw"], t + 1)   # slot t may get partial bytes
-            res = self._bus.read_latest_into(
-                device_id, g["buf"][t], min_seq=cursor,
-            )
+            res = self._read_into(device_id, g["buf"][t], cursor,
+                                  pooled=g["idx"] is not None)
             if res is None:
                 continue
             if isinstance(res, Frame):   # geometry drifted mid-window
@@ -921,6 +1007,8 @@ class Collector:
         tick."""
         if device_ids is None:
             device_ids = self.inference_streams()
+        acc = self._acc
+        acc["read_ahead_s"] = acc["read_s"]   # assemble_step, between ticks
         self._begin_tick()
         buckets = self._effective_buckets()
         max_bucket = buckets[-1]
@@ -994,14 +1082,12 @@ class Collector:
                 hw = 0   # attempt high-water for _zero_pad_rows
                 for device_id in chunk:
                     hw = max(hw, len(ids) + 1)
-                    res = self._bus.read_latest_into(
+                    res = self._read_into(
                         device_id, batch[len(ids)],
-                        min_seq=self._cursors.get(device_id, 0),
-                    )
+                        self._cursors.get(device_id, 0), bidx is not None)
                     if res is None and self._rebase_if_restarted(device_id):
-                        res = self._bus.read_latest_into(
-                            device_id, batch[len(ids)], min_seq=0,
-                        )
+                        res = self._read_into(
+                            device_id, batch[len(ids)], 0, bidx is not None)
                     if res is None:
                         continue
                     if isinstance(res, Frame):   # geometry drifted
@@ -1036,11 +1122,9 @@ class Collector:
         # Generic path: first sight (geometry unknown), clips, drift.
         by_key: Dict[tuple, list] = {}
         for device_id in slow_ids:
-            frame = self._bus.read_latest(
-                device_id, min_seq=self._cursors.get(device_id, 0)
-            )
+            frame = self._read(device_id, self._cursors.get(device_id, 0))
             if frame is None and self._rebase_if_restarted(device_id):
-                frame = self._bus.read_latest(device_id, min_seq=0)
+                frame = self._read(device_id, 0)
             if frame is None:
                 continue
             self._note_read(device_id, frame.seq, frame.meta)
@@ -1058,7 +1142,11 @@ class Collector:
                 window.append(frame)
                 if len(window) < clip_len:
                     continue
+                t0 = time.perf_counter()
                 sample = np.stack([f.data for f in window])
+                acc["clip_s"] += time.perf_counter() - t0
+                acc["bytes_copied"] += int(sample.nbytes)
+                acc["bytes_fresh"] += int(sample.nbytes)
             else:
                 sample = frame.data
             by_key.setdefault((model, hw), []).append(
@@ -1079,6 +1167,7 @@ class Collector:
                 n = len(chunk)
                 bucket = next(b for b in buckets if b >= n)
                 # Fused stack+pad: one pass instead of np.stack + concat.
+                t0 = time.perf_counter()
                 batch = np.empty(
                     (bucket,) + chunk[0][1].shape, chunk[0][1].dtype
                 )
@@ -1086,6 +1175,7 @@ class Collector:
                     batch[i] = arr
                 if bucket != n:
                     batch[n:] = 0
+                self._note_fill(t0, batch.nbytes, fresh=True)
                 groups.append(BatchGroup(
                     src_hw=hw,
                     device_ids=[d for d, _, _ in chunk],
@@ -1094,6 +1184,7 @@ class Collector:
                     bucket=bucket,
                     model=model,
                 ))
+        self.last_trace, self._acc = acc, _new_trace()
         return groups
 
     def _collect_fast_sharded(self, model: str, geom: tuple,
@@ -1124,14 +1215,12 @@ class Collector:
                 for device_id in shard_devs:
                     t = s * seg_a + len(per[s])
                     touched = max(touched, t + 1)
-                    res = self._bus.read_latest_into(
+                    res = self._read_into(
                         device_id, batch[t],
-                        min_seq=self._cursors.get(device_id, 0),
-                    )
+                        self._cursors.get(device_id, 0), bidx is not None)
                     if res is None and self._rebase_if_restarted(device_id):
-                        res = self._bus.read_latest_into(
-                            device_id, batch[t], min_seq=0,
-                        )
+                        res = self._read_into(
+                            device_id, batch[t], 0, bidx is not None)
                     if res is None:
                         continue
                     if isinstance(res, Frame):   # geometry drifted
@@ -1170,6 +1259,7 @@ class Collector:
             bucket = next(b for b in buckets if b // S >= need)
             seg = bucket // S
             first = next(l[0] for l in chunk if l)
+            t0 = time.perf_counter()
             batch = np.zeros((bucket,) + first[1].shape, first[1].dtype)
             ids: List[str] = []
             metas: List[FrameMeta] = []
@@ -1180,6 +1270,7 @@ class Collector:
                     ids.append(device_id)
                     metas.append(meta)
                     rows.append(s * seg + i)
+            self._note_fill(t0, batch.nbytes, fresh=True)
             groups.append(BatchGroup(
                 src_hw=hw, device_ids=ids, frames=batch, metas=metas,
                 bucket=bucket, model=model, rows=rows,

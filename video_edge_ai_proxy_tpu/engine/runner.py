@@ -152,11 +152,13 @@ def build_serving_step(model, spec, *, quality_thumb: int = 0, mesh=None):
 
             x = pre(frames_u8, (size, size))
             logits = model.apply(variables, x)
-            probs = jax.nn.softmax(logits, axis=-1)
-            top_p, top_i = jax.lax.top_k(
-                probs, min(TOP_K_CLASSES, probs.shape[-1])
-            )
-            return {"top_probs": top_p, "top_ids": top_i.astype(jnp.int32)}
+            with jax.named_scope("softmax_topk"):
+                probs = jax.nn.softmax(logits, axis=-1)
+                top_p, top_i = jax.lax.top_k(
+                    probs, min(TOP_K_CLASSES, probs.shape[-1])
+                )
+                return {"top_probs": top_p,
+                        "top_ids": top_i.astype(jnp.int32)}
 
     if not quality_thumb or spec.clip_len:
         return raw
@@ -402,8 +404,20 @@ class _Inflight:
     group: BatchGroup
     outputs: Any              # tree of jax.Arrays (async)
     t_submit: float
-    t_collect: float = 0.0    # wall s the collector returned this group
-                              # (stage_trace only; 0 when tracing is off)
+    # The batch's trace: the tick's measurement (tick number, collector
+    # phases and byte counts, ``t_collect``) plus this batch's stamps,
+    # filled in as the batch moves through the transfer, tick and drain
+    # threads (see InferenceEngine._dispatch). One dict per batch, shared
+    # by the three sinks: stage records, ``engine.*`` tracer events,
+    # registry counters. None for coast groups (no device work).
+    tr: Optional[dict] = None
+
+
+# vep_tick_phase_seconds_total{phase=...}: what the tick thread did with a
+# tick that read at least one frame, plus "idle" (ticks that found nothing,
+# and the between-tick wait for frames).
+_TICK_PHASES = ("pre_collect", "read", "clip", "fill", "collect_other",
+                "place_wait", "step_call", "idle")
 
 
 class _TimedStep:
@@ -756,7 +770,7 @@ class _Prefetched:
     """Handle for one batch placement in flight on the transfer thread."""
 
     __slots__ = ("group", "ready", "placed", "error", "transfer_s",
-                 "overlapped_s", "slot")
+                 "overlapped_s", "slot", "t_q", "t0", "t1")
 
     def __init__(self, group: BatchGroup):
         self.group = group
@@ -766,6 +780,10 @@ class _Prefetched:
         self.transfer_s = 0.0
         self.overlapped_s = 0.0   # transfer wall time with >=1 batch in flight
         self.slot = 0             # which of the key's two input slots
+        # wall stamps for the batch trace: handed to the stage, picked up
+        # by the transfer thread, block_until_ready returned
+        self.t_q = time.time()
+        self.t0 = self.t1 = 0.0
 
 
 class _PrefetchStage:
@@ -873,6 +891,7 @@ class _PrefetchStage:
             pre = self._q.get()
             if pre is None:
                 return
+            pre.t0 = time.time()
             busy = self._busy()
             t0 = time.perf_counter()
             try:
@@ -883,6 +902,7 @@ class _PrefetchStage:
             except BaseException as exc:   # surfaced on the tick thread
                 pre.error = exc
             pre.transfer_s = time.perf_counter() - t0
+            pre.t1 = time.time()
             if busy or self._busy():
                 # Device work was in flight while this copy ran: the
                 # whole window was hidden behind compute.
@@ -1073,8 +1093,10 @@ class InferenceEngine:
         # (annotation suppression already has this treatment).
         self.subscriber_drops = 0
         self.subscriber_drops_by_stream: Dict[str, int] = {}
-        # stage_trace: per-frame stage timestamps (wall s), bounded deque
-        # of dicts — see tools/bench_latency.py for the consumer.
+        # stage_trace: one dict per emitted result carrying its batch's
+        # trace (wall s stamps, phase seconds, byte counts), bounded deque.
+        # Readers: benchmark/vbench/spans.py and batch_trace.py (the
+        # per-layer metrics), replay/harness.py (the occupancy timeline).
         import collections
 
         self.stage_records: collections.deque = collections.deque(
@@ -1138,6 +1160,21 @@ class InferenceEngine:
             "vep_frames_late_total",
             "Results slower end-to-end than engine.obs_late_ms",
             ("stream",))
+        # Where the tick thread's time and the collector's bytes go, fed
+        # once per tick that read a frame from the same measurement the
+        # stage records and the engine.* tracer events carry (_close_tick).
+        phase = obs_registry.counter(
+            "vep_tick_phase_seconds_total",
+            "Engine tick thread seconds by phase", ("phase",))
+        self._m_phase = {p: phase.labels(p) for p in _TICK_PHASES}
+        cbytes = obs_registry.counter(
+            "vep_collect_bytes_total",
+            "Collector bytes: read off the rings, copied into host memory, "
+            "copied into first-touched (unpooled) buffers", ("kind",))
+        self._m_cbytes = {k: cbytes.labels(k)
+                          for k in ("read", "copied", "fresh")}
+        self._tick_mark = time.perf_counter()   # end of the last closed tick
+        self._assemble_s = 0.0   # assemble_until seconds since that mark
         # Recompile-storm detection state (tick loop only).
         self._miss_seen = 0.0
         self._miss_streak = 0
@@ -2457,8 +2494,11 @@ class InferenceEngine:
     def _run(self) -> None:
         tick_s = self._cfg.tick_ms / 1000.0
         inferred: List[str] = []
+        self._tick_mark = time.perf_counter()
+        self._assemble_s = 0.0
         while not self._stop.is_set():
             t0 = time.monotonic()
+            t_tick0 = time.time()
             # The loop must outlive any single bad batch: a dead engine
             # thread would leave subscribers blocked forever (same
             # log-and-keep-going stance as the reference's worker loops,
@@ -2537,6 +2577,7 @@ class InferenceEngine:
                         admitted.append(canary)
                     inferred = admitted
                 self._collector.keep_streams_hot(device_ids=inferred)
+                t_collect0, pc_collect0 = time.time(), time.perf_counter()
                 groups = self._collector.collect(device_ids=inferred)
                 if rung != "normal" and groups:
                     # Rung 1+: stale frames leave before they cost device
@@ -2547,8 +2588,11 @@ class InferenceEngine:
                     # crops onto shared canvases, coast gated-idle
                     # streams (ROADMAP item 1).
                     groups = self._roi_transform(groups)
-                t_collect = time.time() if self._cfg.stage_trace else 0.0
-                self._dispatch(groups, t_collect)
+                # t_collect closes the collection: collect() itself plus,
+                # under pressure or cfg.roi, the two group transforms above.
+                tick = self._open_tick(t_tick0, t_collect0, pc_collect0)
+                batches = self._dispatch(groups, tick["t_collect"], tick)
+                self._close_tick(tick, batches)
                 if self._cascade is not None:
                     # CASCADE: scatter harvested track tiles, run the
                     # temporal head on cadence ticks, fan out events
@@ -2626,6 +2670,7 @@ class InferenceEngine:
             # assembly window that absorbs the remaining budget.
             self._last_tick_dur_s = self.last_tick_monotonic - t0
             self._watch_tick(tick_s, inferred)
+            pc_assemble0 = time.perf_counter()
             try:
                 # Tick remainder = incremental assembly: copy next tick's
                 # frames into their batch slots as they arrive (doorbell-
@@ -2641,6 +2686,103 @@ class InferenceEngine:
                 elapsed = time.monotonic() - t0
                 if elapsed < tick_s:
                     self._stop.wait(tick_s - elapsed)
+            self._assemble_s += time.perf_counter() - pc_assemble0
+
+    def _open_tick(self, t_tick0: float, t_collect0: float,
+                   pc_collect0: float) -> dict:
+        """The tick's trace, measured once, on the tick thread, right after
+        the collection: tick number, wall stamps, and the collector's
+        phases and byte counts of this collect() (``Collector.last_trace``).
+        Every batch of the tick carries a copy (``_dispatch``)."""
+        pc_collect = time.perf_counter()
+        tick = dict(self._collector.last_trace)
+        in_collect = (tick["read_s"] - tick["read_ahead_s"]
+                      + tick["clip_s"] + tick["fill_s"])
+        tick.update(
+            tick=self.ticks, t_tick0=t_tick0,
+            # end of the previous tick's dispatch -> collect() entry, less
+            # the assembly window (its reads are in read_s, its waiting is
+            # idle): tail of the last tick, head of this one
+            pre_collect_s=max(
+                0.0, pc_collect0 - self._tick_mark - self._assemble_s),
+            t_collect0=t_collect0, t_collect=time.time(),
+            collect_other_s=max(0.0, pc_collect - pc_collect0 - in_collect),
+        )
+        return tick
+
+    def _close_tick(self, tick: dict, batches: List[dict]) -> None:
+        """Feed the tick's measurement to the counters and, when the tick
+        is sampled, to the ``engine.tick`` tracer track. A tick that read
+        no frame leaves no event; its whole time is idle."""
+        now = time.perf_counter()
+        whole_s = now - self._tick_mark
+        idle_s = max(0.0, self._assemble_s - tick["read_ahead_s"])
+        self._tick_mark, self._assemble_s = now, 0.0
+        if not tick["frames_read"] and not batches:
+            self._m_phase["idle"].inc(whole_s)
+            return
+        place_wait_s = sum(b["place_wait_s"] for b in batches)
+        step_call_s = sum(b["step_call_s"] for b in batches)
+        for phase, seconds in (
+                ("pre_collect", tick["pre_collect_s"]),
+                ("read", tick["read_s"]), ("clip", tick["clip_s"]),
+                ("fill", tick["fill_s"]),
+                ("collect_other", tick["collect_other_s"]),
+                ("place_wait", place_wait_s), ("step_call", step_call_s),
+                ("idle", idle_s)):
+            self._m_phase[phase].inc(seconds)
+        for kind in ("read", "copied", "fresh"):
+            self._m_cbytes[kind].inc(tick["bytes_" + kind])
+        n = tick["tick"]
+        if not tracer.sampled(n):
+            return
+        t_end = time.time()
+        tracer.record(
+            "engine.tick", "tick", n, ts=t_end, dur_ms=whole_s * 1e3,
+            tick=n, batches=len(batches), frames_read=tick["frames_read"],
+            bytes_read=tick["bytes_read"],
+            bytes_copied=tick["bytes_copied"],
+            bytes_fresh=tick["bytes_fresh"])
+        tracer.record(
+            "engine.tick", "pre_collect", n, ts=tick["t_collect0"],
+            dur_ms=tick["pre_collect_s"] * 1e3, tick=n)
+        tracer.record(
+            "engine.tick", "collect_tick", n, ts=tick["t_collect"],
+            dur_ms=(tick["t_collect"] - tick["t_collect0"]) * 1e3, tick=n,
+            read_ms=round(tick["read_s"] * 1e3, 3),
+            clip_ms=round(tick["clip_s"] * 1e3, 3),
+            fill_ms=round(tick["fill_s"] * 1e3, 3))
+        for b in batches:
+            extra = {"tick": n, "batch": list(b["batch"])}
+            tracer.record(
+                "engine.tick", "place_wait", n, ts=b["t_place_got"],
+                dur_ms=b["place_wait_s"] * 1e3, **extra)
+            tracer.record(
+                "engine.tick", "step_call", n, ts=b["t_step1"],
+                dur_ms=b["step_call_s"] * 1e3, **extra)
+
+    @staticmethod
+    def _trace_batch(tr: dict) -> None:
+        """The batch's transfer- and drain-thread spans as complete events
+        on their own tracer tracks (called by the drain thread once the
+        batch is emitted, for sampled ticks)."""
+        n = tr["tick"]
+        extra = {"tick": n, "batch": list(tr["batch"])}
+        tracer.record(
+            "engine.transfer", "place", n, ts=tr["t_placed"],
+            dur_ms=(tr["t_placed"] - tr["t_place0"]) * 1e3,
+            queued_ms=round((tr["t_place0"] - tr["t_place_q"]) * 1e3, 3),
+            **extra)
+        tracer.record(
+            "engine.drain", "drain_wake", n, ts=tr["t_deq"],
+            dur_ms=(tr["t_deq"] - tr["t_submit"]) * 1e3, **extra)
+        tracer.record(
+            "engine.drain", "fetch", n, ts=tr["t_drained"],
+            dur_ms=(tr["t_drained"] - tr["t_drain0"]) * 1e3, **extra)
+        t_end = time.time()
+        tracer.record(
+            "engine.drain", "emit_batch", n, ts=t_end,
+            dur_ms=(t_end - tr["t_drained"]) * 1e3, **extra)
 
     def _probe_shards(self) -> List[int]:
         """Default stall probe (engine/fault.py): one tiny H2D+D2H
@@ -2874,8 +3016,19 @@ class InferenceEngine:
             kept, len(streams), evacuated, aot,
         )
 
-    def _dispatch(self, groups: List[BatchGroup], t_collect: float) -> None:
-        """Dispatch one tick's collected groups to the device.
+    def _dispatch(self, groups: List[BatchGroup], t_collect: float,
+                  tick: Optional[dict] = None) -> List[dict]:
+        """Dispatch one tick's collected groups to the device; returns
+        the traces of the batches handed to the drain thread.
+
+        ``tick`` is the tick's trace (``_open_tick``); each device batch
+        gets a copy with its own stamps added: ``batch`` = (tick, index of
+        the group), ``t_place_q``/``t_place0``/``t_placed`` (handed to the
+        transfer thread, picked up, placed), ``place_wait_s`` (this
+        thread's blocked time on the placement, ending at
+        ``t_place_got``), ``t_step0``/``t_step1`` and ``step_call_s``
+        (around the step call; a compile shows here), ``t_submit``. The
+        drain thread adds ``t_deq``, ``t_drain0``, ``t_drained``.
 
         With cfg.prefetch the placement of group g+1 (and g+2) runs on
         the transfer thread while this thread dispatches group g and the
@@ -2897,6 +3050,9 @@ class InferenceEngine:
         may still be reading the pooled host buffer.
         """
         trace_on = tracer.enabled
+        if tick is None:   # called outside the tick loop (tests, smokes)
+            tick = {"tick": self.ticks, "t_collect": t_collect}
+        batches: List[dict] = []
         if self.faults is not None:
             # FaultLedger conservation: every stream slot entering the
             # device pipeline is counted in here and counted out in the
@@ -2911,8 +3067,7 @@ class InferenceEngine:
             rest = []
             for g in groups:
                 if g.coast is not None:
-                    self._enqueue_drain(
-                        _Inflight(g, None, time.time(), t_collect))
+                    self._enqueue_drain(_Inflight(g, None, time.time()))
                 else:
                     rest.append(g)
             groups = rest
@@ -2926,6 +3081,7 @@ class InferenceEngine:
         if self._xfer is not None and groups:
             _top_up(_PrefetchStage.DEPTH)
         for gi, group in enumerate(groups):
+            tr = dict(tick, batch=(tick["tick"], gi))
             try:
                 step = self._step(group.src_hw, group.bucket, group.model)
                 _, _, variables = self._ensure_model(
@@ -2944,6 +3100,8 @@ class InferenceEngine:
                                 "engine stopping; prefetched placement "
                                 "abandoned")
                     wait_s = time.perf_counter() - t_wait
+                    tr.update(t_place_q=pre.t_q, t_place0=pre.t0,
+                              t_placed=pre.t1)
                     if pre.error is not None:
                         raise pre.error
                     placed = pre.placed
@@ -2955,10 +3113,14 @@ class InferenceEngine:
                     hidden_s = max(pre.overlapped_s,
                                    max(0.0, pre.transfer_s - wait_s))
                 else:
+                    tr["t_place_q"] = tr["t_place0"] = time.time()
                     t_h2d = time.perf_counter()
                     placed = self._place(group.frames)
-                    h2d_s = time.perf_counter() - t_h2d
+                    wait_s = h2d_s = time.perf_counter() - t_h2d
+                    tr["t_placed"] = time.time()
                     hidden_s = 0.0
+                tr["place_wait_s"] = wait_s
+                tr["t_place_got"] = time.time()
                 idx = None
                 aux_nbytes = 0
                 # Canvas groups (group.crops) never carry quality state:
@@ -2977,6 +3139,7 @@ class InferenceEngine:
                     group.model or self._spec.name, group.bucket,
                     group.nbytes + aux_nbytes, h2d_s, hidden_s=hidden_s,
                 )
+                tr["t_step0"], pc_step0 = time.time(), time.perf_counter()
                 if idx is not None:
                     # Quality-carrying step (3-arg): previous-tick
                     # thumbnails arrive as a device-side gather from the
@@ -2999,6 +3162,8 @@ class InferenceEngine:
                         outputs = dict(outputs)
                         outputs.pop("quality_stats", None)
                         outputs.pop("quality_thumbs", None)
+                tr["step_call_s"] = time.perf_counter() - pc_step0
+                tr["t_step1"] = time.time()
             except Exception as exc:
                 shard = None
                 if self.faults is not None:
@@ -3034,7 +3199,7 @@ class InferenceEngine:
             self._m_occupancy.observe(
                 100.0 * len(group.device_ids) / group.bucket
             )
-            t_submit = time.time()
+            t_submit = tr["t_submit"] = time.time()
             if trace_on:
                 for did, meta in zip(group.device_ids, group.metas):
                     if tracer.sampled(meta.packet):
@@ -3043,9 +3208,9 @@ class InferenceEngine:
                             ts=t_submit, bucket=group.bucket,
                             trace_id=trace_id_of(meta, did),
                         )
-            self._enqueue_drain(
-                _Inflight(group, outputs, t_submit, t_collect)
-            )
+            batches.append(tr)
+            self._enqueue_drain(_Inflight(group, outputs, t_submit, tr))
+        return batches
 
     def _apply_rung_cap(self, rung: str) -> None:
         """bucket_downshift and above: hide the largest batch bucket so
@@ -3611,12 +3776,23 @@ class InferenceEngine:
         ready, instead of parking finished results until the next tick
         boundary (which taxed every result a full tick_ms by design)."""
         while True:
-            inflight = self._drain_q.get()
+            nxt = self._drain_q.get()
+            # t_deq: this thread holds the new batch. The previous one is
+            # let go only on the line after (its host batch is freed here,
+            # on this thread), so that wake-up and release read apart:
+            # t_submit -> t_deq is the wake-up, t_deq -> t_drain0 the rest.
+            t_deq = time.time()
+            inflight = nxt
             if inflight is None:
                 self._drain_q.task_done()
                 return
+            tr = inflight.tr
+            if tr is not None:
+                tr["t_deq"] = t_deq
             try:
                 self._emit(inflight)
+                if tr is not None and tracer.sampled(tr["tick"]):
+                    self._trace_batch(tr)
             except Exception:
                 log.exception("drain failed; continuing")
                 if self.faults is not None:
@@ -3644,6 +3820,9 @@ class InferenceEngine:
         t_drain0 = time.time()
         host = {k: np.asarray(v) for k, v in inflight.outputs.items()}  # D2H
         t_drained = time.time()
+        inflight.tr.update(t_drain0=t_drain0, t_drained=t_drained)
+        inflight.tr.setdefault("t_deq", t_drain0)   # _emit called directly
+        # submit -> outputs on the host: drain-queue wait + device + fetch
         device_ms = (t_drained - inflight.t_submit) * 1000.0
         if self.faults is not None:
             # Stall watchdog signal (engine/fault.py): submit-to-drained
@@ -3748,13 +3927,13 @@ class InferenceEngine:
             try:
                 self._emit_slot(
                     inflight, host, row, device_id, meta, spec, now_ms,
-                    device_ms, slo_latency, t_drain0, t_drained,
+                    device_ms, slo_latency, t_drained,
                 )
             finally:
                 reset_log_context(ctx)
 
     def _emit_slot(self, inflight, host, row, device_id, meta, spec, now_ms,
-                   device_ms, slo_latency, t_drain0, t_drained) -> None:
+                   device_ms, slo_latency, t_drained) -> None:
         group = inflight.group
         detections = self._to_detections(host, row, spec)
         if self._cfg.track and spec.kind == "detect":
@@ -3800,16 +3979,15 @@ class InferenceEngine:
                 device_id, (meta.packet, meta.timestamp_ms))
         self._publish(result)
         if self._cfg.stage_trace:
-            self.stage_records.append({
-                "device_id": device_id,
-                "ts_pub_ms": meta.timestamp_ms,
-                "t_collect": inflight.t_collect,
-                "t_submit": inflight.t_submit,
-                "t_drain0": t_drain0,
-                "t_drained": t_drained,
-                "t_emitted": time.time(),
-                "bucket": group.bucket,
-            })
+            # the batch's whole trace (tick, batch, stamps, the tick's
+            # collector phases and byte counts) plus this result's own
+            self.stage_records.append(dict(
+                inflight.tr,
+                device_id=device_id,
+                ts_pub_ms=meta.timestamp_ms,
+                t_emitted=time.time(),
+                bucket=group.bucket,
+            ))
         self._annotate(device_id, meta, detections, spec)
         st = self._stats.setdefault(device_id, StreamStats())
         st.frames += 1

@@ -238,9 +238,6 @@ class EncoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray, deterministic: bool = True) -> jnp.ndarray:
         c = self.cfg
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x.astype(jnp.float32)).astype(self.dtype)
-        x = x + SelfAttention(c, self.dtype, self.attn_fn, name="attn")(h, deterministic)
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x.astype(jnp.float32)).astype(self.dtype)
         if not c.num_experts:
             mlp_cls = Mlp
         elif c.moe_router == "top1":
@@ -251,7 +248,13 @@ class EncoderBlock(nn.Module):
             raise ValueError(
                 f"unknown moe_router {c.moe_router!r}; expected 'soft' or 'top1'"
             )
-        x = x + mlp_cls(c, self.dtype, name="mlp")(h, deterministic)
+        # one stable name for every layer's operations in a device trace
+        # (flax's own scope is the instance name, block<i>)
+        with jax.named_scope("encoder_block"):
+            h = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x.astype(jnp.float32)).astype(self.dtype)
+            x = x + SelfAttention(c, self.dtype, self.attn_fn, name="attn")(h, deterministic)
+            h = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x.astype(jnp.float32)).astype(self.dtype)
+            x = x + mlp_cls(c, self.dtype, name="mlp")(h, deterministic)
         return x
 
 

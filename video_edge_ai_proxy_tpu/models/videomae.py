@@ -82,11 +82,12 @@ class TubeletEmbed(nn.Module):
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         """[B, T, H, W, 3] -> [B, tokens, dim]."""
         p, ts = self.patch_size, self.tubelet_size
-        x = pad_channels(x.astype(self.dtype), self.pad_c)
-        x = nn.Conv(
-            self.dim, kernel_size=(ts, p, p), strides=(ts, p, p),
-            padding="VALID", dtype=self.dtype, name="proj",
-        )(x)
+        with jax.named_scope("embed"):
+            x = pad_channels(x.astype(self.dtype), self.pad_c)
+            x = nn.Conv(
+                self.dim, kernel_size=(ts, p, p), strides=(ts, p, p),
+                padding="VALID", dtype=self.dtype, name="proj",
+            )(x)
         b = x.shape[0]
         return x.reshape(b, -1, self.dim)
 
@@ -113,7 +114,8 @@ class VideoMAE(nn.Module):
         """Fine-tune / inference path: [B, T, H, W, 3] -> [B, num_classes]."""
         x = self.embed(clips) + self.pos_embed.astype(self.dtype)
         x = self.encoder(x, deterministic=not train)
-        return self.head(jnp.mean(x.astype(jnp.float32), axis=1))
+        with jax.named_scope("head"):
+            return self.head(jnp.mean(x.astype(jnp.float32), axis=1))
 
     def encode_visible(self, clips: jnp.ndarray, keep_mask: jnp.ndarray,
                        train: bool = True) -> jnp.ndarray:

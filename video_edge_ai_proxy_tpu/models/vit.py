@@ -12,6 +12,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from ..ops.preprocess import pad_channels
@@ -53,13 +54,14 @@ class ViT(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = False) -> jnp.ndarray:
         c = self.cfg
-        x = x.astype(self.dtype)
-        x = pad_channels(x, c.patch_pad_c)
         p = c.patch_size
-        x = nn.Conv(
-            c.encoder.dim, kernel_size=(p, p), strides=(p, p),
-            padding="VALID", dtype=self.dtype, name="patch_embed",
-        )(x)
+        with jax.named_scope("embed"):
+            x = x.astype(self.dtype)
+            x = pad_channels(x, c.patch_pad_c)
+            x = nn.Conv(
+                c.encoder.dim, kernel_size=(p, p), strides=(p, p),
+                padding="VALID", dtype=self.dtype, name="patch_embed",
+            )(x)
         b = x.shape[0]
         x = x.reshape(b, -1, c.encoder.dim)
         cls = self.param(
@@ -76,4 +78,6 @@ class ViT(nn.Module):
         x = Encoder(c.encoder, self.dtype, self.attn_fn, name="encoder")(
             x, deterministic=not train
         )
-        return nn.Dense(c.num_classes, dtype=jnp.float32, name="classifier")(x[:, 0])
+        with jax.named_scope("head"):
+            return nn.Dense(
+                c.num_classes, dtype=jnp.float32, name="classifier")(x[:, 0])

@@ -17,7 +17,9 @@ Stage vocabulary (the segments a soak report breaks latency into):
   so the ingest->collect leg is computable from the engine side alone.
 - ``collect`` — engine collector read the frame off the bus.
 - ``submit``  — frame's batch was handed to the device drain thread.
-- ``device``  — jitted step drained; ``dur_ms`` = device wall time.
+- ``device``  — the batch's outputs reached the host; ``dur_ms`` = submit →
+  outputs on the host (drain-queue wait + device + fetch), not the
+  device's own time.
 - ``emit``    — postprocessed result published to the result plane.
 - ``temporal`` — cascade temporal-head pass consumed this frame's track
   crop (temporal/scheduler.py); ``dur_ms`` = head device wall time for
@@ -29,6 +31,26 @@ Stage vocabulary (the segments a soak report breaks latency into):
   (staleness shed, shutdown drain, unrouted ROI crop). Closing the
   lineage here keeps trace export and ``stage_breakdown`` honest about
   drops instead of leaving the span open forever.
+
+Engine threads (``engine.tick``, ``engine.transfer``, ``engine.drain``):
+three reserved stream names carry the engine's own batch trace as complete
+events, so the Chrome export and ``/api/v1/trace`` draw the tick, transfer
+and drain threads as tracks beside the per-camera lineages. ``frame`` is
+the tick number; extras carry ``tick`` and ``batch`` = [tick, group index].
+They are no camera's lineage: ``stage_breakdown`` finds no leg in them, and
+a count of cameras leaves them out (``ENGINE_STREAMS``).
+
+- ``tick`` — one tick that read at least one frame, end of the previous
+  tick's dispatch → end of this one's, with the collector's byte counts;
+  nested in it: ``pre_collect`` (→ collect() entry, less the assembly
+  window), ``collect_tick`` (collect() entry → return, ``read_ms`` /
+  ``clip_ms`` / ``fill_ms`` in the extras), and per batch ``place_wait``
+  (the tick thread blocked on the placement) and ``step_call``.
+- ``place`` — transfer thread: placement picked up → ``block_until_ready``
+  returned; ``queued_ms`` = handed to the stage → picked up.
+- ``drain_wake`` (submit → the drain thread holds the batch), ``fetch``
+  (output fetch: start → outputs on the host), ``emit_batch`` (→ the
+  batch's last result emitted) — drain thread.
 
 Cross-process stitching (r14): the worker stamps ``FrameMeta.trace_id``
 (``trace_id_for`` — deterministic, content-derived) at publish; every
@@ -53,7 +75,13 @@ from collections import deque
 from typing import Dict, Iterable, List, Optional
 
 STAGES = ("publish", "collect", "submit", "device", "emit", "temporal",
-          "dropped")
+          "dropped",
+          # the engine's own threads (streams named ENGINE_STREAMS)
+          "tick", "pre_collect", "collect_tick", "place_wait", "step_call",
+          "place", "drain_wake", "fetch", "emit_batch")
+
+# Reserved stream names: the engine's tick, transfer and drain threads.
+ENGINE_STREAMS = ("engine.tick", "engine.transfer", "engine.drain")
 
 # Latency legs derivable from a complete lineage, in pipeline order.
 LEGS = ("ingest_bus", "batch", "device", "emit", "total")
@@ -210,7 +238,7 @@ def stage_breakdown(events: Iterable[dict]) -> dict:
         ingest_bus  publish stamp (pub_ms on the collect span, or the
                     publish span's ts) -> collected off the bus
         batch       collected -> batch submitted to the device thread
-        device      device span dur_ms (drained jitted step)
+        device      device span dur_ms (submit -> outputs on the host)
         emit        device drain end -> result emitted
         total       publish stamp -> result emitted
 

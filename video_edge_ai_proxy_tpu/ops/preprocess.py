@@ -90,8 +90,9 @@ def resize_bilinear_mxu(
         return x * jnp.asarray(scale, dtype) if scale != 1.0 else x
     rh = jnp.asarray(_resize_matrix(h, th) * scale, dtype)
     rw = jnp.asarray(_resize_matrix(w, tw), dtype)
-    y = jnp.einsum("hH,nHWc->nhWc", rh, x)
-    return jnp.einsum("wW,nhWc->nhwc", rw, y)
+    with jax.named_scope("pre_resize"):
+        y = jnp.einsum("hH,nHWc->nhWc", rh, x)
+        return jnp.einsum("wW,nhWc->nhwc", rw, y)
 
 
 def pad_channels(x: jnp.ndarray, pad_c: int) -> jnp.ndarray:
@@ -125,12 +126,14 @@ def preprocess_classify(
     Resize is plain bilinear (stretch, no aspect preservation) — matching
     what CPU clients of the reference typically do before a classifier.
     """
-    x = frames_u8.astype(out_dtype) * (1.0 / 255.0)
+    with jax.named_scope("pre_cast_scale"):
+        x = frames_u8.astype(out_dtype) * (1.0 / 255.0)
     x = resize_bilinear_mxu(x, size)[..., ::-1]          # BGR -> RGB, small
-    mean_a = jnp.asarray(mean, dtype=jnp.float32)
-    inv_std = jnp.asarray([1.0 / s for s in std], dtype=jnp.float32)
-    x = (x.astype(jnp.float32) - mean_a) * inv_std
-    return x.astype(out_dtype)
+    with jax.named_scope("pre_normalize"):
+        mean_a = jnp.asarray(mean, dtype=jnp.float32)
+        inv_std = jnp.asarray([1.0 / s for s in std], dtype=jnp.float32)
+        x = (x.astype(jnp.float32) - mean_a) * inv_std
+        return x.astype(out_dtype)
 
 
 def preprocess_clip(
@@ -188,7 +191,8 @@ def preprocess_letterbox(
     undo it on output boxes.
     """
     params = letterbox_params(frames_u8.shape[1:3], dst)
-    x = frames_u8.astype(out_dtype) * (1.0 / 255.0)
+    with jax.named_scope("pre_cast_scale"):
+        x = frames_u8.astype(out_dtype) * (1.0 / 255.0)
     x = resize_bilinear_mxu(x, (params.new_h, params.new_w))[..., ::-1]
     top = int(round(params.pad_y))
     left = int(round(params.pad_x))

@@ -356,7 +356,7 @@ def run_fleet_soak(
     from ..engine import InferenceEngine
     from ..models import registry
     from ..obs import registry as obs_registry, tracer
-    from ..obs.spans import stage_breakdown
+    from ..obs.spans import ENGINE_STREAMS, stage_breakdown
     from ..resilience import CircuitBreaker, DeadLetterSpool, RetryPolicy
     from ..uplink.cloud import make_batch_handler
     from ..uplink.queue import AnnotationQueue
@@ -630,7 +630,7 @@ def run_fleet_soak(
     faults_applied = []
     step_cache_samples = []
     timeline: dict[int, dict] = {}
-    seen_submits: dict[float, int] = {}
+    seen_batches: set = set()
     next_sample = 0.0
 
     def drain_stage_records() -> None:
@@ -642,10 +642,9 @@ def run_fleet_soak(
             b = int(max(0.0, r["t_emitted"] - t0_wall) // timeline_bin_s)
             slot = timeline.setdefault(b, {"real": 0, "padded": 0})
             slot["real"] += 1
-            # one batch contributes its bucket once (keyed by submit time)
-            key = r["t_submit"]
-            if key not in seen_submits:
-                seen_submits[key] = r["bucket"]
+            # one batch contributes its bucket once
+            if r["batch"] not in seen_batches:
+                seen_batches.add(r["batch"])
                 slot["padded"] += r["bucket"]
 
     while True:
@@ -707,7 +706,8 @@ def run_fleet_soak(
         "trace": {
             "sample_every": tracer.sample_every,
             "events": len(span_events),
-            "streams": len(tracer.streams()),
+            "streams": len([s for s in tracer.streams()
+                            if s not in ENGINE_STREAMS]),   # cameras
         },
         "quality": eng.quality.snapshot() if eng.quality is not None
         else None,

@@ -172,10 +172,11 @@ class EngineConfig:
     # per-stream SORT-style tracker (engine/tracker.py). Host-side numpy on
     # NMS output — negligible next to a device batch.
     track: bool = True
-    # Per-frame stage timestamps (publish -> collect -> submit -> drain ->
-    # emit) appended to engine.stage_records, bounded. Off in production;
-    # tools/bench_latency.py turns it on to measure the serving latency
-    # budget stage by stage (VERDICT r3 weak #1).
+    # One record per emitted result, carrying its batch's trace (tick and
+    # batch number, wall stamps from tick start to emit, the collector's
+    # phase seconds and byte counts), appended to engine.stage_records,
+    # bounded. Off in production; benchmark/vbench/spans.py and
+    # batch_trace.py (the per-layer metrics) and replay/harness.py read it.
     stage_trace: bool = False
     # End-to-end latency (bus publish -> result emit) above this increments
     # vep_frames_late_total for the stream (obs/watch.py episode checks key
