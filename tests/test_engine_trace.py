@@ -74,8 +74,9 @@ def _collect_bytes():
 
 
 class _Fleet:
-    """An engine serving tag cameras (tiny_vit, the collector's pooled fast
-    path) and clip cameras (tiny_videomae, the generic path) by resolver.
+    """An engine serving tag cameras (tiny_vit, read straight into a pooled
+    batch row) and clip cameras (tiny_videomae, through their clip rings)
+    by resolver.
     ``round()`` publishes one frame from every camera while the collector
     is held, so one tick reads the whole round."""
 
@@ -142,41 +143,62 @@ class TestCollectorTrace:
     def _collector(self, bus):
         return Collector(bus, buckets=(1, 2, 4), model_of=_model_of)
 
-    def test_clip_cameras_copy_each_frame_1_plus_2L_times(self, bus):
+    def test_clip_cameras_copy_each_frame_1_plus_L_times(self, bus):
         n = 2
         for i in range(n):
             bus.create_stream(f"clip{i}", F)
         col = self._collector(bus)
-        for k in range(L):
+        for k in range(L + 3):
             for i in range(n):
                 _publish(bus, f"clip{i}", value=k)
             groups = col.collect()
             tr = col.last_trace
             assert tr["frames_read"] == n and tr["bytes_read"] == n * F
+            assert tr["clip_s"] == 0.0     # nothing is assembled: a ring
             if k < L - 1:          # windows filling: frames read, no clip
                 assert groups == []
-                assert tr["bytes_copied"] == tr["bytes_fresh"] == n * F
-                assert tr["clip_s"] == 0.0
-        assert len(groups) == 1 and groups[0].frames.shape[:2] == (n, L)
-        # ring -> a fresh array, L frames stacked into a fresh clip, the
-        # clip copied into a fresh batch: F + L*F + L*F a camera, all of
-        # it into memory handed out once
-        assert tr["bytes_copied"] == n * F * (1 + 2 * L)
-        assert tr["bytes_fresh"] == tr["bytes_copied"]
-        assert tr["read_s"] > 0 and tr["clip_s"] > 0 and tr["fill_s"] > 0
-        assert tr["read_ahead_s"] == 0.0
+                # first sight: ring -> a fresh array -> slot 0 of a new
+                # ring; then ring -> the next slot, written for the first
+                # time: set-up shows as fresh
+                assert tr["bytes_copied"] == tr["bytes_fresh"] \
+                    == (2 if k == 0 else 1) * n * F
+                continue
+            assert len(groups) == 1 and groups[0].frames.shape[:2] == (n, L)
+            # ring of the bus -> the slot falling out of the window, the
+            # window -> its row of a pooled batch: F + L*F a camera
+            assert tr["bytes_copied"] == n * F * (1 + L)
+            # the last slot and the two pool buffers are new once each
+            assert tr["bytes_fresh"] == {L - 1: n * F * (1 + L),
+                                         L: n * F * L}.get(k, 0)
+            assert tr["read_s"] > 0 and tr["fill_s"] > 0
+            assert tr["read_ahead_s"] == 0.0
 
     def test_padding_rows_count_as_copied(self, bus):
-        for i in range(3):
-            bus.create_stream(f"clip{i}", F)
+        cams = [f"clip{i}" for i in range(3)] + ["tag0", "tag1", "tag2"]
+        for cam in cams:
+            bus.create_stream(cam, F)
         col = self._collector(bus)
-        for k in range(L):
-            for i in range(3):
-                _publish(bus, f"clip{i}", value=k)
+        for cam in cams:
+            _publish(bus, cam)
+        tags, = col.collect()       # first sight: three tags, a fresh batch
+        assert tags.bucket == 4
+        # the fill writes the fourth row too; each clip seeds its ring
+        assert col.last_trace["bytes_copied"] == 3 * F + 4 * F + 3 * 2 * F
+        copied = []
+        for k in range(1, L + 4):
+            # a pooled clip batch: its pad row is written only once a
+            # camera that had filled it sits out (from round L + 1 on)
+            for cam in cams[:3 if k < L + 1 else 2]:
+                _publish(bus, cam, value=k)
             groups = col.collect()
-        assert groups[0].bucket == 4
-        # three clips in a four-row batch: the fill writes the fourth row too
-        assert col.last_trace["bytes_copied"] == 3 * F * (1 + L) + 4 * L * F
+            copied.append(col.last_trace["bytes_copied"])
+        assert groups[0].bucket == 2
+        buf = groups[0].frames.base
+        assert buf.shape[0] == 4 and not buf[2:].any()
+        # both pool buffers had held three clips: each zeroes its third
+        # row once, in the round that finds it dirty, and never again
+        assert copied[-3:] == [2 * F * (1 + L) + L * F,
+                               2 * F * (1 + L) + L * F, 2 * F * (1 + L)]
 
     def test_fast_path_tag_camera_copies_once_into_the_pool(self, bus):
         bus.create_stream("tag0", F)
@@ -254,13 +276,25 @@ class TestStageRecords:
             recs = [r for r in records if r["tick"] == t]
             assert {r["device_id"] for r in recs} \
                 == {"tag0", "clip0", "clip1"}
+            # single frames first: the small batch does not wait behind
+            # the clip batch's placement
+            assert {r["device_id"] for r in recs if r["batch"][1] == 0} \
+                == {"tag0"}
             # both batches carry the one tick's collector counts: the tag
-            # frame ring -> pooled slot once, two clips the generic way
+            # frame ring -> pooled slot once, each clip frame ring -> its
+            # clip ring -> a pooled row
             for r in recs:
                 assert r["bytes_read"] == 3 * F
-                assert r["bytes_copied"] == F + 2 * F * (1 + 2 * L)
-                assert r["bytes_fresh"] == 2 * F * (1 + 2 * L)
+                assert r["bytes_copied"] == F + 2 * F * (1 + L)
                 assert r["frames_read"] == 3
+                assert r["clip_s"] == 0.0
+        # fresh is set-up: the rings' last slots and the first pool buffer
+        # in round L, the second pool buffer in round L+1, and nothing
+        # after unless the drain thread still held a lease (a third buffer)
+        fresh = [next(r["bytes_fresh"] for r in records if r["tick"] == t)
+                 for t in sorted(double)]
+        assert fresh[:2] == [2 * F * (1 + L), 2 * F * L]
+        assert fresh[2] in (0, 2 * F * L)
 
     def test_equal_floats_never_merge_batches(self, bus):
         """Batches are told apart by identifier: forcing every stamp of a
@@ -312,8 +346,9 @@ class TestSinksAgree:
         assert bytes1["read"] - bytes0["read"] == (L + 2) * 3 * F
         for p in PHASES:
             assert phase1[p] >= phase0[p]
-        for p in ("read", "clip", "fill", "step_call"):
+        for p in ("read", "fill", "step_call"):
             assert phase1[p] > phase0[p], p
+        assert phase1["clip"] == phase0["clip"]     # nothing is assembled
         # the records' batches are the events' batches
         batches = {tuple(e["batch"]) for e in engine if "batch" in e}
         assert batches == set(_by_batch(records))

@@ -10,7 +10,10 @@ closed set of shapes (SURVEY.md §7 hard part 1 — no recompilation storms).
 
 Video models get clip assembly: a per-stream sliding window of the last
 ``clip_len`` frames (the temporal axis is just a leading axis, SURVEY.md
-§5.7).
+§5.7). The window is a ring of ``clip_len`` frame slots allocated once per
+stream (``_ClipRing``): the bus writes each new frame over the one falling
+out of the window, and the ring is copied, oldest frame first, into a row
+of the same pooled batch buffers single-frame streams are read into.
 """
 
 from __future__ import annotations
@@ -18,7 +21,6 @@ from __future__ import annotations
 import threading
 import time
 import zlib
-from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
 
@@ -90,15 +92,17 @@ def _new_trace() -> dict:
       ``read_latest_into``, the window's ``assemble_step``);
       ``read_ahead_s`` is the part of it spent between ticks, before
       collect() was entered.
-    - ``clip_s``: making one stream's clip window one contiguous sample.
-    - ``fill_s``: samples -> the batch buffer, with its allocation and
-      zero-padding.
+    - ``clip_s``: making one stream's clip window one contiguous sample;
+      0.0 since the window is a ring copied straight into its batch row.
+    - ``fill_s``: samples -> the batch buffer (a clip ring -> its row),
+      with the buffer's allocation and zero-padding.
     - ``frames_read`` / ``bytes_read``: new frames taken off the rings.
-    - ``bytes_copied``: every byte written into host memory (reads, clip
-      assembly, fill, padding); ``bytes_fresh``: those written into a
-      buffer that is handed out once (a new allocation, the bus's
-      per-read destination) and so first-touched, as against a pooled
-      buffer reused from tick to tick.
+    - ``bytes_copied``: every byte written into host memory (reads, fill,
+      padding); ``bytes_fresh``: those written into a buffer that is
+      handed out once (a new allocation, the bus's per-read destination)
+      and so first-touched, as against a pooled buffer reused from tick
+      to tick. A clip ring's slot and a clip batch's pool buffer count
+      fresh the first time they are written: set-up, not steady state.
     """
     return {"read_s": 0.0, "read_ahead_s": 0.0, "clip_s": 0.0, "fill_s": 0.0,
             "frames_read": 0, "bytes_read": 0, "bytes_copied": 0,
@@ -170,6 +174,38 @@ def pad_to_bucket(group: BatchGroup, buckets: Sequence[int]) -> BatchGroup:
         group.frames = np.concatenate([group.frames, pad], axis=0)
     group.bucket = bucket
     return group
+
+
+class _ClipRing:
+    """One clip camera's window: ``clip_len`` frame slots allocated once
+    and written in place. ``pos`` is the slot the next frame goes to; once
+    the ring is full that is the slot of the frame falling out of the
+    window, so the window in time order is ``buf[pos:]`` then
+    ``buf[:pos]``. Only Collector touches it, on the engine thread, and no
+    BatchGroup ever views it: a batch in flight holds a copy."""
+
+    __slots__ = ("buf", "pos", "count")
+
+    def __init__(self, clip_len: int, geom: tuple):
+        self.buf = np.zeros((clip_len,) + tuple(geom), np.uint8)
+        self.pos = 0
+        self.count = 0
+
+    @property
+    def full(self) -> bool:
+        return self.count == len(self.buf)
+
+    def advance(self) -> None:
+        """The slot at ``pos`` now holds the newest frame."""
+        self.pos = (self.pos + 1) % len(self.buf)
+        self.count = min(self.count + 1, len(self.buf))
+
+    def copy_to(self, row: np.ndarray) -> None:
+        """A full window into ``row`` [clip_len, H, W, C], oldest first —
+        byte for byte the last ``clip_len`` frames stacked."""
+        k = len(self.buf) - self.pos
+        row[:k] = self.buf[self.pos:]
+        row[k:] = self.buf[:self.pos]
 
 
 @dataclass(frozen=True)
@@ -371,7 +407,7 @@ class Collector:
         self._interest_of = interest_of
         self._last_interest: Dict[str, float] = {}
         self._cursors: Dict[str, int] = {}
-        self._clips: Dict[str, deque] = {}
+        self._clips: Dict[str, _ClipRing] = {}
         self._geom: Dict[str, tuple] = {}   # last-seen (h, w, c) per stream
         # shape -> {"bufs": [arr], "prev": set, "cur": [idx], "leased":
         # [idx in lease order]} (_pooled / release)
@@ -497,6 +533,75 @@ class Collector:
         if fresh:
             acc["bytes_fresh"] += int(nbytes)
 
+    def _seed_ring(self, device_id: str, clip_len: int,
+                   frame: Frame) -> Optional[_ClipRing]:
+        """Start the stream's clip window anew from ``frame`` (first sight
+        of the stream, or its geometry drifted): a ring at the frame's
+        geometry with the frame in slot 0. A frame that is not [H, W, C]
+        starts nothing and leaves the stream without a window."""
+        if frame.data.ndim != 3:
+            self._clips.pop(device_id, None)
+            return None
+        ring = self._clips[device_id] = _ClipRing(clip_len, frame.data.shape)
+        t0 = time.perf_counter()
+        ring.buf[0] = frame.data
+        self._note_fill(t0, frame.data.nbytes, fresh=True)
+        ring.advance()
+        return ring
+
+    def _take(self, device_id: str, model: str, clip_len: int,
+              dst: np.ndarray, warm: bool, spill: List[tuple]):
+        """One planned stream's newest unseen frame -> its batch row
+        ``dst``. A single-frame stream is read by the bus straight into
+        ``dst``; a clip stream into the slot of its ring that falls out
+        of the window, and the full window is then copied into ``dst``,
+        oldest frame first. ``warm``: ``dst`` is memory the collector has
+        written before. Returns the frame's meta once ``dst`` holds the
+        stream's sample, else None: no new frame (the window stays as it
+        was: the slot offered to the bus is rewritten whole before it is
+        served again), a window still filling, or a frame of another
+        geometry, which goes to ``spill`` where it is a whole sample and
+        starts a clip stream's window anew."""
+        ring = None
+        slot, slot_warm = dst, warm
+        if clip_len:
+            ring = self._clips.get(device_id)
+            if ring is None or ring.buf.shape != dst.shape:
+                # No window, or one of another length or geometry (the
+                # stream was re-added with another model): never inherit.
+                ring = self._clips[device_id] = _ClipRing(
+                    clip_len, dst.shape[1:])
+            slot, slot_warm = ring.buf[ring.pos], ring.full
+        res = self._read_into(
+            device_id, slot, self._cursors.get(device_id, 0), slot_warm)
+        if res is None and self._rebase_if_restarted(device_id):
+            res = self._read_into(device_id, slot, 0, slot_warm)
+        if res is None:
+            return None
+        if isinstance(res, Frame):   # geometry drifted
+            self._note_read(device_id, res.seq, res.meta)
+            sample = res.data
+            if sample.ndim == 3:     # corrupt 1-D frames must not poison
+                # the geometry cache (the generic path guards the same)
+                self._geom[device_id] = sample.shape
+            if clip_len:
+                ring = self._seed_ring(device_id, clip_len, res)
+                if ring is None or not ring.full:
+                    return None
+                sample = ring.buf
+            spill.append((device_id, model, sample, res.meta))
+            return None
+        seq, meta = res
+        self._note_read(device_id, seq, meta)
+        if ring is not None:
+            ring.advance()
+            if not ring.full:
+                return None
+            t0 = time.perf_counter()
+            ring.copy_to(dst)
+            self._note_fill(t0, dst.nbytes, fresh=not warm)
+        return meta
+
     def _stream_model(self, device_id: str):
         """(model name, clip_len) for one stream — per-stream override via
         the resolver (StreamProcess.inference_model), else engine default."""
@@ -589,9 +694,9 @@ class Collector:
 
     def _pooled(self, shape: tuple):
         """Pooled batch buffer per shape -> (array, pool index). Reuse
-        keeps the pages warm — fresh allocations at the north-star shape
-        fault ~25k pages per tick, which measured as several times the
-        raw memcpy floor (tools/bench_latency host leg). Every call
+        keeps the pages warm — a write into first-touched pages measured
+        ~1 GB/s on the chip's host against 11.4 GB/s into a reused buffer
+        (PERF.md section 6, PR 24). Every call
         within one tick gets a DISTINCT buffer (3 models on same-geometry
         cameras build 3+ same-shape groups per tick), nothing handed out
         the previous tick is reused, and under strict_lease nothing
@@ -641,14 +746,28 @@ class Collector:
             return slot["bufs"][idx], idx
 
     def pool_nbytes(self) -> int:
-        """Total bytes held by the pooled batch buffers across shapes —
-        the obs/hbm.py ``register_pool`` tap for the collector's host
-        staging pool (the canvas/batch buffers the device step reads
+        """Total bytes held by the pooled batch buffers across shapes and
+        by the clip rings — the obs/hbm.py ``register_pool`` tap for the
+        collector's host staging memory (the canvas/batch buffers the
+        device step reads from, and the windows clip batches are copied
         from). Sums live ``.nbytes`` under the pool lock so the figure
         is exact against the constituent arrays at any instant."""
         with self._pool_lock:
-            return sum(buf.nbytes for slot in self._pool.values()
-                       for buf in slot["bufs"])
+            pooled = sum(buf.nbytes for slot in self._pool.values()
+                         for buf in slot["bufs"])
+        # list(): the engine thread adds and drops rings meanwhile
+        return pooled + sum(r.buf.nbytes for r in list(self._clips.values()))
+
+    def _row_warm(self, shape: tuple, idx, clip_len: int) -> bool:
+        """Is a row of this pool buffer memory the collector has written
+        before? A single-frame row counts so whenever it is pooled (the
+        count PR 25 fixed); a clip row once its buffer has been through a
+        tick: ``fill`` gets the buffer's entry at its first zero-padding.
+        A one-off failsafe buffer (``idx`` None) never is."""
+        if idx is None or not clip_len:
+            return idx is not None
+        with self._pool_lock:
+            return idx in self._pool[shape]["fill"]
 
     def _unrotate(self, shape: tuple) -> None:
         """No group was emitted from the last-handed-out buffer (every
@@ -675,7 +794,8 @@ class Collector:
         """Return a strict-leased group's buffer to the pool (called by
         the engine's drain thread once the batch is emitted — i.e. once
         nothing can still be reading the host frames). No-op for
-        generic-path groups (fresh allocations) and non-strict mode."""
+        generic-path groups (fresh allocations: first sight, drift) and
+        non-strict mode."""
         if group.lease is None:
             return
         shape, idx = group.lease
@@ -867,9 +987,10 @@ class Collector:
     ) -> None:
         """Lay out next tick's fast-path batches: (model, geometry)
         grouping and bucket chunking identical to collect()'s, with a
-        pooled buffer acquired per group. Streams with unknown geometry
-        or clip assembly stay unplanned (they take collect()'s generic
-        path and join the window next tick)."""
+        pooled buffer acquired per group. Single-frame streams only: a
+        clip stream's batch row is copied from its ring at collect(), and
+        a stream of unknown geometry takes collect()'s generic path and
+        joins the window next tick."""
         if device_ids is None:
             device_ids = self.inference_streams()
         buckets = self._effective_buckets()
@@ -967,7 +1088,8 @@ class Collector:
                 self._note_read(device_id, res.seq, res.meta)
                 if res.data.ndim == 3:
                     self._geom[device_id] = res.data.shape
-                win["spill"].append((device_id, g["model"], res))
+                win["spill"].append(
+                    (device_id, g["model"], res.data, res.meta))
                 drifted.append(device_id)
                 continue
             seq, meta = res
@@ -999,12 +1121,15 @@ class Collector:
         ``device_ids``: precomputed inferred set (from ``partition``);
         None re-enumerates.
 
-        Single-pass hot path: non-clip streams whose geometry is known
-        from a previous tick are read by the bus DIRECTLY into pooled
-        batch slots (`read_latest_into`) — ring to device batch in one
-        memory pass. First-sight streams, clip assembly, and geometry
-        drift take the generic frame path and join the fast path next
-        tick."""
+        Hot path: a stream whose geometry is known from a previous tick
+        is planned under (model, geometry, clip_len) and served from
+        pooled batch buffers (``_take``). A window of one frame is read
+        by the bus DIRECTLY into its batch row (`read_latest_into`) —
+        ring to device batch in one memory pass; a window of ``clip_len``
+        frames goes through the stream's clip ring: the new frame over
+        the oldest, then the ring into the row. First sight of a stream
+        and geometry drift take the generic frame path and join the hot
+        path next tick."""
         if device_ids is None:
             device_ids = self.inference_streams()
         acc = self._acc
@@ -1056,71 +1181,67 @@ class Collector:
                 self._lease(group, g["shape"], g["idx"])
                 groups.append(group)
 
-        fast_plan: Dict[tuple, list] = {}   # (model, (h,w,c)) -> [ids]
+        # (model, (h, w, c), clip_len) -> [ids]: one plan for every stream
+        # whose geometry is known; the window length is the model spec's.
+        fast_plan: Dict[tuple, list] = {}
         slow_ids: List[str] = []
         for device_id in device_ids:
             if device_id in win_planned:
                 continue   # already served (or known idle) via the window
             model, clip_len = self._stream_model(device_id)
             geom = self._geom.get(device_id)
-            if clip_len or geom is None:
+            if geom is None:
                 slow_ids.append(device_id)
             else:
-                fast_plan.setdefault((model, geom), []).append(device_id)
+                fast_plan.setdefault(
+                    (model, geom, clip_len), []).append(device_id)
 
-        for (model, geom), devs in sorted(fast_plan.items()):
+        # Single frames first, then windows by length (the order groups had
+        # when clips still took the generic path): a small batch's results
+        # do not wait behind the placement of a clip batch.
+        for (model, geom, clip_len), devs in sorted(
+                fast_plan.items(), key=lambda kv: (kv[0][2],) + kv[0][:2]):
             if self._shards > 1:
                 self._collect_fast_sharded(
-                    model, geom, devs, buckets, groups, spill)
+                    model, geom, clip_len, devs, buckets, groups, spill)
                 continue
+            sample = ((clip_len,) + geom) if clip_len else geom
             for start in range(0, len(devs), max_bucket):
                 chunk = devs[start:start + max_bucket]
                 alloc = next(b for b in buckets if b >= len(chunk))
-                batch, bidx = self._pooled((alloc,) + geom)
+                shape = (alloc,) + sample
+                batch, bidx = self._pooled(shape)
+                warm = self._row_warm(shape, bidx, clip_len)
                 ids: List[str] = []
                 metas: List[FrameMeta] = []
                 hw = 0   # attempt high-water for _zero_pad_rows
                 for device_id in chunk:
-                    hw = max(hw, len(ids) + 1)
-                    res = self._read_into(
-                        device_id, batch[len(ids)],
-                        self._cursors.get(device_id, 0), bidx is not None)
-                    if res is None and self._rebase_if_restarted(device_id):
-                        res = self._read_into(
-                            device_id, batch[len(ids)], 0, bidx is not None)
-                    if res is None:
-                        continue
-                    if isinstance(res, Frame):   # geometry drifted
-                        self._note_read(device_id, res.seq, res.meta)
-                        if res.data.ndim == 3:   # corrupt 1-D frames must
-                            # not poison the geometry cache (generic-path
-                            # guard below applies here too)
-                            self._geom[device_id] = res.data.shape
-                        spill.append((device_id, model, res))
-                        continue
-                    seq, meta = res
-                    self._note_read(device_id, seq, meta)
-                    ids.append(device_id)
-                    metas.append(meta)
+                    if not clip_len:   # the bus writes into the row itself
+                        hw = max(hw, len(ids) + 1)
+                    meta = self._take(device_id, model, clip_len,
+                                      batch[len(ids)], warm, spill)
+                    if meta is not None:
+                        ids.append(device_id)
+                        metas.append(meta)
                 n = len(ids)
                 if not n:
                     if bidx is not None:
                         # One-off failsafe buffers never entered "cur";
                         # unrotating would pop a legitimate same-tick entry.
-                        self._unrotate((alloc,) + geom)
+                        self._unrotate(shape)
                     continue
                 bucket = next(b for b in buckets if b >= n)
-                self._zero_pad_rows(batch, (alloc,) + geom, bidx, n, hw)
+                self._zero_pad_rows(batch, shape, bidx, n, hw)
                 view = batch[:bucket]
                 group = BatchGroup(
                     src_hw=geom[:2], device_ids=ids, frames=view,
                     metas=metas, bucket=bucket, model=model,
                 )
-                self._lease(group, (alloc,) + geom, bidx)
+                self._lease(group, shape, bidx)
                 groups.append(group)
 
-        # Generic path: first sight (geometry unknown), clips, drift.
-        by_key: Dict[tuple, list] = {}
+        # Generic path: first sight (geometry unknown) and drift.
+        first_sight: List[tuple] = []
         for device_id in slow_ids:
             frame = self._read(device_id, self._cursors.get(device_id, 0))
             if frame is None and self._rebase_if_restarted(device_id):
@@ -1131,72 +1252,36 @@ class Collector:
             model, clip_len = self._stream_model(device_id)
             if frame.data.ndim == 3:
                 self._geom[device_id] = frame.data.shape
-            hw = frame.data.shape[:2]
+            sample = frame.data
             if clip_len:
-                window = self._clips.get(device_id)
-                if window is None or window.maxlen != clip_len:
-                    # (Re)create on clip-length change — a re-added stream
-                    # with a different model must not inherit a stale window.
-                    window = deque(maxlen=clip_len)
-                    self._clips[device_id] = window
-                window.append(frame)
-                if len(window) < clip_len:
+                ring = self._seed_ring(device_id, clip_len, frame)
+                if ring is None or not ring.full:
                     continue
-                t0 = time.perf_counter()
-                sample = np.stack([f.data for f in window])
-                acc["clip_s"] += time.perf_counter() - t0
-                acc["bytes_copied"] += int(sample.nbytes)
-                acc["bytes_fresh"] += int(sample.nbytes)
-            else:
-                sample = frame.data
-            by_key.setdefault((model, hw), []).append(
-                (device_id, sample, frame.meta)
+                sample = ring.buf   # a window of one frame: full at once
+            first_sight.append((device_id, model, sample, frame.meta))
+        by_key: Dict[tuple, list] = {}      # (model, sample shape) -> items
+        for device_id, model, sample, meta in first_sight + spill:
+            by_key.setdefault((model, sample.shape), []).append(
+                (device_id, sample, meta)
             )
-        for device_id, model, frame in spill:
-            by_key.setdefault((model, frame.data.shape[:2]), []).append(
-                (device_id, frame.data, frame.meta)
-            )
-
-        for (model, hw), items in sorted(by_key.items()):
-            if self._shards > 1:
-                self._collect_generic_sharded(model, hw, items, buckets,
-                                              groups)
-                continue
-            for start in range(0, len(items), max_bucket):
-                chunk = items[start:start + max_bucket]
-                n = len(chunk)
-                bucket = next(b for b in buckets if b >= n)
-                # Fused stack+pad: one pass instead of np.stack + concat.
-                t0 = time.perf_counter()
-                batch = np.empty(
-                    (bucket,) + chunk[0][1].shape, chunk[0][1].dtype
-                )
-                for i, (_, arr, _) in enumerate(chunk):
-                    batch[i] = arr
-                if bucket != n:
-                    batch[n:] = 0
-                self._note_fill(t0, batch.nbytes, fresh=True)
-                groups.append(BatchGroup(
-                    src_hw=hw,
-                    device_ids=[d for d, _, _ in chunk],
-                    frames=batch,
-                    metas=[m for _, _, m in chunk],
-                    bucket=bucket,
-                    model=model,
-                ))
+        for (model, shape), items in sorted(by_key.items()):
+            hw = shape[:-1][-2:]    # of [H, W, C] or [clip_len, H, W, C]
+            self._collect_generic(model, hw, items, buckets, groups)
         self.last_trace, self._acc = acc, _new_trace()
         return groups
 
-    def _collect_fast_sharded(self, model: str, geom: tuple,
+    def _collect_fast_sharded(self, model: str, geom: tuple, clip_len: int,
                               devs: Sequence[str], buckets: tuple,
                               groups: List[BatchGroup],
                               spill: List[tuple]) -> None:
-        """Shard-segmented fast path: one (model, geometry) stream set ->
-        pooled, bucket-padded, shard-segmented batches. Streams read
-        directly into their shard's segment at allocation spacing; the
-        final bucket is the smallest whose PER-SHARD segment covers the
-        fullest shard, then _finish_sharded compacts the segments down."""
+        """Shard-segmented fast path: one (model, geometry, clip_len)
+        stream set -> pooled, bucket-padded, shard-segmented batches.
+        Streams land (``_take``, as in the dense layout) in their shard's
+        segment at allocation spacing; the final bucket is the smallest
+        whose PER-SHARD segment covers the fullest shard, then
+        _finish_sharded compacts the segments down."""
         S = self._shards
+        sample = ((clip_len,) + geom) if clip_len else geom
         max_bucket = buckets[-1]
         cap = max_bucket // S        # per-shard chunk capacity
         by_shard = self._by_shard(devs)
@@ -1206,32 +1291,21 @@ class Collector:
             chunk = [l[c * cap:(c + 1) * cap] for l in by_shard]
             need = max(len(l) for l in chunk)
             alloc = next(b for b in buckets if b // S >= need)
-            shape = (alloc,) + geom
+            shape = (alloc,) + sample
             batch, bidx = self._pooled(shape)
+            warm = self._row_warm(shape, bidx, clip_len)
             seg_a = alloc // S
             per: List[list] = [[] for _ in range(S)]
             touched = 0   # attempt high-water (one past highest row hit)
             for s, shard_devs in enumerate(chunk):
                 for device_id in shard_devs:
                     t = s * seg_a + len(per[s])
-                    touched = max(touched, t + 1)
-                    res = self._read_into(
-                        device_id, batch[t],
-                        self._cursors.get(device_id, 0), bidx is not None)
-                    if res is None and self._rebase_if_restarted(device_id):
-                        res = self._read_into(
-                            device_id, batch[t], 0, bidx is not None)
-                    if res is None:
-                        continue
-                    if isinstance(res, Frame):   # geometry drifted
-                        self._note_read(device_id, res.seq, res.meta)
-                        if res.data.ndim == 3:
-                            self._geom[device_id] = res.data.shape
-                        spill.append((device_id, model, res))
-                        continue
-                    seq, meta = res
-                    self._note_read(device_id, seq, meta)
-                    per[s].append((device_id, meta))
+                    if not clip_len:   # the bus writes into the row itself
+                        touched = max(touched, t + 1)
+                    meta = self._take(device_id, model, clip_len, batch[t],
+                                      warm, spill)
+                    if meta is not None:
+                        per[s].append((device_id, meta))
             counts = [len(p) for p in per]
             if not any(counts):
                 if bidx is not None:
@@ -1242,12 +1316,13 @@ class Collector:
                 batch, shape, bidx, per, seg_a, bucket, touched,
                 src_hw=geom[:2], model=model))
 
-    def _collect_generic_sharded(self, model: str, hw: tuple,
-                                 items: Sequence[tuple], buckets: tuple,
-                                 groups: List[BatchGroup]) -> None:
-        """Shard-segmented generic path (first sight, clips, drift):
-        fresh zeroed buffer, samples written straight at final-bucket
-        spacing — no compaction needed, interior pads already zero."""
+    def _collect_generic(self, model: str, hw: tuple,
+                         items: Sequence[tuple], buckets: tuple,
+                         groups: List[BatchGroup]) -> None:
+        """Generic path (first sight, drift): whole samples, each already
+        in an array of its own, into a fresh zeroed buffer at final-bucket
+        spacing — no compaction needed, pad rows already zero. One shard
+        is the dense identity layout (``rows`` None)."""
         S = self._shards
         cap = buckets[-1] // S
         by_shard = self._by_shard(items)
@@ -1273,7 +1348,7 @@ class Collector:
             self._note_fill(t0, batch.nbytes, fresh=True)
             groups.append(BatchGroup(
                 src_hw=hw, device_ids=ids, frames=batch, metas=metas,
-                bucket=bucket, model=model, rows=rows,
+                bucket=bucket, model=model, rows=rows if S > 1 else None,
             ))
 
     def drop_stream(self, device_id: str) -> None:
