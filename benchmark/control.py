@@ -24,6 +24,19 @@ from vbench import correct, loader, weights     # noqa: E402
 from vbench import traffic as traffic_mod       # noqa: E402
 
 
+def first_window(model: dict, first: int, step: int = 37) -> list:
+    """The first window the model's family gives a camera that reads frames
+    ``first``, ``first + step``, ...: the frames of a control sample."""
+    fam = loader.family(model["family"])
+    reads = []
+    while len(reads) < 4096:
+        reads.append(first + step * len(reads))
+        window = fam.window({"packet": reads[-1]}, reads, model["sizes"])
+        if window:
+            return window
+    raise SystemExit(f"family {model['family']!r} gives no window")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n", 1)[0])
     ap.add_argument("--workload", required=True)
@@ -46,16 +59,17 @@ def main(argv=None) -> int:
         for c in cams:
             name = role_model[c[2]]
             if sum(1 for r in sample if r["model"] == name) < args.per_model:
-                n = max(loader.clip_len(models[name]), 1)
-                first = 9 + c[0] % 5
                 sample.append({"device_id": c[1], "model": name,
-                               "window": [first + 37 * j for j in range(n)]})
-        exact = correct.reference_logits(sample, cams, seed, models, flat,
-                                         loader.reference)
-        low = correct.reference_logits(sample, cams, seed, models, flat,
-                                       loader.reference, quant="fp8")
-        numbers = correct.compare([correct.topk(r) for r in low], exact,
-                                  [r["model"] for r in sample])
+                               "window": first_window(models[name],
+                                                      9 + c[0] % 5)})
+        exact = correct.reference_rows(sample, cams, seed, models, flat,
+                                       loader.reference)
+        low = correct.reference_rows(sample, cams, seed, models, flat,
+                                     loader.reference, quant="fp8")
+        names = [r["model"] for r in sample]
+        numbers = correct.compare(
+            [loader.family(models[n]["family"]).as_served(r)
+             for n, r in zip(names, low)], exact, names, models)
         # judged on the numbers this comparison gives, and on no other
         limits = {k: v for k, v in cell["config"]["limits"].items()
                   if k in numbers}

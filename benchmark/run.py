@@ -82,24 +82,6 @@ def seeded_spec(base, variables):
                      for f in dataclasses.fields(base)})
 
 
-def check_sizes(model, family, sizes):
-    """The configuration file is what is run: refuse a registry model whose
-    sizes differ from it."""
-    c = model.cfg
-    got = {"hidden_size": c.encoder.dim, "image_size": c.image_size,
-           "num_hidden_layers": c.encoder.num_layers,
-           "num_attention_heads": c.encoder.num_heads,
-           "intermediate_size": c.encoder.mlp_dim,
-           "patch_size": c.patch_size, "num_labels": c.num_classes}
-    if family == "videomae":
-        got["num_frames"] = c.num_frames
-        got["tubelet_size"] = c.tubelet_size
-    bad = {k: (sizes.get(k), v) for k, v in got.items() if sizes.get(k) != v}
-    if bad:
-        raise SystemExit(f"configuration file and registry model disagree "
-                         f"(file, program): {bad}")
-
-
 def prewarm_entries(cams, role_model, buckets):
     """[h, w, bucket, model] for every bucket of the engine's list that a
     group of cameras can be dispatched in (a configuration that states the
@@ -145,8 +127,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     trace_dir = None
     shipped_specs = []          # the registry's own entries, put back after
     try:
-        import jax.numpy as jnp
-
         from video_edge_ai_proxy_tpu.bus.shm_bus import ShmFrameBus
         from video_edge_ai_proxy_tpu.engine import InferenceEngine
         from video_edge_ai_proxy_tpu.models import registry
@@ -170,21 +150,25 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         peak = loader.peak(cell["peaks"], dev.device_kind) if on_chip else None
 
         # -- weights from the seed, on the device, one jitted call a model
-        model_of, flat = {}, {}
+        model_of, flat, kept_of = {}, {}, {}
         models = loader.models(config)
         for salt, m in enumerate(models):
             name = m["registry_model"]
             base = registry.get(name)
             shipped_specs.append(base)
             module = base.build()
-            check_sizes(module, m["family"], m["sizes"])
+            fam = loader.family(m["family"])
+            # the configuration file is what is run: refuse a registry
+            # model whose sizes differ from it
+            bad = fam.check_sizes(module, m["sizes"])
+            if bad:
+                raise SystemExit(f"configuration file and registry model "
+                                 f"disagree (file, program): {bad}")
             flat[name] = weights.generate(seed, m["family"], m["sizes"], salt)
-            template = jax.eval_shape(
-                module.init, jax.random.PRNGKey(0),
-                jnp.zeros(base.example_shape(1), jnp.bfloat16))
-            registry.register(seeded_spec(
-                base, weights.as_variables(flat[name], template)))
+            registry.register(seeded_spec(base, weights.as_variables(
+                flat[name], fam.template(base, module))))
             model_of[name] = m
+            kept_of[name] = fam.kept
             phases[f"weights_{name}"] = time.monotonic() - _T0
         default_model = models[0]["registry_model"]
         phases["weights_done"] = time.monotonic() - _T0
@@ -228,16 +212,17 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         eng.start()             # compiles (or loads) every prewarm entry
         phases["programs_warm"] = time.monotonic() - _T0
 
-        # -- subscriber: every result, with its receive time
+        # -- subscriber: every result, with its receive time and what its
+        # model's family compares of it
         received, recv_lock = [], threading.Lock()
 
         def subscriber():
             for res in eng.subscribe():
                 now = time.monotonic()
+                kept = kept_of.get(res.model)
                 rec = {"device_id": res.device_id, "packet": res.frame_packet,
                        "timestamp": res.timestamp, "model": res.model,
-                       "top": [(d.class_id, d.confidence)
-                               for d in res.detections],
+                       "kept": kept(res) if kept else None,
                        "t": now}
                 with recv_lock:
                     received.append(rec)
@@ -333,10 +318,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
               if r["device_id"] in by_id]
     n_of = collections.Counter(r["device_id"] for r in in_window)
     rounds = max(n_of.values(), default=0)
-    clip_len = {c[1]: loader.clip_len(model_of[role_model[c[2]]])
-                for c in cams}
-    failed = correct.unanswered(events, results, cams, clip_len, t_start,
-                                t_end, wall_minus_mono)
+    model_of_camera = {c[1]: model_of[role_model[c[2]]] for c in cams}
+    failed = correct.unanswered(
+        events, results, cams,
+        {d: loader.family(m["family"]).sample_frames(m["sizes"])
+         for d, m in model_of_camera.items()},
+        t_start, t_end, wall_minus_mono)
     attempted = len(in_window) + failed
     e2e = {
         "latency_p50_ms": percentile(lat_ms, 50) if lat_ms else None,
@@ -354,14 +341,15 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     numbers["window_compiles"] = len(snap["compiles"]) - compiles0
     sample = correct.draw_sample(
         correct.eligible(in_window, correct.reads_by_camera(events),
-                         clip_len),
+                         model_of_camera),
         SAMPLE_PER_MODEL, seed)
     t_ref = time.monotonic()
     if sample and shed == 0:
-        rows = correct.reference_logits(sample, cams, seed, model_of, flat,
-                                        loader.reference)
-        numbers.update(correct.compare([r["top"] for r in sample], rows,
-                                       [r["model"] for r in sample]))
+        rows = correct.reference_rows(sample, cams, seed, model_of, flat,
+                                      loader.reference)
+        numbers.update(correct.compare(
+            [r["kept"] for r in sample], rows, [r["model"] for r in sample],
+            model_of))
     ref_s = time.monotonic() - t_ref
     phases["reference_from"] = t_ref - _T0
     rss["reference"] = rss_gb()

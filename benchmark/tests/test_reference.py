@@ -92,8 +92,10 @@ def test_float8_control_is_not_correct(config):
     exact = np.asarray(ref.jitted(m["family"], key)(flat, clips))
     low = np.asarray(ref.jitted(m["family"], key, "fp8")(flat, clips))
     names = [m["registry_model"]] * len(low)
-    numbers = correct.compare([correct.topk(r) for r in low], list(exact),
-                              names)
+    model_of = {m["registry_model"]: m}
+    as_served = loader.family(m["family"]).as_served
+    numbers = correct.compare([as_served(r) for r in low], list(exact),
+                              names, model_of)
     # judged on the numbers this comparison gives, and on no other: it is a
     # log-probability limit that the control has to fail
     limits = {k: v for k, v in cfg["limits"].items() if k in numbers}
@@ -101,8 +103,8 @@ def test_float8_control_is_not_correct(config):
     ok, checks = correct.verdict(numbers, limits)
     assert not ok, numbers
     assert any(c["value"] > c["limit"] for c in checks.values())
-    same = correct.compare([correct.topk(r) for r in exact], list(exact),
-                           names)
+    same = correct.compare([as_served(r) for r in exact], list(exact),
+                           names, model_of)
     assert correct.verdict(same, limits)[0]
 
 
@@ -121,8 +123,10 @@ def test_windows_follow_the_frames_the_collector_read():
     reads = correct.reads_by_camera(events[::-1])       # any order in
     got = [{"device_id": "c", "packet": k} for k in (38, 44, 52, 57, 60)]
     got += [{"device_id": "t", "packet": 8}]
+    models = {"c": {"family": "videomae", "sizes": {"num_frames": 8}},
+              "t": {"family": "vit", "sizes": {}}}
     out = {(r["device_id"], r["packet"]): r["window"]
-           for r in correct.eligible(got, reads, {"c": 8, "t": 0})}
+           for r in correct.eligible(got, reads, models)}
     assert out[("c", 44)] == [3, 9, 14, 20, 27, 31, 38, 44]
     assert out[("c", 57)] == [14, 20, 27, 31, 38, 44, 52, 57]
     assert ("c", 38) not in out                 # its window was not full
@@ -133,7 +137,7 @@ def test_windows_follow_the_frames_the_collector_read():
 def test_unanswered_counts_what_the_engine_took_and_lost():
     cams = [(0, "a", "tag", 80, 120, 0.0), (1, "b", "tag", 80, 120, 0.01),
             (2, "p", "tag", 80, 120, 0.02)]
-    clip_len = {"a": 0, "b": 0, "p": 0}
+    sample_frames = {"a": 1, "b": 1, "p": 1}
     events, results = [], []
     for i in range(10):                         # one read a second, 1 s each
         for cam in ("a", "b"):
@@ -141,14 +145,14 @@ def test_unanswered_counts_what_the_engine_took_and_lost():
             if not (cam == "b" and i == 4):     # b's frame 4 is never answered
                 results.append({"device_id": cam, "packet": i,
                                 "t": 1.0 + i + 1.0})
-    args = (cams[:2], clip_len, 1.0, 10.5, 99.0)
+    args = (cams[:2], sample_frames, 1.0, 10.5, 99.0)
     assert correct.unanswered(events, results, *args) == 1
     # the last reads are in flight at the close: not counted
     late = [r for r in results if r["t"] < 10.5]
     assert correct.unanswered(events, late, *args) == 1
     # a camera the collector never read (paused) counts once
-    assert correct.unanswered(events, results, cams, clip_len, 1.0, 10.5,
-                              99.0) == 2
+    assert correct.unanswered(events, results, cams, sample_frames, 1.0,
+                              10.5, 99.0) == 2
     events.append(_ev("a", "dropped", 7, 106.5))
     assert correct.unanswered(events, results, *args) == 2
 

@@ -1,11 +1,11 @@
 """``run.py`` end to end on the CPU at a tiny size: the line names ``cpu``
-and carries no device metric; a new configuration, cell, traffic mix and
-per-layer metric come in as new files and entries only (``data/``); and
-with the timed path broken underneath ``correct`` comes out false."""
+and carries no device metric; a new configuration, cell, traffic mix,
+per-layer metric and model family (with its reference) come in as new files
+and entries only (``data/``); and with the timed path broken underneath
+``correct`` comes out false."""
 
 import os
 
-import numpy as np
 import pytest
 
 import run as vrun
@@ -14,25 +14,36 @@ from vbench import loader
 DEVICE_METRICS = {"step_device_ms", "step_mfu_pct", "device_idle_pct"}
 
 
-def tiny_bench():
+def bench_with(config: str, workload: str, traffic: str) -> dict:
+    """The benchmark plus one cell of the tests' own, by entries alone."""
     bench = loader.benchmark()
     before = {k: list(v) if isinstance(v, list) else v
               for k, v in bench.items()}
     bench["configs"].append({
-        "name": "tiny_fleet", "source": "tests: the registry's tiny twins",
-        "file": "benchmark/tests/data/tiny_fleet.json", "reduced": [],
+        "name": config, "source": "tests: the registry's tiny twins",
+        "file": f"benchmark/tests/data/{config}.json", "reduced": [],
         "why": "tests"})
     bench["workloads"].append({
-        "name": "tiny.free", "config": "tiny_fleet",
-        "traffic": "../tests/data/tiny_free", "chips": 1, "why": "tests"})
+        "name": workload, "config": config,
+        "traffic": f"../tests/data/{traffic}", "chips": 1, "why": "tests"})
     for m in bench["per_layer"]:
-        m["workloads"] = m["workloads"] + ["tiny.free"]
+        m["workloads"] = m["workloads"] + [workload]
     bench["per_layer"].append({
         "name": "../tests/data/tiny_batches", "unit": "batches",
         "better": "lower", "source": "program_span", "layer": "collector",
-        "moves": "latency_p95_ms", "workloads": ["tiny.free"]})
+        "moves": "latency_p95_ms", "workloads": [workload]})
     assert before["configs"] == bench["configs"][:-1]   # entries only added
     return bench
+
+
+def tiny_bench():
+    return bench_with("tiny_fleet", "tiny.free", "tiny_free")
+
+
+def toy_bench():
+    """A cell whose model is of a family the harness does not have:
+    ``data/toy_family.py``, named by the configuration's ``family`` key."""
+    return bench_with("toy_fleet", "toy.free", "toy_free")
 
 
 @pytest.fixture(scope="module")
@@ -94,18 +105,13 @@ def _break_clip_order(monkeypatch):
     """Clip assembly hands the model the window's frames newest first."""
     from video_edge_ai_proxy_tpu.engine import collector
 
-    real = np.stack
+    real = collector._ClipRing.copy_to
 
-    class _Np:
-        def __getattr__(self, k):
-            return getattr(np, k)
+    def newest_first(self, row):
+        real(self, row)
+        row[:] = row[::-1].copy()
 
-        @staticmethod
-        def stack(arrs, *a, **kw):
-            arrs = list(arrs)
-            return real(arrs[::-1] if len(arrs) == 4 else arrs, *a, **kw)
-
-    monkeypatch.setattr(collector, "np", _Np())
+    monkeypatch.setattr(collector._ClipRing, "copy_to", newest_first)
 
 
 def _break_routing(monkeypatch):
@@ -146,6 +152,29 @@ def test_a_broken_timed_path_is_not_correct(fault, monkeypatch):
     out = vrun.run("tiny.free", 2**31 + 79, 2.0, False,
                    require_chip=False, bench=tiny_bench())
     assert out["correct"] is False, out["checks"]
+
+
+def test_a_new_family_is_new_files_and_entries():
+    """The toy family's cell runs through the same harness: its sizes hold
+    a list, its window is three reads long, and ``correct`` is decided by
+    its own number."""
+    out = vrun.run("toy.free", 2**31 + 83, 2.0, True, require_chip=False,
+                   bench=toy_bench())
+    assert out["correct"] is True, out["checks"]
+    assert out["failed"] == 0 and out["notes"]["sampled"] == 32
+    assert set(out["checks"]) == {"misrouted", "window_compiles",
+                                  "top1_prob_err_tiny_vit"}
+    assert 0.0 < out["checks"]["top1_prob_err_tiny_vit"]["value"] < 0.1
+    assert out["metrics"]["round_results_per_s"]["value"] > 0
+    assert not DEVICE_METRICS & set(out["metrics"])
+
+
+def test_a_new_family_with_its_timed_path_broken_is_not_correct(monkeypatch):
+    _break_answer(monkeypatch)
+    out = vrun.run("toy.free", 2**31 + 84, 2.0, False, require_chip=False,
+                   bench=toy_bench())
+    assert out["correct"] is False, out["checks"]
+    assert out["checks"]["top1_prob_err_tiny_vit"]["value"] > 0.1
 
 
 def test_cli_refuses_to_run_without_a_chip():

@@ -1,31 +1,30 @@
 """What decides ``correct``: the served results against the plain reference.
 
 Compared, from what the timed window itself emitted: every result's routing
-(exact), and for a seeded sample of results the served top-5 against the
-float32 reference run over the very frames that camera published — which
-covers the collector's clip assembly (the last 8 frames it read, in
-order: its own spans say which those were), the
-device preprocess, the encoder, softmax and top-k in one comparison.
+(exact), and for a seeded sample of results what the model's family keeps
+of a result against the float32 reference run over the very frames that
+camera published — which covers the collector's window assembly (the frames
+it read, in order: its own spans say which those were), the device
+preprocess, the model and what follows it in one comparison.
 
 Numbers (each has its limit in the configuration file, set from readings
 in PERF.md section 2):
 
 - ``misrouted``   results whose (device, packet, timestamp, model) is not
                   one that was published for that camera; limit 0.
-- ``logprob_err_<model>``  widest |log(served probability) - reference
-                  log-softmax| over the served top-5 of that model's sample.
-- ``logprob_mean_<model>`` the mean of the same: steady from seed to seed
-                  where the widest swings.
+- the family's own (``families/<name>.py``, ``compare``), named after the
+  model: for the two classifier families ``logprob_err_<model>``, the
+  widest |log(served probability) - reference log-softmax| over the served
+  top-5 of that model's sample, and ``logprob_mean_<model>``, the mean of
+  the same: steady from seed to seed where the widest swings.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
+from . import loader
 from . import traffic as traffic_mod
-
-CLIP_BLOCK = 4      # clips per reference call (8 x 1080p frames each)
-FRAME_BLOCK = 16    # single frames per reference call
 
 
 def routing_errors(received: list, cams: list, role_model: dict,
@@ -56,11 +55,12 @@ def reads_by_camera(events: list) -> dict:
     return out
 
 
-def unanswered(events: list, results: list, cams: list, clip_len: dict,
+def unanswered(events: list, results: list, cams: list, sample_frames: dict,
                t_start: float, t_end: float, wall_minus_mono: float) -> int:
     """Frames the engine took and never answered, by the window's close.
 
-    Every frame the collector reads from a camera whose clip window is full
+    Every frame the collector reads from a camera that has been read as
+    often as its model's sample has frames (``sample_frames``, by camera)
     is owed a result. Counted: a ``dropped`` span inside the window; a read
     inside the window that is still unanswered at the close although it is
     older than twice the longest read-to-result time the window saw (the
@@ -83,7 +83,7 @@ def unanswered(events: list, results: list, cams: list, clip_len: dict,
     for c in cams:
         seen = sorted(reads.get(c[1], []))
         owed = [(t, k) for i, (t, k) in enumerate(seen)
-                if i + 1 >= max(clip_len[c[1]], 1) and t_start <= t < horizon]
+                if i + 1 >= sample_frames[c[1]] and t_start <= t < horizon]
         # the engine comes round to every camera within one read-to-result
         # time, so a stretch of two of them has to hold a read
         if not owed and horizon - t_start >= 2.0 * span:
@@ -92,24 +92,32 @@ def unanswered(events: list, results: list, cams: list, clip_len: dict,
     return bad
 
 
-def eligible(results: list, reads: dict, clip_len: dict) -> list:
-    """``results`` whose input the harness can rebuild, each with
-    ``window``: the frame numbers the model was given.
+def last_reads(result: dict, reads: list, n: int):
+    """The camera's last ``n`` READ frames, the answered one last: what a
+    model without state was given. ``None`` where the spans do not show
+    the result's frame, or the camera had read fewer than ``n`` by then."""
+    if result["packet"] not in reads:
+        return None
+    at = reads.index(result["packet"])
+    return reads[at + 1 - n:at + 1] if at + 1 >= n else None
 
-    The bus is latest-wins, so most published frames are never read: a
-    clip result's window is its camera's last ``n`` READ frames, the
-    answered one last, and the reads are what the collector's spans say. A
-    result whose frame the spans do not show, or whose camera had read
-    fewer than ``n`` frames by then, is not compared."""
+
+def eligible(results: list, reads: dict, model_of_camera: dict) -> list:
+    """``results`` whose input the harness can rebuild, each with
+    ``window``: the frame numbers its answer depends on.
+
+    The bus is latest-wins, so most published frames are never read: what
+    a camera's model was given is among the frames the collector READ, and
+    the reads are what its spans say. Which of them a result depends on is
+    the family's answer (``window``); a result it gives no window for is
+    not compared."""
     out = []
     for r in results:
-        n = max(clip_len[r["device_id"]], 1)
-        seen = reads.get(r["device_id"], [])
-        if r["packet"] not in seen:
-            continue
-        at = seen.index(r["packet"])
-        if at + 1 >= n:
-            out.append(dict(r, window=seen[at + 1 - n:at + 1]))
+        m = model_of_camera[r["device_id"]]
+        window = loader.family(m["family"]).window(
+            r, reads.get(r["device_id"], []), m["sizes"])
+        if window:
+            out.append(dict(r, window=window))
     return out
 
 
@@ -124,22 +132,22 @@ def draw_sample(results: list, per_model: int, seed: int) -> list:
     return out
 
 
-def reference_logits(sample: list, cams: list, seed: int, model_of: dict,
-                     weights: dict, load_reference, quant: str = "") -> list:
-    """One float32 logit row per sampled result, in order."""
+def reference_rows(sample: list, cams: list, seed: int, model_of: dict,
+                   weights: dict, load_reference, quant: str = "") -> list:
+    """The float32 reference's output for each sampled result, in order
+    (for a classifier one logit row)."""
     import jax
 
     by_id = {c[1]: c for c in cams}
     rows = [None] * len(sample)
     for model in sorted({r["model"] for r in sample}):
         m = model_of[model]
-        ref = load_reference(m["reference"])
-        fwd = ref.jitted(m["family"], tuple(sorted(m["sizes"].items())),
-                         quant)
-        n_frames = m["sizes"].get("num_frames", 0) \
-            if m["family"] == "videomae" else 0
+        fam = loader.family(m["family"])
+        fwd = load_reference(m["reference"]).jitted(
+            m["family"], loader.frozen(m["sizes"]), quant)
         idx = [i for i, r in enumerate(sample) if r["model"] == model]
-        block = CLIP_BLOCK if n_frames else FRAME_BLOCK
+        block = fam.REFERENCE_BLOCK
+        frames = max(len(sample[i]["window"]) for i in idx)
         # one host buffer a model, written in place block after block (one
         # compiled shape; rows past the last block's end keep old frames):
         # fresh pages cost this host ~1 s a GB, and 32 clips are 1.6 GB
@@ -150,14 +158,14 @@ def reference_logits(sample: list, cams: list, seed: int, model_of: dict,
                 r = sample[i]
                 cam, _, _, h, w, _ = by_id[r["device_id"]]
                 if buf is None:
-                    buf = np.zeros((block, max(n_frames, 1), h, w, 3),
-                                   np.uint8)
+                    buf = np.zeros((block, frames, h, w, 3), np.uint8)
                 for t, j in enumerate(r["window"]):
                     traffic_mod.fill_frame(buf[row, t], seed, cam, j)
-            batch = buf if n_frames else buf[:, 0]
-            out = np.asarray(jax.device_get(fwd(weights[model], batch)))
+            out = jax.device_get(
+                fwd(weights[model], *fam.reference_args(
+                    buf, [sample[i]["window"] for i in part], m["sizes"])))
             for j, i in enumerate(part):
-                rows[i] = out[j]
+                rows[i] = jax.tree_util.tree_map(lambda a: a[j], out)
     return rows
 
 
@@ -174,34 +182,15 @@ def topk(logits: np.ndarray, k: int = 5) -> list:
     return [(int(i), float(np.exp(lp[i]))) for i in ids]
 
 
-def compare(served_tops: list, ref_rows: list, models: list) -> dict:
-    """Per model, from served top-k lists and reference rows:
-    ``logprob_err_<model>`` the widest and ``logprob_mean_<model>`` the mean
-    |log(served probability) - reference log-softmax| over the top-5 of that
-    model's sampled results. (``top1_gap``, the widest gap by which a served
-    top-1's reference logit lies below the reference's best, is returned too
-    but carries no limit: it is 0 unless two classes tie within the rounding,
-    and the float8 control reads as low as sound runs do; PERF.md.)"""
-    out, gap = {}, 0.0
+def compare(served: list, rows: list, models: list, model_of: dict) -> dict:
+    """The named numbers of every model in ``models`` (one name a sampled
+    result): its family's ``compare`` over what was kept of that model's
+    results and the reference's rows for them."""
+    out = {}
     for model in sorted(set(models)):
-        errs = []
-        for top, row, m in zip(served_tops, ref_rows, models):
-            if m != model:
-                continue
-            lp = log_softmax(row)
-            if not top:
-                errs.append(1e30)
-                continue
-            if 0 <= top[0][0] < len(row):
-                gap = max(gap, float(row.max() - row[top[0][0]]))
-            for cid, p in top:
-                if not 0 <= cid < len(row) or not p > 0:
-                    errs.append(1e30)
-                else:
-                    errs.append(abs(float(np.log(p)) - float(lp[cid])))
-        out[f"logprob_err_{model}"] = max(errs)
-        out[f"logprob_mean_{model}"] = float(np.mean(errs))
-    out["top1_gap"] = gap
+        mine = [i for i, name in enumerate(models) if name == model]
+        out.update(loader.family(model_of[model]["family"]).compare(
+            [served[i] for i in mine], [rows[i] for i in mine], model))
     return out
 
 

@@ -1,13 +1,14 @@
 """Find a cell's files by the names in ``BENCHMARK.json``.
 
-Nothing here knows a particular cell, configuration, traffic mix or metric:
-a later PR adds ``configs/<name>.json``, ``traffic/<name>.json``,
-``layer_metrics/<metric>.py`` and the entries that name them, and edits no
-file that is there.
+Nothing here knows a particular cell, configuration, traffic mix, metric or
+model family: a later PR adds ``configs/<name>.json``, ``traffic/<name>.json``,
+``layer_metrics/<metric>.py``, ``families/<name>.py``, ``reference/<name>.py``
+and the entries that name them, and edits no file that is there.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -64,27 +65,45 @@ def cell(workload: str, bench: dict | None = None) -> dict:
 
 
 def models(config: dict) -> list:
-    """[{registry_model, family, sizes, head_std}], the default first. A
+    """[{registry_model, family, reference, sizes}], the default first. A
     configuration's top level describes its default model; ``extra_models``
-    names nested groups that describe the others."""
+    names nested groups that describe the others. ``sizes`` holds the
+    group's scalars and, of its lists and nested groups, those the family
+    names in ``STRUCTURED_SIZES`` (a layer pattern, rope parameters)."""
     out = []
     for group in [config] + [config[k] for k in
                              config.get("extra_models", [])]:
+        structured = family(group["family"]).STRUCTURED_SIZES
         out.append({
             "registry_model": group["registry_model"],
             "family": group["family"],
             "reference": group.get("reference", config.get("reference")),
             "sizes": {k: v for k, v in group.items()
-                      if isinstance(v, (int, float, str, bool))},
+                      if isinstance(v, (int, float, str, bool))
+                      or k in structured},
         })
     return out
 
 
-def clip_len(model: dict) -> int:
-    """Frames one sample of ``model`` is made of (0: a single frame)."""
-    if model["family"] != "videomae":
-        return 0
-    return int(model["sizes"]["num_frames"])
+def frozen(value):
+    """``value`` with every list a tuple and every dict a sorted tuple of
+    items, so that a configuration's sizes can key a cache."""
+    if isinstance(value, dict):
+        return tuple(sorted((k, frozen(v)) for k, v in value.items()))
+    if isinstance(value, (list, tuple)):
+        return tuple(frozen(v) for v in value)
+    return value
+
+
+@functools.lru_cache(maxsize=None)
+def family(name: str):
+    """What the harness knows of one model family (``families/<name>.py``):
+    its weights, its sizes against the program, its template, its sample
+    and window, its operations, what of a result is compared and how.
+    ``families/vit.py`` lists the answers a family gives."""
+    return _module(os.path.join(HERE, "families", name + ".py"),
+                   "vbench_family_" + "".join(
+                       c if c.isalnum() else "_" for c in name))
 
 
 def reference(name: str):

@@ -7,12 +7,10 @@ program through :func:`as_variables`, which only re-shapes it into the
 nested tree the program's model expects (and refuses a tree whose names or
 shapes differ — the configuration file would then not be what is run).
 
-Distribution (the benchmark's choice; the program's own initialiser leaves
-LayerNorm at identity and every bias at zero, which would let a dropped
-bias or scale pass): matrices N(0, 1/fan_in), biases N(0, 0.05²),
-LayerNorm scale 1 + N(0, 0.1²), position table and class token N(0, 0.1²),
-classifier N(0, head_std²/fan_in) so that logits spread by about
-``head_std`` and the top classes are distinct.
+Which tensors a model has, and how each is spread, is its family's answer
+(``families/<name>.py``: ``param_spec`` lists them in a fixed order,
+``spread`` gives each kind its mean and standard deviation). Every tensor
+is ``mean + std * z`` of one normal draw.
 """
 
 from __future__ import annotations
@@ -21,46 +19,7 @@ import functools
 
 import numpy as np
 
-
-def param_spec(family: str, cfg: dict) -> list:
-    """[(name, shape, kind, fan_in)] in a fixed order."""
-    d, m = cfg["hidden_size"], cfg["intermediate_size"]
-    ps, g = cfg["patch_size"], cfg["image_size"] // cfg["patch_size"]
-    out = []
-
-    def dense(name, fan_in, fan_out, kind="matrix", shape=None):
-        out.append((f"{name}/kernel", shape or (fan_in, fan_out), kind,
-                    fan_in))
-        out.append((f"{name}/bias", (fan_out,), "bias", 0))
-
-    def norm(name):
-        out.append((f"{name}/scale", (d,), "ln_scale", 0))
-        out.append((f"{name}/bias", (d,), "bias", 0))
-
-    if family == "vit":
-        dense("patch_embed", ps * ps * 3, d, shape=(ps, ps, 3, d))
-        out.append(("cls_token", (1, 1, d), "table", 0))
-        out.append(("pos_embed", (1, g * g + 1, d), "table", 0))
-        head = "classifier"
-    elif family == "videomae":
-        ts = cfg["tubelet_size"]
-        dense("tubelet/proj", ts * ps * ps * 3, d, shape=(ts, ps, ps, 3, d))
-        out.append(("pos_embed",
-                    (1, (cfg["num_frames"] // ts) * g * g, d), "table", 0))
-        head = "head"
-    else:
-        raise ValueError(f"unknown model family {family!r}")
-    for i in range(cfg["num_hidden_layers"]):
-        b = f"encoder/block{i}"
-        norm(f"{b}/ln1")
-        dense(f"{b}/attn/qkv", d, 3 * d)
-        dense(f"{b}/attn/out", d, d)
-        norm(f"{b}/ln2")
-        dense(f"{b}/mlp/fc1", d, m)
-        dense(f"{b}/mlp/fc2", m, d)
-    norm("encoder/ln_final")
-    dense(head, d, cfg["num_labels"], kind="head")
-    return out
+from . import loader
 
 
 def seed_key(seed: int, salt: int = 0):
@@ -75,11 +34,12 @@ def seed_key(seed: int, salt: int = 0):
 
 
 @functools.lru_cache(maxsize=None)
-def _generator(spec: tuple, head_std: float):
+def _generator(tensors: tuple):
+    """``tensors``: ((name, shape, mean, std), ...) in the draw's order."""
     import jax
     import jax.numpy as jnp
 
-    sizes = [int(np.prod(shape)) for _, shape, _, _ in spec]
+    sizes = [int(np.prod(shape)) for _, shape, _, _ in tensors]
 
     def gen(key):
         # one draw for the whole model, cut into the tensors: a draw per
@@ -87,21 +47,11 @@ def _generator(spec: tuple, head_std: float):
         # ~3 s to load from the compile cache in every run
         z_all = jax.random.normal(key, (sum(sizes),), jnp.float32)
         flat, at = {}, 0
-        for (name, shape, kind, fan_in), n in zip(spec, sizes):
-            z = z_all[at:at + n].reshape(shape)
+        for (name, shape, mean, std), n in zip(tensors, sizes):
+            z = z_all[at:at + n].reshape(shape) * np.float32(std)
             at += n
-            if kind == "matrix":
-                flat[name] = z * np.float32(fan_in ** -0.5)
-            elif kind == "head":
-                flat[name] = z * np.float32(head_std * fan_in ** -0.5)
-            elif kind == "bias":
-                flat[name] = z * np.float32(0.05)
-            elif kind == "ln_scale":
-                flat[name] = 1.0 + z * np.float32(0.1)
-            elif kind == "table":
-                flat[name] = z * np.float32(0.1)
-            else:
-                raise ValueError(kind)
+            # no add where the mean is 0: 0.0 + z would turn a -0.0 into 0.0
+            flat[name] = mean + z if mean else z
         return flat
 
     return jax.jit(gen)
@@ -109,9 +59,10 @@ def _generator(spec: tuple, head_std: float):
 
 def generate(seed: int, family: str, cfg: dict, salt: int = 0) -> dict:
     """Flat float32 weights on the default device, one jitted call."""
-    spec = tuple(param_spec(family, cfg))
-    return _generator(spec, float(cfg.get("head_std", 3.0)))(
-        seed_key(seed, salt))
+    fam = loader.family(family)
+    tensors = tuple((name, tuple(shape)) + tuple(fam.spread(kind, fan_in, cfg))
+                    for name, shape, kind, fan_in in fam.param_spec(cfg))
+    return _generator(tensors)(seed_key(seed, salt))
 
 
 def as_variables(flat: dict, template) -> dict:
