@@ -259,6 +259,54 @@ def kernels_phase(seed: int) -> None:
               f"flash T={t}: outside the stated bf16 tolerance")
 
 
+def stream_head_phase(seed: int) -> None:
+    """The ``stream`` step kind on the chip at the tiny twin's size: two
+    rounds of two streams through ``build_serving_step`` and a
+    ``StreamStatePool`` (state donated, gathered and scattered by slot
+    inside the program), the second continuing the first's state."""
+    import jax
+    import jax.numpy as jnp
+
+    from video_edge_ai_proxy_tpu.engine.runner import build_serving_step
+    from video_edge_ai_proxy_tpu.engine.stream_state import StreamStatePool
+    from video_edge_ai_proxy_tpu.models import registry
+
+    spec = registry.get("tiny_videomae_lfm2")
+    model, variables = spec.init_params(jax.random.PRNGKey(seed))
+    variables = spec.prepare(model, variables)
+    c = model.cfg
+    step = jax.jit(build_serving_step(model, spec), donate_argnums=(2,))
+    pool = StreamStatePool(model, grow=4)
+    rng = np.random.default_rng(seed)
+    frames = rng.integers(0, 255, (4, spec.clip_len, 96, 128, 3), np.uint8)
+    n_i = len(c.instruction_ids)
+    for r in (1, 2):
+        plan = pool.plan(["a", "b"], 4)
+        out = dict(step(variables, frames, pool.state, plan["idx"],
+                        plan["pos0"], plan["reset"], plan["rounds"]))
+        pool.state = out.pop("state")
+        host = {k: np.asarray(v) for k, v in out.items()}
+        check(np.isfinite(host["top_probs"]).all()
+              and (host["top_probs"] > 0).all(),
+              f"stream head round {r}: non-finite or zero probabilities")
+        check(host["positions"][:2].tolist()
+              == (plan["pos0"][:2] + c.round_positions).tolist()
+              and (plan["pos0"][:2] >= n_i).all(),
+              f"stream head round {r}: positions {host['positions']}")
+        check((host["history"][:2, :c.decode_steps * int(host["rounds"][0])]
+               >= 0).all(), f"stream head round {r}: token history has holes")
+        check(int(host["moe_load"].sum()) > 0,
+              f"stream head round {r}: no routed pair on the held experts")
+    held = pool.nbytes()
+    check(held == sum(int(a.nbytes)
+                      for a in jax.tree_util.tree_leaves(pool.state)),
+          "stream head: pool bytes differ from its buffers'")
+    say(f"stream head: 2 rounds x 2 streams through the state pool "
+        f"({held} B on {sorted(str(d) for d in pool.state['conv'].devices())}"
+        f"), {int(host['moe_load'].sum())} routed pairs on the held experts "
+        f"in the last round, tokens {host['tokens'][0].tolist()}")
+
+
 # ---------------------------------------------------------------------------
 # phase 3: the server
 
@@ -833,6 +881,7 @@ def main(argv=None) -> int:
         facts = four_chip_phase(args.seed)
     else:
         kernels_phase(args.seed)
+        stream_head_phase(args.seed)
         facts = server_phase(args.seed)
     counts = cache.snapshot()
     say(f"cache: {counts} in "

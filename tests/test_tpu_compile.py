@@ -172,3 +172,98 @@ def test_whole_yolov8n_step_16x1080p_has_pallas_nms_inside(v5e, monkeypatch):
         variables, frames, thumbs).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+class TestStreamHeadLayers:
+    """The streaming head's expert layer and cached attention at the
+    published widths (models/lfm2.py), compiled INSIDE a loop, as the
+    serving step runs them (prefill chunks, decode steps): the TPU
+    compiler refused a scatter there (``bincount``, ``.at[].set``, a
+    batched slice update) that it takes outside a loop."""
+
+    def test_expert_layer_in_a_loop_is_a_grouped_matmul(self, v5e):
+        import flax.linen as nn
+
+        from video_edge_ai_proxy_tpu.models.transformer import (
+            TopKMoeConfig, TopKMoeMlp,
+        )
+
+        layer = TopKMoeMlp(TopKMoeConfig(
+            dim=2048, mlp_dim=1536, num_experts=64, top_k=4,
+            experts_held=tuple(range(16))))
+        shapes = jax.eval_shape(lambda: nn.meta.unbox(layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((8, 2048), jnp.bfloat16))))
+        variables = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            shapes)
+
+        def twice(variables, x):
+            def body(_, carry):
+                x, load = carry
+                y, n = layer.apply(variables, x)
+                return x + y, load + n
+            return jax.lax.fori_loop(
+                0, 2, body, (x, jnp.zeros((16,), jnp.int32)))
+
+        x = jax.ShapeDtypeStruct((6272, 2048), jnp.bfloat16, sharding=v5e)
+        text = _compiled_text(twice, variables, x)
+        # XLA's own grouped-matmul lowering of ragged_dot
+        assert "ragged-dot" in text and "tpu_custom_call" in text
+
+    @pytest.mark.parametrize("t", [784, 1], ids=["prefill", "decode"])
+    def test_cached_attention_in_a_loop(self, v5e, t):
+        import flax.linen as nn
+
+        from video_edge_ai_proxy_tpu.models import lfm2
+
+        cfg = lfm2.Lfm2Config()
+        attn = lfm2.CachedAttention(cfg)
+        b, r = 8, 792
+        on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+            a.shape, a.dtype, sharding=v5e)
+        pool = jax.tree_util.tree_map(
+            on_chip, jax.eval_shape(lambda: lfm2.empty_state(cfg, b)[1]))
+        rbuf = tuple(on_chip(a) for a in jax.eval_shape(
+            lambda: tuple(a[0] for a in lfm2.round_buffer(cfg, b, r))))
+        h = jax.ShapeDtypeStruct((b, t, cfg.dim), jnp.bfloat16, sharding=v5e)
+        ints = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e)
+        variables = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+            lambda: nn.meta.unbox(attn.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 1, cfg.dim),
+                                                 jnp.bfloat16),
+                lfm2.empty_state(cfg, 1, 8)[1],
+                tuple(a[0] for a in lfm2.round_buffer(cfg, 1, 4)),
+                jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32), 0))))
+
+        def twice(variables, h, pool, rbuf, slots, pos0):
+            def body(i, carry):
+                h, rbuf = carry
+                y, rbuf = attn.apply(variables, h, pool, rbuf, slots, pos0,
+                                     0 if t > 1 else 784 + i)
+                return h + y, rbuf
+            return jax.lax.fori_loop(0, 2, body, (h, rbuf))
+
+        assert "while" in _compiled_text(
+            twice, variables, h, pool, rbuf, ints, ints)
+
+    def test_flush_writes_the_donated_pool_in_place(self, v5e):
+        """The round's keys and values go into the pool by a loop of slice
+        updates: with the pool donated, no copy of it is compiled."""
+        from video_edge_ai_proxy_tpu.models import lfm2
+
+        cfg = lfm2.Lfm2Config()
+        b = 8
+        on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+            a.shape, a.dtype, sharding=v5e)
+        pool = jax.tree_util.tree_map(
+            on_chip, jax.eval_shape(lambda: lfm2.empty_state(cfg, b)[1]))
+        rbuf = jax.tree_util.tree_map(
+            on_chip, jax.eval_shape(lambda: lfm2.round_buffer(cfg, b, 792)))
+        ints = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e)
+        text = jax.jit(lfm2.flush_round, donate_argnums=(0,)).lower(
+            pool, rbuf, ints, ints).compile().as_text()
+        assert "dynamic-update-slice" in text and "while" in text
+        whole = "bf16[%d,%d,%d,%d,%d]" % pool[0].shape
+        assert not [line for line in text.splitlines()
+                    if " copy(" in line and line.split("=")[1].strip()
+                    .startswith(whole)]

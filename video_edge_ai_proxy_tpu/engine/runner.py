@@ -103,7 +103,21 @@ def build_serving_step(model, spec, *, quality_thumb: int = 0, mesh=None):
     NMS inside, does not compile for a real mesh. A mesh that also shards
     the model (tp/fsdp/sp/ep > 1) keeps the compiler's partitioning: it
     serves the transformer families, which carry no such kernel below
-    ``FLASH_THRESHOLD_T`` tokens."""
+    ``FLASH_THRESHOLD_T`` tokens.
+
+    Step kind ``stream`` (``spec.kind == "stream"``, models/lfm2.py): a
+    step with state in and state out,
+    ``stream_step(variables, frames, state, idx, pos0, reset, rounds)``.
+    ``state`` is the model's ``StreamStatePool`` buffers (``conv`` and
+    ``tokens`` [slots, ...], ``kv`` = (keys, values) [attention layers,
+    slots, ...]; the caller donates them), ``idx``
+    [bucket] the slot of each batch row, ``pos0`` where its visual tokens
+    start, ``reset`` which rows start a new context, ``rounds`` the rounds
+    since each row's reset (engine/stream_state.py ``plan``). Encoder,
+    connector, prefill and the D decode steps are ONE program a batch; the
+    output carries ``state`` (the same buffers, rewritten in place), which
+    the dispatcher takes back before the drain fetches the rest. One chip
+    only: experts over a real ``ep`` mesh are PERF.md's open question."""
     import jax
 
     if mesh is not None and all(
@@ -113,6 +127,11 @@ def build_serving_step(model, spec, *, quality_thumb: int = 0, mesh=None):
             mesh)
     size = spec.input_size
 
+    if spec.kind == "stream":
+        if mesh is not None:
+            raise ValueError(
+                f"model {spec.name!r}: a stream head serves on one chip")
+        return _build_stream_step(model, size)
     if spec.kind == "detect":
         # Stem-variant dispatch (round 15): an s2d-stem model gets the
         # fused letterbox+normalize+s2d megakernel — the 1080p uint8
@@ -182,6 +201,40 @@ def build_serving_step(model, spec, *, quality_thumb: int = 0, mesh=None):
         return out
 
     return with_stats
+
+
+def _build_stream_step(model, size: int):
+    """The ``stream`` step kind (``build_serving_step``)."""
+    import jax.numpy as jnp
+
+    steps = model.cfg.decode_steps
+
+    def stream_step(variables, frames_u8, state, idx, pos0, reset, rounds):
+        take = lambda a: jnp.take(a, idx, axis=0, mode="clip")  # noqa: E731
+        # the small buffers are gathered and scattered by slot; the
+        # key-value pool is read and written in place, by slot
+        out = model.serve_round(
+            variables, frames_u8, take(state["conv"]), state["kv"],
+            idx, pos0, reset,
+            preprocess=lambda clips: preprocess_clip(
+                clips, (size, size), out_dtype=model.dtype))
+        # the ids decoded since the reset, this round's last
+        history = jnp.where(reset[:, None], -1, take(state["tokens"]))
+        at = jnp.arange(history.shape[1])[None] // steps
+        history = jnp.where(
+            at == rounds[:, None],
+            jnp.tile(out["tokens"], (1, history.shape[1] // steps)), history)
+        put = lambda a, v: a.at[idx].set(  # noqa: E731
+            v.astype(a.dtype), mode="drop")
+        out["state"] = {"conv": put(state["conv"], out.pop("conv")),
+                        "kv": out.pop("kv"),
+                        "tokens": put(state["tokens"], history)}
+        out["history"] = history
+        out["rounds"] = rounds + 1
+        out["positions"] = pos0 + model.cfg.round_positions
+        return out
+
+    return stream_step
 
 
 def _dp_sharded(step, mesh):
@@ -417,7 +470,7 @@ class _Inflight:
 # tick that read at least one frame, plus "idle" (ticks that found nothing,
 # and the between-tick wait for frames).
 _TICK_PHASES = ("pre_collect", "read", "clip", "fill", "collect_other",
-                "place_wait", "step_call", "idle")
+                "place_wait", "pool", "state_wait", "step_call", "idle")
 
 
 class _TimedStep:
@@ -1173,6 +1226,24 @@ class InferenceEngine:
             "copied into first-touched (unpooled) buffers", ("kind",))
         self._m_cbytes = {k: cbytes.labels(k)
                           for k in ("read", "copied", "fresh")}
+        # Stream heads (step kind "stream"): tokens through the head,
+        # context resets, routed (token, expert) pairs the held experts took.
+        head_tok = obs_registry.counter(
+            "vep_head_tokens_total",
+            "Tokens through a stream head", ("kind",))
+        self._m_head_tokens = {k: head_tok.labels(k)
+                               for k in ("prefill", "decode")}
+        self._m_head_resets = obs_registry.counter(
+            "vep_head_resets_total",
+            "Stream-head contexts reset (full, first context over, new "
+            "stream)").labels()
+        self._m_moe_pairs = obs_registry.counter(
+            "vep_moe_pairs_total",
+            "Routed (token, expert) pairs computed by the held experts"
+        ).labels()
+        # model name -> StreamStatePool (engine/stream_state.py), built
+        # when a stream-head model is first dispatched or prewarmed
+        self._head_pools: Dict[str, Any] = {}
         self._tick_mark = time.perf_counter()   # end of the last closed tick
         self._assemble_s = 0.0   # assemble_until seconds since that mark
         # Recompile-storm detection state (tick loop only).
@@ -1392,6 +1463,9 @@ class InferenceEngine:
                 lambda: self._cascade.pool_nbytes()
                 if self._cascade is not None else 0)
             self.hbm.register_pool(
+                "stream_state",
+                lambda: sum(p.nbytes() for p in self._head_pools.values()))
+            self.hbm.register_pool(
                 "prefetch",
                 lambda: self._xfer.nbytes() if self._xfer is not None else 0)
             self.hbm.register_pool(
@@ -1496,6 +1570,8 @@ class InferenceEngine:
             self._spec, self._model, self._variables
         )
         self._variables = self._maybe_quantize(self._variables)
+        if self._spec.prepare is not None:
+            self._variables = self._spec.prepare(self._model, self._variables)
         buckets = tuple(self._cfg.batch_buckets)
         if self._cfg.mesh:
             # Multi-chip serving: batch axis sharded over dp; params
@@ -1778,6 +1854,8 @@ class InferenceEngine:
             model, variables = spec.init_params(jax.random.PRNGKey(0))
             variables = self._maybe_calibrate(spec, model, variables)
             variables = self._maybe_quantize(variables)
+            if spec.prepare is not None:
+                variables = spec.prepare(model, variables)
             if self._mesh is not None:
                 variables = self._place_variables(variables)
                 model = self._maybe_seq_parallel(model)
@@ -2348,6 +2426,17 @@ class InferenceEngine:
             (spec.clip_len,) if spec.clip_len else ()
         ) + tuple(src_hw) + (3,)
         args = [self._place(np.zeros(shape, np.uint8))]
+        if spec.kind == "stream":
+            # every row padded: the state is gathered clipped, nothing is
+            # scattered, and the pool's shapes are the serving ones
+            pool = self._head_pool(spec.name)
+            pool.ensure(bucket)
+            plan = pool.plan((), bucket)
+            out = self._step(src_hw, bucket, model)(
+                variables, args[0], pool.state, plan["idx"], plan["pos0"],
+                plan["reset"], plan["rounds"])
+            pool.state = out["state"]
+            return
         if self._quality_device and not spec.clip_len:
             side = self._cfg.quality_thumb
             thumbs = np.zeros((bucket, side, side), np.float32)
@@ -2357,6 +2446,17 @@ class InferenceEngine:
             args.append(self._place(thumbs) if self._mesh is not None
                         else thumbs)
         self._step(src_hw, bucket, model)(variables, *args)
+
+    def _head_pool(self, model: str):
+        """The state pool of a stream-head model (one a model)."""
+        pool = self._head_pools.get(model)
+        if pool is None:
+            from .stream_state import StreamStatePool
+
+            _, mod, _ = self._ensure_model(model)
+            pool = self._head_pools[model] = StreamStatePool(
+                mod, grow=max(self._buckets or (1,)))
+        return pool
 
     def _place(self, frames: np.ndarray):
         """Shard the batch dim over dp when serving on a mesh; pass through
@@ -2432,6 +2532,10 @@ class InferenceEngine:
                     and jax.default_backend() == "tpu"
                     and self._mesh is not None and self._mesh.size > 1):
                 donate = (1,)
+            if spec.kind == "stream":
+                # the pool's buffers (argnum 2) come back as the output's
+                # "state", same shapes: rewritten in place
+                donate += (2,)
             # Compile attribution (obs/perf.py): the wrapper AOT-compiles
             # on first call, recording wall time + XLA cost analysis per
             # (model, geometry, bucket) — this is the only cache-miss
@@ -2607,6 +2711,7 @@ class InferenceEngine:
                 # that window must not reset the stream's track-id
                 # numbering (invariant in _assign_tracks).
                 if self._trackers or self._ann_state or self._thumbs \
+                        or any(self._head_pools.values()) \
                         or (self._roi is not None and self._roi) \
                         or (self._cascade is not None and self._cascade):
                     now = time.monotonic()
@@ -2620,10 +2725,11 @@ class InferenceEngine:
                         else set()
                     casc_ids = set(self._cascade) \
                         if self._cascade is not None else set()
+                    head_ids = set().union(*self._head_pools.values())
                     with self._state_lock:
                         for d in (set(self._trackers) | set(self._ann_state)
                                   | set(self._thumbs) | roi_ids
-                                  | casc_ids):
+                                  | casc_ids | head_ids):
                             if d in present:
                                 self._tracker_absent.pop(d, None)
                                 continue
@@ -2652,6 +2758,10 @@ class InferenceEngine:
                                 # machines clear without firing.
                                 if self._cascade is not None:
                                     self._cascade.pop(d, None)
+                                # A stream head's slot frees with the
+                                # stream; a returning one starts over.
+                                for pool in self._head_pools.values():
+                                    pool.pop(d, None)
                                 if self.quality is not None:
                                     self.quality.forget(d)
                                 del self._tracker_absent[d]
@@ -2723,12 +2833,15 @@ class InferenceEngine:
             return
         place_wait_s = sum(b["place_wait_s"] for b in batches)
         step_call_s = sum(b["step_call_s"] for b in batches)
+        pool_s = sum(b.get("pool_s", 0.0) for b in batches)
+        state_wait_s = sum(b.get("state_wait_s", 0.0) for b in batches)
         for phase, seconds in (
                 ("pre_collect", tick["pre_collect_s"]),
                 ("read", tick["read_s"]), ("clip", tick["clip_s"]),
                 ("fill", tick["fill_s"]),
                 ("collect_other", tick["collect_other_s"]),
-                ("place_wait", place_wait_s), ("step_call", step_call_s),
+                ("place_wait", place_wait_s), ("pool", pool_s),
+                ("state_wait", state_wait_s), ("step_call", step_call_s),
                 ("idle", idle_s)):
             self._m_phase[phase].inc(seconds)
         for kind in ("read", "copied", "fresh"):
@@ -2757,6 +2870,18 @@ class InferenceEngine:
             tracer.record(
                 "engine.tick", "place_wait", n, ts=b["t_place_got"],
                 dur_ms=b["place_wait_s"] * 1e3, **extra)
+            if "pool_s" in b:
+                tracer.record(
+                    "engine.tick", "pool", n,
+                    ts=b["t_step0"] - b["state_wait_s"],
+                    dur_ms=b["pool_s"] * 1e3,
+                    prefill_tokens=b["head_prefill_tokens"],
+                    decode_steps=b["head_decode_steps"],
+                    ctx_mean=round(b["head_ctx_mean"], 1),
+                    resets=b["head_resets"], **extra)
+                tracer.record(
+                    "engine.tick", "state_wait", n, ts=b["t_step0"],
+                    dur_ms=b["state_wait_s"] * 1e3, **extra)
             tracer.record(
                 "engine.tick", "step_call", n, ts=b["t_step1"],
                 dur_ms=b["step_call_s"] * 1e3, **extra)
@@ -3026,7 +3151,9 @@ class InferenceEngine:
         the group), ``t_place_q``/``t_place0``/``t_placed`` (handed to the
         transfer thread, picked up, placed), ``place_wait_s`` (this
         thread's blocked time on the placement, ending at
-        ``t_place_got``), ``t_step0``/``t_step1`` and ``step_call_s``
+        ``t_place_got``), for a stream head ``pool_s`` (the state pool's
+        plan) and ``state_wait_s`` (blocked on the predecessor step, whose
+        state this one takes), ``t_step0``/``t_step1`` and ``step_call_s``
         (around the step call; a compile shows here), ``t_submit``. The
         drain thread adds ``t_deq``, ``t_drain0``, ``t_drained``.
 
@@ -3139,8 +3266,53 @@ class InferenceEngine:
                     group.model or self._spec.name, group.bucket,
                     group.nbytes + aux_nbytes, h2d_s, hidden_s=hidden_s,
                 )
+                head = None
+                name = group.model or self._spec.name
+                if self._models[name][0].kind == "stream":
+                    # slots, reset and index vectors: the only per-stream
+                    # host work a stream head adds to the tick thread
+                    pc_pool0 = time.perf_counter()
+                    pool = self._head_pool(name)
+                    head = pool.plan(group.device_ids, group.bucket,
+                                     rows=group.rows)
+                    real = head["idx"] < pool.capacity
+                    tr.update(
+                        head_prefill_tokens=int(real.sum())
+                        * pool.cfg.visual_tokens,
+                        head_decode_steps=pool.cfg.decode_steps,
+                        head_ctx_mean=float(
+                            head["pos0"][real].mean()
+                            + pool.cfg.round_positions) if real.any()
+                        else 0.0,
+                        head_resets=int(head["reset"][real].sum()),
+                        pool_s=time.perf_counter() - pc_pool0)
+                    # One stream step on the device at a time: this one
+                    # takes the last one's state anyway, and two launched
+                    # together hold their temporaries (GBs) together.
+                    # Where the device sets the pace this wait is most of
+                    # a round; it is no part of the step call.
+                    pc_wait0 = time.perf_counter()
+                    pool.wait()
+                    tr["state_wait_s"] = time.perf_counter() - pc_wait0
                 tr["t_step0"], pc_step0 = time.time(), time.perf_counter()
-                if idx is not None:
+                if head is not None:
+                    try:
+                        outputs = dict(step(
+                            variables, placed, pool.state, head["idx"],
+                            head["pos0"], head["reset"], head["rounds"]))
+                    except Exception:
+                        # the buffers were donated and the host's
+                        # bookkeeping ran ahead of the device: every
+                        # stream of this model starts over in a new pool
+                        self._head_pools.pop(name, None)
+                        raise
+                    pool.state = outputs.pop("state")
+                    self._m_head_tokens["prefill"].inc(
+                        tr["head_prefill_tokens"])
+                    self._m_head_tokens["decode"].inc(
+                        int(real.sum()) * pool.cfg.decode_steps)
+                    self._m_head_resets.inc(tr["head_resets"])
+                elif idx is not None:
                     # Quality-carrying step (3-arg): previous-tick
                     # thumbnails arrive as a device-side gather from the
                     # resident pool (no host rows cross); this tick's
@@ -3822,6 +3994,14 @@ class InferenceEngine:
         t_drained = time.time()
         inflight.tr.update(t_drain0=t_drain0, t_drained=t_drained)
         inflight.tr.setdefault("t_deq", t_drain0)   # _emit called directly
+        if "moe_load" in host:
+            # stream head: the step's own count of the routed pairs each
+            # held expert took (prefill and decode of this batch)
+            load = host.pop("moe_load")
+            inflight.tr.update(moe_pairs_local=int(load.sum()),
+                               moe_load_max=int(load.max()),
+                               moe_load_mean=float(load.mean()))
+            self._m_moe_pairs.inc(int(load.sum()))
         # submit -> outputs on the host: drain-queue wait + device + fetch
         device_ms = (t_drained - inflight.t_submit) * 1000.0
         if self.faults is not None:
@@ -3971,6 +4151,8 @@ class InferenceEngine:
             trace_id=meta.trace_id,
             parent_span=meta.parent_span,
         )
+        if spec.kind == "stream":
+            self._fill_head(result.head, host, row)
         if self.faults is not None:
             # (packet, timestamp_ms): monotone per stream even for
             # producers that never stamp packet ids (ledger dup/rebase
@@ -4429,6 +4611,12 @@ class InferenceEngine:
                     class_id=cid,
                     class_name=class_name(cid, self._num_classes(spec)),
                 ))
+        elif spec.kind == "stream":
+            # this round's tokens as (id, probability of the greedy pick);
+            # the whole answer is the result's ``head``
+            for p, cid in zip(host["top_probs"][i, :, 0], host["tokens"][i]):
+                out.append(pb.Detection(confidence=float(p),
+                                        class_id=int(cid)))
         elif spec.kind == "embed":
             out.append(pb.Detection(
                 confidence=1.0, class_id=-1,
@@ -4441,6 +4629,18 @@ class InferenceEngine:
                     class_name=class_name(int(cid), self._num_classes(spec)),
                 ))
         return out
+
+    @staticmethod
+    def _fill_head(head, host: dict, i: int) -> None:
+        """A stream head's answer for batch row ``i``: the ids decoded
+        since the stream's reset (this round's last), the top-5 of each of
+        this round's steps, and where the state stands."""
+        head.token_ids.extend(int(t) for t in host["history"][i] if t >= 0)
+        for ids, probs in zip(host["top_ids"][i], host["top_probs"][i]):
+            head.steps.add(token_ids=[int(t) for t in ids],
+                           probs=[float(p) for p in probs])
+        head.rounds_since_reset = int(host["rounds"][i])
+        head.positions = int(host["positions"][i])
 
     def _num_classes(self, spec=None) -> int:
         spec = spec or self._spec

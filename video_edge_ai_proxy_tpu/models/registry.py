@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from .blob import BlobGauge, BlobGaugeConfig
+from .lfm2 import (StreamHeadConfig, VideoMAELfm2, prepare_for_serving,
+                   tiny_stream_head_config)
 from .mobilenet_v2 import MobileNetV2, MobileNetV2Config, tiny_mobilenet_v2_config
 from .resnet import ResNet, ResNetConfig, tiny_resnet_config
 from .videomae import VideoMAE, VideoMAEConfig, tiny_videomae_config
@@ -30,9 +32,13 @@ class ModelSpec:
     build: Callable[[], Any]              # () -> nn.Module
     input_size: int                       # square side the model consumes
     preprocess: str                       # "classify" | "letterbox" | "clip"
-    kind: str                             # "classify" | "detect" | "embed" | "video"
+    kind: str                             # "classify" | "detect" | "embed" | "video" | "stream"
     clip_len: int = 0                     # >0 for video models
     description: str = ""
+    # (module, variables) -> variables, applied once when the engine takes
+    # the model (a stream head's weights are cast to bfloat16 here and its
+    # instruction's state is computed)
+    prepare: Optional[Callable[[Any, Any], Any]] = None
 
     def init_params(self, rng: Optional[jax.Array] = None, batch: int = 1):
         rng = rng if rng is not None else jax.random.PRNGKey(0)
@@ -119,6 +125,18 @@ register(ModelSpec(
                 "auto-dispatches to the Pallas flash kernel",
 ))
 
+register(ModelSpec(
+    "videomae_b_lfm2", lambda: VideoMAELfm2(StreamHeadConfig()),
+    input_size=224, preprocess="clip", kind="stream", clip_len=8,
+    prepare=prepare_for_serving,
+    description="streaming video-language head (models/lfm2.py): VideoMAE-B "
+                "encoder -> connector -> LFM2-MoE decoder at the published "
+                "widths, one chip's share (9 layers, 16 of 64 experts a "
+                "layer); per-stream conv state and key-value cache in "
+                "engine/stream_state.py; 784 tokens prefilled and 8 decoded "
+                "a stream a round",
+))
+
 # --- diagnostic gauges ----------------------------------------------------
 
 register(ModelSpec(
@@ -164,4 +182,13 @@ register(ModelSpec(
 register(ModelSpec(
     "tiny_videomae", lambda: VideoMAE(tiny_videomae_config()),
     input_size=32, preprocess="clip", kind="video", clip_len=4,
+))
+register(ModelSpec(
+    "tiny_videomae_lfm2",
+    lambda: VideoMAELfm2(tiny_stream_head_config(), dtype=jnp.float32),
+    input_size=32, preprocess="clip", kind="stream", clip_len=4,
+    prepare=prepare_for_serving,
+    description="CPU/CI twin of videomae_b_lfm2 (tests/test_stream_head.py), "
+                "in float32 throughout (no cast at load), so that a test "
+                "tells a broken state from rounding",
 ))

@@ -20,6 +20,7 @@ from typing import Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .common import Dtype
 
@@ -116,23 +117,27 @@ class Mlp(nn.Module):
         return _dense(c.dim, ("mlp", "embed"), self.dtype, "fc2")(h)
 
 
-def _expert_weights(mod: nn.Module, cfg: EncoderConfig):
-    """The [E, d, mlp] / [E, mlp, d] expert stacks, shared by both MoE
-    variants (one definition of the 'expert' logical sharding axis)."""
-    w1 = mod.param(
-        "w1",
-        nn.with_logical_partitioning(
-            nn.initializers.xavier_uniform(), ("expert", "embed", "mlp")
-        ),
-        (cfg.num_experts, cfg.dim, cfg.mlp_dim), jnp.float32,
-    )
-    w2 = mod.param(
-        "w2",
-        nn.with_logical_partitioning(
-            nn.initializers.xavier_uniform(), ("expert", "mlp", "embed")
-        ),
-        (cfg.num_experts, cfg.mlp_dim, cfg.dim), jnp.float32,
-    )
+def _expert_weights(mod: nn.Module, cfg, *, stack: int = 0,
+                    gated: bool = False):
+    """The [E, d, mlp] / [E, mlp, d] expert stacks, shared by every MoE
+    variant (one definition of the 'expert' logical sharding axis).
+    ``stack`` is how many experts this holder keeps (default: all
+    ``cfg.num_experts``); ``gated`` adds the SwiGLU up-projection ``w3``
+    beside ``w1`` and returns (w1, w3, w2)."""
+    e = stack or cfg.num_experts
+
+    def one(name, axes, shape):
+        return mod.param(
+            name,
+            nn.with_logical_partitioning(
+                nn.initializers.xavier_uniform(), ("expert",) + axes),
+            (e,) + shape, jnp.float32,
+        )
+
+    w1 = one("w1", ("embed", "mlp"), (cfg.dim, cfg.mlp_dim))
+    w2 = one("w2", ("mlp", "embed"), (cfg.mlp_dim, cfg.dim))
+    if gated:
+        return w1, one("w3", ("embed", "mlp"), (cfg.dim, cfg.mlp_dim)), w2
     return w1, w2
 
 
@@ -228,6 +233,103 @@ class RoutedMoeMlp(nn.Module):
         prob = gates.mean(axis=0)
         self.sow("losses", "moe_aux", e * jnp.sum(frac * prob))
         return out.reshape(b, t, d)
+
+
+@dataclass(frozen=True)
+class TopKMoeConfig:
+    """A routed SwiGLU expert layer as ``lfm2_moe`` publishes it: the
+    router scores ALL ``num_experts``; this holder computes the experts in
+    ``experts_held`` (their ids among the ``num_experts``; empty = all)."""
+    dim: int
+    mlp_dim: int
+    num_experts: int
+    top_k: int
+    experts_held: tuple = ()
+    use_expert_bias: bool = True
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+
+    @property
+    def held(self) -> tuple:
+        return tuple(self.experts_held) or tuple(range(self.num_experts))
+
+
+def topk_route(scores: jnp.ndarray, bias, cfg: TopKMoeConfig):
+    """([N, k] expert ids, [N, k] weights) from [N, E] router scores
+    (sigmoid already applied): the top-k of ``scores + bias``, weighted by
+    the unbiased scores of the chosen, renormalised to sum 1."""
+    pick = scores + bias if bias is not None else scores
+    _, sel = jax.lax.top_k(pick, cfg.top_k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    if cfg.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return sel, w * cfg.routed_scaling_factor
+
+
+class TopKMoeMlp(nn.Module):
+    """Dropless top-k routed SwiGLU experts, told which experts it holds.
+
+    The sibling of :class:`RoutedMoeMlp` (top-1, static capacity, drops)
+    for serving a published router: every token's ``top_k`` experts are
+    chosen over all ``num_experts``; the (token, expert) pairs whose expert
+    is held here are sorted by expert and run through three grouped matmuls
+    (``jax.lax.ragged_dot``: XLA's own grouped-matmul lowering on TPU, a
+    masked dense product elsewhere), whatever the load of each expert: no
+    capacity, no dropped token, all tokens to one expert included. Pairs
+    whose expert lives on another holder add nothing here: the caller sums
+    the holders' partial outputs (on one chip of a deployment, nothing
+    stands in for the others). The stacks hold ``len(experts_held)``
+    experts and carry the "expert" logical axis (ep sharding).
+
+    Returns ``(y [N, d], load [held])``: the pairs each held expert took.
+    """
+
+    cfg: TopKMoeConfig
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        c = self.cfg
+        held = c.held
+        n, d = x.shape
+        k, h = c.top_k, len(held)
+        gate = self.param(
+            "gate",
+            nn.with_logical_partitioning(
+                nn.initializers.xavier_uniform(), ("embed", "expert_gate")),
+            (d, c.num_experts), jnp.float32)
+        bias = self.param(
+            "expert_bias", nn.initializers.zeros_init(),
+            (c.num_experts,), jnp.float32) if c.use_expert_bias else None
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), gate.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        sel, w = topk_route(scores, bias, c)                    # [N, k]
+
+        # global expert id -> row of this holder's stack, h = elsewhere
+        # (built on the host, and counted by comparison: inside a loop the
+        # TPU compiler refuses the scatters ``.at[].set`` and ``bincount``
+        # would be)
+        lut = np.full((c.num_experts,), h, np.int32)
+        lut[list(held)] = np.arange(h, dtype=np.int32)
+        key = jnp.asarray(lut)[sel].reshape(n * k)
+        order = jnp.argsort(key, stable=True)                   # local first
+        sizes = jnp.sum(key[:, None] == jnp.arange(h, dtype=jnp.int32)[None],
+                        axis=0, dtype=jnp.int32)
+        here = jnp.arange(n * k) < jnp.sum(sizes)               # sorted rows
+
+        w1, w3, w2 = (a.astype(self.dtype) for a in _expert_weights(
+            self, c, stack=h, gated=True))
+        xs = jnp.take(x.astype(self.dtype), order // k, axis=0)  # [N*k, d]
+        up = jax.lax.ragged_dot(xs, w3, sizes)
+        act = nn.silu(jax.lax.ragged_dot(xs, w1, sizes)) * up
+        ys = jax.lax.ragged_dot(act, w2, sizes,
+                                preferred_element_type=jnp.float32)
+        # rows past the last group are whatever the kernel left there
+        ys = jnp.where(here[:, None], ys, 0.0)
+        back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(n, k, d)
+        y = jnp.einsum("nkd,nk->nd", back, w.astype(jnp.float32))
+        return y.astype(self.dtype), sizes
 
 
 class EncoderBlock(nn.Module):

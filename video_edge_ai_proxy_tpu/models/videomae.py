@@ -110,10 +110,16 @@ class VideoMAE(nn.Module):
         self.encoder = Encoder(c.encoder, self.dtype, self.attn_fn, name="encoder")
         self.head = nn.Dense(c.num_classes, dtype=jnp.float32, name="head")
 
+    def features(self, clips: jnp.ndarray, train: bool = False) -> jnp.ndarray:
+        """[B, T, H, W, 3] -> [B, tokens, dim]: the encoder's final-norm
+        output, every tubelet token (what a head behind the encoder takes;
+        the classifier's weights are not touched)."""
+        x = self.embed(clips) + self.pos_embed.astype(self.dtype)
+        return self.encoder(x, deterministic=not train)
+
     def __call__(self, clips: jnp.ndarray, train: bool = False) -> jnp.ndarray:
         """Fine-tune / inference path: [B, T, H, W, 3] -> [B, num_classes]."""
-        x = self.embed(clips) + self.pos_embed.astype(self.dtype)
-        x = self.encoder(x, deterministic=not train)
+        x = self.features(clips, train)
         with jax.named_scope("head"):
             return self.head(jnp.mean(x.astype(jnp.float32), axis=1))
 

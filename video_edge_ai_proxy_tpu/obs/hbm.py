@@ -27,7 +27,9 @@ Three tiers, one object (``HbmTracker``, engine-owned like
   sum of every workspace.
 - **Dynamic pool accounting.** A ``register_pool(name, nbytes_fn)``
   protocol: each device-resident pool (thumb pools, track-state clip
-  rings, prefetch slots, collector host batch buffers) registers a
+  rings, stream-head state: ``stream_state``, the conv states and
+  key-value caches of engine/stream_state.py, prefetch slots, collector
+  host batch buffers) registers a
   zero-argument callable returning its CURRENT bytes — an int, or a
   ``{shard: int}`` mapping for per-chip pools under ``engine.mesh``.
   Reading the pool's own ``.nbytes`` at call time makes the exactness

@@ -45,7 +45,9 @@ a count of cameras leaves them out (``ENGINE_STREAMS``).
   nested in it: ``pre_collect`` (→ collect() entry, less the assembly
   window), ``collect_tick`` (collect() entry → return, ``read_ms`` /
   ``clip_ms`` / ``fill_ms`` in the extras), and per batch ``place_wait``
-  (the tick thread blocked on the placement) and ``step_call``.
+  (the tick thread blocked on the placement) and ``step_call``; before a
+  stream head's step call also ``pool`` (the state pool's plan) and
+  ``state_wait`` (blocked on the predecessor step, whose state it takes).
 - ``place`` — transfer thread: placement picked up → ``block_until_ready``
   returned; ``queued_ms`` = handed to the stage → picked up.
 - ``drain_wake`` (submit → the drain thread holds the batch), ``fetch``
@@ -77,8 +79,9 @@ from typing import Dict, Iterable, List, Optional
 STAGES = ("publish", "collect", "submit", "device", "emit", "temporal",
           "dropped",
           # the engine's own threads (streams named ENGINE_STREAMS)
-          "tick", "pre_collect", "collect_tick", "place_wait", "step_call",
-          "place", "drain_wake", "fetch", "emit_batch")
+          "tick", "pre_collect", "collect_tick", "place_wait", "pool",
+          "state_wait", "step_call", "place", "drain_wake", "fetch",
+          "emit_batch")
 
 # Reserved stream names: the engine's tick, transfer and drain threads.
 ENGINE_STREAMS = ("engine.tick", "engine.transfer", "engine.drain")
