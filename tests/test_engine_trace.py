@@ -73,9 +73,15 @@ def _collect_bytes():
             for k in ("read", "copied", "fresh")}
 
 
+# Where a clip camera's window lives follows from what the engine is: one
+# device keeps it on the device, a mesh (here of one chip) on the host.
+HOMES = {"device": {}, "host": {"mesh": {"dp": 1}}}
+
+
 class _Fleet:
     """An engine serving tag cameras (tiny_vit, read straight into a pooled
-    batch row) and clip cameras (tiny_videomae, through their clip rings)
+    batch row) and clip cameras (tiny_videomae: their new frame likewise,
+    into a window on the device, or through their clip rings on the host)
     by resolver.
     ``round()`` publishes one frame from every camera while the collector
     is held, so one tick reads the whole round."""
@@ -140,14 +146,16 @@ def _by_batch(records):
 class TestCollectorTrace:
     """Exact counts on a fleet the test controls, collector alone."""
 
-    def _collector(self, bus):
-        return Collector(bus, buckets=(1, 2, 4), model_of=_model_of)
+    def _collector(self, bus, **kw):
+        return Collector(bus, buckets=(1, 2, 4), model_of=_model_of, **kw)
 
-    def test_clip_cameras_copy_each_frame_1_plus_L_times(self, bus):
+    @pytest.mark.parametrize("home", ["host", "device"])
+    def test_clip_cameras_copy_each_frame_1_plus_L_times(self, bus, home):
+        """... with the window on the host; once, with it on the device."""
         n = 2
         for i in range(n):
             bus.create_stream(f"clip{i}", F)
-        col = self._collector(bus)
+        col = self._collector(bus, device_windows=home == "device")
         for k in range(L + 3):
             for i in range(n):
                 _publish(bus, f"clip{i}", value=k)
@@ -155,6 +163,17 @@ class TestCollectorTrace:
             tr = col.last_trace
             assert tr["frames_read"] == n and tr["bytes_read"] == n * F
             assert tr["clip_s"] == 0.0     # nothing is assembled: a ring
+            if home == "device":
+                # the frame is the sample from the first read on: ring of
+                # the bus -> a fresh array -> a fresh batch at first
+                # sight, then -> the camera's row of a pooled batch
+                (g,) = groups
+                assert g.window == L and g.frames.shape == (n, H, W, 3)
+                assert (g.frames == k).all()
+                assert (tr["bytes_copied"], tr["bytes_fresh"]) \
+                    == ((2 * n * F,) * 2 if k == 0 else (n * F, 0))
+                assert tr["read_s"] > 0 and tr["fill_s"] > 0
+                continue
             if k < L - 1:          # windows filling: frames read, no clip
                 assert groups == []
                 # first sight: ring -> a fresh array -> slot 0 of a new
@@ -263,8 +282,13 @@ class TestStageRecords:
             for r in recs[1:]:      # one trace a batch, shared
                 assert {k: r[k] for k in first} == first
 
-    def test_two_groups_of_one_tick_share_tick_and_differ_in_batch(self, bus):
-        records = _Fleet(bus, tags=1, clips=2).run(L + 2)
+    @pytest.mark.parametrize("home", ["device", "host"])
+    def test_two_groups_of_one_tick_share_tick_and_differ_in_batch(
+            self, bus, home):
+        records = _Fleet(bus, tags=1, clips=2, **HOMES[home]).run(L + 2)
+        # copies of a clip camera's frame: ring -> pooled row; on the host
+        # ring -> its clip ring -> a pooled row
+        per_clip = 1 if home == "device" else 1 + L
         by_tick = {}
         for r in records:
             by_tick.setdefault(r["tick"], set()).add(r["batch"])
@@ -281,18 +305,24 @@ class TestStageRecords:
             assert {r["device_id"] for r in recs if r["batch"][1] == 0} \
                 == {"tag0"}
             # both batches carry the one tick's collector counts: the tag
-            # frame ring -> pooled slot once, each clip frame ring -> its
-            # clip ring -> a pooled row
+            # frame ring -> pooled slot once, each clip frame as above
             for r in recs:
                 assert r["bytes_read"] == 3 * F
-                assert r["bytes_copied"] == F + 2 * F * (1 + L)
+                assert r["bytes_copied"] == F + 2 * F * per_clip
                 assert r["frames_read"] == 3
                 assert r["clip_s"] == 0.0
+                # rows written into windows on the device, by batch
+                assert r["window_rows"] == (
+                    2 if home == "device" and r["batch"][1] == 1 else 0)
+                assert r["window_restarts"] == 0
+        fresh = [next(r["bytes_fresh"] for r in records if r["tick"] == t)
+                 for t in sorted(double)]
+        if home == "device":
+            assert fresh == [0, 0, 0]    # pooled rows, written before
+            return
         # fresh is set-up: the rings' last slots and the first pool buffer
         # in round L, the second pool buffer in round L+1, and nothing
         # after unless the drain thread still held a lease (a third buffer)
-        fresh = [next(r["bytes_fresh"] for r in records if r["tick"] == t)
-                 for t in sorted(double)]
         assert fresh[:2] == [2 * F * (1 + L), 2 * F * L]
         assert fresh[2] in (0, 2 * F * L)
 
@@ -349,13 +379,21 @@ class TestSinksAgree:
         for p in ("read", "fill", "step_call"):
             assert phase1[p] > phase0[p], p
         assert phase1["clip"] == phase0["clip"]     # nothing is assembled
-        # the records' batches are the events' batches
+        # the records' batches are the events' batches, but for the clip
+        # batches of rounds 1 .. L-1: their frames were written into
+        # windows still filling on the device and nothing was computed,
+        # so they end on the tick thread, with no record and no drain
         batches = {tuple(e["batch"]) for e in engine if "batch" in e}
-        assert batches == set(_by_batch(records))
-        for stage in ("place_wait", "step_call", "place", "drain_wake",
-                      "fetch", "emit_batch"):
+        drained = set(_by_batch(records))
+        assert drained <= batches and len(batches - drained) == L - 1
+        assert sum(e["window_rows"] for e in ticks) == 2 * (L + 2)
+        assert sum(e["window_restarts"] for e in ticks) == 0
+        for stage in ("place_wait", "step_call"):
             assert {tuple(e["batch"]) for e in engine
                     if e["stage"] == stage} == batches, stage
+        for stage in ("place", "drain_wake", "fetch", "emit_batch"):
+            assert {tuple(e["batch"]) for e in engine
+                    if e["stage"] == stage} == drained, stage
         # the tick's spans nest inside its "tick" event
         for t in ticks:
             inner = [e for e in engine if e["stream"] == "engine.tick"

@@ -249,6 +249,64 @@ def test_rounds_through_the_pool_match_one_full_forward():
     assert max(history["cam_a"]) <= c.max_rounds
 
 
+def test_the_stream_step_through_the_window_gives_the_tokens_of_whole_clips():
+    """The windowed form of the ``stream`` step (one new frame a row, the
+    window on the device) against the step fed whole clips, both through
+    a state pool of their own: the same tokens, probabilities and state
+    every round, with a camera that joins late and one that sits out."""
+    from video_edge_ai_proxy_tpu.engine.stream_state import ClipWindowPool
+
+    _, _, spec, module, _, variables = _variables(7)
+    n = module.cfg.video.num_frames
+    geom = (H, W, 3)
+    plain = jax.jit(runner.build_serving_step(module, spec),
+                    donate_argnums=(2,))
+    windowed = jax.jit(runner.build_serving_step(module, spec, window=True),
+                       donate_argnums=(2, 5))
+    heads = StreamStatePool(module, grow=2), StreamStatePool(module, grow=2)
+    wpool = ClipWindowPool(n, (1, 2))
+    rng = np.random.default_rng(1)
+    seen = {"cam_a": [], "cam_b": []}
+    compared = 0
+    for r in range(n + 5):
+        ids = ["cam_a"] if r == 0 or r == n + 2 else ["cam_a", "cam_b"]
+        single = np.zeros((2,) + geom, np.uint8)
+        for i, d in enumerate(ids):
+            single[i] = rng.integers(0, 255, geom, dtype=np.uint8)
+            seen[d].append(single[i])
+        wplan = wpool.plan(ids, geom, 2)
+        emit = wplan["emit"]
+        full = [ids[j] for j in emit]
+        hw = heads[1].plan(full, 2, rows=emit)
+        out_w = dict(windowed(
+            variables, single, wpool.window(geom), wplan["idx"],
+            wplan["pos"], heads[1].state, hw["idx"], hw["pos0"],
+            hw["reset"], hw["rounds"]))
+        wpool.put(geom, out_w.pop("window"))
+        heads[1].state = out_w.pop("state")
+        if not emit:
+            continue
+        clips = np.zeros((2, n) + geom, np.uint8)
+        for j in emit:
+            clips[j] = np.stack(seen[ids[j]][-n:])
+        hp = heads[0].plan(full, 2, rows=emit)
+        for k in hp:
+            np.testing.assert_array_equal(hp[k], hw[k])
+        out = dict(plain(variables, clips, heads[0].state, hp["idx"],
+                         hp["pos0"], hp["reset"], hp["rounds"]))
+        heads[0].state = out.pop("state")
+        for j in emit:
+            for k in ("tokens", "top_ids", "history", "rounds", "positions"):
+                np.testing.assert_array_equal(
+                    np.asarray(out_w[k][j]), np.asarray(out[k][j]), err_msg=k)
+            np.testing.assert_allclose(
+                np.asarray(out_w["top_probs"][j]),
+                np.asarray(out["top_probs"][j]), rtol=0, atol=1e-6)
+            compared += 1
+    # each from its 4th read on: cam_a read 9 frames, cam_b 7
+    assert compared == 6 + 4
+
+
 def test_a_first_round_without_a_pool_is_the_steps_first_token():
     _, _, spec, module, _, variables = _variables(8)
     clips = jax.random.normal(jax.random.PRNGKey(2), (2, 4, 32, 32, 3))
@@ -329,8 +387,17 @@ def _publish(bus, device_id, packet, rng):
                 meta)
 
 
-def test_engine_serves_the_head_one_result_a_read(monkeypatch):
+@pytest.mark.parametrize("home", ["host", "device"])
+def test_engine_serves_the_head_one_result_a_read(monkeypatch, home):
+    """``host``: as the engine serves the kind (its windows stay on the
+    host, ``InferenceEngine._window_on_device``). ``device``: the same
+    reads with that one decision turned, through the windowed step, a
+    window slot beside the head's: the same results."""
     monkeypatch.setattr(InferenceEngine, "_TRACKER_GC_GRACE_S", 0.2)
+    if home == "device":
+        monkeypatch.setattr(
+            InferenceEngine, "_window_on_device",
+            lambda self, model: self._device_windows)
     bus = MemoryFrameBus()
     cams = [f"clip{i}" for i in range(3)]
     for cam in cams:
@@ -400,7 +467,9 @@ def test_engine_serves_the_head_one_result_a_read(monkeypatch):
                     assert len(s.token_ids) == 5 and s.token_ids[0] == d.class_id
                     assert 0 < sum(s.probs) <= 1.001
         # the batch trace carries the head's fields
-        rec = eng.stage_records[-1]
+        # (a round in which all three were read by one tick: on a loaded
+        # machine a camera's frame can land a tick later)
+        rec = max(eng.stage_records, key=lambda r: r["head_prefill_tokens"])
         assert rec["head_prefill_tokens"] == 3 * c.visual_tokens
         assert rec["head_decode_steps"] == c.decode_steps
         assert rec["pool_s"] >= 0 and rec["moe_pairs_local"] > 0
@@ -427,6 +496,13 @@ def test_engine_serves_the_head_one_result_a_read(monkeypatch):
             time.sleep(0.1)
         assert len(pool) == 2 and "clip2" not in list(pool)
         assert len(got) > n0 and pool.nbytes() == held
+        # where the windows were: a pool slot a stream on the device (freed
+        # with the head's), a ring a stream on the host
+        wpool = eng._window_pools.get(TINY)
+        if home == "device":
+            assert set(wpool) == set(cams[:2]) and not eng._collector._clips
+        else:
+            assert wpool is None and set(eng._collector._clips) >= set(cams[:2])
     finally:
         eng.stop()
         bus.close()

@@ -174,6 +174,41 @@ def test_whole_yolov8n_step_16x1080p_has_pallas_nms_inside(v5e, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
 
 
+def test_windowed_clip_step_rewrites_the_window_in_place(v5e, monkeypatch):
+    """The windowed ``videomae_b`` step (engine/runner.py ``_windowed``)
+    at 1080p, 4 rows over 4 slots: the donated window pool comes back
+    aliased (no second pool), the windows reach the body through ONE
+    ordered copy (a loop of whole-frame slice updates into an allocated,
+    never zeroed buffer), not through a gather split into column strips
+    and stitched back, which is what ``jnp.take`` compiled to. ~10 s."""
+    from video_edge_ai_proxy_tpu.engine.runner import build_serving_step
+    from video_edge_ai_proxy_tpu.models import registry
+
+    _as_if_on_tpu(monkeypatch)
+    spec = registry.get("videomae_b")
+    model = spec.build()
+    on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=v5e)
+    variables = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: spec.init_params(jax.random.PRNGKey(0))[1]))
+    n, geom = 4, (1080, 1920, 3)
+    frames = jax.ShapeDtypeStruct((n,) + geom, jnp.uint8, sharding=v5e)
+    window = jax.ShapeDtypeStruct((n, spec.clip_len) + geom, jnp.uint8,
+                                  sharding=v5e)
+    ints = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=v5e)
+    compiled = jax.jit(
+        build_serving_step(model, spec, window=True),
+        donate_argnums=(2,)).lower(
+            variables, frames, window, ints, ints).compile()
+    pool = n * spec.clip_len * 1080 * 1920 * 3
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool
+    # the ordered copy and the body's own; the gather's strips took 3 pools
+    assert mem.temp_size_in_bytes < 2.5 * pool
+    text = compiled.as_text()
+    assert "mini-gather" not in text and "AllocateBuffer" in text
+
+
 class TestStreamHeadLayers:
     """The streaming head's expert layer and cached attention at the
     published widths (models/lfm2.py), compiled INSIDE a loop, as the

@@ -14,6 +14,31 @@ Video models get clip assembly: a per-stream sliding window of the last
 stream (``_ClipRing``): the bus writes each new frame over the one falling
 out of the window, and the ring is copied, oldest frame first, into a row
 of the same pooled batch buffers single-frame streams are read into.
+
+Where a window lives. A Collector built with ``device_windows`` (the
+one-device engine builds its own so, because it owns a window pool:
+engine/stream_state.py ``ClipWindowPool``) keeps NO window: a clip stream
+is read like a single-frame stream, its new frame straight into its row of
+a pooled ``[bucket, H, W, C]`` buffer (copy count 1), and the group says
+``window = clip_len``: the windowed step writes the frame into the
+stream's window on the device and reads the window there. That holds for
+the fast path and for first sight and drift alike (the frame is the whole
+sample the host ships). Without the argument (the mesh engine, whose rows
+must stay re-pinnable between shards, and callers that consume groups
+themselves) the window is the host ring above and a group carries whole
+clips. The argument may be a predicate over the model a stream runs: the
+engine keeps the ``stream`` kind's windows on the host
+(``InferenceEngine._window_on_device``). Which home a window has follows
+from what the engine observes (one device or a mesh, the step's kind),
+never from a model's name or a setting.
+
+When a device window restarts. Every frame read for such a stream
+(``_note_read``: its ``collect`` span) has to reach the stream's window,
+in read order, or the window starts anew: the engine restarts it for a
+batch it sheds or drops after the read and for a step that raised; the
+collector reports the reads it could not hand on (a frame that is not
+[H, W, C]) through ``take_window_breaks``. A stream with no new frame is
+not in the batch and its window is untouched.
 """
 
 from __future__ import annotations
@@ -119,6 +144,10 @@ class BatchGroup:
     metas: List[FrameMeta]
     bucket: int = 0          # padded batch size chosen by pad_to_bucket
     model: str = ""          # registry model these streams run (engine key)
+    window: int = 0          # clip_len when ``frames`` are single frames
+                             # bound for each stream's window on the device
+                             # (Collector(device_windows=True)); 0 = whole
+                             # samples
     lease: Optional[tuple] = None  # (pool shape, buf idx) when the frames
                                    # view a pooled buffer under strict
                                    # leasing (Collector.release returns it)
@@ -364,6 +393,7 @@ class Collector:
         interest_of: Optional[callable] = None,  # device_id -> bool
         strict_lease: bool = False,
         shards: int = 1,
+        device_windows=False,       # bool, or model name -> bool
     ):
         self._bus = bus
         self._buckets = tuple(sorted(buckets))
@@ -389,6 +419,17 @@ class Collector:
         # Stream -> shard routing override (device-fault failover,
         # ``repin``): None = the stable crc32 ``stream_shard`` map.
         self._shard_fn = None
+        # Clip windows live on the device (module docstring): the caller
+        # owns a window pool and runs the windowed step; it may say so a
+        # model. Dense layout only.
+        if self._shards > 1 or not device_windows:
+            self._device_windows = None
+        elif callable(device_windows):
+            self._device_windows = device_windows
+        else:
+            self._device_windows = lambda model: True
+        # streams whose read frame could not be handed on (take_window_breaks)
+        self._window_breaks: List[str] = []
         # Degradation-ladder bucket cap (resilience/ladder.py rung 2):
         # None = full bucket list; an int hides buckets above it so new
         # batches compile/run at the next-smaller device program.
@@ -550,18 +591,21 @@ class Collector:
         return ring
 
     def _take(self, device_id: str, model: str, clip_len: int,
-              dst: np.ndarray, warm: bool, spill: List[tuple]):
+              dst: np.ndarray, warm: bool, spill: List[tuple],
+              window: int = 0):
         """One planned stream's newest unseen frame -> its batch row
         ``dst``. A single-frame stream is read by the bus straight into
-        ``dst``; a clip stream into the slot of its ring that falls out
-        of the window, and the full window is then copied into ``dst``,
-        oldest frame first. ``warm``: ``dst`` is memory the collector has
-        written before. Returns the frame's meta once ``dst`` holds the
-        stream's sample, else None: no new frame (the window stays as it
-        was: the slot offered to the bus is rewritten whole before it is
-        served again), a window still filling, or a frame of another
-        geometry, which goes to ``spill`` where it is a whole sample and
-        starts a clip stream's window anew."""
+        ``dst``, and so is a clip stream whose window is on the device
+        (``window`` its length, ``clip_len`` 0: the frame is the sample);
+        a clip stream with a host window into the slot of its ring that
+        falls out of the window, and the full window is then copied into
+        ``dst``, oldest frame first. ``warm``: ``dst`` is memory the
+        collector has written before. Returns the frame's meta once
+        ``dst`` holds the stream's sample, else None: no new frame (the
+        window stays as it was: the slot offered to the bus is rewritten
+        whole before it is served again), a window still filling, or a
+        frame of another geometry, which goes to ``spill`` where it is a
+        whole sample and starts a clip stream's window anew."""
         ring = None
         slot, slot_warm = dst, warm
         if clip_len:
@@ -589,7 +633,10 @@ class Collector:
                 if ring is None or not ring.full:
                     return None
                 sample = ring.buf
-            spill.append((device_id, model, sample, res.meta))
+            if window and sample.ndim != 3:
+                self._window_breaks.append(device_id)
+                return None
+            spill.append((device_id, model, sample, res.meta, window))
             return None
         seq, meta = res
         self._note_read(device_id, seq, meta)
@@ -601,6 +648,14 @@ class Collector:
             ring.copy_to(dst)
             self._note_fill(t0, dst.nbytes, fresh=not warm)
         return meta
+
+    def _device_window(self, model: str, clip_len: int) -> int:
+        """``clip_len`` when this model's windows live on the device (the
+        group then carries single frames and says ``window``), else 0."""
+        if clip_len and self._device_windows is not None \
+                and self._device_windows(model):
+            return clip_len
+        return 0
 
     def _stream_model(self, device_id: str):
         """(model name, clip_len) for one stream — per-stream override via
@@ -988,9 +1043,11 @@ class Collector:
         """Lay out next tick's fast-path batches: (model, geometry)
         grouping and bucket chunking identical to collect()'s, with a
         pooled buffer acquired per group. Single-frame streams only: a
-        clip stream's batch row is copied from its ring at collect(), and
-        a stream of unknown geometry takes collect()'s generic path and
-        joins the window next tick."""
+        clip stream's batch row is copied from its ring at collect() (or,
+        with its window on the device, read there: every frame read has
+        to reach the window, and a second publish between ticks would
+        overwrite one that was), and a stream of unknown geometry takes
+        collect()'s generic path and joins the window next tick."""
         if device_ids is None:
             device_ids = self.inference_streams()
         buckets = self._effective_buckets()
@@ -1089,7 +1146,7 @@ class Collector:
                 if res.data.ndim == 3:
                     self._geom[device_id] = res.data.shape
                 win["spill"].append(
-                    (device_id, g["model"], res.data, res.meta))
+                    (device_id, g["model"], res.data, res.meta, 0))
                 drifted.append(device_id)
                 continue
             seq, meta = res
@@ -1125,11 +1182,13 @@ class Collector:
         is planned under (model, geometry, clip_len) and served from
         pooled batch buffers (``_take``). A window of one frame is read
         by the bus DIRECTLY into its batch row (`read_latest_into`) —
-        ring to device batch in one memory pass; a window of ``clip_len``
-        frames goes through the stream's clip ring: the new frame over
-        the oldest, then the ring into the row. First sight of a stream
-        and geometry drift take the generic frame path and join the hot
-        path next tick."""
+        ring to device batch in one memory pass, and so is the new frame
+        of a window that lives on the device (``device_windows``: the
+        group says ``window``); a window of ``clip_len`` frames on the
+        host goes through the stream's clip ring: the new frame over the
+        oldest, then the ring into the row. First sight of a stream and
+        geometry drift take the generic frame path and join the hot path
+        next tick."""
         if device_ids is None:
             device_ids = self.inference_streams()
         acc = self._acc
@@ -1205,6 +1264,10 @@ class Collector:
                 self._collect_fast_sharded(
                     model, geom, clip_len, devs, buckets, groups, spill)
                 continue
+            # a window on the device: the sample the host ships is a frame
+            window = self._device_window(model, clip_len)
+            if window:
+                clip_len = 0
             sample = ((clip_len,) + geom) if clip_len else geom
             for start in range(0, len(devs), max_bucket):
                 chunk = devs[start:start + max_bucket]
@@ -1219,7 +1282,7 @@ class Collector:
                     if not clip_len:   # the bus writes into the row itself
                         hw = max(hw, len(ids) + 1)
                     meta = self._take(device_id, model, clip_len,
-                                      batch[len(ids)], warm, spill)
+                                      batch[len(ids)], warm, spill, window)
                     if meta is not None:
                         ids.append(device_id)
                         metas.append(meta)
@@ -1235,7 +1298,7 @@ class Collector:
                 view = batch[:bucket]
                 group = BatchGroup(
                     src_hw=geom[:2], device_ids=ids, frames=view,
-                    metas=metas, bucket=bucket, model=model,
+                    metas=metas, bucket=bucket, model=model, window=window,
                 )
                 self._lease(group, shape, bidx)
                 groups.append(group)
@@ -1253,20 +1316,28 @@ class Collector:
             if frame.data.ndim == 3:
                 self._geom[device_id] = frame.data.shape
             sample = frame.data
-            if clip_len:
+            window = self._device_window(model, clip_len)
+            if window:
+                # the frame is the sample: the window it opens is on the
+                # device, as every later frame's
+                if sample.ndim != 3:
+                    self._window_breaks.append(device_id)
+                    continue
+            elif clip_len:
                 ring = self._seed_ring(device_id, clip_len, frame)
                 if ring is None or not ring.full:
                     continue
                 sample = ring.buf   # a window of one frame: full at once
-            first_sight.append((device_id, model, sample, frame.meta))
-        by_key: Dict[tuple, list] = {}      # (model, sample shape) -> items
-        for device_id, model, sample, meta in first_sight + spill:
-            by_key.setdefault((model, sample.shape), []).append(
+            first_sight.append((device_id, model, sample, frame.meta, window))
+        # (model, sample shape, device window length) -> items
+        by_key: Dict[tuple, list] = {}
+        for device_id, model, sample, meta, window in first_sight + spill:
+            by_key.setdefault((model, sample.shape, window), []).append(
                 (device_id, sample, meta)
             )
-        for (model, shape), items in sorted(by_key.items()):
+        for (model, shape, window), items in sorted(by_key.items()):
             hw = shape[:-1][-2:]    # of [H, W, C] or [clip_len, H, W, C]
-            self._collect_generic(model, hw, items, buckets, groups)
+            self._collect_generic(model, hw, items, buckets, groups, window)
         self.last_trace, self._acc = acc, _new_trace()
         return groups
 
@@ -1318,7 +1389,7 @@ class Collector:
 
     def _collect_generic(self, model: str, hw: tuple,
                          items: Sequence[tuple], buckets: tuple,
-                         groups: List[BatchGroup]) -> None:
+                         groups: List[BatchGroup], window: int = 0) -> None:
         """Generic path (first sight, drift): whole samples, each already
         in an array of its own, into a fresh zeroed buffer at final-bucket
         spacing — no compaction needed, pad rows already zero. One shard
@@ -1349,7 +1420,17 @@ class Collector:
             groups.append(BatchGroup(
                 src_hw=hw, device_ids=ids, frames=batch, metas=metas,
                 bucket=bucket, model=model, rows=rows if S > 1 else None,
+                window=window,
             ))
+
+    def take_window_breaks(self) -> List[str]:
+        """Streams with a device window whose read frame could not be
+        handed on since the last call (not [H, W, C]): the caller starts
+        their windows anew."""
+        if not self._window_breaks:
+            return []
+        out, self._window_breaks = self._window_breaks, []
+        return out
 
     def drop_stream(self, device_id: str) -> None:
         self._cursors.pop(device_id, None)

@@ -76,7 +76,8 @@ def _rebox(template, values):
     )
 
 
-def build_serving_step(model, spec, *, quality_thumb: int = 0, mesh=None):
+def build_serving_step(model, spec, *, quality_thumb: int = 0, mesh=None,
+                       window: bool = False):
     """The per-tick device program for one model kind: uint8 frames in,
     postprocessed results out. SINGLE source of truth — the engine compiles
     it per (geometry, bucket), bench.py times it, __graft_entry__ exposes
@@ -117,9 +118,25 @@ def build_serving_step(model, spec, *, quality_thumb: int = 0, mesh=None):
     connector, prefill and the D decode steps are ONE program a batch; the
     output carries ``state`` (the same buffers, rewritten in place), which
     the dispatcher takes back before the drain fetches the rest. One chip
-    only: experts over a real ``ep`` mesh are PERF.md's open question."""
+    only: experts over a real ``ep`` mesh are PERF.md's open question.
+
+    ``window`` (the one-device engine's fast path, for a clip-taking
+    spec): the step's windowed form, ``_windowed``. Each stream's
+    ``clip_len``-frame window is device state (engine/stream_state.py
+    ``ClipWindowPool``): the batch carries ONE new frame a row, the step
+    writes it into the window and reads the window back in time order,
+    and the body below runs unchanged on what it read. Without it the
+    clip step takes whole windows, ``raw(variables, clips_u8)``, as
+    bench.py, __graft_entry__, the replay goldens and mesh serving do."""
     import jax
 
+    if window:
+        if mesh is not None or not spec.clip_len:
+            raise ValueError(
+                f"model {spec.name!r}: a device window serves clips on one "
+                "chip")
+        return _windowed(build_serving_step(
+            model, spec, quality_thumb=quality_thumb))
     if mesh is not None and all(
             n == 1 for a, n in mesh.shape.items() if a != "dp"):
         return _dp_sharded(
@@ -235,6 +252,60 @@ def _build_stream_step(model, size: int):
         return out
 
     return stream_step
+
+
+def window_write(window, frames_u8, idx, pos):
+    """Row i's frame into ``window[idx[i], pos[i]]``; a padded row carries
+    ``idx = slots`` and is dropped (as ``StreamStatePool.plan`` pads).
+    The windowed step's first half, and a program of its own for a batch
+    whose windows are all still filling: its frames are written and
+    nothing is computed (``InferenceEngine._dispatch``). The caller
+    donates ``window``: same shape and dtype out, rewritten in place."""
+    return window.at[idx, pos].set(frames_u8, mode="drop")
+
+
+def _windowed(step):
+    """The windowed form of a clip-taking step (``build_serving_step``):
+    ``(variables, frames_u8 [bucket, H, W, C], window [slots, clip_len, H,
+    W, C], idx, pos, *rest)`` -> the step's outputs + ``window``.
+
+    One program: the new frames are written (``window_write``), every
+    row's window is read back oldest frame first (the slot after the one
+    just written holds the oldest) by a loop of whole-frame copies into
+    one [bucket, clip_len, ...] temporary, so the pool itself is never
+    shifted, and the unchanged ``step(variables, clips_u8, *rest)`` runs
+    on that. The caller donates ``window``; it comes back the same shape
+    and dtype, so it is rewritten in place. The jitted program keeps the
+    step's name (a device trace finds it where it found the step)."""
+    import jax
+    import jax.numpy as jnp
+
+    def windowed(variables, frames_u8, window, idx, pos, *rest):
+        slots, clip_len = window.shape[:2]
+        frame = window.shape[2:]
+        window = window_write(window, frames_u8, idx, pos)
+        order = (pos[:, None] + 1 + jnp.arange(clip_len)) % clip_len
+        src = idx[:, None] * clip_len + order      # a padded row is clipped
+        src = jnp.minimum(src, slots * clip_len - 1).reshape(-1)
+        flat = window.reshape((slots * clip_len,) + frame)
+        zero = (0,) * len(frame)
+
+        def copy(i, clips):
+            one = jax.lax.dynamic_slice(flat, (src[i],) + zero,
+                                        (1,) + frame)
+            return jax.lax.dynamic_update_slice(clips, one, (i,) + zero)
+
+        clips = jax.lax.fori_loop(
+            0, src.shape[0], copy,
+            jnp.zeros((src.shape[0],) + frame, window.dtype))
+        out = dict(step(variables, clips.reshape(
+            (idx.shape[0], clip_len) + frame), *rest))
+        out["window"] = window
+        return out
+
+    windowed.__name__ = step.__name__
+    windowed.__qualname__ = step.__qualname__
+    return windowed
 
 
 def _dp_sharded(step, mesh):
@@ -464,6 +535,10 @@ class _Inflight:
     # by the three sinks: stage records, ``engine.*`` tracer events,
     # registry counters. None for coast groups (no device work).
     tr: Optional[dict] = None
+    # Indices into ``group.device_ids`` that are emitted; None = all. A
+    # row whose device window is still filling was written and computed,
+    # and owes no result before its ``clip_len``-th read.
+    emit: Optional[List[int]] = None
 
 
 # vep_tick_phase_seconds_total{phase=...}: what the tick thread did with a
@@ -1244,6 +1319,25 @@ class InferenceEngine:
         # model name -> StreamStatePool (engine/stream_state.py), built
         # when a stream-head model is first dispatched or prewarmed
         self._head_pools: Dict[str, Any] = {}
+        # Clip windows (engine/stream_state.py ClipWindowPool): model name
+        # -> the pool of its streams' windows on the device, built like the
+        # head pools; stream -> the model whose pool holds its window. Only
+        # the one-device engine has them, and not for every kind
+        # (_window_on_device, which its collector asks too).
+        self._device_windows = False    # set by warmup: no mesh
+        self._window_pools: Dict[str, Any] = {}
+        self._window_home: Dict[str, str] = {}
+        win_rows = obs_registry.counter(
+            "vep_clip_window_rows_total",
+            "Clip samples dispatched, by where the stream's window lives",
+            ("home",))
+        self._m_window_rows = {h: win_rows.labels(h)
+                               for h in ("device", "host")}
+        self._m_window_restarts = obs_registry.counter(
+            "vep_clip_window_restarts_total",
+            "Device clip windows started anew because a read frame did not "
+            "reach them", ("reason",))
+        self._window_restarts = 0   # since the last batch trace took them
         self._tick_mark = time.perf_counter()   # end of the last closed tick
         self._assemble_s = 0.0   # assemble_until seconds since that mark
         # Recompile-storm detection state (tick loop only).
@@ -1466,6 +1560,10 @@ class InferenceEngine:
                 "stream_state",
                 lambda: sum(p.nbytes() for p in self._head_pools.values()))
             self.hbm.register_pool(
+                "clip_windows",
+                lambda: sum(p.nbytes()
+                            for p in list(self._window_pools.values())))
+            self.hbm.register_pool(
                 "prefetch",
                 lambda: self._xfer.nbytes() if self._xfer is not None else 0)
             self.hbm.register_pool(
@@ -1644,6 +1742,7 @@ class InferenceEngine:
             self._variables = jax.device_put(self._variables)
         self._models[self._spec.name] = (self._spec, self._model, self._variables)
         self._buckets = buckets   # effective (mesh-filtered) buckets
+        self._device_windows = self._mesh is None
         if self._roi is not None:
             # Canvas count per tick can never exceed the largest batch
             # bucket (the packed group must still pad to a known bucket).
@@ -1670,6 +1769,12 @@ class InferenceEngine:
             # the shard-segmented row layout (group.rows set) so each dp
             # slice receives exactly its streams' frames.
             shards=self._shards,
+            # One device: a clip stream's window is device state (the
+            # window pools below), the host ships single frames and the
+            # step is the windowed form. A mesh keeps host rings (its rows
+            # must stay re-pinnable between shards), and so does the
+            # stream kind: _window_on_device.
+            device_windows=self._window_on_device,
         )
         device = jax.devices()[0]
         # MFU denominator from the peaks table (obs/perf.py): a device
@@ -2025,6 +2130,7 @@ class InferenceEngine:
         self._prewarm_required = len(entries)
         self._prewarm_done = 0
         self._prewarm_started = True   # the entry list is now final
+        self._size_windows(entries)
         for geom in entries:
             # Log-and-continue like every other per-item path here: a bad
             # prewarm entry must not abort server boot, and buckets must be
@@ -2394,7 +2500,8 @@ class InferenceEngine:
         """``(model, stem, src_hw, bucket)`` -> the AOT executable of every
         serving step compiled so far (None for one that fell back to
         jit): what ``chip_smoke.py`` reads to see which kernels the
-        served program really contains."""
+        served program really contains. A windowed clip step's key has a
+        fifth element, its window buffer's slots."""
         return {key: fn.compiled
                 for key, fn in list(self._step_cache.items())}
 
@@ -2422,6 +2529,9 @@ class InferenceEngine:
             )
             return
         spec, _, variables = self._ensure_model(model or self._spec.name)
+        if self._window_on_device(spec.name):
+            self._compile_windowed(tuple(src_hw), bucket, spec, variables)
+            return
         shape = (bucket,) + (
             (spec.clip_len,) if spec.clip_len else ()
         ) + tuple(src_hw) + (3,)
@@ -2447,6 +2557,48 @@ class InferenceEngine:
                         else thumbs)
         self._step(src_hw, bucket, model)(variables, *args)
 
+    def _size_windows(self, entries) -> None:
+        """Before a start's prewarm list is compiled: every windowed
+        model's buffer gets room for the largest bucket its entries name,
+        so each of its programs is keyed by the capacity it is served at
+        (entry by entry a list of three buckets would leave three programs
+        nobody runs). An entry ``compile_for`` will refuse is passed over
+        here too."""
+        for geom in entries:
+            try:
+                h, w, bucket = (int(v) for v in geom[:3])
+                name = str(geom[3]) if len(geom) >= 4 and geom[3] \
+                    else self._spec.name
+                if bucket in self._buckets and self._window_on_device(name):
+                    self._window_pool(name).ensure((h, w, 3), bucket)
+            except Exception:
+                continue    # compile_for logs it
+
+    def _compile_windowed(self, src_hw: tuple, bucket: int, spec,
+                          variables) -> None:
+        """``compile_for`` for a model whose clip windows live on the
+        device: the windowed step, the form this engine serves, on a batch
+        of single frames with every row padded (nothing is written) and
+        the window buffer at its serving shape, kept for the fleet; then
+        the write alone, for rounds in which every window still fills
+        (``_dispatch``). Neither result is waited for."""
+        geom = src_hw + (3,)
+        wpool = self._window_pool(spec.name)
+        wpool.ensure(geom, bucket)
+        slots = wpool.capacity(geom)
+        plan = wpool.plan((), geom, bucket)
+        frames = self._place(np.zeros((bucket,) + geom, np.uint8))
+        try:
+            out = self._step(src_hw, bucket, spec.name, window=slots)(
+                variables, frames, wpool.window(geom), plan["idx"],
+                plan["pos"])
+            wpool.put(geom, self._window_writer(
+                src_hw, bucket, spec.name, slots)(
+                    out["window"], frames, plan["idx"], plan["pos"]))
+        except Exception:
+            wpool.lost(geom, "step_error")      # donated
+            raise
+
     def _head_pool(self, model: str):
         """The state pool of a stream-head model (one a model)."""
         pool = self._head_pools.get(model)
@@ -2457,6 +2609,88 @@ class InferenceEngine:
             pool = self._head_pools[model] = StreamStatePool(
                 mod, grow=max(self._buckets or (1,)))
         return pool
+
+    def _window_on_device(self, model: str) -> bool:
+        """Where a model's clip window lives, from what the engine
+        observes: on the device for a clip-taking step on one device, on
+        the host (``collector._ClipRing``) over a mesh, and for the
+        ``stream`` kind. That kind's round is its second-long step, so a
+        window on the device buys its latency nothing, and its set-up read
+        longer with one (PERF.md 6, PR 31); the windowed step and the
+        dispatcher take it as they take the others."""
+        if not self._device_windows:
+            return False
+        spec = self._ensure_model(model or self._spec.name)[0]
+        return bool(spec.clip_len) and spec.kind != "stream"
+
+    def _window_pool(self, model: str):
+        """The clip-window pool of a clip-taking model (one a model)."""
+        pool = self._window_pools.get(model)
+        if pool is None:
+            from .stream_state import ClipWindowPool
+
+            spec, _, _ = self._ensure_model(model)
+            pool = self._window_pools[model] = ClipWindowPool(
+                spec.clip_len, self._buckets or (1,),
+                note_restart=self._note_window_restart)
+        return pool
+
+    def _plan_window(self, group: BatchGroup, name: str):
+        """(pool, geometry, plan) of a group of single frames bound for
+        their streams' device windows: each row's slot and write position
+        (``ClipWindowPool.plan``). A stream first seen under this model
+        leaves the window it had under another."""
+        geom = group.frames.shape[1:]
+        wpool = self._window_pool(name)
+        self._leave_windows(group.device_ids, name)
+        return wpool, geom, wpool.plan(
+            group.device_ids, geom, group.bucket, rows=group.rows)
+
+    def _leave_windows(self, device_ids, name: Optional[str]) -> None:
+        """These streams are served under model ``name`` now (None: one
+        whose windows live on the host): each leaves the device window it
+        had under another model, counted where that held a frame."""
+        for did in device_ids:
+            was = self._window_home.get(did, name)
+            if was != name and was in self._window_pools:
+                if self._window_pools[was].held(did):
+                    self._note_window_restart("model", 1)
+                self._window_pools[was].pop(did)
+            if name is None:
+                self._window_home.pop(did, None)
+            else:
+                self._window_home[did] = name
+
+    def _window_writer(self, src_hw: tuple, bucket: int, model: str,
+                       slots: int):
+        """``window_write`` compiled for (model, geometry, bucket, window
+        slots): what a batch runs whose windows are all still filling.
+        Cached and timed beside the steps, under a six-element key."""
+        key = (model, getattr(self._cfg, "stem", "classic"), src_hw, bucket,
+               slots, "write")
+        fn = self._step_cache.get(key)
+        if fn is None:
+            import jax
+
+            # its compile is counted under a name of its own: the step's
+            # record carries the FLOPs the live MFU is reckoned from
+            fn = self._step_cache[key] = _TimedStep(
+                jax.jit(window_write, donate_argnums=(0,)), self.perf,
+                f"{model}/window_write", src_hw, bucket)
+        return fn
+
+    def _note_window_restart(self, reason: str, n: int) -> None:
+        self._m_window_restarts.labels(reason).inc(n)
+        self._window_restarts += n
+
+    def _restart_windows(self, groups, reason: str) -> None:
+        """Frames of these groups were read and will not reach their
+        streams' device windows: the windows start anew."""
+        for g in groups:
+            if g.window:
+                pool = self._window_pools.get(g.model or self._spec.name)
+                if pool is not None:
+                    pool.restart(g.device_ids, reason)
 
     def _place(self, frames: np.ndarray):
         """Shard the batch dim over dp when serving on a mesh; pass through
@@ -2485,7 +2719,12 @@ class InferenceEngine:
 
         return shard_put(frames, batch_sharding(self._mesh, frames.ndim))
 
-    def _step(self, src_hw: tuple, bucket: int, model: Optional[str] = None):
+    def _step(self, src_hw: tuple, bucket: int, model: Optional[str] = None,
+              window: int = 0):
+        """The compiled step of (model, geometry, bucket). ``window``: the
+        slots of the model's device window buffer at this geometry; the
+        step is then the windowed form (``_windowed``), one program a
+        capacity, under a five-element key."""
         model = model or self._spec.name
         # The key carries the stem-variant axis (round 15): cfg.stem picks
         # a different compiled program (fused vs classic preprocess, 2x2
@@ -2493,6 +2732,8 @@ class InferenceEngine:
         # cached program by what it actually computes, so introspection
         # and any future runtime stem flip can never alias the variants.
         key = (model, getattr(self._cfg, "stem", "classic"), src_hw, bucket)
+        if window:
+            key += (window,)
         fn = self._step_cache.get(key)
         if fn is not None:
             self._m_cache_hit.inc()
@@ -2505,7 +2746,7 @@ class InferenceEngine:
                 mod, spec,
                 quality_thumb=(self._cfg.quality_thumb
                                if self._quality_device else 0),
-                mesh=self._mesh,
+                mesh=self._mesh, window=bool(window),
             )
             if self._cfg.quantize:
                 from ..models.quantize import dequantize_tree
@@ -2532,10 +2773,15 @@ class InferenceEngine:
                     and jax.default_backend() == "tpu"
                     and self._mesh is not None and self._mesh.size > 1):
                 donate = (1,)
-            if spec.kind == "stream":
-                # the pool's buffers (argnum 2) come back as the output's
-                # "state", same shapes: rewritten in place
+            if window:
+                # the window buffer (argnum 2) comes back as the output's
+                # "window", same shape and dtype: rewritten in place; the
+                # step's own arguments follow idx and pos
                 donate += (2,)
+            if spec.kind == "stream":
+                # the pool's buffers come back as the output's "state",
+                # same shapes: rewritten in place
+                donate += (5 if window else 2,)
             # Compile attribution (obs/perf.py): the wrapper AOT-compiles
             # on first call, recording wall time + XLA cost analysis per
             # (model, geometry, bucket) — this is the only cache-miss
@@ -2682,7 +2928,13 @@ class InferenceEngine:
                     inferred = admitted
                 self._collector.keep_streams_hot(device_ids=inferred)
                 t_collect0, pc_collect0 = time.time(), time.perf_counter()
-                groups = self._collector.collect(device_ids=inferred)
+                try:
+                    groups = self._collector.collect(device_ids=inferred)
+                except Exception:
+                    # frames may have been read and never made a group
+                    for wpool in self._window_pools.values():
+                        wpool.restart(list(wpool), "collect_error")
+                    raise
                 if rung != "normal" and groups:
                     # Rung 1+: stale frames leave before they cost device
                     # time (shed oldest-first with a staleness bound).
@@ -2712,6 +2964,7 @@ class InferenceEngine:
                 # numbering (invariant in _assign_tracks).
                 if self._trackers or self._ann_state or self._thumbs \
                         or any(self._head_pools.values()) \
+                        or any(self._window_pools.values()) \
                         or (self._roi is not None and self._roi) \
                         or (self._cascade is not None and self._cascade):
                     now = time.monotonic()
@@ -2725,7 +2978,8 @@ class InferenceEngine:
                         else set()
                     casc_ids = set(self._cascade) \
                         if self._cascade is not None else set()
-                    head_ids = set().union(*self._head_pools.values())
+                    head_ids = set().union(*self._head_pools.values(),
+                                           *self._window_pools.values())
                     with self._state_lock:
                         for d in (set(self._trackers) | set(self._ann_state)
                                   | set(self._thumbs) | roi_ids
@@ -2762,6 +3016,10 @@ class InferenceEngine:
                                 # stream; a returning one starts over.
                                 for pool in self._head_pools.values():
                                     pool.pop(d, None)
+                                # So does its window's on the device.
+                                for pool in self._window_pools.values():
+                                    pool.pop(d, None)
+                                self._window_home.pop(d, None)
                                 if self.quality is not None:
                                     self.quality.forget(d)
                                 del self._tracker_absent[d]
@@ -2855,7 +3113,10 @@ class InferenceEngine:
             tick=n, batches=len(batches), frames_read=tick["frames_read"],
             bytes_read=tick["bytes_read"],
             bytes_copied=tick["bytes_copied"],
-            bytes_fresh=tick["bytes_fresh"])
+            bytes_fresh=tick["bytes_fresh"],
+            window_rows=sum(b.get("window_rows", 0) for b in batches),
+            window_restarts=sum(b.get("window_restarts", 0)
+                                for b in batches))
         tracer.record(
             "engine.tick", "pre_collect", n, ts=tick["t_collect0"],
             dur_ms=tick["pre_collect_s"] * 1e3, tick=n)
@@ -3154,8 +3415,22 @@ class InferenceEngine:
         ``t_place_got``), for a stream head ``pool_s`` (the state pool's
         plan) and ``state_wait_s`` (blocked on the predecessor step, whose
         state this one takes), ``t_step0``/``t_step1`` and ``step_call_s``
-        (around the step call; a compile shows here), ``t_submit``. The
-        drain thread adds ``t_deq``, ``t_drain0``, ``t_drained``.
+        (around the step call; a compile shows here), ``t_submit``,
+        ``window_rows`` (rows of this batch written into their streams'
+        windows on the device) and ``window_restarts`` (device windows
+        started anew since the last batch's trace). The drain thread adds
+        ``t_deq``, ``t_drain0``, ``t_drained``.
+
+        A group of single frames bound for device windows (``group.window``,
+        from a Collector told ``device_windows``) runs the windowed step:
+        the model's ``ClipWindowPool`` plans each row's slot and write
+        position, its buffer goes in donated and the returned one is taken
+        back before the drain fetches the rest; the next step takes that
+        handle at once (jax orders the two, nothing waits here). Rows
+        whose window is still filling are computed and not emitted; a
+        batch with no full window at all only writes its frames
+        (``window_write``) and ends at the dispatch. A failure after the
+        frames were read restarts the windows involved.
 
         With cfg.prefetch the placement of group g+1 (and g+2) runs on
         the transfer thread while this thread dispatches group g and the
@@ -3180,6 +3455,10 @@ class InferenceEngine:
         if tick is None:   # called outside the tick loop (tests, smokes)
             tick = {"tick": self.ticks, "t_collect": t_collect}
         batches: List[dict] = []
+        for did in self._collector.take_window_breaks():
+            wpool = self._window_pools.get(self._window_home.get(did))
+            if wpool is not None:
+                wpool.restart([did], "corrupt")
         if self.faults is not None:
             # FaultLedger conservation: every stream slot entering the
             # device pipeline is counted in here and counted out in the
@@ -3210,7 +3489,9 @@ class InferenceEngine:
         for gi, group in enumerate(groups):
             tr = dict(tick, batch=(tick["tick"], gi))
             try:
-                step = self._step(group.src_hw, group.bucket, group.model)
+                # (a windowed step is keyed by its buffer's slots: below)
+                step = None if group.window else self._step(
+                    group.src_hw, group.bucket, group.model)
                 _, _, variables = self._ensure_model(
                     group.model or self._spec.name
                 )
@@ -3255,7 +3536,7 @@ class InferenceEngine:
                 # pool rows, and a canvas "frame" has no per-stream diff
                 # meaning anyway (full-frame refreshes keep the signal).
                 if self._quality_device and group.frames.ndim == 4 \
-                        and group.crops is None:
+                        and group.crops is None and not group.window:
                     idx = self._thumbs.gather_indices(
                         group.device_ids, group.bucket, rows=group.rows)
                     aux_nbytes = (
@@ -3266,15 +3547,45 @@ class InferenceEngine:
                     group.model or self._spec.name, group.bucket,
                     group.nbytes + aux_nbytes, h2d_s, hidden_s=hidden_s,
                 )
-                head = None
+                head = wplan = emit = None
+                write_only = False
                 name = group.model or self._spec.name
-                if self._models[name][0].kind == "stream":
+                ids, rows = group.device_ids, group.rows
+                if group.window:
+                    wpool, geom, wplan = self._plan_window(group, name)
+                    self._m_window_rows["device"].inc(len(ids))
+                    if len(wplan["emit"]) < len(ids):
+                        # the filling rows are written and computed; only
+                        # full windows are emitted (and advance a head)
+                        emit = wplan["emit"]
+                        rows = [j if rows is None else rows[j] for j in emit]
+                        ids = [ids[j] for j in emit]
+                    # no window of the batch is full yet: the frames are
+                    # written and nothing is computed (a stream head's
+                    # round costs a second)
+                    write_only = not ids
+                    slots = wpool.capacity(geom)
+                    if write_only:
+                        step = self._window_writer(
+                            group.src_hw, group.bucket, name, slots)
+                    else:
+                        step = self._step(group.src_hw, group.bucket,
+                                          group.model, window=slots)
+                elif group.frames.ndim == 5:
+                    self._m_window_rows["host"].inc(len(ids))
+                    if self._window_home:
+                        self._leave_windows(ids, None)
+                tr.update(
+                    window_rows=len(group.device_ids) if group.window else 0,
+                    window_restarts=self._window_restarts)
+                self._window_restarts = 0
+                if self._models[name][0].kind == "stream" \
+                        and not write_only:
                     # slots, reset and index vectors: the only per-stream
                     # host work a stream head adds to the tick thread
                     pc_pool0 = time.perf_counter()
                     pool = self._head_pool(name)
-                    head = pool.plan(group.device_ids, group.bucket,
-                                     rows=group.rows)
+                    head = pool.plan(ids, group.bucket, rows=rows)
                     real = head["idx"] < pool.capacity
                     tr.update(
                         head_prefill_tokens=int(real.sum())
@@ -3295,23 +3606,42 @@ class InferenceEngine:
                     pool.wait()
                     tr["state_wait_s"] = time.perf_counter() - pc_wait0
                 tr["t_step0"], pc_step0 = time.time(), time.perf_counter()
-                if head is not None:
+                if write_only:
                     try:
-                        outputs = dict(step(
-                            variables, placed, pool.state, head["idx"],
-                            head["pos0"], head["reset"], head["rounds"]))
+                        wpool.put(geom, step(wpool.window(geom), placed,
+                                             wplan["idx"], wplan["pos"]))
+                    except Exception:
+                        wpool.lost(geom, "step_error")     # donated
+                        raise
+                elif head is not None or wplan is not None:
+                    extra: tuple = ()
+                    if wplan is not None:
+                        extra += (wpool.window(geom), wplan["idx"],
+                                  wplan["pos"])
+                    if head is not None:
+                        extra += (pool.state, head["idx"], head["pos0"],
+                                  head["reset"], head["rounds"])
+                    try:
+                        outputs = dict(step(variables, placed, *extra))
                     except Exception:
                         # the buffers were donated and the host's
                         # bookkeeping ran ahead of the device: every
-                        # stream of this model starts over in a new pool
-                        self._head_pools.pop(name, None)
+                        # window of this geometry starts anew in a new
+                        # buffer, every stream of a head in a new pool
+                        if wplan is not None:
+                            wpool.lost(geom, "step_error")
+                        if head is not None:
+                            self._head_pools.pop(name, None)
                         raise
-                    pool.state = outputs.pop("state")
-                    self._m_head_tokens["prefill"].inc(
-                        tr["head_prefill_tokens"])
-                    self._m_head_tokens["decode"].inc(
-                        int(real.sum()) * pool.cfg.decode_steps)
-                    self._m_head_resets.inc(tr["head_resets"])
+                    if wplan is not None:
+                        wpool.put(geom, outputs.pop("window"))
+                    if head is not None:
+                        pool.state = outputs.pop("state")
+                        self._m_head_tokens["prefill"].inc(
+                            tr["head_prefill_tokens"])
+                        self._m_head_tokens["decode"].inc(
+                            int(real.sum()) * pool.cfg.decode_steps)
+                        self._m_head_resets.inc(tr["head_resets"])
                 elif idx is not None:
                     # Quality-carrying step (3-arg): previous-tick
                     # thumbnails arrive as a device-side gather from the
@@ -3346,6 +3676,8 @@ class InferenceEngine:
                     shard = self.faults.note_error(exc, self.ticks)
                 reason = ("device_fault" if shard is not None
                           else "dispatch_error")
+                # read, and now never to reach their windows
+                self._restart_windows(groups[gi:], "dropped")
                 for gj in range(gi, len(groups)):
                     if gj < len(handles) and handles[gj] is not None:
                         # Bounded: block_until_ready in the transfer loop
@@ -3372,6 +3704,19 @@ class InferenceEngine:
                 100.0 * len(group.device_ids) / group.bucket
             )
             t_submit = tr["t_submit"] = time.time()
+            if write_only:
+                # nothing to fetch or emit: the batch ends here. The
+                # placement is done with the host buffer (without the
+                # prefetch stage the call itself may still be reading
+                # it); no row owes a result before its clip_len-th read.
+                batches.append(tr)
+                if self._xfer is None:
+                    wpool.window(geom).block_until_ready()
+                self._collector.release(group)
+                if self.faults is not None:
+                    self.faults.note_dropped(
+                        len(group.device_ids), "window_filling")
+                continue
             if trace_on:
                 for did, meta in zip(group.device_ids, group.metas):
                     if tracer.sampled(meta.packet):
@@ -3381,7 +3726,8 @@ class InferenceEngine:
                             trace_id=trace_id_of(meta, did),
                         )
             batches.append(tr)
-            self._enqueue_drain(_Inflight(group, outputs, t_submit, tr))
+            self._enqueue_drain(
+                _Inflight(group, outputs, t_submit, tr, emit))
         return batches
 
     def _apply_rung_cap(self, rung: str) -> None:
@@ -3405,10 +3751,19 @@ class InferenceEngine:
         out: List[BatchGroup] = []
         tick_shed = 0
         for group in groups:
+            before = list(group.device_ids) if group.window else ()
             kept, shed = shed_stale(
                 group, now_ms, self._cfg.shed_staleness_ms, self._buckets,
                 shards=self._shards,
             )
+            if shed and before:
+                # read, shed before their windows saw them: those start anew
+                left = set(kept.device_ids) if kept is not None else ()
+                wpool = self._window_pools.get(
+                    group.model or self._spec.name)
+                if wpool is not None:
+                    wpool.restart(
+                        [d for d in before if d not in left], "shed")
             if shed:
                 self.shed_frames += shed
                 tick_shed += shed
@@ -4094,7 +4449,15 @@ class InferenceEngine:
                 for device_id in group.device_ids:
                     self._roi.note_full(device_id, now_mono)
             self.perf.note_roi_emit(len(group.device_ids))
-        for i, device_id in enumerate(group.device_ids):
+        emit = range(len(group.device_ids)) if inflight.emit is None \
+            else inflight.emit
+        if self.faults is not None and len(emit) < len(group.device_ids):
+            # rows whose device window is still filling: computed, owed
+            # no result yet
+            self.faults.note_dropped(
+                len(group.device_ids) - len(emit), "window_filling")
+        for i in emit:
+            device_id = group.device_ids[i]
             meta = group.metas[i]
             # Shard-segmented layout (r17): slot i's device outputs (and
             # its leased frame) live at batch row rows[i]; identity on
