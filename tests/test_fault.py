@@ -3,7 +3,7 @@ the FaultLedger conservation/duplicate/rebase accounting, the FaultPlane
 watchdog state machine (hard-error attribution, drain-deadline
 hysteresis, stall probe resolution), the deterministic ``make_repin``
 rendezvous (survivors keep their pins, composition across cascaded
-faults), ``_PrefetchStage`` slot-parity across a mesh rebuild, a live
+faults), the ``_PrefetchStage``'s bounded queue at shutdown, a live
 dp2 -> dp1 engine failover on the CPU twin, the ``/api/v1/faults``
 endpoint convention, and the fault=False bit-identical serving pin.
 
@@ -277,7 +277,7 @@ class TestMakeRepin:
 
 
 # ---------------------------------------------------------------------------
-# prefetch slot parity across a rebuild (r22 satellite)
+# the prefetch stage's bounded queue
 
 
 class TestPrefetchParityAcrossRebuild:
@@ -287,26 +287,26 @@ class TestPrefetchParityAcrossRebuild:
             rows=((0, 1) if sharded else None),
             frames=np.zeros((bucket, 64, 64, 3), np.uint8))
 
-    def test_reset_clears_parity_and_restarts_at_slot_zero(self):
+    def test_a_full_stage_refuses_at_shutdown_and_takes_again_once_drained(
+            self):
         from video_edge_ai_proxy_tpu.engine.runner import _PrefetchStage
 
-        stage = _PrefetchStage(lambda f: f, lambda: False, shards=2)
+        stage = _PrefetchStage(lambda f: f, lambda: False)
         stop = threading.Event()
-        # Two submissions of the same key toggle the double-buffer slot
-        # per shard; never started, so entries sit in the depth-2 queue.
+        # Never started, so two submissions sit in the depth-2 queue.
         p0 = stage.submit(self._group(), stop)
         p1 = stage.submit(self._group(), stop)
-        assert (p0.slot, p1.slot) == (0, 1)
-        assert len(stage._slots) == 2            # one per shard
-        # Mesh rebuild: the failover path waits every handle and returns
-        # leases (dispatch-failure path) before calling reset — here the
-        # queue just drains.
+        assert p0 is not None and p1 is not None and p0 is not p1
+        assert not p0.ready.is_set() and p0.placed is None
+        # Both slots taken: a third waits, and shutdown ends the wait with
+        # no handle (the caller returns the lease).
+        stop.set()
+        assert stage.submit(self._group(), stop) is None
+        # A mesh rebuild waits every handle first — here the queue just
+        # drains — and the stage takes the survivor mesh's batches.
         stage._q.get_nowait(), stage._q.get_nowait()
-        stage.reset(1)
-        assert stage.shards == 1 and stage._slots == {}
-        p2 = stage.submit(self._group(), stop)
-        assert p2.slot == 0                      # parity restarted
-        assert len(stage._slots) == 1            # survivor keying
+        stop.clear()
+        assert stage.submit(self._group(sharded=False), stop) is not None
 
 
 # ---------------------------------------------------------------------------
@@ -398,8 +398,6 @@ class TestEngineFailover:
         snap = eng.faults.snapshot()
         assert snap["failovers"] == 1 and snap["shards"] == 1
         assert eng._shards == 1
-        if eng._xfer is not None:
-            assert eng._xfer.shards == 1
         det = [e for e in snap["events"] if e["event"] == "detected"]
         fo = [e for e in snap["events"] if e["event"] == "failover"]
         assert det[0]["kind"] == "xla_error" and det[0]["shard"] == 1

@@ -141,18 +141,25 @@ class TestProcessManager:
     def test_runaway_worker_is_contained(self, tmp_path):
         """A worker that tries to eat the host's memory hits RLIMIT_AS and
         dies (MemoryError) instead of stalling the machine — the supervisor
-        restart policy then owns it."""
+        restart policy then owns it. Under the limit the workers really
+        run with, and 4 GiB asked for over it: a limit made small for the
+        test's sake (it was 256 MB) is under what ``import numpy`` itself
+        maps on a host of 8 cores or more (OpenBLAS's buffers, one set a
+        thread), and a child that runs out of address space half way
+        through that import does not die, it hangs at exit."""
         import subprocess
         import sys as _sys
 
         from video_edge_ai_proxy_tpu.serve.process_manager import (
-            _worker_preexec,
+            WORKER_MEM_LIMIT_MB, _worker_preexec,
         )
 
+        assert WORKER_MEM_LIMIT_MB << 20 < 8 << 29      # the 4 GiB below
         proc = subprocess.run(
             [_sys.executable, "-c",
              "import numpy; numpy.ones((1 << 29,), dtype=numpy.float64)"],
-            preexec_fn=lambda: _worker_preexec(mem_limit_mb=256, nice=0),
+            preexec_fn=lambda: _worker_preexec(
+                mem_limit_mb=WORKER_MEM_LIMIT_MB, nice=0),
             capture_output=True, text=True, timeout=60,
         )
         assert proc.returncode != 0

@@ -117,8 +117,6 @@ def _new_trace() -> dict:
       ``read_latest_into``, the window's ``assemble_step``);
       ``read_ahead_s`` is the part of it spent between ticks, before
       collect() was entered.
-    - ``clip_s``: making one stream's clip window one contiguous sample;
-      0.0 since the window is a ring copied straight into its batch row.
     - ``fill_s``: samples -> the batch buffer (a clip ring -> its row),
       with the buffer's allocation and zero-padding.
     - ``frames_read`` / ``bytes_read``: new frames taken off the rings.
@@ -129,7 +127,7 @@ def _new_trace() -> dict:
       to tick. A clip ring's slot and a clip batch's pool buffer count
       fresh the first time they are written: set-up, not steady state.
     """
-    return {"read_s": 0.0, "read_ahead_s": 0.0, "clip_s": 0.0, "fill_s": 0.0,
+    return {"read_s": 0.0, "read_ahead_s": 0.0, "fill_s": 0.0,
             "frames_read": 0, "bytes_read": 0, "bytes_copied": 0,
             "bytes_fresh": 0}
 

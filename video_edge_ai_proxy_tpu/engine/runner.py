@@ -55,6 +55,8 @@ from ..utils.config import EngineConfig
 from ..utils.logging import get_logger, reset_log_context, set_log_context
 from .classes import class_name
 from .collector import BatchGroup, CanvasPacker, Collector, pad_to_bucket
+from .stream_state import (
+    ClipWindowPool, StreamStatePool, _ShardedThumbPool, _ThumbPool)
 
 log = get_logger("engine.runner")
 
@@ -106,8 +108,8 @@ def build_serving_step(model, spec, *, quality_thumb: int = 0, mesh=None,
     serves the transformer families, which carry no such kernel below
     ``FLASH_THRESHOLD_T`` tokens.
 
-    Step kind ``stream`` (``spec.kind == "stream"``, models/lfm2.py): a
-    step with state in and state out,
+    Step kind ``stream`` (models/lfm2.py): a step with state in and
+    state out,
     ``stream_step(variables, frames, state, idx, pos0, reset, rounds)``.
     ``state`` is the model's ``StreamStatePool`` buffers (``conv`` and
     ``tokens`` [slots, ...], ``kv`` = (keys, values) [attention layers,
@@ -544,7 +546,7 @@ class _Inflight:
 # vep_tick_phase_seconds_total{phase=...}: what the tick thread did with a
 # tick that read at least one frame, plus "idle" (ticks that found nothing,
 # and the between-tick wait for frames).
-_TICK_PHASES = ("pre_collect", "read", "clip", "fill", "collect_other",
+_TICK_PHASES = ("pre_collect", "read", "fill", "collect_other",
                 "place_wait", "pool", "state_wait", "step_call", "idle")
 
 
@@ -662,243 +664,11 @@ def _build_cascade_head(model, score_w, score_b):
     return head
 
 
-class _ThumbPool:
-    """Device-resident per-stream quality-thumbnail state (ROADMAP item
-    5 host-work fold): one [capacity, th, tw] f32 device array plus a
-    host slot map, replacing the per-dispatch host ``jnp.stack`` of
-    zero rows the old ``_gather_thumbs`` built. The previous tick's
-    thumbnails for a batch are a device-side ``jnp.take`` keyed by slot
-    indices; this tick's rows scatter back with ``.at[idx].set`` —
-    thumbnail state never crosses back to host, and the dispatch loop
-    ships only a [bucket] int32 index vector.
-
-    Row 0 is a permanent zero row: first-seen streams (and padded batch
-    slots) gather it, preserving the zero-reference/first-diff contract
-    ``frame_quality_stats`` documents. Dict-like surface (``__iter__``/
-    ``__len__``/``pop``) so the tick loop's debounced per-stream GC
-    treats it exactly like the tracker/annotation state dicts. All
-    methods run on the tick thread (same single-writer discipline the
-    old per-stream dict had).
-    """
-
-    __slots__ = ("side", "device", "_slots", "_free", "_pool", "_capacity",
-                 "_high")
-
-    _GROW = 64    # rows added per capacity growth (keeps re-pads rare)
-
-    def __init__(self, side: int, device=None):
-        self.side = int(side)
-        # r17: a sharded parent pins each sub-pool to its mesh slice's
-        # lead device, so gathers/scatters stay chip-local. None keeps
-        # the legacy default-device placement bit-identical.
-        self.device = device
-        self._slots: Dict[str, int] = {}   # device_id -> pool row (>= 1)
-        self._free: List[int] = []
-        self._pool = None                  # lazy: jax import stays off the
-        self._capacity = 0                 # control plane (CLAUDE.md)
-        self._high = 0                     # highest row ever assigned
-
-    def __bool__(self) -> bool:
-        return bool(self._slots)
-
-    def __iter__(self):
-        return iter(list(self._slots))
-
-    def __len__(self) -> int:
-        return len(self._slots)
-
-    def pop(self, device_id: str, default=None):
-        """Forget a stream (tick-loop GC): its row returns to the free
-        list. The stale row contents are unreachable — nothing gathers a
-        row until scatter() reassigns it, which overwrites it first."""
-        row = self._slots.pop(device_id, None)
-        if row is not None:
-            self._free.append(row)
-        return default
-
-    def _ensure(self, rows: int) -> None:
-        import jax.numpy as jnp
-
-        if self._pool is None:
-            cap = max(self._GROW, rows)
-            pool = jnp.zeros((cap, self.side, self.side), jnp.float32)
-            if self.device is not None:
-                import jax
-
-                pool = jax.device_put(pool, self.device)
-            self._pool = pool
-            self._capacity = cap
-        elif rows > self._capacity:
-            grow = -(-(rows - self._capacity) // self._GROW) * self._GROW
-            # Padding a committed array computes on (and stays on) its
-            # device, so the shard pinning survives growth.
-            self._pool = jnp.pad(self._pool, ((0, grow), (0, 0), (0, 0)))
-            self._capacity += grow
-
-    def gather_indices(self, device_ids, bucket: int, rows=None) -> np.ndarray:
-        """[bucket] int32 gather rows for a batch, slot order: each
-        known stream's row, row 0 (zeros) for first-seen streams and
-        padded slots. ``rows`` (shard-segmented layouts) maps slot i to
-        its batch row; None keeps the legacy identity order. This
-        vector is the only host->device bytes the quality path still
-        ships per batch."""
-        idx = np.zeros(bucket, np.int32)
-        for i, did in enumerate(device_ids):
-            r = i if rows is None else rows[i]
-            idx[r] = self._slots.get(did, 0)
-        return idx
-
-    def gather(self, idx: np.ndarray):
-        """Previous-tick [bucket, th, tw] rows as a device-side gather."""
-        import jax.numpy as jnp
-
-        self._ensure(1)
-        return jnp.take(self._pool, jnp.asarray(idx), axis=0)
-
-    def scatter(self, device_ids, thumbs, rows=None) -> None:
-        """Store this tick's [>=n, th, tw] device rows (the step output,
-        still async) for next tick's diff; assigns pool rows on first
-        sight. ``rows`` names each stream's source row inside ``thumbs``
-        (shard-segmented layouts); None = slot order, legacy path."""
-        import jax.numpy as jnp
-
-        pool_rows = []
-        for did in device_ids:
-            row = self._slots.get(did)
-            if row is None:
-                row = self._free.pop() if self._free else self._high + 1
-                self._high = max(self._high, row)
-                self._slots[did] = row
-            pool_rows.append(row)
-        if not pool_rows:
-            return
-        self._ensure(max(pool_rows) + 1)
-        idx = jnp.asarray(np.asarray(pool_rows, np.int32))
-        if rows is None:
-            src = thumbs[:len(pool_rows)]
-        else:
-            src = jnp.take(
-                thumbs, jnp.asarray(np.asarray(rows, np.int32)), axis=0)
-        self._pool = self._pool.at[idx].set(src)
-
-    def nbytes(self) -> int:
-        """Device bytes held by the thumbnail ring right now (0 before
-        first scatter) — obs/hbm.py ``register_pool`` tap. Capacity-
-        based like the track-state ring: grown rows stay allocated after
-        their streams GC. Metadata only, no transfer."""
-        return int(self._pool.nbytes) if self._pool is not None else 0
-
-
-class _ShardedThumbPool:
-    """Per-mesh-slice thumbnail state for mesh serving (r17 tentpole
-    leg 3): one ``_ThumbPool`` per dp shard, each pinned to its slice's
-    lead device, speaking the collector's shard-segmented row layout
-    (``group.rows``). ``gather`` assembles the per-shard device takes
-    into one dp-sharded [bucket, th, tw] array (the same sharding the
-    frames carry, so the compiled step sees one stable signature);
-    ``scatter`` splits the step's sharded thumbnail output back per
-    slice via its addressable shards — a stream's t-1 thumbnail lives
-    on the chip that serves its frames, and no thumbnail bytes ever
-    cross the host or a chip boundary. Dict-like surface mirrors
-    ``_ThumbPool`` for the tick loop's per-stream GC."""
-
-    __slots__ = ("side", "shards", "_mesh", "_shard_of", "_subs")
-
-    def __init__(self, side: int, *, mesh, shards: int, shard_of):
-        from ..temporal.state_pool import shard_devices
-
-        self.side = int(side)
-        self.shards = int(shards)
-        self._mesh = mesh
-        self._shard_of = shard_of
-        self._subs = [
-            _ThumbPool(side, device=d)
-            for d in shard_devices(mesh, self.shards)
-        ]
-
-    def __bool__(self) -> bool:
-        return any(bool(sub) for sub in self._subs)
-
-    def __iter__(self):
-        ids: List[str] = []
-        for sub in self._subs:
-            ids.extend(sub)
-        return iter(ids)
-
-    def __len__(self) -> int:
-        return sum(len(sub) for sub in self._subs)
-
-    def pop(self, device_id: str, default=None):
-        self._subs[self._shard_of(device_id) % self.shards].pop(device_id)
-        return default
-
-    def gather_indices(self, device_ids, bucket: int, rows=None):
-        """Per-shard [seg] int32 local gather rows (list, one array per
-        shard). Row r of the batch lives in shard r // seg at local row
-        r % seg — the collector's segmented layout."""
-        seg = max(1, bucket // self.shards)
-        per = [np.zeros(seg, np.int32) for _ in range(self.shards)]
-        for i, did in enumerate(device_ids):
-            r = i if rows is None else rows[i]
-            per[r // seg][r % seg] = self._subs[r // seg]._slots.get(did, 0)
-        return per
-
-    def gather(self, idx):
-        """Previous-tick [bucket, th, tw] thumbnails as one dp-sharded
-        array: a chip-local take per shard, assembled without any
-        cross-chip movement."""
-        import jax.numpy as jnp
-
-        from ..parallel import assemble_sharded, batch_sharding
-
-        pieces = []
-        for s, sub in enumerate(self._subs):
-            sub._ensure(1)
-            pieces.append(jnp.take(sub._pool, jnp.asarray(idx[s]), axis=0))
-        bucket = sum(int(p.shape[0]) for p in pieces)
-        return assemble_sharded(
-            pieces, (bucket, self.side, self.side),
-            batch_sharding(self._mesh, 3),
-        )
-
-    def scatter(self, device_ids, thumbs, rows=None) -> None:
-        """Route this tick's sharded [bucket, th, tw] step output into
-        the per-shard pools: each shard scatters from its own
-        addressable slice (chip-local), with a sliced-view fallback
-        when the compiled output's layout hides a shard."""
-        bucket = int(thumbs.shape[0])
-        seg = max(1, bucket // self.shards)
-        by_shard: Dict[int, List[tuple]] = {}
-        for i, did in enumerate(device_ids):
-            r = i if rows is None else rows[i]
-            by_shard.setdefault(r // seg, []).append((r % seg, did))
-        pieces: Dict[int, Any] = {}
-        for sh in getattr(thumbs, "addressable_shards", ()):
-            if int(sh.data.shape[0]) != seg:
-                continue   # unexpected output layout: fallback below
-            start = sh.index[0].start or 0
-            pieces.setdefault(start // seg, sh.data)
-        for s, pairs in sorted(by_shard.items()):
-            piece = pieces.get(s)
-            if piece is None:
-                piece = thumbs[s * seg:(s + 1) * seg]
-            self._subs[s].scatter(
-                [did for _, did in pairs], piece,
-                rows=[r for r, _ in pairs],
-            )
-
-    def nbytes(self) -> Dict[str, int]:
-        """Per-shard thumbnail ring bytes ``{shard: bytes}`` — the
-        obs/hbm.py sharded ``register_pool`` shape (each sub-pool's
-        figure is exact against its own ring's ``.nbytes``)."""
-        return {str(s): sub.nbytes() for s, sub in enumerate(self._subs)}
-
-
 class _Prefetched:
     """Handle for one batch placement in flight on the transfer thread."""
 
     __slots__ = ("group", "ready", "placed", "error", "transfer_s",
-                 "overlapped_s", "slot", "t_q", "t0", "t1")
+                 "overlapped_s", "t_q", "t0", "t1")
 
     def __init__(self, group: BatchGroup):
         self.group = group
@@ -907,7 +677,6 @@ class _Prefetched:
         self.error: Optional[BaseException] = None
         self.transfer_s = 0.0
         self.overlapped_s = 0.0   # transfer wall time with >=1 batch in flight
-        self.slot = 0             # which of the key's two input slots
         # wall stamps for the batch trace: handed to the stage, picked up
         # by the transfer thread, block_until_ready returned
         self.t_q = time.time()
@@ -920,34 +689,25 @@ class _PrefetchStage:
     input slots — feeding one transfer thread that places each collected
     batch with a real async ``jax.device_put``. The copy of batch t+1
     runs while the tick thread dispatches batch t and the device
-    computes it, instead of serializing inside the dispatch loop (the
-    pre-r12 behavior: single-device placement was a passthrough and the
-    whole uint8 plane crossed synchronously inside the step call).
+    computes it, instead of serializing inside the dispatch loop.
     ``block_until_ready`` on the placed array bounds the transfer window
     AND guarantees the pooled host buffer is no longer being read when
     the handle resolves — the lease-return failure path relies on that.
 
-    Slot parity per key is bookkeeping for attribution (at most DEPTH
-    placements of a key are ever outstanding); the HBM itself returns to
-    the allocator when the dispatched step is done with its frames
-    argument — under a mesh XLA may take it earlier, as a donated buffer
-    (see ``_step`` for why only there).
+    At most DEPTH placements are ever outstanding; the HBM itself
+    returns to the allocator when the dispatched step is done with its
+    frames argument — under a mesh XLA may take it earlier, as a donated
+    buffer (see ``_step`` for why only there).
     """
 
     DEPTH = 2
 
-    def __init__(self, place_fn, busy_fn, shards: int = 1):
+    def __init__(self, place_fn, busy_fn):
         self._place = place_fn       # host frames -> device array
         self._busy = busy_fn         # True when >=1 dispatched batch in flight
-        # r17: under mesh serving each placement fans out one async
-        # device_put per dp slice; slot parity tracks per (shard, model,
-        # geometry, bucket) so attribution stays per-chip even though
-        # the shard-segmented group advances all slices together.
-        self.shards = int(shards)
         self._q: "queue.Queue[Optional[_Prefetched]]" = queue.Queue(
             maxsize=self.DEPTH)
         self._thread: Optional[threading.Thread] = None
-        self._slots: Dict[tuple, int] = {}
 
     def start(self) -> None:
         self._thread = threading.Thread(
@@ -985,27 +745,11 @@ class _PrefetchStage:
                 total += int(getattr(part, "nbytes", 0) or 0)
         return total
 
-    def reset(self, shards: int) -> None:
-        """Survivor-mesh failover (engine/fault.py): parity slots keyed
-        on the old shard count are meaningless once the mesh shrinks, so
-        drop them wholesale and restart attribution at slot 0. The tick
-        thread owns both submission and failover, and the failover path
-        waits every in-flight handle before calling this, so the queue
-        is empty and no key can be mid-flight."""
-        self.shards = max(1, int(shards))
-        self._slots.clear()
-
     def submit(self, group: BatchGroup, stop_event) -> Optional[_Prefetched]:
         """Queue a placement; blocks (in interruptible slices) while both
         slots are occupied — same bounded-pipeline stance as the drain
         queue. Returns None on shutdown (caller returns the lease)."""
         pre = _Prefetched(group)
-        n_keys = self.shards if group.rows is not None else 1
-        keys = [(s, group.model, group.src_hw, group.bucket)
-                for s in range(n_keys)]
-        pre.slot = self._slots.get(keys[0], 0)
-        for key in keys:
-            self._slots[key] = self._slots.get(key, 0) ^ 1
         while not stop_event.is_set():
             try:
                 self._q.put(pre, timeout=0.1)
@@ -1051,8 +795,9 @@ def _group_slots(group: BatchGroup) -> int:
     return len(group.device_ids)
 
 
-class _RoiGate:
-    """Per-stream motion-gate state for MOSAIC ROI serving (cfg.roi).
+class _RoiGate(dict):
+    """Per-stream motion-gate state for MOSAIC ROI serving (cfg.roi):
+    ``device_id -> {"diff", "full_at"}``.
 
     Classification inputs are both *feedback* signals: the previous
     tick's device thumbnail diff energy (ops/preprocess.py
@@ -1070,32 +815,19 @@ class _RoiGate:
     - ``roi``   — motion with live tracks: crops around the predicted
       track boxes join the shared canvases.
 
-    Dict-like protocol (``__iter__``/``__len__``/``pop``) so the
-    engine's debounced stream GC treats it exactly like the tracker /
-    thumbnail state maps. All access runs under the engine's
-    ``_state_lock`` (tick-thread classify + GC, drain-thread feedback).
+    A dict, so the engine's debounced stream GC treats it exactly like
+    the tracker / thumbnail state maps. All access runs under the
+    engine's ``_state_lock`` (tick-thread classify + GC, drain-thread
+    feedback).
     """
 
     def __init__(self, idle_diff: float, full_interval_ms: float):
+        super().__init__()
         self.idle_diff = float(idle_diff)
         self.full_interval_s = full_interval_ms / 1000.0
-        self._streams: Dict[str, dict] = {}
-
-    def __bool__(self) -> bool:
-        return bool(self._streams)
-
-    def __iter__(self):
-        return iter(self._streams)
-
-    def __len__(self) -> int:
-        return len(self._streams)
-
-    def pop(self, device_id: str, default=None):
-        return self._streams.pop(device_id, default)
 
     def state(self, device_id: str) -> dict:
-        return self._streams.setdefault(
-            device_id, {"diff": None, "full_at": 0.0})
+        return self.setdefault(device_id, {"diff": None, "full_at": 0.0})
 
     def note_diff(self, device_id: str, diff: float) -> None:
         self.state(device_id)["diff"] = float(diff)
@@ -1213,6 +945,12 @@ class InferenceEngine:
         # Annotation emit policy state: device_id -> {"sig": {key: conf},
         # "last_ms": int} (cfg.annotation_emit; GC'd with the trackers).
         self._ann_state: Dict[str, dict] = {}
+        # Every per-stream container, in the order the tick loop's GC pops
+        # a departed stream from them (``_run``): these two, the thumbnails,
+        # the ROI gate and its journaled mode, the cascade, ``_window_home``,
+        # then each head and window pool as it is built. The HBM ledger sums
+        # the pools among them by their ``ledger`` name.
+        self._per_stream: List[Any] = [self._trackers, self._ann_state]
         self._ann_policy_warned: set = set()  # (device_id, bad policy)
         self.annotations_suppressed = 0
         # Results dropped on slow subscribers (queue full in _publish):
@@ -1437,6 +1175,7 @@ class InferenceEngine:
         # Under a mesh, warmup swaps in the sharded twin once the mesh
         # exists (_ShardedThumbPool: one _ThumbPool per dp slice).
         self._thumbs = _ThumbPool(self._cfg.quality_thumb)
+        self._per_stream.append(self._thumbs)
         self._quality_device = False
         # Data-parallel serving state (r17 tentpole leg 1): shard count
         # and the stream->shard map, set by warmup once the mesh shape
@@ -1455,6 +1194,7 @@ class InferenceEngine:
         if self._cfg.roi:
             self._roi = _RoiGate(
                 self._cfg.roi_idle_diff, self._cfg.roi_full_interval_ms)
+            self._per_stream += [self._roi, self._roi_mode]
         # Temporal cascade serving (CASCADE, ROADMAP item 2): tracker-
         # keyed device clip rings + cadence-1/N temporal head
         # (temporal/scheduler.py). cascade=False leaves it None — every
@@ -1478,6 +1218,8 @@ class InferenceEngine:
                 perf=self.perf,
             )
             self._cascade.head = self._cascade_head
+            self._per_stream.append(self._cascade)
+        self._per_stream.append(self._window_home)
         # Capacity attribution plane (obs/capacity.py): the per-stream
         # device-time ledger + headroom forecast fed from the same
         # _emit measurements obs/perf.py aggregates, evaluated off the
@@ -1548,21 +1290,14 @@ class InferenceEngine:
                 eval_interval_s=self._cfg.hbm_eval_interval_s,
                 pressure_horizon_s=self._cfg.hbm_pressure_horizon_s,
             )
-            self.hbm.register_pool(
-                "thumbs",
-                lambda: self._thumbs.nbytes() if self._thumbs is not None
-                else 0)
+            for ledger in (_ThumbPool.ledger, StreamStatePool.ledger,
+                           ClipWindowPool.ledger):
+                self.hbm.register_pool(
+                    ledger, lambda name=ledger: self._ledger_nbytes(name))
             self.hbm.register_pool(
                 "track_state",
                 lambda: self._cascade.pool_nbytes()
                 if self._cascade is not None else 0)
-            self.hbm.register_pool(
-                "stream_state",
-                lambda: sum(p.nbytes() for p in self._head_pools.values()))
-            self.hbm.register_pool(
-                "clip_windows",
-                lambda: sum(p.nbytes()
-                            for p in list(self._window_pools.values())))
             self.hbm.register_pool(
                 "prefetch",
                 lambda: self._xfer.nbytes() if self._xfer is not None else 0)
@@ -1587,6 +1322,21 @@ class InferenceEngine:
                 probe_timeout_ms=self._cfg.fault_probe_timeout_ms,
                 journal=self.journal,
             )
+
+    def _ledger_nbytes(self, ledger: str):
+        """Device bytes of the pools filed under one name of the HBM
+        ledger: their sum, or the one sharded pool's ``{shard: bytes}``
+        as it is (the thumbnails under a mesh). list(): the ledger reads
+        while the tick thread may be building a pool."""
+        held = [p.nbytes() for p in list(self._per_stream)
+                if getattr(p, "ledger", None) == ledger]
+        return held[0] if len(held) == 1 else sum(held)
+
+    def _swap_thumbs(self, pool) -> None:
+        """The thumbnail pool's sharded twin takes its place (warmup under
+        a mesh, a survivor-mesh failover), in the GC's list too."""
+        self._per_stream[self._per_stream.index(self._thumbs)] = pool
+        self._thumbs = pool
 
     @property
     def cascade(self):
@@ -1710,13 +1460,11 @@ class InferenceEngine:
 
             self._shards = dp
             self._shard_of = lambda did: stream_shard(did, dp)
-            if self._xfer is not None:
-                self._xfer.shards = dp
             if self._quality_device:
-                self._thumbs = _ShardedThumbPool(
+                self._swap_thumbs(_ShardedThumbPool(
                     self._cfg.quality_thumb, mesh=self._mesh, shards=dp,
                     shard_of=self._shard_of,
-                )
+                ))
             if self._cascade is not None:
                 self._cascade.configure_mesh(
                     mesh=self._mesh, shards=dp, shard_of=self._shard_of,
@@ -2536,7 +2284,7 @@ class InferenceEngine:
             (spec.clip_len,) if spec.clip_len else ()
         ) + tuple(src_hw) + (3,)
         args = [self._place(np.zeros(shape, np.uint8))]
-        if spec.kind == "stream":
+        if self._head_pool(spec.name) is not None:
             # every row padded: the state is gathered clipped, nothing is
             # scattered, and the pool's shapes are the serving ones
             pool = self._head_pool(spec.name)
@@ -2600,15 +2348,24 @@ class InferenceEngine:
             raise
 
     def _head_pool(self, model: str):
-        """The state pool of a stream-head model (one a model)."""
+        """The state pool of a stream-head model (one a model); None for a
+        model whose step carries no head state."""
         pool = self._head_pools.get(model)
         if pool is None:
-            from .stream_state import StreamStatePool
-
-            _, mod, _ = self._ensure_model(model)
+            spec, mod, _ = self._ensure_model(model)
+            if spec.kind not in StreamStatePool.kinds:
+                return None
             pool = self._head_pools[model] = StreamStatePool(
-                mod, grow=max(self._buckets or (1,)))
+                mod, grow=max(self._buckets or (1,)),
+                note_round=self._note_head_round)
+            self._per_stream.append(pool)
         return pool
+
+    def _note_head_round(self, prefill: int, decode: int,
+                         resets: int) -> None:
+        self._m_head_tokens["prefill"].inc(prefill)
+        self._m_head_tokens["decode"].inc(decode)
+        self._m_head_resets.inc(resets)
 
     def _window_on_device(self, model: str) -> bool:
         """Where a model's clip window lives, from what the engine
@@ -2627,29 +2384,49 @@ class InferenceEngine:
         """The clip-window pool of a clip-taking model (one a model)."""
         pool = self._window_pools.get(model)
         if pool is None:
-            from .stream_state import ClipWindowPool
-
             spec, _, _ = self._ensure_model(model)
+
+            def program(geom, bucket, slots, write_only):
+                # called as the step is: the windowed step, or
+                # window_write alone (no output but the window)
+                src_hw = tuple(geom[:2])
+                if not write_only:
+                    return self._step(src_hw, bucket, model, window=slots)
+                write = self._window_writer(src_hw, bucket, model, slots)
+                return lambda variables, frames, window, idx, pos: {
+                    ClipWindowPool.key: write(window, frames, idx, pos)}
+
             pool = self._window_pools[model] = ClipWindowPool(
                 spec.clip_len, self._buckets or (1,),
-                note_restart=self._note_window_restart)
+                note_restart=self._note_window_restart, program=program)
+            self._per_stream.append(pool)
         return pool
 
-    def _plan_window(self, group: BatchGroup, name: str):
-        """(pool, geometry, plan) of a group of single frames bound for
-        their streams' device windows: each row's slot and write position
-        (``ClipWindowPool.plan``). A stream first seen under this model
-        leaves the window it had under another."""
-        geom = group.frames.shape[1:]
-        wpool = self._window_pool(name)
-        self._leave_windows(group.device_ids, name)
-        return wpool, geom, wpool.plan(
-            group.device_ids, geom, group.bucket, rows=group.rows)
+    def _carried(self, model: str, window: bool, canvas: bool = False):
+        """The states the step of (model, window) carries from round to
+        round, in the order of their arguments after ``frames``: ``_step``
+        reckons the donated positions from them, ``_dispatch`` plans,
+        calls and commits them. From what the engine observes: single
+        frames bound for device windows, the model's spec, and the quality
+        plane's device side for a step of single frames (never a canvas
+        group's: its synthetic _canvas<i> ids must not claim thumbnail
+        rows, and a canvas "frame" has no per-stream diff meaning anyway;
+        full-frame refreshes keep the signal)."""
+        spec = self._ensure_model(model)[0]
+        states = [self._window_pool(model)] if window else []
+        head = self._head_pool(model)
+        if head is not None:
+            states.append(head)
+        if self._quality_device and not spec.clip_len and not canvas:
+            states.append(self._thumbs)
+        return states
 
     def _leave_windows(self, device_ids, name: Optional[str]) -> None:
         """These streams are served under model ``name`` now (None: one
         whose windows live on the host): each leaves the device window it
         had under another model, counted where that held a frame."""
+        if name is None and not self._window_home:
+            return
         for did in device_ids:
             was = self._window_home.get(did, name)
             if was != name and was in self._window_pools:
@@ -2773,15 +2550,12 @@ class InferenceEngine:
                     and jax.default_backend() == "tpu"
                     and self._mesh is not None and self._mesh.size > 1):
                 donate = (1,)
-            if window:
-                # the window buffer (argnum 2) comes back as the output's
-                # "window", same shape and dtype: rewritten in place; the
-                # step's own arguments follow idx and pos
-                donate += (2,)
-            if spec.kind == "stream":
-                # the pool's buffers come back as the output's "state",
-                # same shapes: rewritten in place
-                donate += (5 if window else 2,)
+            # what each carried state lets the step rewrite in place, at
+            # its place after (variables, frames)
+            at = 2
+            for state in self._carried(model, bool(window)):
+                donate += tuple(at + i for i in state.donated)
+                at += state.step_args
             # Compile attribution (obs/perf.py): the wrapper AOT-compiles
             # on first call, recording wall time + XLA cost analysis per
             # (model, geometry, bucket) — this is the only cache-miss
@@ -2962,11 +2736,7 @@ class InferenceEngine:
                 # re-creates its ring unlink-then-create — one sample in
                 # that window must not reset the stream's track-id
                 # numbering (invariant in _assign_tracks).
-                if self._trackers or self._ann_state or self._thumbs \
-                        or any(self._head_pools.values()) \
-                        or any(self._window_pools.values()) \
-                        or (self._roi is not None and self._roi) \
-                        or (self._cascade is not None and self._cascade):
+                if any(self._per_stream):
                     now = time.monotonic()
                     # GC keys on bus PRESENCE, not on inference_streams():
                     # a live stream gated >grace (inference_model toggled
@@ -2974,52 +2744,24 @@ class InferenceEngine:
                     # would restart track-id numbering and reuse ids
                     # already uplinked for other objects.
                     present = set(present)
-                    roi_ids = set(self._roi) if self._roi is not None \
-                        else set()
-                    casc_ids = set(self._cascade) \
-                        if self._cascade is not None else set()
-                    head_ids = set().union(*self._head_pools.values(),
-                                           *self._window_pools.values())
                     with self._state_lock:
-                        for d in (set(self._trackers) | set(self._ann_state)
-                                  | set(self._thumbs) | roi_ids
-                                  | casc_ids | head_ids):
+                        for d in set().union(*self._per_stream):
                             if d in present:
                                 self._tracker_absent.pop(d, None)
                                 continue
                             since = self._tracker_absent.setdefault(d, now)
                             if now - since > self._TRACKER_GC_GRACE_S:
-                                self._trackers.pop(d, None)
-                                # Annotation-policy state rides the same
-                                # debounced GC: a worker-restart ring gap
-                                # must not reset on_change/min_interval
-                                # state, but a re-added stream must not
-                                # diff against a months-old signature.
-                                self._ann_state.pop(d, None)
-                                # Quality state too: the device-resident
-                                # thumbnail and the verdict machine both
-                                # restart cleanly when the stream does
-                                # (the tracker re-discards its first
-                                # zero-reference diff).
-                                self._thumbs.pop(d, None)
-                                # ROI gate state restarts with the
-                                # stream (first frame re-gates to full).
-                                if self._roi is not None:
-                                    self._roi.pop(d, None)
-                                    self._roi_mode.pop(d, None)
-                                # Cascade track state goes with the
-                                # stream: device slots free, event
-                                # machines clear without firing.
-                                if self._cascade is not None:
-                                    self._cascade.pop(d, None)
-                                # A stream head's slot frees with the
-                                # stream; a returning one starts over.
-                                for pool in self._head_pools.values():
-                                    pool.pop(d, None)
-                                # So does its window's on the device.
-                                for pool in self._window_pools.values():
-                                    pool.pop(d, None)
-                                self._window_home.pop(d, None)
+                                # Every kind of per-stream state rides the
+                                # same debounced GC: a worker-restart ring
+                                # gap must not reset it, but a re-added
+                                # stream must not diff against a months-old
+                                # signature. It starts over: thumbnail and
+                                # verdict machine restart cleanly, the
+                                # first frame re-gates to full, cascade
+                                # event machines clear without firing,
+                                # head and window slots free.
+                                for container in self._per_stream:
+                                    container.pop(d, None)
                                 if self.quality is not None:
                                     self.quality.forget(d)
                                 del self._tracker_absent[d]
@@ -3064,8 +2806,7 @@ class InferenceEngine:
         Every batch of the tick carries a copy (``_dispatch``)."""
         pc_collect = time.perf_counter()
         tick = dict(self._collector.last_trace)
-        in_collect = (tick["read_s"] - tick["read_ahead_s"]
-                      + tick["clip_s"] + tick["fill_s"])
+        in_collect = tick["read_s"] - tick["read_ahead_s"] + tick["fill_s"]
         tick.update(
             tick=self.ticks, t_tick0=t_tick0,
             # end of the previous tick's dispatch -> collect() entry, less
@@ -3095,8 +2836,7 @@ class InferenceEngine:
         state_wait_s = sum(b.get("state_wait_s", 0.0) for b in batches)
         for phase, seconds in (
                 ("pre_collect", tick["pre_collect_s"]),
-                ("read", tick["read_s"]), ("clip", tick["clip_s"]),
-                ("fill", tick["fill_s"]),
+                ("read", tick["read_s"]), ("fill", tick["fill_s"]),
                 ("collect_other", tick["collect_other_s"]),
                 ("place_wait", place_wait_s), ("pool", pool_s),
                 ("state_wait", state_wait_s), ("step_call", step_call_s),
@@ -3124,7 +2864,6 @@ class InferenceEngine:
             "engine.tick", "collect_tick", n, ts=tick["t_collect"],
             dur_ms=(tick["t_collect"] - tick["t_collect0"]) * 1e3, tick=n,
             read_ms=round(tick["read_s"] * 1e3, 3),
-            clip_ms=round(tick["clip_s"] * 1e3, 3),
             fill_ms=round(tick["fill_s"] * 1e3, 3))
         for b in batches:
             extra = {"tick": n, "batch": list(b["batch"])}
@@ -3304,8 +3043,6 @@ class InferenceEngine:
         self._shards = new_shards
         self._shard_of = repin
         self._buckets = new_buckets
-        if self._xfer is not None:
-            self._xfer.reset(new_shards)
         # (3) Params back onto the survivor mesh. dp-only means fully
         # replicated — every survivor holds a complete copy, so
         # re-placement never needs the dead chip's buffers.
@@ -3325,10 +3062,10 @@ class InferenceEngine:
         evacuated: Dict[str, int] = {}
         if isinstance(self._thumbs, _ShardedThumbPool):
             evacuated["quality_thumbs"] = len(self._thumbs)
-            self._thumbs = _ShardedThumbPool(
+            self._swap_thumbs(_ShardedThumbPool(
                 self._cfg.quality_thumb, mesh=new_mesh, shards=new_shards,
                 shard_of=repin,
-            )
+            ))
         if self._cascade is not None:
             try:
                 evacuated.update(self._cascade.repin_mesh(
@@ -3412,25 +3149,29 @@ class InferenceEngine:
         the group), ``t_place_q``/``t_place0``/``t_placed`` (handed to the
         transfer thread, picked up, placed), ``place_wait_s`` (this
         thread's blocked time on the placement, ending at
-        ``t_place_got``), for a stream head ``pool_s`` (the state pool's
-        plan) and ``state_wait_s`` (blocked on the predecessor step, whose
+        ``t_place_got``), what each carried state stamps (a stream head:
+        ``head_*`` and ``pool_s``, its plan; a device window:
+        ``window_rows``, rows of this batch written into their streams'
+        windows), ``state_wait_s`` (blocked on the predecessor step, whose
         state this one takes), ``t_step0``/``t_step1`` and ``step_call_s``
-        (around the step call; a compile shows here), ``t_submit``,
-        ``window_rows`` (rows of this batch written into their streams'
-        windows on the device) and ``window_restarts`` (device windows
-        started anew since the last batch's trace). The drain thread adds
-        ``t_deq``, ``t_drain0``, ``t_drained``.
+        (around the step call; a compile shows here), ``t_submit`` and
+        ``window_restarts`` (device windows started anew since the last
+        batch's trace). The drain thread adds ``t_deq``, ``t_drain0``,
+        ``t_drained``.
 
-        A group of single frames bound for device windows (``group.window``,
-        from a Collector told ``device_windows``) runs the windowed step:
-        the model's ``ClipWindowPool`` plans each row's slot and write
-        position, its buffer goes in donated and the returned one is taken
-        back before the drain fetches the rest; the next step takes that
-        handle at once (jax orders the two, nothing waits here). Rows
-        whose window is still filling are computed and not emitted; a
-        batch with no full window at all only writes its frames
-        (``window_write``) and ends at the dispatch. A failure after the
-        frames were read restarts the windows involved.
+        One call sequence for every batch: the states its step carries
+        from round to round (``_carried``; engine/stream_state.py) each
+        plan it (``carry``: slots, index vectors, host bookkeeping), the
+        step runs on their arguments with their buffers donated, and each
+        takes its buffer back before the drain fetches the rest; the next
+        step takes that handle at once (jax orders the two, nothing waits
+        here). A group of single frames bound for device windows
+        (``group.window``, from a Collector told ``device_windows``) runs
+        the program its window names: the windowed step, rows whose window
+        is still filling computed and not emitted, or, with no full window
+        at all, the write alone (``window_write``), which ends at the
+        dispatch. A failure after the frames were read restarts the
+        windows involved.
 
         With cfg.prefetch the placement of group g+1 (and g+2) runs on
         the transfer thread while this thread dispatches group g and the
@@ -3488,13 +3229,14 @@ class InferenceEngine:
             _top_up(_PrefetchStage.DEPTH)
         for gi, group in enumerate(groups):
             tr = dict(tick, batch=(tick["tick"], gi))
+            emit = None
             try:
-                # (a windowed step is keyed by its buffer's slots: below)
+                # (a windowed step is keyed by its buffer's slots: its
+                # window's carry names the program, below)
                 step = None if group.window else self._step(
                     group.src_hw, group.bucket, group.model)
-                _, _, variables = self._ensure_model(
-                    group.model or self._spec.name
-                )
+                name = group.model or self._spec.name
+                _, _, variables = self._ensure_model(name)
                 if self._xfer is not None:
                     _top_up(gi + 1 + _PrefetchStage.DEPTH)
                     pre = handles[gi]
@@ -3529,141 +3271,66 @@ class InferenceEngine:
                     hidden_s = 0.0
                 tr["place_wait_s"] = wait_s
                 tr["t_place_got"] = time.time()
-                idx = None
-                aux_nbytes = 0
-                # Canvas groups (group.crops) never carry quality state:
-                # their synthetic _canvas<i> ids must not claim thumbnail
-                # pool rows, and a canvas "frame" has no per-stream diff
-                # meaning anyway (full-frame refreshes keep the signal).
-                if self._quality_device and group.frames.ndim == 4 \
-                        and group.crops is None and not group.window:
-                    idx = self._thumbs.gather_indices(
-                        group.device_ids, group.bucket, rows=group.rows)
-                    aux_nbytes = (
-                        sum(int(a.nbytes) for a in idx)
-                        if isinstance(idx, list) else int(idx.nbytes)
-                    )
-                self.perf.note_h2d(
-                    group.model or self._spec.name, group.bucket,
-                    group.nbytes + aux_nbytes, h2d_s, hidden_s=hidden_s,
-                )
-                head = wplan = emit = None
-                write_only = False
-                name = group.model or self._spec.name
                 ids, rows = group.device_ids, group.rows
-                if group.window:
-                    wpool, geom, wplan = self._plan_window(group, name)
-                    self._m_window_rows["device"].inc(len(ids))
-                    if len(wplan["emit"]) < len(ids):
-                        # the filling rows are written and computed; only
-                        # full windows are emitted (and advance a head)
-                        emit = wplan["emit"]
+                if group.frames.ndim == 5:      # whole windows, host rings
+                    self._m_window_rows["host"].inc(len(ids))
+                if group.window or group.frames.ndim == 5:
+                    self._leave_windows(ids, name if group.window else None)
+                # What the step carries from round to round, planned in the
+                # order of its arguments: host bookkeeping advances here.
+                carried: List[Any] = []
+                tr["window_rows"] = 0
+                for state in self._carried(name, bool(group.window),
+                                           canvas=group.crops is not None):
+                    carry = state.carry(ids, group.bucket, rows,
+                                        group.frames.shape[1:])
+                    carried.append(carry)
+                    tr.update(carry.trace)
+                    step = carry.step or step
+                    if carry.emit is not None:
+                        emit = carry.emit
                         rows = [j if rows is None else rows[j] for j in emit]
                         ids = [ids[j] for j in emit]
-                    # no window of the batch is full yet: the frames are
-                    # written and nothing is computed (a stream head's
-                    # round costs a second)
-                    write_only = not ids
-                    slots = wpool.capacity(geom)
-                    if write_only:
-                        step = self._window_writer(
-                            group.src_hw, group.bucket, name, slots)
-                    else:
-                        step = self._step(group.src_hw, group.bucket,
-                                          group.model, window=slots)
-                elif group.frames.ndim == 5:
-                    self._m_window_rows["host"].inc(len(ids))
-                    if self._window_home:
-                        self._leave_windows(ids, None)
-                tr.update(
-                    window_rows=len(group.device_ids) if group.window else 0,
-                    window_restarts=self._window_restarts)
+                        if not ids:
+                            # no row is owed a result: what follows sits
+                            # the round out (the program is the first's)
+                            break
+                self._m_window_rows["device"].inc(tr["window_rows"])
+                tr["window_restarts"] = self._window_restarts
                 self._window_restarts = 0
-                if self._models[name][0].kind == "stream" \
-                        and not write_only:
-                    # slots, reset and index vectors: the only per-stream
-                    # host work a stream head adds to the tick thread
-                    pc_pool0 = time.perf_counter()
-                    pool = self._head_pool(name)
-                    head = pool.plan(ids, group.bucket, rows=rows)
-                    real = head["idx"] < pool.capacity
-                    tr.update(
-                        head_prefill_tokens=int(real.sum())
-                        * pool.cfg.visual_tokens,
-                        head_decode_steps=pool.cfg.decode_steps,
-                        head_ctx_mean=float(
-                            head["pos0"][real].mean()
-                            + pool.cfg.round_positions) if real.any()
-                        else 0.0,
-                        head_resets=int(head["reset"][real].sum()),
-                        pool_s=time.perf_counter() - pc_pool0)
-                    # One stream step on the device at a time: this one
-                    # takes the last one's state anyway, and two launched
-                    # together hold their temporaries (GBs) together.
-                    # Where the device sets the pace this wait is most of
-                    # a round; it is no part of the step call.
-                    pc_wait0 = time.perf_counter()
-                    pool.wait()
-                    tr["state_wait_s"] = time.perf_counter() - pc_wait0
+                self.perf.note_h2d(
+                    name, group.bucket,
+                    group.nbytes + sum(c.aux_nbytes for c in carried),
+                    h2d_s, hidden_s=hidden_s,
+                )
+                # a state that serialises its steps (a stream head waits
+                # for its predecessor): stamped apart, no part of the call
+                pc_wait0 = time.perf_counter()
+                for carry in carried:
+                    if carry.wait is not None:
+                        carry.wait()
+                        tr["state_wait_s"] = time.perf_counter() - pc_wait0
                 tr["t_step0"], pc_step0 = time.time(), time.perf_counter()
-                if write_only:
-                    try:
-                        wpool.put(geom, step(wpool.window(geom), placed,
-                                             wplan["idx"], wplan["pos"]))
-                    except Exception:
-                        wpool.lost(geom, "step_error")     # donated
-                        raise
-                elif head is not None or wplan is not None:
-                    extra: tuple = ()
-                    if wplan is not None:
-                        extra += (wpool.window(geom), wplan["idx"],
-                                  wplan["pos"])
-                    if head is not None:
-                        extra += (pool.state, head["idx"], head["pos0"],
-                                  head["reset"], head["rounds"])
-                    try:
-                        outputs = dict(step(variables, placed, *extra))
-                    except Exception:
-                        # the buffers were donated and the host's
-                        # bookkeeping ran ahead of the device: every
-                        # window of this geometry starts anew in a new
-                        # buffer, every stream of a head in a new pool
-                        if wplan is not None:
-                            wpool.lost(geom, "step_error")
-                        if head is not None:
-                            self._head_pools.pop(name, None)
-                        raise
-                    if wplan is not None:
-                        wpool.put(geom, outputs.pop("window"))
-                    if head is not None:
-                        pool.state = outputs.pop("state")
-                        self._m_head_tokens["prefill"].inc(
-                            tr["head_prefill_tokens"])
-                        self._m_head_tokens["decode"].inc(
-                            int(real.sum()) * pool.cfg.decode_steps)
-                        self._m_head_resets.inc(tr["head_resets"])
-                elif idx is not None:
-                    # Quality-carrying step (3-arg): previous-tick
-                    # thumbnails arrive as a device-side gather from the
-                    # resident pool (no host rows cross); this tick's
-                    # rows scatter back for the next diff. The pop keeps
-                    # them out of _emit's D2H fetch.
-                    outputs = dict(step(
-                        variables, placed, self._thumbs.gather(idx),
-                    ))
-                    self._thumbs.scatter(
-                        group.device_ids, outputs.pop("quality_thumbs"),
-                        rows=group.rows)
-                else:
-                    outputs = step(variables, placed)
-                    if group.crops is not None and isinstance(outputs, dict):
-                        # Quality-carrying steps still compute stats for
-                        # the canvas batch (same compiled program); they
-                        # are meaningless per-stream — drop them before
-                        # _emit's D2H fetch.
-                        outputs = dict(outputs)
-                        outputs.pop("quality_stats", None)
-                        outputs.pop("quality_thumbs", None)
+                try:
+                    ran = step(variables, placed,
+                               *(a for c in carried for a in c.args()))
+                except Exception:
+                    # donated buffers are gone and the host's bookkeeping
+                    # ran ahead of the device: each state starts anew
+                    for carry in carried:
+                        carry.lost()
+                    raise
+                outputs = dict(ran) if carried else ran
+                for carry in carried:
+                    carry.commit(outputs)
+                if group.crops is not None and isinstance(outputs, dict):
+                    # Quality-carrying steps still compute stats for
+                    # the canvas batch (same compiled program); they
+                    # are meaningless per-stream — drop them before
+                    # _emit's D2H fetch.
+                    outputs = dict(outputs)
+                    outputs.pop("quality_stats", None)
+                    outputs.pop("quality_thumbs", None)
                 tr["step_call_s"] = time.perf_counter() - pc_step0
                 tr["t_step1"] = time.time()
             except Exception as exc:
@@ -3704,14 +3371,17 @@ class InferenceEngine:
                 100.0 * len(group.device_ids) / group.bucket
             )
             t_submit = tr["t_submit"] = time.time()
-            if write_only:
-                # nothing to fetch or emit: the batch ends here. The
+            if carried and not outputs:
+                # a round that only wrote its frames into their windows:
+                # nothing to fetch or emit, the batch ends here. The
                 # placement is done with the host buffer (without the
                 # prefetch stage the call itself may still be reading
                 # it); no row owes a result before its clip_len-th read.
                 batches.append(tr)
                 if self._xfer is None:
-                    wpool.window(geom).block_until_ready()
+                    import jax
+
+                    jax.block_until_ready(ran)
                 self._collector.release(group)
                 if self.faults is not None:
                     self.faults.note_dropped(

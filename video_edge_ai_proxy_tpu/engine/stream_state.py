@@ -1,11 +1,22 @@
-"""Per-stream decoder state on the device: one pool, two kinds of buffer.
+"""What a serving step carries from round to round, per stream, on the device.
 
 The reference keeps no model state at all (it ships frames to external
-clients, `/root/reference/README.md:5-27`); a streaming head
-(``models/lfm2.py``) carries, for every camera, a fixed short-convolution
-state and a growing key-value cache with a length. Precedents here:
-``_ThumbPool`` (runner.py) and ``TrackStatePool`` (temporal/state_pool.py),
-one kind of fixed tile each.
+clients, `/root/reference/README.md:5-27`). Here a step may take a
+per-stream device buffer in and give it back, a stream owning a slot of it:
+the quality thumbnails (:class:`_ThumbPool`), a streaming head's state
+(:class:`StreamStatePool`) and the clip windows (:class:`ClipWindowPool`);
+the cascade's track tiles (``temporal/state_pool.py``) are held the same
+way beside the step. Two decisions live here once. :class:`SlotMap` is the
+``device_id -> slot`` allocator under all four, and the dict-like surface
+the tick loop's GC reads (each pool keeps its own growth rule: it decides
+compiled shapes). And the three a step carries answer
+``InferenceEngine._dispatch`` through one surface: ``carry`` (the batch's
+:class:`Carry`), ``step_args`` / ``donated`` (the layout ``_step`` reckons
+``donate_argnums`` from) and ``nbytes()`` under ``ledger``, the pool's
+name in the HBM ledger (obs/hbm.py).
+
+A streaming head (``models/lfm2.py``) carries, for every camera, a fixed
+short-convolution state and a growing key-value cache with a length.
 
 One :class:`StreamStatePool` a stream-head model: ``conv`` [slots, conv
 layers, L-1, d], ``kv`` = (keys, values), each [attention layers, slots, kv
@@ -45,41 +56,34 @@ is lost: every stream of that geometry). Nothing is seeded from the host.
 A mesh engine, and a Collector built without ``device_windows``, keep the
 window on the host (``collector._ClipRing``).
 
-All methods run on the tick thread (single writer, as ``_ThumbPool``).
+All methods run on the tick thread (single writer).
 """
 
 from __future__ import annotations
 
+import time
 import zlib
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
 
-def first_context_rounds(device_id: str, mod: int) -> int:
-    """Rounds of a stream's first context (de-phased resets)."""
-    return 1 + zlib.crc32(device_id.encode()) % max(1, int(mod))
+class SlotMap:
+    """``key -> slot`` with a free list and a high-water mark: the one
+    allocator under the per-stream pools, which are slot maps with device
+    buffers (the clip windows hold one a geometry). A new key takes the
+    slot freed last (LIFO), else the next never given, counting up from
+    ``first`` (the thumbnail and track pools reserve row 0 as their zero
+    row). Dict-like as the tick loop's GC reads its containers (its truth
+    is its length); iteration is over a copy, so the caller may ``pop``
+    while it walks."""
 
+    __slots__ = ("_slots", "_free", "high")
 
-class StreamStatePool:
-    __slots__ = ("model", "cfg", "state", "capacity", "_grow", "_slots",
-                 "_free", "_len", "_rounds", "_first_left")
-
-    def __init__(self, model, grow: int = 64):
-        self.model = model                 # answers ``empty_state(slots)``
-        self.cfg = model.cfg               # the round's sizes and policy
-        self.state: Optional[dict] = None  # lazy: jax stays off the
-        self.capacity = 0                  # control plane (CLAUDE.md)
-        self._grow = max(1, int(grow))
+    def __init__(self, first: int = 0):
         self._slots: Dict[str, int] = {}
         self._free: List[int] = []
-        self._len: Dict[str, int] = {}         # positions committed
-        self._rounds: Dict[str, int] = {}      # rounds since the reset
-        self._first_left: Dict[str, int] = {}  # rounds left of context one
-
-    # dict-like surface for the tick loop's per-stream GC
-    def __bool__(self) -> bool:
-        return bool(self._slots)
+        self.high = int(first)      # one past the highest slot ever given
 
     def __iter__(self):
         return iter(list(self._slots))
@@ -87,16 +91,308 @@ class StreamStatePool:
     def __len__(self) -> int:
         return len(self._slots)
 
+    def __contains__(self, key) -> bool:
+        return key in self._slots
+
+    def take(self, key):
+        """``(slot, is_new)``: the key's slot, given now if it had none."""
+        slot = self._slots.get(key)
+        if slot is not None:
+            return slot, False
+        if self._free:
+            slot = self._free.pop()
+        else:
+            slot, self.high = self.high, self.high + 1
+        self._slots[key] = slot
+        return slot, True
+
+    def pop(self, key, default=None):
+        """Free the key's slot; the slot, or ``default`` for a stranger."""
+        slot = self._slots.pop(key, None)
+        if slot is None:
+            return default
+        self._free.append(slot)
+        return slot
+
+
+class Carry:
+    """What one carried state adds to one step call: made by the state's
+    ``carry(device_ids, bucket, rows, geom)`` for a batch, used up by
+    ``InferenceEngine._dispatch``. ``args()``: its arguments after
+    ``frames``, the buffer first, read when the step is called;
+    ``commit(outputs)`` pops its key and takes the buffer back; ``lost()``
+    after a step that raised with the buffer donated; ``wait`` where the
+    state serialises its steps; ``trace``: its batch-trace fields;
+    ``emit``: the indices into ``device_ids`` still owed a result (None:
+    all); ``step``: the program to run in the model step's place;
+    ``aux_nbytes``: what its index vectors add to the placement's bytes."""
+
+    __slots__ = ("args", "commit", "lost", "wait", "trace", "emit", "step",
+                 "aux_nbytes")
+
+    def __init__(self, args, commit, lost=None, wait=None, trace=None,
+                 emit=None, step=None, aux_nbytes: int = 0):
+        self.args, self.commit = args, commit
+        self.lost = lost or (lambda: None)
+        self.wait, self.trace = wait, trace or {}
+        self.emit, self.step, self.aux_nbytes = emit, step, aux_nbytes
+
+
+class _Thumbs:
+    """The thumbnail pools as a state a step carries: the previous tick's
+    rows in as a gather view of the pool (never donated), this tick's back
+    under ``quality_thumbs`` and scattered for the next diff."""
+
+    __slots__ = ()
+    ledger = "thumbs"
+    key = "quality_thumbs"
+    step_args = 1
+    donated = ()
+
+    def carry(self, device_ids, bucket: int, rows=None, geom=None) -> Carry:
+        idx = self.gather_indices(device_ids, bucket, rows=rows)
+        return Carry(
+            # a device-side gather from the resident pool (no host rows
+            # cross); the pop keeps the new rows out of _emit's D2H fetch
+            args=lambda: (self.gather(idx),),
+            commit=lambda outputs: self.scatter(
+                device_ids, outputs.pop(self.key), rows=rows),
+            aux_nbytes=(sum(int(a.nbytes) for a in idx)
+                        if isinstance(idx, list) else int(idx.nbytes)))
+
+
+class _ThumbPool(_Thumbs, SlotMap):
+    """Device-resident per-stream quality-thumbnail state: one
+    [capacity, th, tw] f32 device array plus a host slot map. The
+    previous tick's thumbnails for a batch are a device-side ``jnp.take``
+    keyed by slot indices; this tick's rows scatter back with
+    ``.at[idx].set`` — thumbnail state never crosses back to host, and the
+    dispatch loop ships only a [bucket] int32 index vector.
+
+    Row 0 is a permanent zero row: first-seen streams (and padded batch
+    slots) gather it, preserving the zero-reference/first-diff contract
+    ``frame_quality_stats`` documents. A ``SlotMap`` (stream -> pool row),
+    so the tick loop's debounced per-stream GC treats it exactly like the
+    tracker/annotation state dicts; a freed row's stale contents are
+    unreachable: nothing gathers a row until scatter() reassigns it, which
+    overwrites it first. All methods run on the tick thread.
+    """
+
+    __slots__ = ("side", "device", "_pool", "_capacity")
+
+    _GROW = 64    # rows added per capacity growth (keeps re-pads rare)
+
+    def __init__(self, side: int, device=None):
+        self.side = int(side)
+        # a sharded parent pins each sub-pool to its mesh slice's lead
+        # device, so gathers/scatters stay chip-local; None: the default
+        self.device = device
+        SlotMap.__init__(self, first=1)    # device_id -> pool row (>= 1)
+        self._pool = None                  # lazy: jax import stays off the
+        self._capacity = 0                 # control plane (CLAUDE.md)
+
+    def _ensure(self, rows: int) -> None:
+        import jax.numpy as jnp
+
+        if self._pool is None:
+            cap = max(self._GROW, rows)
+            pool = jnp.zeros((cap, self.side, self.side), jnp.float32)
+            if self.device is not None:
+                import jax
+
+                pool = jax.device_put(pool, self.device)
+            self._pool = pool
+            self._capacity = cap
+        elif rows > self._capacity:
+            grow = -(-(rows - self._capacity) // self._GROW) * self._GROW
+            # Padding a committed array computes on (and stays on) its
+            # device, so the shard pinning survives growth.
+            self._pool = jnp.pad(self._pool, ((0, grow), (0, 0), (0, 0)))
+            self._capacity += grow
+
+    def gather_indices(self, device_ids, bucket: int, rows=None) -> np.ndarray:
+        """[bucket] int32 gather rows for a batch, slot order: each
+        known stream's row, row 0 (zeros) for first-seen streams and
+        padded slots. ``rows`` (shard-segmented layouts) maps slot i to
+        its batch row; None keeps the legacy identity order. This
+        vector is the only host->device bytes the quality path still
+        ships per batch."""
+        idx = np.zeros(bucket, np.int32)
+        for i, did in enumerate(device_ids):
+            r = i if rows is None else rows[i]
+            idx[r] = self._slots.get(did, 0)
+        return idx
+
+    def gather(self, idx: np.ndarray):
+        """Previous-tick [bucket, th, tw] rows as a device-side gather."""
+        import jax.numpy as jnp
+
+        self._ensure(1)
+        return jnp.take(self._pool, jnp.asarray(idx), axis=0)
+
+    def scatter(self, device_ids, thumbs, rows=None) -> None:
+        """Store this tick's [>=n, th, tw] device rows (the step output,
+        still async) for next tick's diff; assigns pool rows on first
+        sight. ``rows`` names each stream's source row inside ``thumbs``
+        (shard-segmented layouts); None = slot order, legacy path."""
+        import jax.numpy as jnp
+
+        pool_rows = [self.take(did)[0] for did in device_ids]
+        if not pool_rows:
+            return
+        self._ensure(max(pool_rows) + 1)
+        idx = jnp.asarray(np.asarray(pool_rows, np.int32))
+        if rows is None:
+            src = thumbs[:len(pool_rows)]
+        else:
+            src = jnp.take(
+                thumbs, jnp.asarray(np.asarray(rows, np.int32)), axis=0)
+        self._pool = self._pool.at[idx].set(src)
+
+    def nbytes(self) -> int:
+        """Device bytes held by the thumbnail ring right now (0 before
+        first scatter) — obs/hbm.py ``register_pool`` tap. Capacity-
+        based like the track-state ring: grown rows stay allocated after
+        their streams GC. Metadata only, no transfer."""
+        return int(self._pool.nbytes) if self._pool is not None else 0
+
+
+class _ShardedThumbPool(_Thumbs):
+    """Per-mesh-slice thumbnail state for mesh serving (r17 tentpole
+    leg 3): one ``_ThumbPool`` per dp shard, each pinned to its slice's
+    lead device, speaking the collector's shard-segmented row layout
+    (``group.rows``). ``gather`` assembles the per-shard device takes
+    into one dp-sharded [bucket, th, tw] array (the same sharding the
+    frames carry, so the compiled step sees one stable signature);
+    ``scatter`` splits the step's sharded thumbnail output back per
+    slice via its addressable shards — a stream's t-1 thumbnail lives
+    on the chip that serves its frames, and no thumbnail bytes ever
+    cross the host or a chip boundary. Dict-like as ``_ThumbPool``."""
+
+    __slots__ = ("side", "shards", "_mesh", "_shard_of", "_subs")
+
+    def __init__(self, side: int, *, mesh, shards: int, shard_of):
+        from ..temporal.state_pool import shard_devices
+
+        self.side = int(side)
+        self.shards = int(shards)
+        self._mesh = mesh
+        self._shard_of = shard_of
+        self._subs = [
+            _ThumbPool(side, device=d)
+            for d in shard_devices(mesh, self.shards)
+        ]
+
+    def __iter__(self):
+        ids: List[str] = []
+        for sub in self._subs:
+            ids.extend(sub)
+        return iter(ids)
+
+    def __len__(self) -> int:
+        return sum(len(sub) for sub in self._subs)
+
+    def pop(self, device_id: str, default=None):
+        self._subs[self._shard_of(device_id) % self.shards].pop(device_id)
+        return default
+
+    def gather_indices(self, device_ids, bucket: int, rows=None):
+        """Per-shard [seg] int32 local gather rows (list, one array per
+        shard). Row r of the batch lives in shard r // seg at local row
+        r % seg — the collector's segmented layout."""
+        seg = max(1, bucket // self.shards)
+        per = [np.zeros(seg, np.int32) for _ in range(self.shards)]
+        for i, did in enumerate(device_ids):
+            r = i if rows is None else rows[i]
+            per[r // seg][r % seg] = self._subs[r // seg]._slots.get(did, 0)
+        return per
+
+    def gather(self, idx):
+        """Previous-tick [bucket, th, tw] thumbnails as one dp-sharded
+        array: a chip-local take per shard, assembled without any
+        cross-chip movement."""
+        import jax.numpy as jnp
+
+        from ..parallel import assemble_sharded, batch_sharding
+
+        pieces = []
+        for s, sub in enumerate(self._subs):
+            sub._ensure(1)
+            pieces.append(jnp.take(sub._pool, jnp.asarray(idx[s]), axis=0))
+        bucket = sum(int(p.shape[0]) for p in pieces)
+        return assemble_sharded(
+            pieces, (bucket, self.side, self.side),
+            batch_sharding(self._mesh, 3),
+        )
+
+    def scatter(self, device_ids, thumbs, rows=None) -> None:
+        """Route this tick's sharded [bucket, th, tw] step output into
+        the per-shard pools: each shard scatters from its own
+        addressable slice (chip-local), with a sliced-view fallback
+        when the compiled output's layout hides a shard."""
+        bucket = int(thumbs.shape[0])
+        seg = max(1, bucket // self.shards)
+        by_shard: Dict[int, List[tuple]] = {}
+        for i, did in enumerate(device_ids):
+            r = i if rows is None else rows[i]
+            by_shard.setdefault(r // seg, []).append((r % seg, did))
+        pieces: Dict[int, Any] = {}
+        for sh in getattr(thumbs, "addressable_shards", ()):
+            if int(sh.data.shape[0]) != seg:
+                continue   # unexpected output layout: fallback below
+            start = sh.index[0].start or 0
+            pieces.setdefault(start // seg, sh.data)
+        for s, pairs in sorted(by_shard.items()):
+            piece = pieces.get(s)
+            if piece is None:
+                piece = thumbs[s * seg:(s + 1) * seg]
+            self._subs[s].scatter(
+                [did for _, did in pairs], piece,
+                rows=[r for r, _ in pairs],
+            )
+
+    def nbytes(self) -> Dict[str, int]:
+        """Per-shard thumbnail ring bytes ``{shard: bytes}`` — the
+        obs/hbm.py sharded ``register_pool`` shape (each sub-pool's
+        figure is exact against its own ring's ``.nbytes``)."""
+        return {str(s): sub.nbytes() for s, sub in enumerate(self._subs)}
+
+
+def first_context_rounds(device_id: str, mod: int) -> int:
+    """Rounds of a stream's first context (de-phased resets)."""
+    return 1 + zlib.crc32(device_id.encode()) % max(1, int(mod))
+
+
+class StreamStatePool(SlotMap):
+    __slots__ = ("model", "cfg", "state", "capacity", "_grow", "_ctx",
+                 "_note")
+
+    kinds = ("stream",)     # the step kinds that carry it (ModelSpec.kind)
+    ledger = "stream_state"
+    key = "state"           # the buffers come back as the output's "state",
+    step_args = 5           # same shapes: (state, idx, pos0, reset, rounds)
+    donated = (0,)          # with the state rewritten in place
+
+    def __init__(self, model, grow: int = 64,
+                 note_round: Optional[Callable[[int, int, int], None]] = None):
+        self.model = model                 # answers ``empty_state(slots)``
+        self.cfg = model.cfg               # the round's sizes and policy
+        self.state: Optional[dict] = None  # lazy: jax stays off the
+        self.capacity = 0                  # control plane (CLAUDE.md)
+        self._grow = max(1, int(grow))
+        # (prefill tokens, decode tokens, resets) of a committed round
+        self._note = note_round or (lambda prefill, decode, resets: None)
+        SlotMap.__init__(self)
+        # device_id -> [positions committed, rounds since the reset,
+        # rounds left of context one]
+        self._ctx: Dict[str, list] = {}
+
     def pop(self, device_id: str, default=None):
         """Forget a stream: its slot returns to the free list. Nothing
         reads a freed slot's rows before its next owner's first round,
         which is a reset."""
-        slot = self._slots.pop(device_id, None)
-        if slot is not None:
-            self._free.append(slot)
-            self._len.pop(device_id, None)
-            self._rounds.pop(device_id, None)
-            self._first_left.pop(device_id, None)
+        SlotMap.pop(self, device_id)
+        self._ctx.pop(device_id, None)
         return default
 
     def ensure(self, slots: int) -> None:
@@ -129,39 +425,72 @@ class StreamStatePool:
         c = self.cfg
         n_i = len(c.instruction_ids)
         for did in device_ids:
-            if did not in self._slots:
-                self._slots[did] = (self._free.pop() if self._free
-                                    else len(self._slots))
-                self._len[did] = 0
-                self._rounds[did] = 0
-                self._first_left[did] = first_context_rounds(
-                    did, c.max_rounds)
-        self.ensure(1 + max(self._slots.values(), default=0))
+            if self.take(did)[1]:
+                self._ctx[did] = [
+                    0, 0, first_context_rounds(did, c.max_rounds)]
+        self.ensure(max(1, self.high))
         idx = np.full(bucket, self.capacity, np.int32)
         pos0 = np.full(bucket, n_i, np.int32)
         reset = np.ones(bucket, bool)
         rounds = np.zeros(bucket, np.int32)
         for i, did in enumerate(device_ids):
             r = i if rows is None else rows[i]
-            length = self._len[did]
+            ctx = self._ctx[did]
+            length, _, first_left = ctx
             fresh = (length == 0
                      or length + c.round_positions > c.head.max_context
-                     or self._first_left[did] == 0)
+                     or first_left == 0)
             if fresh:
-                length = n_i
-                self._rounds[did] = 0
-                if self._first_left[did] == 0:
-                    self._first_left[did] = -1       # context one is over
-            if self._first_left[did] > 0:
-                self._first_left[did] -= 1
+                length, ctx[1] = n_i, 0
+                if first_left == 0:
+                    ctx[2] = -1                      # context one is over
+            if first_left > 0:
+                ctx[2] -= 1
             idx[r], pos0[r], reset[r] = self._slots[did], length, fresh
-            rounds[r] = self._rounds[did]
-            self._len[did] = length + c.round_positions
-            self._rounds[did] += 1
+            rounds[r] = ctx[1]
+            ctx[0] = length + c.round_positions
+            ctx[1] += 1
         return {"idx": idx, "pos0": pos0, "reset": reset, "rounds": rounds}
 
+    def carry(self, device_ids, bucket: int, rows=None, geom=None) -> Carry:
+        """Slots, reset and index vectors: the only per-stream host work
+        a stream head adds to the tick thread, timed as ``pool_s``."""
+        pc0 = time.perf_counter()
+        plan = self.plan(device_ids, bucket, rows=rows)
+        real = plan["idx"] < self.capacity
+        n, c = int(real.sum()), self.cfg
+        trace = {
+            "head_prefill_tokens": n * c.visual_tokens,
+            "head_decode_steps": c.decode_steps,
+            "head_ctx_mean": float(plan["pos0"][real].mean()
+                                   + c.round_positions) if n else 0.0,
+            "head_resets": int(plan["reset"][real].sum())}
+        trace["pool_s"] = time.perf_counter() - pc0
+
+        def commit(outputs):
+            self.state = outputs.pop(self.key)
+            self._note(trace["head_prefill_tokens"], n * c.decode_steps,
+                       trace["head_resets"])
+
+        return Carry(
+            args=lambda: (self.state, plan["idx"], plan["pos0"],
+                          plan["reset"], plan["rounds"]),
+            commit=commit, lost=self.lost, wait=self.wait, trace=trace)
+
+    def lost(self) -> None:
+        """The buffers went into a step that raised (donated) and the
+        host's bookkeeping ran ahead of the device: the pool starts empty,
+        every stream anew in a new slot of new buffers."""
+        self.state, self.capacity = None, 0
+        SlotMap.__init__(self)
+        self._ctx.clear()
+
     def wait(self) -> None:
-        """Block until the step that last wrote the state has finished."""
+        """Block until the step that last wrote the state has finished.
+        One stream step on the device at a time: the next takes this one's
+        state anyway, and two launched together hold their temporaries
+        (GBs) together. Where the device sets the pace this wait is most
+        of a round; it is no part of the step call."""
         if self.state is not None:
             self.state["tokens"].block_until_ready()
 
@@ -178,23 +507,30 @@ class StreamStatePool:
 class ClipWindowPool:
     """Every clip window of one model on the device (module docstring)."""
 
-    __slots__ = ("clip_len", "_buckets", "_note", "_bufs", "_streams")
+    __slots__ = ("clip_len", "_buckets", "_note", "_program", "_bufs",
+                 "_streams")
+
+    ledger = "clip_windows"
+    key = "window"          # the buffer comes back as the output's "window",
+    step_args = 3           # same shape and dtype: (window, idx, pos), the
+    donated = (0,)          # step's own arguments follow idx and pos
 
     def __init__(self, clip_len: int, buckets: Sequence[int],
-                 note_restart: Optional[Callable[[str, int], None]] = None):
+                 note_restart: Optional[Callable[[str, int], None]] = None,
+                 program: Optional[Callable[..., Any]] = None):
         self.clip_len = int(clip_len)
         self._buckets = tuple(sorted(buckets)) or (1,)
         self._note = note_restart or (lambda reason, n: None)
+        # (geometry, bucket, slots, write_only) -> the compiled program a
+        # batch runs, called as the model's step is (``carry``)
+        self._program = program
         # geometry (H, W, C) -> {"window": jax.Array or None, "capacity",
-        # "free": [slot], "used": slots ever given}
+        # "slots": SlotMap of this geometry's streams}
         self._bufs: Dict[tuple, dict] = {}
         # device_id -> [geometry, slot, write position, frames held]
         self._streams: Dict[str, list] = {}
 
     # dict-like surface for the tick loop's per-stream GC
-    def __bool__(self) -> bool:
-        return bool(self._streams)
-
     def __iter__(self):
         return iter(list(self._streams))
 
@@ -207,12 +543,12 @@ class ClipWindowPool:
         it has rewritten every frame of the slot."""
         st = self._streams.pop(device_id, None)
         if st is not None:
-            self._bufs[st[0]]["free"].append(st[1])
+            self._bufs[st[0]]["slots"].pop(device_id)
         return default
 
     def _buf(self, geom: tuple) -> dict:
         return self._bufs.setdefault(
-            geom, {"window": None, "capacity": 0, "free": [], "used": 0})
+            geom, {"window": None, "capacity": 0, "slots": SlotMap()})
 
     def capacity(self, geom: tuple) -> int:
         buf = self._bufs.get(tuple(geom))
@@ -265,12 +601,8 @@ class ClipWindowPool:
                     self._note("geometry", 1)
                 st = None
             if st is None:
-                if buf["free"]:
-                    slot = buf["free"].pop()
-                else:
-                    slot, buf["used"] = buf["used"], buf["used"] + 1
-                self._streams[did] = [geom, slot, 0, 0]
-        self.ensure(geom, max(buf["used"], 1))
+                self._streams[did] = [geom, buf["slots"].take(did)[0], 0, 0]
+        self.ensure(geom, max(buf["slots"].high, 1))
         idx = np.full(bucket, buf["capacity"], np.int32)
         pos = np.zeros(bucket, np.int32)
         emit: List[int] = []
@@ -283,6 +615,24 @@ class ClipWindowPool:
             if st[3] == self.clip_len:
                 emit.append(i)
         return {"idx": idx, "pos": pos, "emit": emit}
+
+    def carry(self, device_ids, bucket: int, rows=None, geom=None) -> Carry:
+        """A batch of single frames. Rows whose window is still filling
+        are written and computed and only full windows are emitted (and
+        advance a head); while no window of the batch is full the program
+        is the write alone: nothing is computed (a stream head's round
+        costs a second) and nothing comes out."""
+        geom = tuple(geom)
+        plan = self.plan(device_ids, geom, bucket, rows=rows)
+        full = plan["emit"]
+        return Carry(
+            args=lambda: (self.window(geom), plan["idx"], plan["pos"]),
+            commit=lambda outputs: self.put(geom, outputs.pop(self.key)),
+            lost=lambda: self.lost(geom, "step_error"),
+            trace={"window_rows": len(device_ids)},
+            emit=full if len(full) < len(device_ids) else None,
+            step=self._program and self._program(
+                geom, bucket, self.capacity(geom), not full))
 
     def restart(self, device_ids, reason: str) -> int:
         """These streams' windows start anew (a frame that was read did

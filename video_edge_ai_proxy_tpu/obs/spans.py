@@ -44,7 +44,7 @@ a count of cameras leaves them out (``ENGINE_STREAMS``).
   tick's dispatch → end of this one's, with the collector's byte counts;
   nested in it: ``pre_collect`` (→ collect() entry, less the assembly
   window), ``collect_tick`` (collect() entry → return, ``read_ms`` /
-  ``clip_ms`` / ``fill_ms`` in the extras), and per batch ``place_wait``
+  ``fill_ms`` in the extras), and per batch ``place_wait``
   (the tick thread blocked on the placement) and ``step_call``; before a
   stream head's step call also ``pool`` (the state pool's plan) and
   ``state_wait`` (blocked on the predecessor step, whose state it takes).

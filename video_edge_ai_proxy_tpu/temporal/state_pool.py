@@ -1,11 +1,11 @@
 """Device-resident per-track clip ring for the temporal cascade.
 
-Modeled on the r12 quality thumbnail pool (engine/runner.py
+Modeled on the r12 quality thumbnail pool (engine/stream_state.py
 ``_ThumbPool``), re-keyed from stream to track: one static-shape device
 array ``[slots, clip_len, side, side, 3] uint8`` holds every live
-track's last ``clip_len`` crop tiles as a ring. Slot assignment is a
-host-side dict (track key -> row) plus a free list; per-row write
-cursors and fill counts also live on the host, so the ONLY host<->device
+track's last ``clip_len`` crop tiles as a ring. Slot assignment is the
+pools' shared host-side ``SlotMap`` (track key -> row, free list); per-row
+write cursors and fill counts also live on the host, so the ONLY host<->device
 traffic is the new tiles themselves plus two small int32 index vectors
 per scatter (``vep_h2d_*`` aux bytes) — the clip contents NEVER round-
 trip to the host between ticks (ISSUE 14 acceptance: no per-tick D2H of
@@ -30,14 +30,16 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..engine.stream_state import SlotMap
 
-class TrackStatePool:
+
+class TrackStatePool(SlotMap):
     """Per-track device clip ring with host-side slot bookkeeping."""
 
     _GROW = 8
 
-    __slots__ = ("side", "clip_len", "device", "_slots", "_free", "_cursor",
-                 "_fill", "_pool", "_capacity", "_high")
+    __slots__ = ("side", "clip_len", "device", "_cursor", "_fill", "_pool",
+                 "_capacity")
 
     def __init__(self, side: int, clip_len: int, device=None):
         self.side = int(side)
@@ -47,34 +49,17 @@ class TrackStatePool:
         # chip that serves the shard's streams. None = default placement
         # (single-chip behavior unchanged).
         self.device = device
-        self._slots: Dict[str, int] = {}      # track key -> row (>= 1)
-        self._free: List[int] = []
+        SlotMap.__init__(self, first=1)       # track key -> row (>= 1)
         self._cursor: Dict[int, int] = {}     # row -> next write position
         self._fill: Dict[int, int] = {}       # row -> frames written (<= T)
         self._pool = None                     # [cap, T, side, side, 3] u8
         self._capacity = 0
-        self._high = 0                        # highest row ever assigned
-
-    # -- dict-protocol surface (mirrors _ThumbPool so GC reads the same) --
-
-    def __bool__(self) -> bool:
-        return bool(self._slots)
-
-    def __len__(self) -> int:
-        return len(self._slots)
-
-    def __iter__(self):
-        return iter(self._slots)
-
-    def __contains__(self, key: str) -> bool:
-        return key in self._slots
 
     def pop(self, key: str, default=None):
         """Release a track's slot back to the free list."""
-        row = self._slots.pop(key, None)
+        row = SlotMap.pop(self, key)
         if row is None:
             return default
-        self._free.append(row)
         self._cursor.pop(row, None)
         self._fill.pop(row, None)
         return row
@@ -85,7 +70,7 @@ class TrackStatePool:
     def high_water(self) -> int:
         """Highest row ever assigned (slot-conservation evidence: stays
         bounded across track churn because freed rows are reused)."""
-        return self._high
+        return self.high - 1
 
     def slots_in_use(self) -> int:
         return len(self._slots)
@@ -136,11 +121,8 @@ class TrackStatePool:
             self._capacity += grow
 
     def _row_for(self, key: str) -> int:
-        row = self._slots.get(key)
-        if row is None:
-            row = self._free.pop() if self._free else self._high + 1
-            self._high = max(self._high, row)
-            self._slots[key] = row
+        row, new = self.take(key)
+        if new:
             self._cursor[row] = 0
             self._fill[row] = 0
         return row
@@ -253,9 +235,6 @@ class ShardedTrackStatePool:
 
     def _pool_for(self, key: str) -> TrackStatePool:
         return self.pools[self._shard_of(key)]
-
-    def __bool__(self) -> bool:
-        return any(len(p) for p in self.pools)
 
     def __len__(self) -> int:
         return sum(len(p) for p in self.pools)

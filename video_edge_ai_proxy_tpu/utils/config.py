@@ -232,11 +232,12 @@ class EngineConfig:
     # per-frame luma mean/variance + inter-frame diff energy folded into
     # the serving step (ops/preprocess.py frame_quality_stats; under
     # engine.mesh the thumbnail carry state is dp-sharded per slice —
-    # runner._ShardedThumbPool — so quality rides the mesh path too), host
-    # black/frozen/flatline verdict state machines with time hysteresis,
-    # detection drift scoring, and the degradation ladder's first-shed
-    # set. quality=False disables the subsystem and /api/v1/quality
-    # answers 400 (same kill-switch convention as slo/prof above).
+    # engine/stream_state.py _ShardedThumbPool — so quality rides the
+    # mesh path too), host black/frozen/flatline verdict state machines
+    # with time hysteresis, detection drift scoring, and the degradation
+    # ladder's first-shed set. quality=False disables the subsystem and
+    # /api/v1/quality answers 400 (same kill-switch convention as
+    # slo/prof above).
     quality: bool = True
     quality_thumb: int = 32            # luma thumbnail side (device state)
     quality_black_luma: float = 0.04   # black: thumb luma mean below this
