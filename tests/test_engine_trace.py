@@ -29,8 +29,8 @@ L = 4                             # tiny_videomae's clip length
 STAMPS = ("t_tick0", "t_collect0", "t_collect", "t_place_q", "t_place0",
           "t_placed", "t_place_got", "t_step0", "t_step1", "t_submit",
           "t_deq", "t_drain0", "t_drained", "t_emitted")
-PHASES = ("pre_collect", "read", "clip", "fill", "collect_other",
-          "place_wait", "step_call", "idle")
+PHASES = ("pre_collect", "pace_wait", "read", "clip", "fill",
+          "collect_other", "place_wait", "step_call", "idle")
 
 
 def _publish(bus, device_id, value=128):
@@ -273,6 +273,10 @@ class TestStageRecords:
             assert in_collect <= r["t_collect"] - r["t_collect0"] + 1e-3
             assert r["collect_other_s"] >= 0 and r["pre_collect_s"] >= 0
             assert r["place_wait_s"] >= 0 and r["step_call_s"] > 0
+            # every tick's trace carries the paced wait (0.0 unless the
+            # tick was waiting on the previous round's batch when this
+            # round was published)
+            assert 0.0 <= r["pace_wait_s"] < 1.0
         for recs in _by_batch(records).values():
             first = {k: v for k, v in recs[0].items()
                      if k not in ("device_id", "ts_pub_ms", "t_emitted")}
@@ -321,6 +325,49 @@ class TestStageRecords:
         # after unless the drain thread still held a lease (a third buffer)
         assert fresh[:2] == [2 * F * (1 + L), 2 * F * L]
         assert fresh[2] in (0, 2 * F * L)
+
+    def test_the_paced_wait_is_stamped_apart_from_pre_collect(self, bus,
+                                                              spans_on):
+        """``pace_wait_s`` (ISSUE 33): the tick thread's wait before the
+        read is a phase of its own in all three sinks, and no part of
+        ``pre_collect_s`` (so ``tick_other_ms`` reads what it read)."""
+        fleet = _Fleet(bus, tags=1, clips=0)
+        held = 0.06
+
+        def wait(stop):             # every tick's wait engages
+            t0 = time.perf_counter()
+            time.sleep(held)
+            return time.perf_counter() - t0
+
+        fleet.eng._pacer.wait = wait
+        before = _phase_seconds()
+        records = fleet.run(4)
+        after = _phase_seconds()
+        assert len(records) == 4
+        for r in records:
+            assert r["pace_wait_s"] >= held
+            # the wait lies between the previous dispatch's end and
+            # collect() entry, and is taken out of that span
+            assert r["pre_collect_s"] < 0.5 * held
+            assert r["t_tick0"] + r["pace_wait_s"] <= r["t_collect0"] + 1e-3
+        # the counter rose by the waits of the ticks that read a frame
+        # (an idle tick's wait is idle time)
+        assert after["pace_wait"] - before["pace_wait"] == pytest.approx(
+            sum(r["pace_wait_s"] for r in records), abs=1e-6)
+        assert after["pre_collect"] - before["pre_collect"] < 4 * 0.5 * held
+        assert after["idle"] - before["idle"] >= held
+        # and the tick track draws it, ending where collect() starts
+        events = [e for e in spans_on.events()
+                  if e["stream"] == "engine.tick"]
+        paced = {e["tick"]: e for e in events if e["stage"] == "pace_wait"}
+        assert sorted(paced) == sorted(r["tick"] for r in records)
+        for r in records:
+            e = paced[r["tick"]]
+            assert e["dur_ms"] == pytest.approx(r["pace_wait_s"] * 1e3)
+            assert e["ts"] == pytest.approx(r["t_collect0"])
+            pre = next(x for x in events if x["stage"] == "pre_collect"
+                       and x["tick"] == r["tick"])
+            assert pre["ts"] == pytest.approx(e["ts"] - e["dur_ms"] / 1e3)
 
     def test_equal_floats_never_merge_batches(self, bus):
         """Batches are told apart by identifier: forcing every stamp of a

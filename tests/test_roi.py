@@ -519,6 +519,39 @@ class TestRoiEngine:
         finally:
             eng._drain_q.join()
 
+    def test_coasted_groups_add_nothing_to_the_pacers_backlog(self, bus):
+        """ISSUE 33: a coasted group rides the drain queue with no device
+        work, so the read pacer (engine/pacing.py) never hears of it; a
+        batch the device runs is in its backlog until its outputs are on
+        the host."""
+        bus.create_stream("camA", 64 * 64 * 3)
+        eng = _roi_engine(bus)
+        sub = _subscribe(eng)
+        blob = [self.BLOB_A + (1,)]
+        seen = []
+        enqueue = eng._enqueue_drain
+
+        def spy(inflight):
+            seen.append((inflight.group.coast is not None,
+                         eng._pacer.in_flight()))
+            enqueue(inflight)
+
+        eng._enqueue_drain = spy
+        try:
+            self._publish_scene(bus, "camA", blob)
+            _only(_tick(eng, sub))              # full frame: device work
+            assert seen == [(False, 1)]
+            assert eng._pacer.in_flight() == 0  # drained by _emit
+            eng._roi.state("camA")["diff"] = 0.0
+            self._publish_scene(bus, "camA", blob)
+            batches = eng.batches
+            _only(_tick(eng, sub))              # gated idle: coasted
+            assert eng.batches == batches
+            assert seen[1:] == [(True, 0)]
+            assert eng._pacer.read_at() is None
+        finally:
+            eng._drain_q.join()
+
     def test_two_streams_share_canvas_no_cross_talk(self, bus):
         """Two streams' crops on one shared canvas: each stream gets
         exactly its own blob back (distinct color keys prove routing),

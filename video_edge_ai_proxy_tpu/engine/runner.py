@@ -18,9 +18,12 @@ the device outputs and emits the moment the device finishes (event-driven
 drain). H2D/compute for tick N+1 overlaps D2H/postprocess for tick N
 (double buffering, SURVEY.md §7 hard part 2) WITHOUT parking results
 until the next tick boundary — the r4-measured full-tick drain deferral
-(~tick_ms of p50) is gone. The drain queue is depth-2: beyond that the
-engine thread blocks, which is the natural backpressure when the device
-is slower than the tick rate. Collector buffers
+(~tick_ms of p50) is gone. Where the device is slower than the tick rate
+the engine thread waits BEFORE it reads (engine/pacing.py: a round's
+frames are read so that their placement ends as the device frees, and
+that wait is the backpressure the ladder hears); the depth-2 drain
+queue, beyond which the engine thread blocks, is the hard limit behind
+it. Collector buffers
 backing in-flight batches are strict-leased and released by the drain
 thread after emit, so a deep pipeline can never alias host frames.
 """
@@ -55,6 +58,7 @@ from ..utils.config import EngineConfig
 from ..utils.logging import get_logger, reset_log_context, set_log_context
 from .classes import class_name
 from .collector import BatchGroup, CanvasPacker, Collector, pad_to_bucket
+from .pacing import ReadPacer
 from .stream_state import (
     ClipWindowPool, StreamStatePool, _ShardedThumbPool, _ThumbPool)
 
@@ -546,7 +550,7 @@ class _Inflight:
 # vep_tick_phase_seconds_total{phase=...}: what the tick thread did with a
 # tick that read at least one frame, plus "idle" (ticks that found nothing,
 # and the between-tick wait for frames).
-_TICK_PHASES = ("pre_collect", "read", "fill", "collect_other",
+_TICK_PHASES = ("pre_collect", "pace_wait", "read", "fill", "collect_other",
                 "place_wait", "pool", "state_wait", "step_call", "idle")
 
 
@@ -1112,9 +1116,16 @@ class InferenceEngine:
         # "device behind". The signal that still does is the tick
         # thread having had to BLOCK handing a batch to the drain
         # thread (_enqueue_drain found the queue full) — a device that
-        # keeps up absorbs the handoff without blocking.
+        # keeps up absorbs the handoff without blocking. Since the tick
+        # thread reads just in time (engine/pacing.py) that wait is taken
+        # BEFORE the read wherever the device's backlog can be predicted,
+        # and a tick that waited and then read frames is the same
+        # condition: it raises the same flag, and the queue blocks only
+        # when the prediction was off.
         self._drain_blocked = False
         self._bp_depth = 0
+        self._pacer = ReadPacer()
+        self._pace_s = 0.0        # paced wait since the last _close_tick
         # Live device-performance attribution (obs/perf.py): compile
         # cost per (model, geometry, bucket) fed from _step misses,
         # per-batch device time / padding waste / MFU fed from _emit.
@@ -2619,7 +2630,7 @@ class InferenceEngine:
         tick_s = self._cfg.tick_ms / 1000.0
         inferred: List[str] = []
         self._tick_mark = time.perf_counter()
-        self._assemble_s = 0.0
+        self._assemble_s = self._pace_s = 0.0
         while not self._stop.is_set():
             t0 = time.monotonic()
             t_tick0 = time.time()
@@ -2642,9 +2653,12 @@ class InferenceEngine:
                 # qsize without prefetch; with prefetch, a full queue
                 # counts only when the tick thread actually blocked on
                 # the handoff since the last observation (see
-                # _drain_blocked above).
+                # _drain_blocked above). A paced read is that block taken
+                # early: the queue it kept from filling counts as full.
                 depth = self._drain_q.qsize()
-                if self._xfer is not None and not self._drain_blocked:
+                if self._drain_blocked:
+                    depth = max(depth, self._drain_q.maxsize)
+                elif self._xfer is not None:
                     depth = min(depth, 1)
                 self._drain_blocked = False
                 self._bp_depth = depth
@@ -2701,6 +2715,13 @@ class InferenceEngine:
                         admitted.append(canary)
                     inferred = admitted
                 self._collector.keep_streams_hot(device_ids=inferred)
+                # Read just in time (engine/pacing.py): where the device
+                # is behind, wait HERE, before the frames are read, until
+                # their placement would end as the device frees. A plain
+                # wait: nothing is read ahead during it. It never engages
+                # where the host sets the pace.
+                paced_s = self._pacer.wait(self._stop)
+                self._pace_s += paced_s
                 t_collect0, pc_collect0 = time.time(), time.perf_counter()
                 try:
                     groups = self._collector.collect(device_ids=inferred)
@@ -2709,6 +2730,11 @@ class InferenceEngine:
                     for wpool in self._window_pools.values():
                         wpool.restart(list(wpool), "collect_error")
                     raise
+                if paced_s and groups:
+                    # frames that had to wait for the device: the signal a
+                    # blocked handoff gives (a tick that waited and found
+                    # nothing to read kept nobody waiting)
+                    self._drain_blocked = True
                 if rung != "normal" and groups:
                     # Rung 1+: stale frames leave before they cost device
                     # time (shed oldest-first with a staleness bound).
@@ -2722,6 +2748,12 @@ class InferenceEngine:
                 # under pressure or cfg.roi, the two group transforms above.
                 tick = self._open_tick(t_tick0, t_collect0, pc_collect0)
                 batches = self._dispatch(groups, tick["t_collect"], tick)
+                if batches:
+                    # the host's lead: collect() entry -> the tick's first
+                    # step call, less its wait for a predecessor step
+                    self._pacer.note_lead(
+                        batches[0]["t_step0"] - t_collect0
+                        - batches[0].get("state_wait_s", 0.0))
                 self._close_tick(tick, batches)
                 if self._cascade is not None:
                     # CASCADE: scatter harvested track tiles, run the
@@ -2811,9 +2843,12 @@ class InferenceEngine:
             tick=self.ticks, t_tick0=t_tick0,
             # end of the previous tick's dispatch -> collect() entry, less
             # the assembly window (its reads are in read_s, its waiting is
-            # idle): tail of the last tick, head of this one
+            # idle) and the paced wait, stamped apart: tail of the last
+            # tick, head of this one
             pre_collect_s=max(
-                0.0, pc_collect0 - self._tick_mark - self._assemble_s),
+                0.0, pc_collect0 - self._tick_mark - self._assemble_s
+                - self._pace_s),
+            pace_wait_s=self._pace_s,
             t_collect0=t_collect0, t_collect=time.time(),
             collect_other_s=max(0.0, pc_collect - pc_collect0 - in_collect),
         )
@@ -2826,7 +2861,7 @@ class InferenceEngine:
         now = time.perf_counter()
         whole_s = now - self._tick_mark
         idle_s = max(0.0, self._assemble_s - tick["read_ahead_s"])
-        self._tick_mark, self._assemble_s = now, 0.0
+        self._tick_mark, self._assemble_s, self._pace_s = now, 0.0, 0.0
         if not tick["frames_read"] and not batches:
             self._m_phase["idle"].inc(whole_s)
             return
@@ -2836,6 +2871,7 @@ class InferenceEngine:
         state_wait_s = sum(b.get("state_wait_s", 0.0) for b in batches)
         for phase, seconds in (
                 ("pre_collect", tick["pre_collect_s"]),
+                ("pace_wait", tick["pace_wait_s"]),
                 ("read", tick["read_s"]), ("fill", tick["fill_s"]),
                 ("collect_other", tick["collect_other_s"]),
                 ("place_wait", place_wait_s), ("pool", pool_s),
@@ -2858,8 +2894,13 @@ class InferenceEngine:
             window_restarts=sum(b.get("window_restarts", 0)
                                 for b in batches))
         tracer.record(
-            "engine.tick", "pre_collect", n, ts=tick["t_collect0"],
+            "engine.tick", "pre_collect", n,
+            ts=tick["t_collect0"] - tick["pace_wait_s"],
             dur_ms=tick["pre_collect_s"] * 1e3, tick=n)
+        if tick["pace_wait_s"]:
+            tracer.record(
+                "engine.tick", "pace_wait", n, ts=tick["t_collect0"],
+                dur_ms=tick["pace_wait_s"] * 1e3, tick=n)
         tracer.record(
             "engine.tick", "collect_tick", n, ts=tick["t_collect"],
             dur_ms=(tick["t_collect"] - tick["t_collect0"]) * 1e3, tick=n,
@@ -3396,8 +3437,9 @@ class InferenceEngine:
                             trace_id=trace_id_of(meta, did),
                         )
             batches.append(tr)
-            self._enqueue_drain(
-                _Inflight(group, outputs, t_submit, tr, emit))
+            inflight = _Inflight(group, outputs, t_submit, tr, emit)
+            self._pacer.launched(inflight, (name, group.src_hw, group.bucket))
+            self._enqueue_drain(inflight)
         return batches
 
     def _apply_rung_cap(self, rung: str) -> None:
@@ -3965,6 +4007,7 @@ class InferenceEngine:
         if self.faults is not None:
             self.faults.note_dropped(
                 _group_slots(inflight.group), "shutdown_drain")
+        self._pacer.forget(inflight)
         self._collector.release(inflight.group)
 
     def _drain_loop(self) -> None:
@@ -3999,6 +4042,7 @@ class InferenceEngine:
                     self.faults.note_dropped(
                         _group_slots(inflight.group), "drain_error")
             finally:
+                self._pacer.forget(inflight)   # a fetch that failed
                 self._collector.release(inflight.group)
                 # Closes the in-flight window the prefetch stage's
                 # "busy" signal (hidden-transfer attribution) reads.
@@ -4017,6 +4061,7 @@ class InferenceEngine:
         t_drain0 = time.time()
         host = {k: np.asarray(v) for k, v in inflight.outputs.items()}  # D2H
         t_drained = time.time()
+        self._pacer.drained(inflight)
         inflight.tr.update(t_drain0=t_drain0, t_drained=t_drained)
         inflight.tr.setdefault("t_deq", t_drain0)   # _emit called directly
         if "moe_load" in host:

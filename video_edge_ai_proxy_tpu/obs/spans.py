@@ -43,7 +43,10 @@ a count of cameras leaves them out (``ENGINE_STREAMS``).
 - ``tick`` — one tick that read at least one frame, end of the previous
   tick's dispatch → end of this one's, with the collector's byte counts;
   nested in it: ``pre_collect`` (→ collect() entry, less the assembly
-  window), ``collect_tick`` (collect() entry → return, ``read_ms`` /
+  window and the paced wait), ``pace_wait`` (the wait before the read,
+  until the frames' placement would end as the device frees:
+  engine/pacing.py; only where it engaged),
+  ``collect_tick`` (collect() entry → return, ``read_ms`` /
   ``fill_ms`` in the extras), and per batch ``place_wait``
   (the tick thread blocked on the placement) and ``step_call``; before a
   stream head's step call also ``pool`` (the state pool's plan) and
@@ -79,8 +82,8 @@ from typing import Dict, Iterable, List, Optional
 STAGES = ("publish", "collect", "submit", "device", "emit", "temporal",
           "dropped",
           # the engine's own threads (streams named ENGINE_STREAMS)
-          "tick", "pre_collect", "collect_tick", "place_wait", "pool",
-          "state_wait", "step_call", "place", "drain_wake", "fetch",
+          "tick", "pre_collect", "pace_wait", "collect_tick", "place_wait",
+          "pool", "state_wait", "step_call", "place", "drain_wake", "fetch",
           "emit_batch")
 
 # Reserved stream names: the engine's tick, transfer and drain threads.
