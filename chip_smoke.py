@@ -259,11 +259,13 @@ def kernels_phase(seed: int) -> None:
               f"flash T={t}: outside the stated bf16 tolerance")
 
 
-def stream_head_phase(seed: int) -> None:
-    """The ``stream`` step kind on the chip at the tiny twin's size: two
+def stream_head_phase(seed: int, name: str = "tiny_videomae_lfm2") -> None:
+    """The ``stream`` step kind on the chip at a tiny twin's size: two
     rounds of two streams through ``build_serving_step`` and a
-    ``StreamStatePool`` (state donated, gathered and scattered by slot
-    inside the program), the second continuing the first's state."""
+    ``StreamStatePool`` (state donated, read and written by slot inside
+    the program), the second continuing the first's state. Both heads:
+    LFM2's conv state and key-value cache, Xing4's latent cache, whose
+    decode loop the prediction module drafts for."""
     import jax
     import jax.numpy as jnp
 
@@ -271,7 +273,7 @@ def stream_head_phase(seed: int) -> None:
     from video_edge_ai_proxy_tpu.engine.stream_state import StreamStatePool
     from video_edge_ai_proxy_tpu.models import registry
 
-    spec = registry.get("tiny_videomae_lfm2")
+    spec = registry.get(name)
     model, variables = spec.init_params(jax.random.PRNGKey(seed))
     variables = spec.prepare(model, variables)
     c = model.cfg
@@ -297,14 +299,22 @@ def stream_head_phase(seed: int) -> None:
                >= 0).all(), f"stream head round {r}: token history has holes")
         check(int(host["moe_load"].sum()) > 0,
               f"stream head round {r}: no routed pair on the held experts")
+        if "mtp_drafted" in host:
+            check(1 <= int(host["decode_iters"]) <= c.decode_steps
+                  and (host["mtp_accepted"] <= host["mtp_drafted"]).all()
+                  and (host["draft_probs"] > 0).all(),
+                  f"stream head round {r}: the drafted loop's counts")
     held = pool.nbytes()
     check(held == sum(int(a.nbytes)
                       for a in jax.tree_util.tree_leaves(pool.state)),
           "stream head: pool bytes differ from its buffers'")
-    say(f"stream head: 2 rounds x 2 streams through the state pool "
-        f"({held} B on {sorted(str(d) for d in pool.state['conv'].devices())}"
+    say(f"stream head {name}: 2 rounds x 2 streams through the state pool "
+        f"({held} B of {sorted(pool.state)} on "
+        f"{sorted(str(d) for d in pool.state['tokens'].devices())}"
         f"), {int(host['moe_load'].sum())} routed pairs on the held experts "
-        f"in the last round, tokens {host['tokens'][0].tolist()}")
+        f"in the last round, tokens {host['tokens'][0].tolist()}"
+        + (f", {int(host['decode_iters'])} decode iterations"
+           if "decode_iters" in host else ""))
 
 
 # ---------------------------------------------------------------------------
@@ -882,6 +892,7 @@ def main(argv=None) -> int:
     else:
         kernels_phase(args.seed)
         stream_head_phase(args.seed)
+        stream_head_phase(args.seed, "tiny_videomae_xing4")
         facts = server_phase(args.seed)
     counts = cache.snapshot()
     say(f"cache: {counts} in "

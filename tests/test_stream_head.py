@@ -312,9 +312,8 @@ def test_a_first_round_without_a_pool_is_the_steps_first_token():
     clips = jax.random.normal(jax.random.PRNGKey(2), (2, 4, 32, 32, 3))
     logits = module.apply(variables, clips)
     c = module.cfg
-    conv, kv = module.empty_state(2)
     out = module.serve_round(
-        variables, clips, conv, kv, jnp.arange(2),
+        variables, clips, module.empty_state(2)[0], jnp.arange(2),
         jnp.full((2,), len(c.instruction_ids), jnp.int32),
         jnp.ones((2,), bool))
     assert out["tokens"][:, 0].tolist() == jnp.argmax(logits, -1).tolist()

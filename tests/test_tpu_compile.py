@@ -302,3 +302,90 @@ class TestStreamHeadLayers:
         assert not [line for line in text.splitlines()
                     if " copy(" in line and line.split("=")[1].strip()
                     .startswith(whole)]
+
+
+class TestXing4HeadLayers:
+    """The second streaming head's own operators at the published widths
+    (models/xing4.py), compiled INSIDE a loop as the serving step runs
+    them: latent attention's two paths over the one cache, the residual
+    maps, and the flush of a round's latent rows into the donated pool."""
+
+    @staticmethod
+    def _shapes(v5e, b):
+        from video_edge_ai_proxy_tpu.models import xing4
+
+        cfg = xing4.Xing4Config()
+        on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+            a.shape, a.dtype, sharding=v5e)
+        pool = on_chip(jax.eval_shape(
+            lambda: xing4.empty_latent(cfg, b, cfg.max_context)))
+        rbuf = on_chip(jax.eval_shape(
+            lambda: xing4.empty_latent(cfg, b, 795)))
+        ints = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e)
+        return xing4, cfg, on_chip, pool, rbuf, ints
+
+    @pytest.mark.parametrize("t", [784, 2], ids=["prefill", "decode"])
+    def test_latent_attention_in_a_loop(self, v5e, t):
+        import flax.linen as nn
+
+        xing4, cfg, on_chip, pool, rbuf, ints = self._shapes(v5e, 8)
+        attn = xing4.MlaAttention(cfg)
+        h = jax.ShapeDtypeStruct((8, t, cfg.dim), jnp.bfloat16, sharding=v5e)
+        variables = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+            lambda: nn.meta.unbox(attn.init(
+                jax.random.PRNGKey(0),
+                jnp.zeros((1, 2, cfg.dim), jnp.bfloat16),
+                xing4.empty_latent(cfg, 1, 8)[0],
+                xing4.empty_latent(cfg, 1, 4)[0],
+                jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
+                jnp.zeros((1,), jnp.int32), 0))))
+
+        def twice(variables, h, pool, rbuf, slots, pos0):
+            def body(i, carry):
+                h, rows = carry
+                y, rows = attn.apply(
+                    variables, h, pool[i], rows, slots, pos0,
+                    None if t > 2 else pos0 * 0 + 784 + i, 3328)
+                return h + y, rows
+            return jax.lax.fori_loop(0, 2, body, (h, rbuf[0]))
+
+        text = _compiled_text(twice, variables, h, pool, rbuf, ints, ints)
+        assert "while" in text
+        # neither path makes another layout of the pool: the 640-wide rows
+        # are the layout both paths' products take
+        whole = "bf16[%d,%d,%d,%d]" % pool.shape
+        assert not [line for line in text.splitlines()
+                    if " copy(" in line and whole in line.split("=")[1][:60]]
+
+    def test_residual_maps_in_a_loop(self, v5e):
+        import flax.linen as nn
+
+        xing4, cfg, on_chip, _, _, _ = self._shapes(v5e, 8)
+        maps = xing4.HyperResidual(cfg)
+        x = jax.ShapeDtypeStruct((4, 8, 784, cfg.dim), jnp.bfloat16,
+                                 sharding=v5e)
+        variables = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+            lambda: nn.meta.unbox(maps.init(
+                jax.random.PRNGKey(0),
+                jnp.zeros((4, 1, 2, cfg.dim), jnp.bfloat16)))))
+
+        def twice(variables, x):
+            def body(_, x):
+                pre, post, res = maps.apply(variables, x)
+                return xing4.hc_write(res, post, x, xing4.hc_read(pre, x))
+            return jax.lax.fori_loop(0, 2, body, x)
+
+        assert "while" in _compiled_text(twice, variables, x)
+
+    def test_flush_writes_the_donated_latent_pool_in_place(self, v5e):
+        xing4, cfg, _, pool, rbuf, ints = self._shapes(v5e, 8)
+        text = jax.jit(
+            functools.partial(xing4.flush_round, keep=792, main_blocks=5),
+            donate_argnums=(0,)).lower(pool, rbuf, ints, ints).compile(
+        ).as_text()
+        assert "dynamic-update-slice" in text and "while" in text
+        whole = "bf16[%d,%d,%d,%d]" % pool.shape
+        assert not [line for line in text.splitlines()
+                    if " copy(" in line and line.split("=")[1].strip()
+                    .startswith(whole)]
+

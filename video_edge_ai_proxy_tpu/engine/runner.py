@@ -112,12 +112,12 @@ def build_serving_step(model, spec, *, quality_thumb: int = 0, mesh=None,
     serves the transformer families, which carry no such kernel below
     ``FLASH_THRESHOLD_T`` tokens.
 
-    Step kind ``stream`` (models/lfm2.py): a step with state in and
-    state out,
+    Step kind ``stream`` (models/lfm2.py, models/xing4.py): a step with
+    state in and state out,
     ``stream_step(variables, frames, state, idx, pos0, reset, rounds)``.
-    ``state`` is the model's ``StreamStatePool`` buffers (``conv`` and
-    ``tokens`` [slots, ...], ``kv`` = (keys, values) [attention layers,
-    slots, ...]; the caller donates them), ``idx``
+    ``state`` is the model's ``StreamStatePool`` buffers by name (the kinds
+    its ``empty_state`` declares and the pool's ``tokens`` [slots, ...];
+    the caller donates them), ``idx``
     [bucket] the slot of each batch row, ``pos0`` where its visual tokens
     start, ``reset`` which rows start a new context, ``rounds`` the rounds
     since each row's reset (engine/stream_state.py ``plan``). Encoder,
@@ -234,10 +234,12 @@ def _build_stream_step(model, size: int):
 
     def stream_step(variables, frames_u8, state, idx, pos0, reset, rounds):
         take = lambda a: jnp.take(a, idx, axis=0, mode="clip")  # noqa: E731
-        # the small buffers are gathered and scattered by slot; the
-        # key-value pool is read and written in place, by slot
+        # the model's kinds of state go in and come back by name (each
+        # read and written by slot inside the round); the token history is
+        # the pool's own
         out = model.serve_round(
-            variables, frames_u8, take(state["conv"]), state["kv"],
+            variables, frames_u8,
+            {k: v for k, v in state.items() if k != "tokens"},
             idx, pos0, reset,
             preprocess=lambda clips: preprocess_clip(
                 clips, (size, size), out_dtype=model.dtype))
@@ -247,11 +249,8 @@ def _build_stream_step(model, size: int):
         history = jnp.where(
             at == rounds[:, None],
             jnp.tile(out["tokens"], (1, history.shape[1] // steps)), history)
-        put = lambda a, v: a.at[idx].set(  # noqa: E731
-            v.astype(a.dtype), mode="drop")
-        out["state"] = {"conv": put(state["conv"], out.pop("conv")),
-                        "kv": out.pop("kv"),
-                        "tokens": put(state["tokens"], history)}
+        out["state"]["tokens"] = state["tokens"].at[idx].set(
+            history, mode="drop")
         out["history"] = history
         out["rounds"] = rounds + 1
         out["positions"] = pos0 + model.cfg.round_positions
@@ -1058,6 +1057,11 @@ class InferenceEngine:
             "vep_moe_pairs_total",
             "Routed (token, expert) pairs computed by the held experts"
         ).labels()
+        mtp = obs_registry.counter(
+            "vep_mtp_drafts_total",
+            "Drafts of a stream head's prediction module, verified by the "
+            "decode loop and accepted", ("outcome",))
+        self._m_mtp = {k: mtp.labels(k) for k in ("drafted", "accepted")}
         # model name -> StreamStatePool (engine/stream_state.py), built
         # when a stream-head model is first dispatched or prewarmed
         self._head_pools: Dict[str, Any] = {}
@@ -4072,6 +4076,18 @@ class InferenceEngine:
                                moe_load_max=int(load.max()),
                                moe_load_mean=float(load.mean()))
             self._m_moe_pairs.inc(int(load.sum()))
+        if "mtp_drafted" in host:
+            # a head whose prediction module drafts: the drafts the batch's
+            # streams verified and accepted, the decode loop's iterations
+            rows = (list(group.rows) if group.rows is not None
+                    else list(range(len(group.device_ids))))
+            drafted = int(host.pop("mtp_drafted")[rows].sum())
+            accepted = int(host["mtp_accepted"][rows].sum())
+            inflight.tr.update(
+                mtp_drafted=drafted, mtp_accepted=accepted,
+                head_decode_iters=int(host.pop("decode_iters")))
+            self._m_mtp["drafted"].inc(drafted)
+            self._m_mtp["accepted"].inc(accepted)
         # submit -> outputs on the host: drain-queue wait + device + fetch
         device_ms = (t_drained - inflight.t_submit) * 1000.0
         if self.faults is not None:
@@ -4712,13 +4728,20 @@ class InferenceEngine:
     def _fill_head(head, host: dict, i: int) -> None:
         """A stream head's answer for batch row ``i``: the ids decoded
         since the stream's reset (this round's last), the top-5 of each of
-        this round's steps, and where the state stands."""
+        this round's steps, where the state stands, and of a drafting head
+        the drafts accepted and the round's first draft."""
         head.token_ids.extend(int(t) for t in host["history"][i] if t >= 0)
         for ids, probs in zip(host["top_ids"][i], host["top_probs"][i]):
             head.steps.add(token_ids=[int(t) for t in ids],
                            probs=[float(p) for p in probs])
         head.rounds_since_reset = int(host["rounds"][i])
         head.positions = int(host["positions"][i])
+        if "mtp_accepted" in host:
+            head.accepted = int(host["mtp_accepted"][i])
+            head.first_draft.token_ids.extend(
+                int(t) for t in host["draft_ids"][i])
+            head.first_draft.probs.extend(
+                float(p) for p in host["draft_probs"][i])
 
     def _num_classes(self, spec=None) -> int:
         spec = spec or self._spec

@@ -15,15 +15,19 @@ compiled shapes). And the three a step carries answer
 ``donate_argnums`` from) and ``nbytes()`` under ``ledger``, the pool's
 name in the HBM ledger (obs/hbm.py).
 
-A streaming head (``models/lfm2.py``) carries, for every camera, a fixed
-short-convolution state and a growing key-value cache with a length.
+A streaming head (``models/lfm2.py``, ``models/xing4.py``) carries, for
+every camera, whatever kinds of state its ``empty_state(slots)`` declares:
+a dict of named buffers and, by the same names, the axis of each that
+counts the slots (LFM2: ``conv`` [slots, conv layers, L-1, d] and ``kv`` =
+(keys, values), each [attention layers, slots, kv heads, head_dim,
+max_context]; Xing4: ``latent`` [blocks, slots, max_context, 576], one row
+of 576 numbers a position a block, and ``exit`` [slots, d]).
 
-One :class:`StreamStatePool` a stream-head model: ``conv`` [slots, conv
-layers, L-1, d], ``kv`` = (keys, values), each [attention layers, slots, kv
-heads, head_dim, max_context], and ``tokens`` [slots, rounds a context x D]
-(the ids decoded since the stream's reset), indexed by slot. The serving
+One :class:`StreamStatePool` a stream-head model: the model's kinds and
+``tokens`` [slots, rounds a context x D] (the ids decoded since the
+stream's reset), indexed by slot. The serving
 step reads and writes by slot index INSIDE the program and the buffers are
-donated, so the state never crosses to the host and the key-value cache is
+donated, so the state never crosses to the host and the caches are
 rewritten in place, with no gathered copy. The host keeps what is
 deterministic: each stream's slot, length, rounds since its reset and
 whether its first context is over. :meth:`plan` turns a batch's device ids
@@ -400,19 +404,23 @@ class StreamStatePool(SlotMap):
         changes the step's shapes: its programs compile again)."""
         if slots <= self.capacity and self.state is not None:
             return
+        import jax
         import jax.numpy as jnp
 
         cap = -(-max(slots, 1) // self._grow) * self._grow
         c = self.cfg
-        conv, kv = self.model.empty_state(cap)
-        tokens = jnp.full((cap, c.max_rounds * c.decode_steps), -1, jnp.int32)
+        state, axes = self.model.empty_state(cap)
+        state["tokens"] = jnp.full(
+            (cap, c.max_rounds * c.decode_steps), -1, jnp.int32)
         if self.state is not None:
-            old = self.capacity
-            conv = conv.at[:old].set(self.state["conv"])
-            kv = tuple(a.at[:, :old].set(b)
-                       for a, b in zip(kv, self.state["kv"]))
-            tokens = tokens.at[:old].set(self.state["tokens"])
-        self.state = {"conv": conv, "kv": kv, "tokens": tokens}
+            # what the streams hold moves into the first slots of the new
+            # buffers, each kind along its own slot axis
+            for kind, new in state.items():
+                axis = axes.get(kind, 0)
+                state[kind] = jax.tree_util.tree_map(
+                    lambda a, b: jax.lax.dynamic_update_slice_in_dim(
+                        a, b, 0, axis), new, self.state[kind])
+        self.state = state
         self.capacity = cap
 
     def plan(self, device_ids, bucket: int, rows=None) -> dict:

@@ -22,10 +22,13 @@ Output head tied to the embedding.
 State, per stream: ``conv`` [conv layers, L-1, d] and one slot of ``kv`` =
 (keys, values), each [attention layers, slots, kv heads, head_dim,
 max_context]; a stream's length is kept by the host
-(``engine/stream_state.py``). :func:`serve_round` is one round of a batch
-of streams as a pure function: connector, instruction prefill for the
-streams that reset, visual prefill in chunks of streams, D decode steps,
-all committed to the state.
+(``engine/stream_state.py``). The round itself (connector, instruction
+for the streams that reset, visual prefill in chunks of streams, D decode
+steps, all committed to the state) is ``stream_head.serve_round``, shared
+with the other streaming head (``models/xing4.py``), as are the norm, the
+dense feed-forward, the connector and the cast at load; what is LFM2's own
+is here: its operators, its two kinds of state and what the round asks of
+a head (:class:`VideoMAELfm2`'s ``seed_round`` ... ``commit_round``).
 """
 
 from __future__ import annotations
@@ -37,11 +40,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from . import stream_head
 from .common import Dtype
+from .stream_head import (Connector, RmsNorm, SwiGlu, _kernel,  # noqa: F401
+                          cast_for_serving, prepare_for_serving, top_tokens)
 from .transformer import TopKMoeConfig, TopKMoeMlp
 from .videomae import VideoMAE, VideoMAEConfig, tiny_videomae_config
-
-TOP_K_TOKENS = 5
 
 
 @dataclass(frozen=True)
@@ -97,28 +101,11 @@ INSTRUCTION_IDS = tuple((7919 * (i + 1)) % 65521 for i in range(32))
 
 
 @dataclass(frozen=True)
-class StreamHeadConfig:
+class StreamHeadConfig(stream_head.StreamHeadConfig):
     """VideoMAE encoder -> connector -> LFM2 head, and the round's policy."""
     video: VideoMAEConfig = field(default_factory=VideoMAEConfig)
     head: Lfm2Config = field(default_factory=Lfm2Config)
     instruction_ids: Tuple[int, ...] = INSTRUCTION_IDS
-    decode_steps: int = 8
-    # streams per prefill chunk (bounds the dense layer's activations)
-    prefill_chunk: int = 16
-
-    @property
-    def visual_tokens(self) -> int:
-        return self.video.num_tokens
-
-    @property
-    def round_positions(self) -> int:
-        return self.visual_tokens + self.decode_steps
-
-    @property
-    def max_rounds(self) -> int:
-        """Rounds a context holds after the instruction."""
-        return ((self.head.max_context - len(self.instruction_ids))
-                // self.round_positions)
 
 
 def tiny_stream_head_config() -> StreamHeadConfig:
@@ -135,25 +122,6 @@ def tiny_stream_head_config() -> StreamHeadConfig:
             experts_held=(0, 1, 2, 3), max_context=160),
         instruction_ids=(5, 17, 3, 90),
         decode_steps=3, prefill_chunk=2)
-
-
-def _kernel(mod, name, shape, axes):
-    return mod.param(name, nn.with_logical_partitioning(
-        nn.initializers.xavier_uniform(), axes), shape, jnp.float32)
-
-
-class RmsNorm(nn.Module):
-    eps: float
-    dtype: Dtype = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones_init(),
-                           (x.shape[-1],), jnp.float32)
-        x = x.astype(jnp.float32)
-        x = x * jax.lax.rsqrt(
-            jnp.mean(x * x, axis=-1, keepdims=True) + self.eps)
-        return (x * scale.astype(jnp.float32)).astype(self.dtype)
 
 
 class ShortConv(nn.Module):
@@ -338,21 +306,6 @@ def flush_round(pool, rbuf, slots, pos0):
     return jax.lax.fori_loop(0, rbuf[0].shape[1], row, tuple(pool))
 
 
-class SwiGlu(nn.Module):
-    cfg: Lfm2Config
-    dtype: Dtype = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, h):
-        d, m = self.cfg.dim, self.cfg.mlp_dim
-        w1 = _kernel(self, "w1", (d, m), ("embed", "mlp"))
-        w3 = _kernel(self, "w3", (d, m), ("embed", "mlp"))
-        w2 = _kernel(self, "w2", (m, d), ("mlp", "embed"))
-        with jax.named_scope("head_dense_mlp"):
-            a = nn.silu(h @ w1.astype(self.dtype)) * (h @ w3.astype(self.dtype))
-            return a @ w2.astype(self.dtype)
-
-
 class Lfm2Stack(nn.Module):
     """The decoder's layers over [B, T, d] embeddings that continue each
     stream's state at ``pos0`` [B]."""
@@ -380,7 +333,8 @@ class Lfm2Stack(nn.Module):
             else:
                 raise ValueError(f"unknown layer type {kind!r}")
             if i < c.num_dense_layers:
-                ffns.append(SwiGlu(c, self.dtype, name=f"layer{i}_mlp"))
+                ffns.append(SwiGlu(c.dim, c.mlp_dim, self.dtype,
+                                   name=f"layer{i}_mlp"))
             else:
                 ffns.append(TopKMoeMlp(c.moe, self.dtype,
                                        name=f"layer{i}_moe"))
@@ -438,19 +392,6 @@ class Lfm2Stack(nn.Module):
         return x, conv, (rk, rv), load
 
 
-class Connector(nn.Module):
-    """LLaVA-style per-token projector: Linear -> GELU -> Linear."""
-    dim: int
-    dtype: Dtype = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        with jax.named_scope("head_connector"):
-            x = nn.Dense(self.dim, dtype=self.dtype, name="fc1")(x)
-            return nn.Dense(self.dim, dtype=self.dtype, name="fc2")(
-                nn.gelu(x, approximate=False))
-
-
 class VideoMAELfm2(nn.Module):
     """VideoMAE encoder (no classifier) -> connector -> LFM2 head."""
     cfg: StreamHeadConfig
@@ -475,17 +416,96 @@ class VideoMAELfm2(nn.Module):
     def logits(self, h):
         return self.head.logits(h)
 
-    # what the engine's ``stream`` step kind asks of a model
+    # what the engine's ``stream`` step kind and the pool ask of a model
     @nn.nowrap
     def empty_state(self, slots: int):
-        """Zeroed (conv, kv) for ``slots`` streams, in the model's dtype."""
-        return empty_state(self.cfg.head, slots, dtype=self.dtype)
+        """Zeroed state for ``slots`` streams in the model's dtype, by
+        kind, and the axis of each kind's buffers that counts the slots."""
+        conv, kv = empty_state(self.cfg.head, slots, dtype=self.dtype)
+        return {"conv": conv, "kv": kv}, {"conv": 0, "kv": 1}
 
     @nn.nowrap
-    def serve_round(self, variables, clips, conv, kv, slots, pos0, reset,
+    def serve_round(self, variables, clips, state, slots, pos0, reset,
                     preprocess=lambda clips: clips):
-        return serve_round(self, variables, clips, conv, kv, slots, pos0,
-                           reset, preprocess)
+        return stream_head.serve_round(self, variables, clips, state, slots,
+                                       pos0, reset, preprocess)
+
+    @nn.nowrap
+    def instruction_state(self, variables):
+        """The standing instruction through a fresh state: the conv state
+        and the keys and values every context starts from ({"conv": [1,
+        conv layers, L-1, d], "kv": (keys, values), each [attention layers,
+        1, KV, hd, instruction length]}). A function of the weights
+        alone."""
+        c, hc = self.cfg, self.cfg.head
+        apply = lambda method, *a: self.apply(variables, *a, method=method)  # noqa: E731
+        ids = jnp.asarray(c.instruction_ids, jnp.int32)
+        zero = jnp.zeros((1,), jnp.int32)
+        conv, none = empty_state(hc, 1, 0, self.dtype)
+        _, conv, kv, _ = apply(
+            VideoMAELfm2.forward, apply(VideoMAELfm2.embed, ids)[None], conv,
+            none, round_buffer(hc, 1, len(c.instruction_ids), self.dtype),
+            zero, zero)
+        return {"conv": conv, "kv": kv}
+
+    # what ``stream_head.serve_round`` asks of a head
+    @nn.nowrap
+    def seed_round(self, variables, state, slots, reset):
+        """The key-value pool with the instruction's keys and values as
+        the first rows of every slot (read and written in place, by slot),
+        and the rows' conv state (small: gathered by slot), the
+        instruction's for a stream that resets."""
+        ins = variables["instruction"]
+        n_i = len(self.cfg.instruction_ids)
+        conv = jnp.take(state["conv"], slots, axis=0, mode="clip")
+        conv = jnp.where(reset[:, None, None, None],
+                         ins["conv"].astype(conv.dtype), conv)
+        kv = tuple(a.at[..., :n_i].set(jnp.broadcast_to(
+            i.astype(a.dtype), a[..., :n_i].shape))
+            for a, i in zip(state["kv"], ins["kv"]))
+        return kv, conv
+
+    @nn.nowrap
+    def round_buffer(self, rows: int, dtype):
+        return round_buffer(self.cfg.head, rows, self.cfg.round_positions,
+                            dtype)
+
+    @nn.nowrap
+    def prefill(self, variables, x, kv, conv, rbuf, slots, pos0):
+        h, conv, rbuf, load = self.apply(
+            variables, x, conv, kv, rbuf, slots, pos0,
+            method=VideoMAELfm2.forward)
+        return h[:, -1], conv, rbuf, load
+
+    @nn.nowrap
+    def decode(self, variables, kv, h, conv, rbuf, slots, pos0, load):
+        """D steps that each commit one position a stream."""
+        c = self.cfg
+        apply = lambda method, *a: self.apply(variables, *a, method=method)  # noqa: E731
+
+        def step(carry, r):
+            h, conv, rbuf, load = carry
+            with jax.named_scope("head_decode"):
+                tok, top_i, top_p = top_tokens(
+                    apply(VideoMAELfm2.logits, h))
+                h, conv, rbuf, m = apply(
+                    VideoMAELfm2.forward,
+                    apply(VideoMAELfm2.embed, tok)[:, None],
+                    conv, kv, rbuf, slots, pos0, c.visual_tokens + r)
+            return (h[:, 0], conv, rbuf, load + m), (tok, top_i, top_p)
+
+        (_, conv, rbuf, load), (toks, top_i, top_p) = jax.lax.scan(
+            step, (h, conv, rbuf, load),
+            jnp.arange(c.decode_steps, dtype=pos0.dtype))
+        return {"tokens": toks.T, "top_ids": top_i.transpose(1, 0, 2),
+                "top_probs": top_p.transpose(1, 0, 2), "rows": conv,
+                "rbuf": rbuf, "moe_load": load}
+
+    @nn.nowrap
+    def commit_round(self, state, kv, conv, rbuf, slots, pos0):
+        return {"conv": state["conv"].at[slots].set(
+                    conv.astype(state["conv"].dtype), mode="drop"),
+                "kv": flush_round(kv, rbuf, slots, pos0)}
 
     def __call__(self, clips):
         """A fresh stream's first round without a pool: the logits that
@@ -521,135 +541,3 @@ def empty_state(cfg: Lfm2Config, slots: int, context=None,
     kv = tuple(jnp.zeros((cfg.attn_layers, slots, cfg.num_kv_heads,
                           cfg.head_dim, context), dtype) for _ in "kv")
     return conv, kv
-
-
-def cast_for_serving(variables):
-    """The head's and the connector's matrices in bfloat16, once, when the
-    engine takes the model (the config's own dtype; a decode step is bound
-    by reading them). Vectors (norm scales, biases, the router's bias) and
-    the router stay float32: 0.13M parameters a layer, and the choice of
-    experts is then made on float32 scores. The encoder stays as every
-    cell runs it."""
-    is_box = lambda x: isinstance(x, nn.meta.AxisMetadata)  # noqa: E731
-
-    def cast(path, leaf):
-        keys = [getattr(k, "key", str(k)) for k in path]
-        raw = leaf.unbox() if is_box(leaf) else leaf
-        if (keys[1] in ("head", "connector") and raw.ndim >= 2
-                and keys[-1] != "gate" and raw.dtype == jnp.float32):
-            raw = raw.astype(jnp.bfloat16)
-            return leaf.replace_boxed(raw) if is_box(leaf) else raw
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(cast, variables, is_leaf=is_box)
-
-
-def instruction_state(model: "VideoMAELfm2", variables):
-    """The standing instruction through a fresh state: the conv state and
-    the keys and values every context starts from ({"conv": [1, conv
-    layers, L-1, d], "kv": (keys, values), each [attention layers, 1, KV,
-    hd, instruction length]}). A function of the weights alone."""
-    c, hc = model.cfg, model.cfg.head
-    apply = lambda method, *a: model.apply(variables, *a, method=method)  # noqa: E731
-    ids = jnp.asarray(c.instruction_ids, jnp.int32)
-    zero = jnp.zeros((1,), jnp.int32)
-    conv, none = empty_state(hc, 1, 0, model.dtype)
-    _, conv, kv, _ = apply(
-        VideoMAELfm2.forward, apply(VideoMAELfm2.embed, ids)[None], conv,
-        none, round_buffer(hc, 1, len(c.instruction_ids), model.dtype), zero,
-        zero)
-    return {"conv": conv, "kv": kv}
-
-
-def prepare_for_serving(model: "VideoMAELfm2", variables):
-    """What the engine does once when it takes the model
-    (``ModelSpec.prepare``): a bfloat16 model's head is cast
-    (:func:`cast_for_serving`), and the instruction's state is computed
-    and kept beside the weights as the collection ``instruction``."""
-    if model.dtype == jnp.bfloat16:
-        variables = cast_for_serving(variables)
-    return {**variables, "instruction": jax.jit(
-        lambda v: instruction_state(model, v))(variables)}
-
-
-def serve_round(model: VideoMAELfm2, variables, clips, conv, kv, slots,
-                pos0, reset, preprocess=lambda clips: clips):
-    """One round of a batch of streams (a pure function of its arguments).
-
-    ``variables`` as :func:`prepare_for_serving` leaves them;
-    ``clips`` [B, T, H, W, 3], which ``preprocess`` turns into the
-    encoder's input (chunk by chunk); ``conv`` [B, ...] the rows'
-    conv state; ``kv`` the whole key-value pool (row b owns slot
-    ``slots[b]``; a slot past the pool is a padded row), read during the
-    round and written once at its end, in place;
-    ``pos0`` [B] where each stream's visual tokens start (its length, or
-    the instruction's length if it resets now); ``reset`` [B] bool.
-    Returns a dict: ``tokens`` [B, D] the greedy ids, ``top_ids`` /
-    ``top_probs`` [B, D, 5] of each step's distribution, ``conv``/``kv``
-    the state after the round (visual tokens and all D decoded tokens
-    committed), ``moe_load`` [held] routed pairs a held expert took
-    (prefill and decode).
-    """
-    c = model.cfg
-    hc = c.head
-    b = clips.shape[0]
-    apply = lambda method, *a: model.apply(variables, *a, method=method)  # noqa: E731
-
-    n_v = c.visual_tokens
-
-    # Every context starts with the standing instruction, so its keys and
-    # values are the first rows of EVERY slot (written anew each round: a
-    # few MB), and a stream that resets takes its conv state.
-    ins = variables["instruction"]
-    n_i = len(c.instruction_ids)
-    conv = jnp.where(reset[:, None, None, None],
-                     ins["conv"].astype(conv.dtype), conv)
-    kv = tuple(a.at[..., :n_i].set(jnp.broadcast_to(
-        i.astype(a.dtype), a[..., :n_i].shape)) for a, i in zip(kv, ins["kv"]))
-
-    # Preprocess, encoder, connector and visual prefill, streams in chunks
-    # (the whole batch at once would hold the encoder's [B, 12, V, V]
-    # scores and the dense layer's [B * V, 11776] activations). The pool
-    # is only read; the round's keys and values go to the round buffer.
-    n = c.prefill_chunk if b % c.prefill_chunk == 0 else b
-    rbuf = round_buffer(hc, b, c.round_positions, kv[0].dtype)
-
-    def prefill(i, carry):
-        h, conv, rbuf, load = carry
-        rows = lambda a, ax=0: jax.lax.dynamic_slice_in_dim(  # noqa: E731
-            a, i * n, n, axis=ax)
-        put = lambda a, v, ax=0: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731
-            a, v.astype(a.dtype), i * n, ax)
-        x = apply(VideoMAELfm2.encode, preprocess(rows(clips)))  # [n, V, d]
-        hn, cn, rn, m = apply(
-            VideoMAELfm2.forward, x, rows(conv), kv,
-            tuple(rows(a, 1) for a in rbuf), rows(slots), rows(pos0))
-        return (put(h, hn[:, -1]), put(conv, cn),
-                tuple(put(a, v, 1) for a, v in zip(rbuf, rn)), load + m)
-
-    h, conv, rbuf, load = jax.lax.fori_loop(
-        0, b // n, prefill,
-        (jnp.zeros((b, hc.dim), model.dtype), conv, rbuf,
-         jnp.zeros((len(hc.moe.held),), jnp.int32)))
-
-    def emit(h):
-        logits = apply(VideoMAELfm2.logits, h)                  # [B, vocab]
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_i = jax.lax.top_k(probs, min(TOP_K_TOKENS, hc.vocab_size))
-        return top_i[:, 0].astype(jnp.int32), top_i.astype(jnp.int32), top_p
-
-    def decode(carry, step):
-        h, conv, rbuf, load = carry
-        with jax.named_scope("head_decode"):
-            tok, top_i, top_p = emit(h)
-            h, conv, rbuf, m = apply(
-                VideoMAELfm2.forward, apply(VideoMAELfm2.embed, tok)[:, None],
-                conv, kv, rbuf, slots, pos0, n_v + step)
-        return (h[:, 0], conv, rbuf, load + m), (tok, top_i, top_p)
-
-    (_, conv, rbuf, load), (toks, top_i, top_p) = jax.lax.scan(
-        decode, (h, conv, rbuf, load),
-        jnp.arange(c.decode_steps, dtype=pos0.dtype))
-    return {"tokens": toks.T, "top_ids": top_i.transpose(1, 0, 2),
-            "top_probs": top_p.transpose(1, 0, 2), "conv": conv,
-            "kv": flush_round(kv, rbuf, slots, pos0), "moe_load": load}

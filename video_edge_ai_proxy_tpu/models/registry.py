@@ -17,10 +17,10 @@ import jax
 import jax.numpy as jnp
 
 from .blob import BlobGauge, BlobGaugeConfig
-from .lfm2 import (StreamHeadConfig, VideoMAELfm2, prepare_for_serving,
-                   tiny_stream_head_config)
+from .lfm2 import StreamHeadConfig, VideoMAELfm2, tiny_stream_head_config
 from .mobilenet_v2 import MobileNetV2, MobileNetV2Config, tiny_mobilenet_v2_config
 from .resnet import ResNet, ResNetConfig, tiny_resnet_config
+from .stream_head import prepare_for_serving
 from .videomae import VideoMAE, VideoMAEConfig, tiny_videomae_config
 from .vit import ViT, ViTConfig, tiny_vit_config
 from .yolov8 import YOLOv8, tiny_yolov8_config, yolov8n_config, yolov8s_config
@@ -137,6 +137,34 @@ register(ModelSpec(
                 "a stream a round",
 ))
 
+
+
+def _xing4(tiny: bool = False):
+    """The second streaming head, imported when it is first built: a
+    process that serves another model never loads its module."""
+    from . import xing4
+
+    if tiny:
+        return xing4.VideoMAEXing4(xing4.tiny_stream_head_config(),
+                                   dtype=jnp.float32)
+    return xing4.VideoMAEXing4(xing4.StreamHeadConfig())
+
+
+register(ModelSpec(
+    "videomae_b_xing4", _xing4,
+    input_size=224, preprocess="clip", kind="stream", clip_len=8,
+    prepare=prepare_for_serving,
+    description="second streaming head (models/xing4.py): VideoMAE-B "
+                "encoder -> connector -> Xing4.0-29B-A4B decoder at the "
+                "published widths, one chip's share (1 dense + 4 routed "
+                "blocks and the prediction module, 16 of 64 experts a "
+                "block, a quarter of the vocabulary); latent attention with "
+                "a 576-wide cache row a position a block and a four-stream "
+                "hyper-connected residual; per-stream latent cache in "
+                "engine/stream_state.py; 784 tokens prefilled and 8 decoded "
+                "a stream a round, the prediction module drafting",
+))
+
 # --- diagnostic gauges ----------------------------------------------------
 
 register(ModelSpec(
@@ -191,4 +219,11 @@ register(ModelSpec(
     description="CPU/CI twin of videomae_b_lfm2 (tests/test_stream_head.py), "
                 "in float32 throughout (no cast at load), so that a test "
                 "tells a broken state from rounding",
+))
+register(ModelSpec(
+    "tiny_videomae_xing4", lambda: _xing4(tiny=True),
+    input_size=32, preprocess="clip", kind="stream", clip_len=4,
+    prepare=prepare_for_serving,
+    description="CPU/CI twin of videomae_b_xing4 (tests/test_xing4_head.py), "
+                "in float32 throughout",
 ))

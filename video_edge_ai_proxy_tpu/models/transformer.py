@@ -237,9 +237,11 @@ class RoutedMoeMlp(nn.Module):
 
 @dataclass(frozen=True)
 class TopKMoeConfig:
-    """A routed SwiGLU expert layer as ``lfm2_moe`` publishes it: the
-    router scores ALL ``num_experts``; this holder computes the experts in
-    ``experts_held`` (their ids among the ``num_experts``; empty = all)."""
+    """A routed SwiGLU expert layer as ``lfm2_moe`` and ``xing4_0`` publish
+    it: the router scores ALL ``num_experts``; this holder computes the
+    experts in ``experts_held`` (their ids among the ``num_experts``; empty
+    = all). ``shared_mlp_dim``: the width of the shared expert every token
+    takes beside the routed ones, unweighted (0: none, and no parameter)."""
     dim: int
     mlp_dim: int
     num_experts: int
@@ -248,6 +250,7 @@ class TopKMoeConfig:
     use_expert_bias: bool = True
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    shared_mlp_dim: int = 0
 
     @property
     def held(self) -> tuple:
@@ -257,12 +260,13 @@ class TopKMoeConfig:
 def topk_route(scores: jnp.ndarray, bias, cfg: TopKMoeConfig):
     """([N, k] expert ids, [N, k] weights) from [N, E] router scores
     (sigmoid already applied): the top-k of ``scores + bias``, weighted by
-    the unbiased scores of the chosen, renormalised to sum 1."""
+    the unbiased scores of the chosen, renormalised to sum 1 (over their
+    sum + 1e-20, as published), times ``routed_scaling_factor``."""
     pick = scores + bias if bias is not None else scores
     _, sel = jax.lax.top_k(pick, cfg.top_k)
     w = jnp.take_along_axis(scores, sel, axis=-1)
     if cfg.norm_topk_prob:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return sel, w * cfg.routed_scaling_factor
 
 
@@ -279,7 +283,9 @@ class TopKMoeMlp(nn.Module):
     whose expert lives on another holder add nothing here: the caller sums
     the holders' partial outputs (on one chip of a deployment, nothing
     stands in for the others). The stacks hold ``len(experts_held)``
-    experts and carry the "expert" logical axis (ep sharding).
+    experts and carry the "expert" logical axis (ep sharding). A shared
+    expert (``shared_mlp_dim``) is every holder's alike: its output is
+    added here unweighted, and counted once where holders are summed.
 
     Returns ``(y [N, d], load [held])``: the pairs each held expert took.
     """
@@ -329,6 +335,19 @@ class TopKMoeMlp(nn.Module):
         ys = jnp.where(here[:, None], ys, 0.0)
         back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(n, k, d)
         y = jnp.einsum("nkd,nk->nd", back, w.astype(jnp.float32))
+        if c.shared_mlp_dim:
+            m = c.shared_mlp_dim
+            s1, s3, s2 = (self.param(
+                name, nn.with_logical_partitioning(
+                    nn.initializers.xavier_uniform(), axes), shape,
+                jnp.float32).astype(self.dtype) for name, shape, axes in (
+                    ("shared_w1", (d, m), ("embed", "mlp")),
+                    ("shared_w3", (d, m), ("embed", "mlp")),
+                    ("shared_w2", (m, d), ("mlp", "embed"))))
+            with jax.named_scope("moe_shared"):
+                xd = x.astype(self.dtype)
+                y = y + jnp.dot(nn.silu(xd @ s1) * (xd @ s3), s2,
+                                preferred_element_type=jnp.float32)
         return y.astype(self.dtype), sizes
 
 

@@ -1,0 +1,882 @@
+"""Xing4.0's decoder as a per-stream streaming head behind the VideoMAE
+encoder (``xing4_0``: XingChen-AGI/Xing4.0-29B-A4B ``config.json``).
+
+The reference ships frames to external clients and has no model at all
+(`/root/reference/README.md:5-27`); this is the second head of ROADMAP R9,
+served exactly where ``models/lfm2.py`` is: the round, the connector and
+the cast at load are ``models/stream_head.py``'s. What is this head's own:
+
+- **The residual is ``hc_mult`` streams wide** (manifold-constrained
+  hyper-connections, arXiv:2512.24880): the state of a position is
+  ``X [n, C]`` (held streams-first, [n, B, T, C]). Before a sublayer ``F``, from ``x̂ = RMS(flatten X)``:
+  ``H_pre = σ(α_pre · x̂φ_pre + b_pre)`` [n], ``H_post = 2σ(α_post ·
+  x̂φ_post + b_post)`` [n], ``H_res = SK(α_res · x̂φ_res + b_res)`` [n, n]
+  (exp of the clamped logits, then ``hc_sinkhorn_iters`` times rows over
+  their sums + ``hc_eps``, columns over theirs); ``h = H_pre X``, ``y =
+  F(RMS(h))``, ``X' = H_res X + H_postᵀ y``. Entry: the embedding repeated
+  n times; exit: the sum of the streams. The maps are float32.
+- **Attention is latent (MLA)**: ``c_q = RMS(h W_qa)``, ``[q_n | q_r] = c_q
+  W_qb`` a head; ``[c_kv | k_r] = h W_kva``, ``ĉ = RMS(c_kv)``; **a
+  position's cache row is ``[ĉ | rope(k_r)]``**, 576 numbers, ``k_r``
+  shared by all heads; ``[k_n | v] = ĉ W_kvb`` a head; yarn rope on the
+  rope part only; scores scaled by ``(d_n + d_r)^-½ · mscale²``. Two paths
+  over the one cache: :func:`mla_prefill_attention` up-projects the cached
+  rows it attends to per-head keys and values (per stream, never stored);
+  :func:`mla_decode_attention` folds ``W_kvb``'s key half into the query
+  and its value half into the output and attends over the 576-wide rows as
+  they lie.
+- **Feed-forward**: block 0 dense SwiGLU, the others
+  ``transformer.TopKMoeMlp`` with the shared expert (sigmoid router over
+  all experts, top-k of score + bias, renormalised, × 2, dropless, this
+  chip's ``experts_held``).
+- **The prediction module** (one; DeepSeek-V3's MTP): ``u = [RMS(h_i) |
+  RMS(e_{i+1})] W_eh`` through one routed block with its own cache, its own
+  final norm, the shared embedding and head: logits for position ``i + 2``.
+  Its row ``j`` pairs the main model's exit state at ``j`` with the input
+  at ``j + 1``, so its cache trails the main one by a position, and the
+  exit state of a stream's last position is carried to the next round.
+  It is the decode loop's depth-1 drafter (:meth:`VideoMAEXing4.decode`).
+
+State, per stream: one slot of ``latent`` [blocks, slots, max_context,
+576] (the main blocks, then the module's; a position's row minor-most: the
+layout the TPU compiler gives both paths' products, which it would copy
+the whole pool into otherwise) and ``exit`` [slots, C].
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass, field
+from typing import Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from . import stream_head
+from .common import Dtype
+from .stream_head import Connector, RmsNorm, SwiGlu, _kernel, top_tokens
+from .transformer import TopKMoeConfig, TopKMoeMlp
+from .videomae import VideoMAE, VideoMAEConfig, tiny_videomae_config
+
+
+@dataclass(frozen=True)
+class Xing4Config:
+    vocab_size: int = 32768           # the first quarter of 131072
+    dim: int = 3584
+    num_layers: int = 5               # published layer 0 and four routed
+    num_dense_layers: int = 1
+    num_heads: int = 32
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mlp_dim: int = 9216               # dense SwiGLU width
+    moe_mlp_dim: int = 1024           # one routed expert's width
+    num_experts: int = 64             # router width
+    top_k: int = 4
+    experts_held: Tuple[int, ...] = tuple(range(16))
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.0
+    hc_mult: int = 4
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp_min: float = -30.0
+    hc_clamp_max: float = 30.0
+    norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_factor: float = 64.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    num_nextn_predict_layers: int = 1
+    max_context: int = 4096
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def row_dim(self) -> int:
+        """A cache row's width in memory: the latent row up to a lane
+        tile (576 -> 640: the TPU pads a 576-wide minor axis to 640 anyway,
+        and with an axis it would pad the compiler keeps the whole pool in
+        a second layout beside the first)."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def blocks(self) -> int:
+        """Blocks that keep a latent cache: the main ones, then the
+        prediction module's."""
+        return self.num_layers + self.num_nextn_predict_layers
+
+    @property
+    def moe(self) -> TopKMoeConfig:
+        return TopKMoeConfig(
+            dim=self.dim, mlp_dim=self.moe_mlp_dim,
+            num_experts=self.num_experts, top_k=self.top_k,
+            experts_held=tuple(self.experts_held),
+            routed_scaling_factor=self.routed_scaling_factor,
+            shared_mlp_dim=self.n_shared_experts * self.moe_mlp_dim)
+
+
+# the standing instruction: 32 token ids of the held vocabulary slice, a
+# constant of the registry entry (the configuration file repeats them)
+INSTRUCTION_IDS = tuple((7919 * (i + 1)) % 32749 for i in range(32))
+
+
+@dataclass(frozen=True)
+class StreamHeadConfig(stream_head.StreamHeadConfig):
+    """VideoMAE encoder -> connector -> Xing4 head, and the round's policy."""
+    video: VideoMAEConfig = field(default_factory=VideoMAEConfig)
+    head: Xing4Config = field(default_factory=Xing4Config)
+    instruction_ids: Tuple[int, ...] = INSTRUCTION_IDS
+    prefill_chunk: int = 8
+
+
+def tiny_stream_head_config(vocab_size: int = 96) -> StreamHeadConfig:
+    """CPU twin: every mechanism at toy widths (one dense and two routed
+    blocks and the module, 8 experts of which 4 are held, top-2, the
+    published 4 residual streams and 20 Sinkhorn iterations)."""
+    return StreamHeadConfig(
+        video=tiny_videomae_config(),
+        head=Xing4Config(
+            vocab_size=vocab_size, dim=32, num_layers=3, num_dense_layers=1,
+            num_heads=4, q_lora_rank=24, kv_lora_rank=16,
+            qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+            mlp_dim=80, moe_mlp_dim=24, num_experts=8, top_k=2,
+            experts_held=(0, 1, 2, 3), hc_sinkhorn_iters=20,
+            rope_original_max=64, max_context=160),
+        instruction_ids=tuple(i % vocab_size for i in (5, 17, 3, 90)),
+        decode_steps=3, prefill_chunk=2)
+
+
+# -- yarn rope -----------------------------------------------------------------
+
+def yarn_mscale(factor: float, m: float) -> float:
+    return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(cfg: Xing4Config) -> np.ndarray:
+    """[d_r / 2] inverse frequencies: extrapolated (as published) below the
+    low correction dimension, interpolated (÷ factor) above the high one,
+    blended by the linear ramp between."""
+    d = cfg.qk_rope_head_dim
+    extra = cfg.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    inter = extra / cfg.rope_factor
+
+    def correction(rotations):
+        return (d * math.log(cfg.rope_original_max
+                             / (rotations * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(correction(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction(cfg.rope_beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope(x, pos, cfg: Xing4Config):
+    """Rotate-half yarn rope: x [B, T, (H,) d_r], pos [B, T]."""
+    ang = pos.astype(jnp.float32)[..., None] * yarn_inv_freq(cfg)
+    m = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+         / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1) * m
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1) * m
+    if x.ndim == 4:
+        cos, sin = cos[:, :, None], sin[:, :, None]
+    x = x.astype(jnp.float32)
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+def softmax_scale(cfg: Xing4Config) -> float:
+    return ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+            * yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2)
+
+
+# -- the two attention paths over the one latent cache ------------------------
+
+def mla_prefill_attention(q, new, w_uk, w_uv, pool, slots, ctx, scale, cap):
+    """A round's first T positions: ``q`` [B, T, H, d_n + d_r] (the rope
+    part roped) against each row's slot of the pool (``pool`` [slots, S,
+    row]: the positions before ``ctx`` [B] are the stream's context; the
+    rest is stale or unwritten, and masked; only the first ``cap`` can be
+    context when a round starts) and, causally, against the T new rows
+    themselves (``new`` [B, T, row]). The cached rows are up-projected to
+    per-head keys and values (``w_uk`` [r, H, d_n], ``w_uv`` [r, H, d_v])
+    here, one stream at a time, so one stream's [cap, H, d] keys and its
+    [H, T, cap] and [H, T, T] scores are all that is held; and of the
+    cached rows only as many quarters of ``cap`` as the stream's context
+    reaches into (a switch a stream: the de-phased fleet holds every
+    depth, and a fresh context has one quarter to attend, not four). The
+    two partial softmaxes are merged by their maxima and sums."""
+    b, t, h, _ = q.shape
+    r, dn = w_uk.shape[0], w_uk.shape[-1]
+    dr = q.shape[-1] - dn
+    causal = jnp.tril(jnp.ones((t, t), bool))[None, None]
+
+    def part(qc, rows, mask):
+        """(max, sum, unnormalised output) of one stream's softmax over
+        ``rows`` [1, S, row], the rope part shared by the heads."""
+        c_rows = rows[..., :r]
+        keys = jnp.concatenate(
+            [jnp.einsum("bsr,rhd->bshd", c_rows, w_uk),
+             jnp.broadcast_to(rows[:, :, None, r:r + dr],
+                              rows.shape[:2] + (h, dr))], axis=-1)
+        s = jnp.einsum("bthd,bshd->bhts", qc, keys).astype(
+            jnp.float32) * scale
+        s = jnp.where(mask, s, -1e30)
+        m = jnp.max(s, axis=-1)
+        e = jnp.exp(s - m[..., None])
+        o = jnp.einsum("bhts,bshd->bthd", e.astype(qc.dtype),
+                       jnp.einsum("bsr,rhd->bshd", c_rows, w_uv),
+                       preferred_element_type=jnp.float32)
+        return m, jnp.sum(e, axis=-1), o
+
+    step = -(-cap // (4 * 128)) * 128 if cap else 0
+    depths = sorted({min(cap, step * k) for k in range(1, 5)}) if cap else []
+
+    def one(args):
+        # a leading axis of one stream: the batched products below are the
+        # form the TPU compiler lays out well (models/lfm2.py)
+        qc, nw, sc, cc = (a[None] for a in args)
+        m, total, o = part(qc, nw, causal)
+        if cap:
+            def cached(depth):
+                rows = jnp.take(pool, sc, axis=0, mode="clip")[:, :depth]
+                seen = jnp.arange(depth)[None, :] < cc[:, None]
+                return part(qc, rows, seen[:, None, None])
+
+            m_a, l_a, o_a = jax.lax.switch(
+                jnp.clip(-(-cc[0] // step) - 1, 0, len(depths) - 1),
+                [lambda d=d: cached(d) for d in depths])
+            m_b, total_b, o_b = m, total, o
+            m = jnp.maximum(m_a, m_b)
+            w_a, w_b = jnp.exp(m_a - m), jnp.exp(m_b - m)
+            total = l_a * w_a + total_b * w_b
+            o = (o_a * w_a.transpose(0, 2, 1)[..., None]
+                 + o_b * w_b.transpose(0, 2, 1)[..., None])
+        o = o / total.transpose(0, 2, 1)[..., None]
+        return o.astype(qc.dtype).reshape(t, -1)
+
+    return jax.lax.map(one, (q, new, slots, ctx))
+
+
+def mla_decode_attention(q_n, q_r, w_uk, w_uv, pool, rbuf, slots, ctx, upto,
+                         scale):
+    """A few new positions a stream, in the latent space: ``q_n`` [B, T, H,
+    d_n] is carried through ``w_uk`` to the cache's own width (``q_n W_ukᵀ``
+    [r] a head), joined with ``q_r``, and attends over the 576-wide rows as
+    they lie: the pool READ IN PLACE in slot order (the queries are carried
+    to their slots and the partial results back by a one-hot product, as
+    ``models/lfm2.py`` does: gathering the rows would copy the cache), and
+    this round's own rows ``rbuf`` [B, R, r + d_r], of which query t of row
+    b sees those up to ``upto[b, t]``. The two partial softmaxes are merged
+    by their maxima and sums; the output leaves the latent space through
+    ``w_uv``."""
+    b, t, h, _ = q_n.shape
+    r = w_uk.shape[0]
+    c = pool.shape[0]
+    hi = jax.lax.Precision.HIGHEST
+    width = pool.shape[-1]          # the rows' width in memory, zero-padded
+    q = jnp.concatenate(
+        [jnp.einsum("bthd,rhd->bthr", q_n, w_uk), q_r,
+         jnp.zeros(q_r.shape[:-1] + (width - r - q_r.shape[-1],),
+                   q_r.dtype)], axis=-1)
+    q = q.reshape(b, t * h, width)
+    onehot = (slots[:, None] == jnp.arange(c)[None]).astype(jnp.float32)
+
+    def partial(query, rows, mask):
+        s = jnp.einsum("bqd,bsd->bqs", query, rows,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask, s, -1e30)
+        m = jnp.max(s, axis=-1)
+        e = jnp.exp(s - m[..., None])
+        # the rows whole (their rope part's output is dropped after): no
+        # slice of the cache is made
+        o = jnp.einsum("bqs,bsd->bqd", e.astype(rows.dtype), rows,
+                       preferred_element_type=jnp.float32)
+        return m, jnp.sum(e, axis=-1), o[..., :r]
+
+    # the pool's part, in slot order
+    q_slot = jnp.einsum("bc,bqd->cqd", onehot, q.astype(jnp.float32))
+    ctx_slot = jnp.einsum("bc,b->c", onehot, ctx.astype(jnp.float32),
+                          precision=hi)
+    seen = jnp.arange(pool.shape[1])[None] < ctx_slot[:, None]      # [c, S]
+    part_a = partial(q_slot.astype(q.dtype), pool, seen[:, None])
+    m_a, l_a, o_a = (jnp.einsum("bc,c...->b...", onehot, x, precision=hi)
+                     for x in part_a)
+    # this round's part, in batch order
+    new = jnp.arange(rbuf.shape[1])[None, None] <= upto[:, :, None]
+    new = jnp.repeat(new, h, axis=1)                            # [B, T*H, R]
+    m_b, l_b, o_b = partial(q, rbuf, new)
+    m = jnp.maximum(m_a, m_b)
+    w_a, w_b = jnp.exp(m_a - m), jnp.exp(m_b - m)
+    o = (o_a * w_a[..., None] + o_b * w_b[..., None]) \
+        / (l_a * w_a + l_b * w_b)[..., None]
+    o = jnp.einsum("bthr,rhd->bthd",
+                   o.astype(q_n.dtype).reshape(b, t, h, r), w_uv)
+    return o.reshape(b, t, -1)
+
+
+def write_rows(rbuf, new, at):
+    """``new`` [B, T, d] into ``rbuf`` [B, R, d] at each row's own
+    ``at[b]``, by selection (a scatter with a window a row is refused by
+    the TPU compiler inside a loop)."""
+    idx = jnp.arange(rbuf.shape[1])[None] - at[:, None]             # [B, R]
+    for t in range(new.shape[1]):
+        rbuf = jnp.where((idx == t)[..., None], new[:, t:t + 1], rbuf)
+    return rbuf
+
+
+class MlaAttention(nn.Module):
+    """Latent attention over a stream's context in the latent pool (read
+    only: ``pool`` [slots, S, r + d_r]) and this round's own rows in the
+    round buffer ``rbuf`` [B, R, r + d_r], written to the pool once, when
+    the round is over (:func:`flush_round`)."""
+    cfg: Xing4Config
+    dtype: Dtype = jnp.bfloat16
+
+    def setup(self):
+        c, d, h = self.cfg, self.cfg.dim, self.cfg.num_heads
+        qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+        self.q_a = _kernel(self, "q_a", (d, c.q_lora_rank), ("embed", "qkv"))
+        self.q_norm = RmsNorm(c.norm_eps, self.dtype, name="q_norm")
+        self.q_b = _kernel(self, "q_b", (c.q_lora_rank, h * qk),
+                           ("embed", "qkv"))
+        self.kv_a = _kernel(self, "kv_a", (d, c.latent_dim),
+                            ("embed", "qkv"))
+        self.kv_norm = RmsNorm(c.norm_eps, self.dtype, name="kv_norm")
+        self.kv_b = _kernel(
+            self, "kv_b",
+            (c.kv_lora_rank, h * (c.qk_nope_head_dim + c.v_head_dim)),
+            ("embed", "qkv"))
+        self.o = _kernel(self, "o", (h * c.v_head_dim, d), ("qkv", "embed"))
+
+    def latent(self, h, pos):
+        """[B, T, C] -> the positions' cache rows [B, T, r + d_r]."""
+        c, r = self.cfg, self.cfg.kv_lora_rank
+        ckv = h @ self.kv_a.astype(self.dtype)
+        return jnp.concatenate(
+            [self.kv_norm(ckv[..., :r]),
+             rope(ckv[..., r:], pos, c).astype(self.dtype),
+             jnp.zeros(h.shape[:-1] + (c.row_dim - c.latent_dim,),
+                       self.dtype)], axis=-1)
+
+    def __call__(self, h, pool, rbuf, slots, ctx, at, cap):
+        """``at`` None: a prefill, whose T positions start the round's
+        buffer; else [B], where each row's T decode positions go."""
+        c = self.cfg
+        b, t, _ = h.shape
+        dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+        pos = ctx[:, None] + jnp.arange(t, dtype=ctx.dtype)[None]
+        if at is not None:
+            pos = pos + at[:, None]
+        new = self.latent(h, pos).astype(rbuf.dtype)
+        q = self.q_norm(h @ self.q_a.astype(self.dtype)) \
+            @ self.q_b.astype(self.dtype)
+        q = q.reshape(b, t, c.num_heads, dn + dr)
+        q_n, q_r = q[..., :dn], rope(q[..., dn:], pos, c).astype(self.dtype)
+        q = jnp.concatenate([q_n, q_r], axis=-1)
+        w = self.kv_b.astype(self.dtype).reshape(
+            c.kv_lora_rank, c.num_heads, dn + dv)
+        w_uk, w_uv = w[..., :dn], w[..., dn:]
+        if at is None:
+            with jax.named_scope("mla_prefill"):
+                rbuf = jax.lax.dynamic_update_slice_in_dim(
+                    rbuf, new, 0, axis=1)
+                o = mla_prefill_attention(
+                    q, new, w_uk, w_uv, pool, slots, ctx, softmax_scale(c),
+                    min(cap, pool.shape[1]))
+        else:
+            with jax.named_scope("mla_decode"):
+                rbuf = write_rows(rbuf, new, at)
+                upto = at[:, None] + jnp.arange(t, dtype=at.dtype)[None]
+                o = mla_decode_attention(
+                    q_n, q_r, w_uk, w_uv, pool, rbuf, slots, ctx, upto,
+                    softmax_scale(c))
+        return o @ self.o.astype(self.dtype), rbuf
+
+
+def flush_round(pool, rbuf, slots, pos0, keep, main_blocks):
+    """The round's first ``keep`` rows (``rbuf`` [blocks, B, R, d]) into
+    each row's slot of the pool, in place: the main blocks' at ``pos0``,
+    the prediction module's a position before (its cache trails by one). A
+    loop over the rows, guarded slice updates (``models/lfm2.py``
+    ``flush_round``). A row whose slot is past the pool (a padded batch
+    row) writes nothing."""
+    blocks, _, _, d = rbuf.shape
+    last = pool.shape[1] - 1
+
+    def row(i, pool):
+        for lo, hi, back in ((0, main_blocks, 0), (main_blocks, blocks, 1)):
+            new = jax.lax.dynamic_slice(
+                rbuf, (lo, i, 0, 0), (hi - lo, 1, keep, d)).astype(pool.dtype)
+            at = (lo, jnp.minimum(slots[i], last), pos0[i] - back, 0)
+            old = jax.lax.dynamic_slice(pool, at, new.shape)
+            pool = jax.lax.dynamic_update_slice(
+                pool, jnp.where(slots[i] <= last, new, old), at)
+        return pool
+
+    return jax.lax.fori_loop(0, rbuf.shape[1], row, pool)
+
+
+# -- the residual ------------------------------------------------------------
+
+class HyperResidual(nn.Module):
+    """One sublayer's three maps from the residual streams themselves: X
+    [n, ..., C] (the streams lead: each is a plain [..., C] array, where a
+    [..., n, C] layout would put 4 rows on an 8-row tile) -> (H_pre [n, N],
+    H_post [n, N], H_res [n, n, N]), N the positions, minor-most (a [N, 4,
+    4] layout would pad every 4 x 4 to a tile). float32 throughout, as the
+    router is."""
+    cfg: Xing4Config
+
+    @nn.compact
+    def __call__(self, x):
+        c, n = self.cfg, self.cfg.hc_mult
+        width = n * c.dim
+        scale = self.param("norm_scale", nn.initializers.ones_init(),
+                           (width,), jnp.float32)
+        phi = self.param("phi", nn.initializers.xavier_uniform(),
+                         (width, 2 * n + n * n), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (2 * n + n * n,), jnp.float32)
+        alpha = self.param("alpha", nn.initializers.ones_init(), (3,),
+                           jnp.float32)
+        with jax.named_scope("mhc_maps"):
+            # x̂ φ = (x (g ⊙ φ)) / rms(x), x the n streams side by side: the
+            # streams go into the product as they are, one after the other,
+            # and neither a joined nor a normed copy of them is made
+            x = x.reshape(n, -1, c.dim)
+            w = (scale[:, None] * phi).reshape(n, c.dim, -1)
+            z = sum(jnp.dot(x[k], w[k], precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+                    for k in range(n))
+            ms = sum(jnp.mean(jnp.square(x[k].astype(jnp.float32)), axis=-1)
+                     for k in range(n)) / n
+            z = (z * jax.lax.rsqrt(ms + c.norm_eps)[:, None]).T
+            pre = jax.nn.sigmoid(alpha[0] * z[:n] + bias[:n, None])
+            post = 2.0 * jax.nn.sigmoid(
+                alpha[1] * z[n:2 * n] + bias[n:2 * n, None])
+            res = (alpha[2] * z[2 * n:] + bias[2 * n:, None]).reshape(
+                n, n, -1)
+            return pre, post, sinkhorn(res, c)
+
+
+def sinkhorn(logits, cfg: Xing4Config):
+    """[n, n, N] logits -> doubly stochastic [n, n, N]: exp of the clamped
+    logits, then ``hc_sinkhorn_iters`` times each row over (its sum +
+    ``hc_eps``), then each column over (its sum + ``hc_eps``)."""
+    m = jnp.exp(jnp.clip(logits, cfg.hc_clamp_min, cfg.hc_clamp_max))
+    for _ in range(cfg.hc_sinkhorn_iters):
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + cfg.hc_eps)
+        m = m / (jnp.sum(m, axis=0, keepdims=True) + cfg.hc_eps)
+    return m
+
+
+def hc_read(pre, x):
+    """h = H_pre X: [n, ..., C] -> [..., C]."""
+    n, c = x.shape[0], x.shape[-1]
+    xf = x.reshape(n, -1, c)
+    h = sum(pre[k][:, None] * xf[k].astype(jnp.float32) for k in range(n))
+    return h.reshape(x.shape[1:]).astype(x.dtype)
+
+
+def hc_write(res, post, x, y):
+    """X' = H_res X + H_postᵀ y."""
+    n, c = x.shape[0], x.shape[-1]
+    xf = x.reshape(n, -1, c).astype(jnp.float32)
+    yf = y.reshape(-1, c).astype(jnp.float32)
+    out = [sum(res[i, j][:, None] * xf[j] for j in range(n))
+           + post[i][:, None] * yf for i in range(n)]
+    return jnp.stack(out).reshape(x.shape).astype(x.dtype)
+
+
+class Xing4Block(nn.Module):
+    """Two sublayers, each between its own residual maps: latent attention,
+    then the dense or the routed feed-forward."""
+    cfg: Xing4Config
+    dense: bool = False
+    dtype: Dtype = jnp.bfloat16
+
+    def setup(self):
+        c = self.cfg
+        self.attn_hc = HyperResidual(c, name="attn_hc")
+        self.attn_norm = RmsNorm(c.norm_eps, self.dtype, name="attn_norm")
+        self.attn = MlaAttention(c, self.dtype, name="attn")
+        self.ffn_hc = HyperResidual(c, name="ffn_hc")
+        self.ffn_norm = RmsNorm(c.norm_eps, self.dtype, name="ffn_norm")
+        if self.dense:
+            self.mlp = SwiGlu(c.dim, c.mlp_dim, self.dtype, name="mlp")
+        else:
+            self.moe = TopKMoeMlp(c.moe, self.dtype, name="moe")
+
+    def latent(self, x, pos):
+        """The cache rows of X's positions, and nothing else of the block
+        (what a position leaves behind for later ones)."""
+        pre, _, _ = self.attn_hc(x)
+        return self.attn.latent(self.attn_norm(hc_read(pre, x)), pos)
+
+    def __call__(self, x, pool, rbuf, slots, ctx, at, cap):
+        b, t = x.shape[1:3]
+        pre, post, res = self.attn_hc(x)
+        y, rbuf = self.attn(self.attn_norm(hc_read(pre, x)), pool, rbuf,
+                            slots, ctx, at, cap)
+        x = hc_write(res, post, x, y)
+        pre, post, res = self.ffn_hc(x)
+        h = self.ffn_norm(hc_read(pre, x))
+        if self.dense:
+            y, n = self.mlp(h), 0
+        else:
+            with jax.named_scope("head_moe"):
+                y, n = self.moe(h.reshape(b * t, -1))
+        return hc_write(res, post, x, y), rbuf, n
+
+
+class Xing4Stack(nn.Module):
+    """The decoder's blocks and its prediction module over [B, T, C]
+    embeddings that continue each stream's state."""
+    cfg: Xing4Config
+    dtype: Dtype = jnp.bfloat16
+
+    def setup(self):
+        c = self.cfg
+        table = lambda name: self.param(  # noqa: E731
+            name, nn.with_logical_partitioning(
+                nn.initializers.normal(0.02), ("vocab", "embed")),
+            (c.vocab_size, c.dim), jnp.float32)
+        self.embed_table, self.lm_head = table("embed"), table("lm_head")
+        self.layers = [Xing4Block(c, i < c.num_dense_layers, self.dtype,
+                                  name=f"layer{i}")
+                       for i in range(c.num_layers)]
+        self.final_norm = RmsNorm(c.norm_eps, self.dtype, name="final_norm")
+        self.mtp_h_norm = RmsNorm(c.norm_eps, self.dtype, name="mtp_h_norm")
+        self.mtp_e_norm = RmsNorm(c.norm_eps, self.dtype, name="mtp_e_norm")
+        self.mtp_eh_proj = _kernel(self, "mtp_eh_proj", (2 * c.dim, c.dim),
+                                   ("mlp", "embed"))
+        self.mtp_block = Xing4Block(c, False, self.dtype, name="mtp_block")
+        self.mtp_final_norm = RmsNorm(c.norm_eps, self.dtype,
+                                      name="mtp_final_norm")
+
+    def embed(self, ids):
+        return jnp.take(self.embed_table, ids, axis=0).astype(self.dtype)
+
+    def logits(self, h, mtp: bool = False):
+        """float32 logits over the (untied) head from [..., C] exit
+        states, through the main model's final norm or the module's."""
+        with jax.named_scope("head_lm"):
+            h = (self.mtp_final_norm if mtp else self.final_norm)(h)
+            return jnp.einsum(
+                "...d,vd->...v", h, self.lm_head.astype(self.dtype),
+                preferred_element_type=jnp.float32)
+
+    def _enter(self, x):
+        return jnp.broadcast_to(x.astype(self.dtype)[None],
+                                (self.cfg.hc_mult,) + x.shape)
+
+    def _exit(self, x):
+        return jnp.sum(x.astype(jnp.float32), axis=0).astype(self.dtype)
+
+    def __call__(self, x, pool, rbuf, slots, ctx, at=None, cap=0):
+        """x [B, T, C]: T positions of streams whose context holds ``ctx``
+        [B] positions (the round's start); pool [blocks, slots, S, d],
+        read only, of which row b owns slot ``slots[b]``; rbuf [blocks, B,
+        R, d] the round's own. Returns (exit states [B, T, C], rbuf,
+        load) with ``load`` [held] the routed pairs each held expert took,
+        summed over the blocks."""
+        load = jnp.zeros((len(self.cfg.moe.held),), jnp.int32)
+        x = self._enter(x)
+        for i, block in enumerate(self.layers):
+            x, rows, n = block(x, pool[i], rbuf[i], slots, ctx, at, cap)
+            rbuf, load = rbuf.at[i].set(rows), load + n
+        return self._exit(x), rbuf, load
+
+    def _mtp_input(self, h, e):
+        u = jnp.concatenate([self.mtp_h_norm(h), self.mtp_e_norm(e)], -1)
+        return self._enter(u @ self.mtp_eh_proj.astype(self.dtype))
+
+    def mtp_rows(self, h, e, rbuf, ctx):
+        """The module's cache rows for T prefilled positions (exit states
+        ``h`` [B, T, C] paired with the NEXT positions' inputs ``e``), into
+        the start of its round buffer: nothing else of the module is needed
+        of a position nobody drafts from."""
+        i = self.cfg.num_layers
+        pos = ctx[:, None] + jnp.arange(h.shape[1], dtype=ctx.dtype)[None]
+        rows = self.mtp_block.latent(self._mtp_input(h, e), pos)
+        return rbuf.at[i].set(jax.lax.dynamic_update_slice_in_dim(
+            rbuf[i], rows.astype(rbuf.dtype), 0, axis=1))
+
+    def mtp(self, h, e, pool, rbuf, slots, ctx, at):
+        """The module on T decode positions: (its exit states [B, T, C],
+        rbuf, load)."""
+        i = self.cfg.num_layers
+        x, rows, n = self.mtp_block(self._mtp_input(h, e), pool[i], rbuf[i],
+                                    slots, ctx, at, 0)
+        return self._exit(x), rbuf.at[i].set(rows), n
+
+
+class VideoMAEXing4(nn.Module):
+    """VideoMAE encoder (no classifier) -> connector -> Xing4 head."""
+    cfg: StreamHeadConfig
+    dtype: Dtype = jnp.bfloat16
+
+    def setup(self):
+        c = self.cfg
+        self.video = VideoMAE(c.video, self.dtype, name="video")
+        self.connector = Connector(c.head.dim, self.dtype, name="connector")
+        self.head = Xing4Stack(c.head, self.dtype, name="head")
+
+    def encode(self, clips):
+        """[B, T, H, W, 3] preprocessed clips -> [B, tokens, head dim]."""
+        return self.connector(self.video.features(clips))
+
+    def forward(self, x, pool, rbuf, slots, ctx, at=None, cap=0):
+        return self.head(x, pool, rbuf, slots, ctx, at, cap)
+
+    def mtp_rows(self, h, e, rbuf, ctx):
+        return self.head.mtp_rows(h, e, rbuf, ctx)
+
+    def mtp(self, h, e, pool, rbuf, slots, ctx, at):
+        return self.head.mtp(h, e, pool, rbuf, slots, ctx, at)
+
+    def embed(self, ids):
+        return self.head.embed(ids)
+
+    def logits(self, h, mtp: bool = False):
+        return self.head.logits(h, mtp)
+
+    def __call__(self, clips):
+        """A fresh stream's first round without a pool: the logits that
+        predict its first token and the module's first draft, [B, 2,
+        vocab] (what ``init`` traces)."""
+        c = self.cfg
+        x = self.encode(clips)
+        b = x.shape[0]
+        ids = jnp.asarray(c.instruction_ids, jnp.int32)
+        x = jnp.concatenate(
+            [jnp.broadcast_to(self.embed(ids)[None], (b, len(ids), c.head.dim)),
+             x], axis=1)
+        t = x.shape[1]
+        zero = jnp.zeros((b,), jnp.int32)
+        pool = empty_latent(c.head, b, 1, self.dtype)   # nothing cached
+        rbuf = empty_latent(c.head, b, t + 1, self.dtype)
+        h, rbuf, _ = self.head(x, pool, rbuf, jnp.arange(b), zero)
+        first = self.logits(h[:, -1])
+        rbuf = self.head.mtp_rows(h[:, :-1], x[:, 1:], rbuf, zero)
+        tok = jnp.argmax(first, axis=-1)
+        hm, _, _ = self.head.mtp(h[:, -1:], self.embed(tok)[:, None], pool,
+                                 rbuf, jnp.arange(b), zero, zero + t - 1)
+        return jnp.stack([first, self.logits(hm[:, 0], True)], axis=1)
+
+    # what the engine's ``stream`` step kind and the pool ask of a model
+    @nn.nowrap
+    def empty_state(self, slots: int):
+        """Zeroed state for ``slots`` streams in the model's dtype, by
+        kind, and the axis of each kind's buffers that counts the slots."""
+        c = self.cfg.head
+        return ({"latent": empty_latent(c, slots, c.max_context, self.dtype),
+                 "exit": jnp.zeros((slots, c.dim), self.dtype)},
+                {"latent": 1, "exit": 0})
+
+    @nn.nowrap
+    def serve_round(self, variables, clips, state, slots, pos0, reset,
+                    preprocess=lambda clips: clips):
+        return stream_head.serve_round(self, variables, clips, state, slots,
+                                       pos0, reset, preprocess)
+
+    @nn.nowrap
+    def instruction_state(self, variables):
+        """The standing instruction through a fresh state: the cache rows
+        every context starts from ({"latent": [blocks, 1, instruction
+        length, d], of which the module's block holds one row fewer, "exit":
+        [1, C] the exit state of its last position}). A function of the
+        weights alone."""
+        c = self.cfg
+        apply = lambda method, *a: self.apply(variables, *a, method=method)  # noqa: E731
+        ids = jnp.asarray(c.instruction_ids, jnp.int32)
+        zero = jnp.zeros((1,), jnp.int32)
+        x = apply(VideoMAEXing4.embed, ids)[None]
+        h, rbuf, _ = apply(
+            VideoMAEXing4.forward, x, empty_latent(c.head, 1, 0, self.dtype),
+            empty_latent(c.head, 1, len(ids), self.dtype), zero, zero)
+        rbuf = apply(VideoMAEXing4.mtp_rows, h[:, :-1], x[:, 1:], rbuf, zero)
+        return {"latent": rbuf, "exit": h[:, -1]}
+
+    # what ``stream_head.serve_round`` asks of a head
+    @nn.nowrap
+    def seed_round(self, variables, state, slots, reset):
+        """The latent pool (read and written in place, by slot) with the
+        instruction's rows as the first of the slots that reset (the
+        module's block holds one fewer), and the rows' exit state
+        (gathered by slot), the instruction's for a stream that resets."""
+        ins = variables["instruction"]
+        n_i, main = len(self.cfg.instruction_ids), self.cfg.head.num_layers
+        pool, rows = state["latent"], ins["latent"].astype(
+            state["latent"].dtype)
+
+        last = pool.shape[1] - 1
+
+        def row(i, pool):
+            # a guarded slice update a row, as the flush makes them (one
+            # update of all slots at once has the TPU compiler copy the
+            # whole pool into another layout and back): the rows that
+            # reset, whose slots hold another context's rows
+            at = (0, jnp.minimum(slots[i], last), 0, 0)
+            old = jax.lax.dynamic_slice(pool, at, rows.shape)
+            keep = (jnp.arange(n_i) < n_i - 1)[None, None, :, None] | (
+                jnp.arange(rows.shape[0]) < main)[:, None, None, None]
+            new = jnp.where(reset[i] & (slots[i] <= last) & keep, rows, old)
+            return jax.lax.dynamic_update_slice(pool, new, at)
+
+        pool = jax.lax.fori_loop(0, slots.shape[0], row, pool)
+        exit_ = jnp.take(state["exit"], slots, axis=0, mode="clip")
+        return pool, jnp.where(reset[:, None],
+                               ins["exit"].astype(exit_.dtype), exit_)
+
+    @nn.nowrap
+    def round_buffer(self, rows: int, dtype):
+        # three rows past the round's: a rejected draft's row, and where
+        # a row that has its tokens writes while the others finish
+        return empty_latent(self.cfg.head, rows,
+                            self.cfg.round_positions + 3, dtype)
+
+    @property
+    def _cap(self) -> int:
+        """Positions that can be context when a round starts (the pool
+        resets a stream whose round would pass ``max_context``), up to a
+        lane tile."""
+        c = self.cfg
+        return -(-(c.head.max_context - c.round_positions) // 128) * 128
+
+    @nn.nowrap
+    def prefill(self, variables, x, pool, exit_, rbuf, slots, pos0):
+        apply = lambda method, *a: self.apply(variables, *a, method=method)  # noqa: E731
+        h, rbuf, load = apply(VideoMAEXing4.forward, x, pool, rbuf, slots,
+                              pos0, None, self._cap)
+        # the module's row j pairs the exit state at j with the input at
+        # j + 1: the stream's last position before this round comes first
+        before = jnp.concatenate(
+            [exit_[:, None].astype(h.dtype), h[:, :-1]], axis=1)
+        rbuf = apply(VideoMAEXing4.mtp_rows, before, x, rbuf, pos0 - 1)
+        return h[:, -1], h[:, -1], rbuf, load
+
+    @nn.nowrap
+    def decode(self, variables, pool, h, exit_, rbuf, slots, pos0, load):
+        """Greedy decoding with the prediction module as a depth-1
+        self-drafter. An iteration runs the main model on two positions a
+        stream, the next token and the module's draft of the one after:
+        the first position's logits give the token that follows; where it
+        IS the draft the second's give one more, and two positions are
+        committed, else one (the draft's cache row is dead: the next
+        iteration overwrites it, in the main cache and in the module's).
+        The loop ends when every row has committed its D tokens; a row
+        that has is masked. Tokens, distributions and state are what one
+        position an iteration would give."""
+        c = self.cfg
+        d, n_v = c.decode_steps, c.visual_tokens
+        b = h.shape[0]
+        apply = lambda method, *a: self.apply(variables, *a, method=method)  # noqa: E731
+        seen = pos0 - 1                 # the module's cache trails by one
+
+        def draft(h, toks, rbuf, at):
+            """The module over exit states ``h`` [B, T, C] and the tokens
+            that follow them: its logits [B, T, vocab]."""
+            with jax.named_scope("mtp_draft"):
+                hm, rbuf, m = apply(
+                    VideoMAEXing4.mtp, h, apply(VideoMAEXing4.embed, toks),
+                    pool, rbuf, slots, seen, at)
+                return apply(VideoMAEXing4.logits, hm, True), rbuf, m
+
+        def place(buf, k, mask, value):
+            """``value`` [B, ...] into ``buf`` [B, D, ...] at each row's
+            ``k[b]`` where ``mask[b]`` (a k past D writes nothing)."""
+            hit = mask[:, None] & (jnp.arange(d)[None] == k[:, None])
+            hit = hit.reshape(hit.shape + (1,) * (buf.ndim - 2))
+            return jnp.where(hit, value[:, None], buf)
+
+        with jax.named_scope("head_decode"):
+            tok, top_i, top_p = top_tokens(apply(VideoMAEXing4.logits, h))
+            lg, rbuf, m = draft(h[:, None], tok[:, None], rbuf,
+                                jnp.full((b,), n_v, pos0.dtype))
+            first = top_tokens(lg[:, 0])
+        everyone = jnp.ones((b,), bool)
+        zero = jnp.zeros((b,), jnp.int32)
+        carry = {
+            "done": zero,               # tokens committed to the cache
+            "tok": tok, "draft": first[0], "h": h, "rbuf": rbuf,
+            "load": load + m, "iters": jnp.zeros((), jnp.int32),
+            "drafted": zero, "accepted": zero,
+            "tokens": place(jnp.zeros((b, d), jnp.int32), zero, everyone,
+                            tok),
+            "top_ids": place(jnp.zeros((b, d) + top_i.shape[1:], jnp.int32),
+                             zero, everyone, top_i),
+            "top_probs": place(jnp.zeros((b, d) + top_p.shape[1:],
+                                         top_p.dtype), zero, everyone, top_p),
+        }
+
+        def iteration(s):
+            with jax.named_scope("head_decode"):
+                done = s["done"]
+                live = done < d
+                at = (n_v + done).astype(pos0.dtype)
+                x = apply(VideoMAEXing4.embed,
+                          jnp.stack([s["tok"], s["draft"]], axis=1))
+                h2, rbuf, m = apply(VideoMAEXing4.forward, x, pool,
+                                    s["rbuf"], slots, pos0, at)
+                lg = apply(VideoMAEXing4.logits, h2)            # [B, 2, V]
+                t1, i1, p1 = top_tokens(lg[:, 0])
+                t2, i2, p2 = top_tokens(lg[:, 1])
+                accept = live & (t1 == s["draft"])
+                two = accept & (done + 2 <= d)
+                out = {k: place(place(s[k], done + 1, live, a), done + 2,
+                                two, b_)
+                       for k, a, b_ in (("tokens", t1, t2),
+                                        ("top_ids", i1, i2),
+                                        ("top_probs", p1, p2))}
+                lgm, rbuf, mm = draft(h2, jnp.stack([t1, t2], axis=1), rbuf,
+                                      at + 1)
+                guess = jnp.argmax(lgm, axis=-1).astype(jnp.int32)
+                keep = lambda new, old: jnp.where(  # noqa: E731
+                    live.reshape((b,) + (1,) * (new.ndim - 1)), new, old)
+                pick = lambda a: jnp.where(  # noqa: E731
+                    two.reshape((b,) + (1,) * (a.ndim - 2)), a[:, 1], a[:, 0])
+                return {
+                    **out, "rbuf": rbuf, "load": s["load"] + m + mm,
+                    "iters": s["iters"] + 1,
+                    "done": jnp.where(live, done + 1 + two, done),
+                    "tok": keep(jnp.where(two, t2, t1), s["tok"]),
+                    "draft": keep(pick(guess), s["draft"]),
+                    "h": keep(pick(h2), s["h"]),
+                    "drafted": s["drafted"] + live,
+                    "accepted": s["accepted"] + accept}
+
+        s = jax.lax.while_loop(lambda s: jnp.any(s["done"] < d), iteration,
+                               carry)
+        return {"tokens": s["tokens"], "top_ids": s["top_ids"],
+                "top_probs": s["top_probs"], "rows": s["h"],
+                "rbuf": s["rbuf"], "moe_load": s["load"],
+                "mtp_drafted": s["drafted"], "mtp_accepted": s["accepted"],
+                "decode_iters": s["iters"],
+                "draft_ids": first[1], "draft_probs": first[2]}
+
+    @nn.nowrap
+    def commit_round(self, state, pool, exit_, rbuf, slots, pos0):
+        return {"latent": flush_round(pool, rbuf, slots, pos0,
+                                      self.cfg.round_positions,
+                                      self.cfg.head.num_layers),
+                "exit": state["exit"].at[slots].set(
+                    exit_.astype(state["exit"].dtype), mode="drop")}
+
+
+def empty_latent(cfg: Xing4Config, rows: int, positions: int,
+                 dtype=jnp.bfloat16):
+    """Zeroed latent rows [blocks, rows, positions, r + d_r]: the pool
+    (rows = slots), a round buffer (rows = the batch's)."""
+    return jnp.zeros((cfg.blocks, rows, positions, cfg.row_dim), dtype)

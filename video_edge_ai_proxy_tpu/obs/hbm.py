@@ -27,8 +27,9 @@ Three tiers, one object (``HbmTracker``, engine-owned like
   sum of every workspace.
 - **Dynamic pool accounting.** A ``register_pool(name, nbytes_fn)``
   protocol: each device-resident pool (thumb pools, track-state clip
-  rings, stream-head state: ``stream_state``, the conv states and
-  key-value caches of engine/stream_state.py, prefetch slots, collector
+  rings, stream-head state: ``stream_state``, whatever kinds a head
+  declares (LFM2's conv states and key-value caches, Xing4's latent cache)
+  in engine/stream_state.py, prefetch slots, collector
   host batch buffers) registers a
   zero-argument callable returning its CURRENT bytes — an int, or a
   ``{shard: int}`` mapping for per-chip pools under ``engine.mesh``.

@@ -13,7 +13,7 @@ _sym_db = _symbol_database.Default()
 
 
 
-DESCRIPTOR = _descriptor_pool.Default().AddSerializedFile(b'\n\x15video_streaming.proto\x12\"chrys.cloud.videostreaming.v1beta1\"\xd3\x06\n\x0f\x41nnotateRequest\x12\x13\n\x0b\x64\x65vice_name\x18\x01 \x01(\t\x12\x18\n\x10remote_stream_id\x18\x02 \x01(\t\x12\x0c\n\x04type\x18\x03 \x01(\t\x12\x17\n\x0fstart_timestamp\x18\x04 \x01(\x03\x12\x15\n\rend_timestamp\x18\x05 \x01(\x03\x12\x13\n\x0bobject_type\x18\x06 \x01(\t\x12\x11\n\tobject_id\x18\x07 \x01(\t\x12\x1a\n\x12object_tracking_id\x18\x08 \x01(\t\x12\x12\n\nconfidence\x18\t \x01(\x01\x12K\n\x12object_bouding_box\x18\n \x01(\x0b\x32/.chrys.cloud.videostreaming.v1beta1.BoundingBox\x12>\n\x08location\x18\x0b \x01(\x0b\x32,.chrys.cloud.videostreaming.v1beta1.Location\x12I\n\x11object_coordinate\x18\x0c \x01(\x0b\x32..chrys.cloud.videostreaming.v1beta1.Coordinate\x12<\n\x04mask\x18\r \x03(\x0b\x32..chrys.cloud.videostreaming.v1beta1.Coordinate\x12\x18\n\x10object_signature\x18\x0e \x03(\x01\x12\x10\n\x08ml_model\x18\x0f \x01(\t\x12\x18\n\x10ml_model_version\x18\x10 \x01(\t\x12\r\n\x05width\x18\x11 \x01(\x05\x12\x0e\n\x06height\x18\x12 \x01(\x05\x12\x13\n\x0bis_keyframe\x18\x13 \x01(\x08\x12\x12\n\nvideo_type\x18\x14 \x01(\t\x12\x18\n\x10offset_timestamp\x18\x15 \x01(\x03\x12\x17\n\x0foffset_duration\x18\x16 \x01(\x03\x12\x17\n\x0foffset_frame_id\x18\x17 \x01(\x03\x12\x18\n\x10offset_packet_id\x18\x18 \x01(\x03\x12\x15\n\rcustom_meta_1\x18\x19 \x01(\t\x12\x15\n\rcustom_meta_2\x18\x1a \x01(\t\x12\x15\n\rcustom_meta_3\x18\x1b \x01(\t\x12\x15\n\rcustom_meta_4\x18\x1c \x01(\t\x12\x15\n\rcustom_meta_5\x18\x1d \x01(\t\"h\n\x10\x41nnotateResponse\x12\x13\n\x0b\x64\x65vice_name\x18\x01 \x01(\t\x12\x18\n\x10remote_stream_id\x18\x02 \x01(\t\x12\x0c\n\x04type\x18\x03 \x01(\t\x12\x17\n\x0fstart_timestamp\x18\x04 \x01(\x03\"$\n\x08Location\x12\x0b\n\x03lat\x18\x01 \x01(\x01\x12\x0b\n\x03lon\x18\x02 \x01(\x01\"-\n\nCoordinate\x12\t\n\x01x\x18\x01 \x01(\x01\x12\t\n\x01y\x18\x02 \x01(\x01\x12\t\n\x01z\x18\x03 \x01(\x01\"G\n\x0b\x42oundingBox\x12\x0b\n\x03top\x18\x01 \x01(\x05\x12\x0c\n\x04left\x18\x02 \x01(\x05\x12\r\n\x05width\x18\x03 \x01(\x05\x12\x0e\n\x06height\x18\x04 \x01(\x05\"p\n\nShapeProto\x12?\n\x03\x64im\x18\x02 \x03(\x0b\x32\x32.chrys.cloud.videostreaming.v1beta1.ShapeProto.Dim\x1a!\n\x03\x44im\x12\x0c\n\x04size\x18\x01 \x01(\x03\x12\x0c\n\x04name\x18\x02 \x01(\t\"\xd1\x02\n\nVideoFrame\x12\r\n\x05width\x18\x01 \x01(\x03\x12\x0e\n\x06height\x18\x02 \x01(\x03\x12\x0c\n\x04\x64\x61ta\x18\x03 \x01(\x0c\x12\x11\n\ttimestamp\x18\x04 \x01(\x03\x12\x13\n\x0bis_keyframe\x18\x05 \x01(\x08\x12\x0b\n\x03pts\x18\x06 \x01(\x03\x12\x0b\n\x03\x64ts\x18\x07 \x01(\x03\x12\x12\n\nframe_type\x18\x08 \x01(\t\x12\x12\n\nis_corrupt\x18\t \x01(\x08\x12\x11\n\ttime_base\x18\n \x01(\x01\x12=\n\x05shape\x18\x0b \x01(\x0b\x32..chrys.cloud.videostreaming.v1beta1.ShapeProto\x12\x11\n\tdevice_id\x18\x0c \x01(\t\x12\x0e\n\x06packet\x18\r \x01(\x03\x12\x10\n\x08keyframe\x18\x0e \x01(\x03\x12\x10\n\x08trace_id\x18\x0f \x01(\x03\x12\x13\n\x0bparent_span\x18\x10 \x01(\x03\">\n\x11VideoFrameRequest\x12\x16\n\x0ekey_frame_only\x18\x01 \x01(\x08\x12\x11\n\tdevice_id\x18\x02 \x01(\t\"\xee\x01\n\nListStream\x12\x0c\n\x04name\x18\x01 \x01(\t\x12\x0e\n\x06status\x18\x02 \x01(\t\x12\x16\n\x0e\x66\x61iling_streak\x18\x03 \x01(\x03\x12\x15\n\rhealth_status\x18\x04 \x01(\t\x12\x0c\n\x04\x64\x65\x61\x64\x18\x05 \x01(\x08\x12\x11\n\texit_code\x18\x06 \x01(\x03\x12\x0b\n\x03pid\x18\x07 \x01(\x05\x12\x0f\n\x07running\x18\x08 \x01(\x08\x12\x0e\n\x06paused\x18\t \x01(\x08\x12\x12\n\nrestarting\x18\n \x01(\x08\x12\x11\n\toomkilled\x18\x0b \x01(\x08\x12\r\n\x05\x65rror\x18\x0c \x01(\t\x12\x0e\n\x06source\x18\r \x01(\t\"\x13\n\x11ListStreamRequest\"6\n\x0cProxyRequest\x12\x11\n\tdevice_id\x18\x01 \x01(\t\x12\x13\n\x0bpassthrough\x18\x02 \x01(\x08\"7\n\rProxyResponse\x12\x11\n\tdevice_id\x18\x01 \x01(\t\x12\x13\n\x0bpassthrough\x18\x02 \x01(\x08\"2\n\x0eStorageRequest\x12\x11\n\tdevice_id\x18\x01 \x01(\t\x12\r\n\x05start\x18\x02 \x01(\x08\"3\n\x0fStorageResponse\x12\x11\n\tdevice_id\x18\x01 \x01(\t\x12\r\n\x05start\x18\x02 \x01(\x08\"\xa8\x01\n\tDetection\x12<\n\x03\x62ox\x18\x01 \x01(\x0b\x32/.chrys.cloud.videostreaming.v1beta1.BoundingBox\x12\x12\n\nconfidence\x18\x02 \x01(\x01\x12\x10\n\x08\x63lass_id\x18\x03 \x01(\x05\x12\x12\n\nclass_name\x18\x04 \x01(\t\x12\x11\n\tembedding\x18\x05 \x03(\x02\x12\x10\n\x08track_id\x18\x06 \x01(\t\"\xc3\x02\n\x0fInferenceResult\x12\x11\n\tdevice_id\x18\x01 \x01(\t\x12\x11\n\ttimestamp\x18\x02 \x01(\x03\x12\r\n\x05model\x18\x03 \x01(\t\x12\x15\n\rmodel_version\x18\x04 \x01(\t\x12\x41\n\ndetections\x18\x05 \x03(\x0b\x32-.chrys.cloud.videostreaming.v1beta1.Detection\x12\x12\n\nlatency_ms\x18\x06 \x01(\x01\x12\x12\n\nbatch_size\x18\x07 \x01(\x05\x12\x14\n\x0c\x66rame_packet\x18\x08 \x01(\x03\x12\x10\n\x08trace_id\x18\t \x01(\x03\x12\x13\n\x0bparent_span\x18\n \x01(\x03\x12<\n\x04head\x18\x0b \x01(\x0b\x32..chrys.cloud.videostreaming.v1beta1.StreamHead\"-\n\tTokenStep\x12\x11\n\ttoken_ids\x18\x01 \x03(\x05\x12\r\n\x05probs\x18\x02 \x03(\x02\"\x8c\x01\n\nStreamHead\x12\x11\n\ttoken_ids\x18\x01 \x03(\x05\x12<\n\x05steps\x18\x02 \x03(\x0b\x32-.chrys.cloud.videostreaming.v1beta1.TokenStep\x12\x1a\n\x12rounds_since_reset\x18\x03 \x01(\x05\x12\x11\n\tpositions\x18\x04 \x01(\x05\"5\n\x10InferenceRequest\x12\x12\n\ndevice_ids\x18\x01 \x03(\t\x12\r\n\x05model\x18\x02 \x01(\t2\xdd\x05\n\x05Image\x12\x7f\n\x10VideoLatestImage\x12\x35.chrys.cloud.videostreaming.v1beta1.VideoFrameRequest\x1a..chrys.cloud.videostreaming.v1beta1.VideoFrame\"\x00(\x01\x30\x01\x12x\n\x0bListStreams\x12\x35.chrys.cloud.videostreaming.v1beta1.ListStreamRequest\x1a..chrys.cloud.videostreaming.v1beta1.ListStream\"\x00\x30\x01\x12w\n\x08\x41nnotate\x12\x33.chrys.cloud.videostreaming.v1beta1.AnnotateRequest\x1a\x34.chrys.cloud.videostreaming.v1beta1.AnnotateResponse\"\x00\x12n\n\x05Proxy\x12\x30.chrys.cloud.videostreaming.v1beta1.ProxyRequest\x1a\x31.chrys.cloud.videostreaming.v1beta1.ProxyResponse\"\x00\x12t\n\x07Storage\x12\x32.chrys.cloud.videostreaming.v1beta1.StorageRequest\x1a\x33.chrys.cloud.videostreaming.v1beta1.StorageResponse\"\x00\x12z\n\tInference\x12\x34.chrys.cloud.videostreaming.v1beta1.InferenceRequest\x1a\x33.chrys.cloud.videostreaming.v1beta1.InferenceResult\"\x00\x30\x01\x62\x06proto3')
+DESCRIPTOR = _descriptor_pool.Default().AddSerializedFile(b'\n\x15video_streaming.proto\x12\"chrys.cloud.videostreaming.v1beta1\"\xd3\x06\n\x0f\x41nnotateRequest\x12\x13\n\x0b\x64\x65vice_name\x18\x01 \x01(\t\x12\x18\n\x10remote_stream_id\x18\x02 \x01(\t\x12\x0c\n\x04type\x18\x03 \x01(\t\x12\x17\n\x0fstart_timestamp\x18\x04 \x01(\x03\x12\x15\n\rend_timestamp\x18\x05 \x01(\x03\x12\x13\n\x0bobject_type\x18\x06 \x01(\t\x12\x11\n\tobject_id\x18\x07 \x01(\t\x12\x1a\n\x12object_tracking_id\x18\x08 \x01(\t\x12\x12\n\nconfidence\x18\t \x01(\x01\x12K\n\x12object_bouding_box\x18\n \x01(\x0b\x32/.chrys.cloud.videostreaming.v1beta1.BoundingBox\x12>\n\x08location\x18\x0b \x01(\x0b\x32,.chrys.cloud.videostreaming.v1beta1.Location\x12I\n\x11object_coordinate\x18\x0c \x01(\x0b\x32..chrys.cloud.videostreaming.v1beta1.Coordinate\x12<\n\x04mask\x18\r \x03(\x0b\x32..chrys.cloud.videostreaming.v1beta1.Coordinate\x12\x18\n\x10object_signature\x18\x0e \x03(\x01\x12\x10\n\x08ml_model\x18\x0f \x01(\t\x12\x18\n\x10ml_model_version\x18\x10 \x01(\t\x12\r\n\x05width\x18\x11 \x01(\x05\x12\x0e\n\x06height\x18\x12 \x01(\x05\x12\x13\n\x0bis_keyframe\x18\x13 \x01(\x08\x12\x12\n\nvideo_type\x18\x14 \x01(\t\x12\x18\n\x10offset_timestamp\x18\x15 \x01(\x03\x12\x17\n\x0foffset_duration\x18\x16 \x01(\x03\x12\x17\n\x0foffset_frame_id\x18\x17 \x01(\x03\x12\x18\n\x10offset_packet_id\x18\x18 \x01(\x03\x12\x15\n\rcustom_meta_1\x18\x19 \x01(\t\x12\x15\n\rcustom_meta_2\x18\x1a \x01(\t\x12\x15\n\rcustom_meta_3\x18\x1b \x01(\t\x12\x15\n\rcustom_meta_4\x18\x1c \x01(\t\x12\x15\n\rcustom_meta_5\x18\x1d \x01(\t\"h\n\x10\x41nnotateResponse\x12\x13\n\x0b\x64\x65vice_name\x18\x01 \x01(\t\x12\x18\n\x10remote_stream_id\x18\x02 \x01(\t\x12\x0c\n\x04type\x18\x03 \x01(\t\x12\x17\n\x0fstart_timestamp\x18\x04 \x01(\x03\"$\n\x08Location\x12\x0b\n\x03lat\x18\x01 \x01(\x01\x12\x0b\n\x03lon\x18\x02 \x01(\x01\"-\n\nCoordinate\x12\t\n\x01x\x18\x01 \x01(\x01\x12\t\n\x01y\x18\x02 \x01(\x01\x12\t\n\x01z\x18\x03 \x01(\x01\"G\n\x0b\x42oundingBox\x12\x0b\n\x03top\x18\x01 \x01(\x05\x12\x0c\n\x04left\x18\x02 \x01(\x05\x12\r\n\x05width\x18\x03 \x01(\x05\x12\x0e\n\x06height\x18\x04 \x01(\x05\"p\n\nShapeProto\x12?\n\x03\x64im\x18\x02 \x03(\x0b\x32\x32.chrys.cloud.videostreaming.v1beta1.ShapeProto.Dim\x1a!\n\x03\x44im\x12\x0c\n\x04size\x18\x01 \x01(\x03\x12\x0c\n\x04name\x18\x02 \x01(\t\"\xd1\x02\n\nVideoFrame\x12\r\n\x05width\x18\x01 \x01(\x03\x12\x0e\n\x06height\x18\x02 \x01(\x03\x12\x0c\n\x04\x64\x61ta\x18\x03 \x01(\x0c\x12\x11\n\ttimestamp\x18\x04 \x01(\x03\x12\x13\n\x0bis_keyframe\x18\x05 \x01(\x08\x12\x0b\n\x03pts\x18\x06 \x01(\x03\x12\x0b\n\x03\x64ts\x18\x07 \x01(\x03\x12\x12\n\nframe_type\x18\x08 \x01(\t\x12\x12\n\nis_corrupt\x18\t \x01(\x08\x12\x11\n\ttime_base\x18\n \x01(\x01\x12=\n\x05shape\x18\x0b \x01(\x0b\x32..chrys.cloud.videostreaming.v1beta1.ShapeProto\x12\x11\n\tdevice_id\x18\x0c \x01(\t\x12\x0e\n\x06packet\x18\r \x01(\x03\x12\x10\n\x08keyframe\x18\x0e \x01(\x03\x12\x10\n\x08trace_id\x18\x0f \x01(\x03\x12\x13\n\x0bparent_span\x18\x10 \x01(\x03\">\n\x11VideoFrameRequest\x12\x16\n\x0ekey_frame_only\x18\x01 \x01(\x08\x12\x11\n\tdevice_id\x18\x02 \x01(\t\"\xee\x01\n\nListStream\x12\x0c\n\x04name\x18\x01 \x01(\t\x12\x0e\n\x06status\x18\x02 \x01(\t\x12\x16\n\x0e\x66\x61iling_streak\x18\x03 \x01(\x03\x12\x15\n\rhealth_status\x18\x04 \x01(\t\x12\x0c\n\x04\x64\x65\x61\x64\x18\x05 \x01(\x08\x12\x11\n\texit_code\x18\x06 \x01(\x03\x12\x0b\n\x03pid\x18\x07 \x01(\x05\x12\x0f\n\x07running\x18\x08 \x01(\x08\x12\x0e\n\x06paused\x18\t \x01(\x08\x12\x12\n\nrestarting\x18\n \x01(\x08\x12\x11\n\toomkilled\x18\x0b \x01(\x08\x12\r\n\x05\x65rror\x18\x0c \x01(\t\x12\x0e\n\x06source\x18\r \x01(\t\"\x13\n\x11ListStreamRequest\"6\n\x0cProxyRequest\x12\x11\n\tdevice_id\x18\x01 \x01(\t\x12\x13\n\x0bpassthrough\x18\x02 \x01(\x08\"7\n\rProxyResponse\x12\x11\n\tdevice_id\x18\x01 \x01(\t\x12\x13\n\x0bpassthrough\x18\x02 \x01(\x08\"2\n\x0eStorageRequest\x12\x11\n\tdevice_id\x18\x01 \x01(\t\x12\r\n\x05start\x18\x02 \x01(\x08\"3\n\x0fStorageResponse\x12\x11\n\tdevice_id\x18\x01 \x01(\t\x12\r\n\x05start\x18\x02 \x01(\x08\"\xa8\x01\n\tDetection\x12<\n\x03\x62ox\x18\x01 \x01(\x0b\x32/.chrys.cloud.videostreaming.v1beta1.BoundingBox\x12\x12\n\nconfidence\x18\x02 \x01(\x01\x12\x10\n\x08\x63lass_id\x18\x03 \x01(\x05\x12\x12\n\nclass_name\x18\x04 \x01(\t\x12\x11\n\tembedding\x18\x05 \x03(\x02\x12\x10\n\x08track_id\x18\x06 \x01(\t\"\xc3\x02\n\x0fInferenceResult\x12\x11\n\tdevice_id\x18\x01 \x01(\t\x12\x11\n\ttimestamp\x18\x02 \x01(\x03\x12\r\n\x05model\x18\x03 \x01(\t\x12\x15\n\rmodel_version\x18\x04 \x01(\t\x12\x41\n\ndetections\x18\x05 \x03(\x0b\x32-.chrys.cloud.videostreaming.v1beta1.Detection\x12\x12\n\nlatency_ms\x18\x06 \x01(\x01\x12\x12\n\nbatch_size\x18\x07 \x01(\x05\x12\x14\n\x0c\x66rame_packet\x18\x08 \x01(\x03\x12\x10\n\x08trace_id\x18\t \x01(\x03\x12\x13\n\x0bparent_span\x18\n \x01(\x03\x12<\n\x04head\x18\x0b \x01(\x0b\x32..chrys.cloud.videostreaming.v1beta1.StreamHead\"-\n\tTokenStep\x12\x11\n\ttoken_ids\x18\x01 \x03(\x05\x12\r\n\x05probs\x18\x02 \x03(\x02\"\xe2\x01\n\nStreamHead\x12\x11\n\ttoken_ids\x18\x01 \x03(\x05\x12<\n\x05steps\x18\x02 \x03(\x0b\x32-.chrys.cloud.videostreaming.v1beta1.TokenStep\x12\x1a\n\x12rounds_since_reset\x18\x03 \x01(\x05\x12\x11\n\tpositions\x18\x04 \x01(\x05\x12\x10\n\x08\x61\x63\x63\x65pted\x18\x05 \x01(\x05\x12\x42\n\x0b\x66irst_draft\x18\x06 \x01(\x0b\x32-.chrys.cloud.videostreaming.v1beta1.TokenStep\"5\n\x10InferenceRequest\x12\x12\n\ndevice_ids\x18\x01 \x03(\t\x12\r\n\x05model\x18\x02 \x01(\t2\xdd\x05\n\x05Image\x12\x7f\n\x10VideoLatestImage\x12\x35.chrys.cloud.videostreaming.v1beta1.VideoFrameRequest\x1a..chrys.cloud.videostreaming.v1beta1.VideoFrame\"\x00(\x01\x30\x01\x12x\n\x0bListStreams\x12\x35.chrys.cloud.videostreaming.v1beta1.ListStreamRequest\x1a..chrys.cloud.videostreaming.v1beta1.ListStream\"\x00\x30\x01\x12w\n\x08\x41nnotate\x12\x33.chrys.cloud.videostreaming.v1beta1.AnnotateRequest\x1a\x34.chrys.cloud.videostreaming.v1beta1.AnnotateResponse\"\x00\x12n\n\x05Proxy\x12\x30.chrys.cloud.videostreaming.v1beta1.ProxyRequest\x1a\x31.chrys.cloud.videostreaming.v1beta1.ProxyResponse\"\x00\x12t\n\x07Storage\x12\x32.chrys.cloud.videostreaming.v1beta1.StorageRequest\x1a\x33.chrys.cloud.videostreaming.v1beta1.StorageResponse\"\x00\x12z\n\tInference\x12\x34.chrys.cloud.videostreaming.v1beta1.InferenceRequest\x1a\x33.chrys.cloud.videostreaming.v1beta1.InferenceResult\"\x00\x30\x01\x62\x06proto3')
 
 _builder.BuildMessageAndEnumDescriptors(DESCRIPTOR, globals())
 _builder.BuildTopDescriptorsAndMessages(DESCRIPTOR, 'video_streaming_pb2', globals())
@@ -57,9 +57,9 @@ if _descriptor._USE_C_DESCRIPTORS == False:
   _TOKENSTEP._serialized_start=2674
   _TOKENSTEP._serialized_end=2719
   _STREAMHEAD._serialized_start=2722
-  _STREAMHEAD._serialized_end=2862
-  _INFERENCEREQUEST._serialized_start=2864
-  _INFERENCEREQUEST._serialized_end=2917
-  _IMAGE._serialized_start=2920
-  _IMAGE._serialized_end=3653
+  _STREAMHEAD._serialized_end=2948
+  _INFERENCEREQUEST._serialized_start=2950
+  _INFERENCEREQUEST._serialized_end=3003
+  _IMAGE._serialized_start=3006
+  _IMAGE._serialized_end=3739
 # @@protoc_insertion_point(module_scope)
