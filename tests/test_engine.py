@@ -1,6 +1,7 @@
 """Engine tests: collector bucketing/gating and end-to-end inference on the
 in-memory bus with tiny models (CPU backend)."""
 
+import threading
 import time
 
 import numpy as np
@@ -474,6 +475,312 @@ class TestClipRing:
         assert sorted(g.model for g in groups) == ["image", "video"]
         clip = next(g for g in groups if g.model == "video")
         np.testing.assert_array_equal(clip.frames[0, -1], read["clip"])
+
+
+class TestEarlyPlacement:
+    """A group's placement starts when its last frame is read (ISSUE 35):
+    ``collect(sink=...)`` hands each finished group to the transfer thread
+    while the groups after it are still being read. Direct-drive where the
+    test says which frames a tick reads (collect -> _dispatch -> drain by
+    hand, only the transfer thread running); a running engine where the
+    tick loop's own conditions and error path are the subject."""
+
+    H, W, L = 48, 64, 4         # tiny_videomae's clip length
+
+    @staticmethod
+    def _model_of(device_id):
+        return "tiny_videomae" if device_id.startswith("clip") else "tiny_vit"
+
+    def _eng(self, bus, cams, **cfg_kw):
+        """Tag cameras (tiny_vit) and clip cameras (tiny_videomae, window
+        on the device) in one engine; ``cams`` maps a camera to its frame
+        height. Groups come out tags first, by geometry, then clips."""
+        for cam, h in cams.items():
+            bus.create_stream(cam, h * self.W * 3)
+        cfg = EngineConfig(model="tiny_vit", batch_buckets=(1, 2, 4),
+                           tick_ms=5, fault=True, **cfg_kw)
+        eng = InferenceEngine(
+            bus, cfg, annotations=AnnotationQueue(handler=lambda b: True),
+            model_resolver=self._model_of)
+        eng.warmup()
+        return eng
+
+    def _publish(self, bus, cams, rng):
+        for cam, h in cams.items():
+            _publish_noise(bus, cam, rng, w=self.W, h=h)
+
+    @staticmethod
+    def _tick(eng, early=True):
+        """One tick by hand. Returns the batch traces and, per batch the
+        drain thread would see, (cameras, outputs on the host)."""
+        sink, handed, handles = eng._early_placements(
+            "normal" if early else "shed")
+        assert (sink is not None) == early
+        groups = eng._collector.collect(sink=sink)
+        if early:
+            assert handed == groups
+        batches = eng._dispatch(groups, time.time(), None, handles)
+        out = []
+        while not eng._drain_q.empty():
+            inflight = eng._drain_q.get(timeout=10)
+            out.append((list(inflight.group.device_ids),
+                        {k: np.asarray(v)
+                         for k, v in inflight.outputs.items()}))
+            eng._emit(inflight)
+            eng._pacer.forget(inflight)
+            eng._collector.release(inflight.group)
+            eng._drain_q.task_done()
+        return batches, out
+
+    @staticmethod
+    def _leases(eng):
+        with eng._collector._pool_lock:
+            return [i for slot in eng._collector._pool.values()
+                    for i in slot["leased"]]
+
+    @staticmethod
+    def _placed_early():
+        from video_edge_ai_proxy_tpu.obs import registry as obs_registry
+
+        fam = {f.name: f for f in obs_registry.families()}
+        return fam["vep_groups_placed_early_total"].value
+
+    def test_group_0_is_picked_up_before_group_1_is_read(self, bus):
+        """By the order of events, not by the clock: the first read of the
+        clip group finds the tag group's placement already picked up by
+        the transfer thread (it would wait for ever without the sink)."""
+        cams = {"tag0": self.H, "tag1": self.H, "clip0": self.H,
+                "clip1": self.H}
+        eng = self._eng(bus, cams)
+        rng = np.random.default_rng(0)
+        log, picked = [], threading.Event()
+        place, read_into = eng._xfer._place, bus.read_latest_into
+
+        def recording_place(frames):
+            log.append(("place", frames.shape))
+            picked.set()
+            return place(frames)
+
+        def recording_read(device_id, dst, **kw):
+            if device_id == "clip0":
+                picked.wait(timeout=10)
+            log.append(("read", device_id))
+            return read_into(device_id, dst, **kw)
+
+        eng._xfer._place = recording_place
+        eng._xfer.start()
+        try:
+            self._publish(bus, cams, rng)
+            self._tick(eng)                 # first sight: geometry learned
+            bus.read_latest_into = recording_read
+            before = self._placed_early()
+            for _ in range(3):
+                self._publish(bus, cams, rng)
+                del log[:]
+                picked.clear()
+                batches, _ = self._tick(eng)
+                shape = (2, self.H, self.W, 3)
+                assert log == [
+                    ("read", "tag0"), ("read", "tag1"), ("place", shape),
+                    ("read", "clip0"), ("read", "clip1"), ("place", shape)]
+                assert [b["batch"][1] for b in batches] == [0, 1]
+                for b in batches:
+                    assert b["place_ahead_s"] == pytest.approx(
+                        b["t_collect"] - b["t_place_q"])
+                # the tag batch was handed over before the clip reads
+                assert batches[0]["place_ahead_s"] \
+                    > batches[1]["place_ahead_s"] > 0.0
+                assert batches[0]["t_place0"] <= batches[0]["t_collect"]
+            assert self._placed_early() - before == 6
+        finally:
+            eng._xfer.stop()
+        assert self._leases(eng) == []
+
+    def test_a_third_group_is_left_to_the_dispatch_loop(self, bus):
+        """At most DEPTH placements start ahead of the dispatch: no more
+        batches are parked on the device than ``_dispatch`` parks."""
+        from video_edge_ai_proxy_tpu.engine.runner import _PrefetchStage
+
+        cams = {"tag0": self.H, "tagB": self.H + 16, "clip0": self.H}
+        eng = self._eng(bus, cams)
+        rng = np.random.default_rng(1)
+        eng._xfer.start()
+        try:
+            for k in range(3):
+                self._publish(bus, cams, rng)
+                sink, handed, handles = eng._early_placements("normal")
+                groups = eng._collector.collect(sink=sink)
+                assert len(groups) == 3 and handed == groups
+                assert len(handles) == _PrefetchStage.DEPTH == 2
+                assert [h.group for h in handles] == groups[:2]
+                batches = eng._dispatch(groups, time.time(), None, handles)
+                assert [b["batch"][1] for b in batches] == [0, 1, 2]
+                assert [b["place_ahead_s"] > 0 for b in batches] \
+                    == [True, True, False]
+                while not eng._drain_q.empty():
+                    inflight = eng._drain_q.get(timeout=10)
+                    eng._collector.release(inflight.group)
+                    eng._drain_q.task_done()
+        finally:
+            eng._xfer.stop()
+        assert self._leases(eng) == []
+
+    def test_results_are_bit_identical_with_and_without_a_sink(
+            self, ring_bus):
+        """Twenty rounds through two engines on one bus, one placing
+        early and one after the collect: the same groups, the same frames
+        in the same pooled rows, the same outputs bit for bit."""
+        cams = {"tag0": self.H, "tag1": self.H, "tag2": self.H,
+                "clip0": self.H, "clip1": self.H}
+        early, late = self._eng(ring_bus, cams), self._eng(ring_bus, {})
+        rng = np.random.default_rng(2)
+        early._xfer.start()
+        late._xfer.start()
+        try:
+            for k in range(20):
+                # a camera sits a round out now and then: buckets change
+                here = {c: h for c, h in cams.items()
+                        if rng.random() > 0.2 or k < self.L}
+                self._publish(ring_bus, here, rng)
+                b_early, got = self._tick(early, early=True)
+                b_late, want = self._tick(late, early=False)
+                assert [c for c, _ in got] == [c for c, _ in want]
+                assert len(b_early) == len(b_late)
+                assert all(b["place_ahead_s"] == 0.0 for b in b_late)
+                for (_, a), (_, b) in zip(got, want):
+                    assert a.keys() == b.keys()
+                    for key in a:
+                        np.testing.assert_array_equal(
+                            a[key], b[key], err_msg=f"round {k} {key}")
+            assert sum(len(c) for c, _ in got) == len(here) > 0
+        finally:
+            early._xfer.stop()
+            late._xfer.stop()
+        for eng in (early, late):
+            bal = eng.faults.ledger.balance()
+            assert bal["lost"] == 0 and bal["emitted"] > 0
+            assert self._leases(eng) == []
+        assert early.faults.ledger.balance()["emitted"] \
+            == late.faults.ledger.balance()["emitted"]
+
+    @pytest.mark.parametrize("why", ["ladder", "roi", "no_prefetch"])
+    def test_a_tick_that_may_rebuild_its_groups_hands_nothing_early(
+            self, bus, why):
+        """The sink exists only where what collect() finishes is what
+        _dispatch will place: not on a degraded rung (stale groups are
+        shed or rebuilt), not under cfg.roi (groups are replaced), and
+        not without a prefetch stage. Such a tick is the old one."""
+        if why == "roi":
+            bus.create_stream("cam1", 64 * 64 * 3)
+            eng = _engine(bus, "tiny_yolov8", roi=True, track=True,
+                          stage_trace=True)
+            cams = {"cam1": 64}
+            publish = lambda: _publish(bus, "cam1")         # noqa: E731
+        else:
+            cams = {"tag0": self.H, "clip0": self.H}
+            eng = self._eng(bus, cams, stage_trace=True,
+                            prefetch=why != "no_prefetch")
+            rng = np.random.default_rng(3)
+            publish = lambda: self._publish(bus, cams, rng)  # noqa: E731
+        if why == "ladder":
+            eng.ladder.observe = lambda **kw: "shed"
+        # the engine's own predicate, and the rung the tick loop gives it
+        assert (eng._early_placements("normal")[0] is None) \
+            == (why != "ladder")
+        assert eng._early_placements("shed")[0] is None
+        collect, sinks = eng._collector.collect, []
+
+        def recording_collect(*a, **kw):
+            sinks.append(kw.get("sink"))
+            return collect(*a, **kw)
+
+        eng._collector.collect = recording_collect
+        before = self._placed_early()
+        eng.start()
+        try:
+            deadline = time.time() + 60
+            while len(eng.stage_records) < 6 and time.time() < deadline:
+                publish()
+                time.sleep(0.02)
+        finally:
+            eng.stop()
+        assert len(eng.stage_records) >= 6
+        assert sinks and all(sink is None for sink in sinks)
+        for r in eng.stage_records:
+            assert r["place_ahead_s"] == 0.0
+            assert r["t_collect"] <= r["t_place_q"]
+        assert self._placed_early() == before
+
+    def test_collect_raising_after_a_hand_off_returns_every_lease(
+            self, bus):
+        """The tag group is on the transfer thread when the clip group's
+        collection raises: the tick is lost, the tag group's lease comes
+        back once its placement resolves, its slots are counted in and out
+        of the fault ledger, and the engine serves the next round."""
+        cams = {"tag0": self.H, "tag1": self.H, "clip0": self.H}
+        eng = self._eng(bus, cams, stage_trace=True)
+        rng = np.random.default_rng(4)
+        col = eng._collector
+        lease, armed, handed = col._lease, [False], []
+
+        def failing_lease(group, shape, idx):
+            if armed[0] and group.model == "tiny_videomae":
+                armed[0] = False
+                handed.extend(self._leases(eng))
+                raise RuntimeError("injected collect failure")
+            return lease(group, shape, idx)
+
+        col._lease = failing_lease
+        gate = threading.Lock()
+        collect = col.collect
+
+        def gated(*a, **kw):
+            with gate:
+                return collect(*a, **kw)
+
+        col.collect = gated
+
+        def round_(want):
+            """Every camera publishes while the collector is held, so one
+            tick reads the round; wait for its ``want`` results."""
+            have = len(eng.stage_records)
+            with gate:
+                self._publish(bus, cams, rng)
+            deadline = time.time() + 60
+            while len(eng.stage_records) < have + want \
+                    and time.time() < deadline:
+                time.sleep(0.005)
+
+        restarts = eng._m_window_restarts.labels("collect_error")
+        eng.start()
+        try:
+            for k in range(self.L):             # windows fill: 2, 2, 2, 3
+                round_(3 if k == self.L - 1 else 2)
+            assert len(eng.stage_records) == 2 * (self.L - 1) + 3
+            r0 = restarts.value
+            with gate:
+                armed[0] = True
+                self._publish(bus, cams, rng)
+            deadline = time.time() + 60
+            while armed[0] and time.time() < deadline:
+                time.sleep(0.005)
+            assert not armed[0], "the injected failure never triggered"
+            for k in range(self.L):             # the window starts anew
+                round_(3 if k == self.L - 1 else 2)
+        finally:
+            eng.stop()
+        # the tag group held its lease, on the transfer thread, when the
+        # collection raised
+        assert len(handed) == 1
+        assert self._leases(eng) == []
+        assert restarts.value - r0 == 1         # clip0's window
+        bal = eng.faults.ledger.balance()
+        assert bal["dropped"].get("collect_error") == 2     # tag0, tag1
+        assert bal["lost"] == 0
+        assert bal["dispatched"] == bal["emitted"] \
+            + sum(bal["dropped"].values())
+        # and every round after it was answered in full
+        assert len(eng.stage_records) == 2 * (2 * (self.L - 1) + 3)
 
 
 class TestIncrementalAssembly:

@@ -266,8 +266,21 @@ class TestStageRecords:
         for r in records:
             assert isinstance(r["tick"], int)
             assert r["batch"][0] == r["tick"]
-            stamps = [r[k] for k in STAMPS]
-            assert stamps == sorted(stamps), dict(zip(STAMPS, stamps))
+            # with the prefetch stage a group is handed to the transfer
+            # thread from inside collect(), the moment its last frame is
+            # read (ISSUE 35): its placement's stamps start before the
+            # collection closes, and the rest of the order holds
+            early = prefetch
+            order = [k for k in STAMPS if not (early and k == "t_collect")]
+            stamps = [r[k] for k in order]
+            assert stamps == sorted(stamps), dict(zip(order, stamps))
+            if early:
+                assert r["t_collect0"] <= r["t_place_q"] <= r["t_collect"] \
+                    <= r["t_place_got"]
+                assert r["place_ahead_s"] == pytest.approx(
+                    r["t_collect"] - r["t_place_q"])
+            else:
+                assert r["place_ahead_s"] == 0.0
             in_collect = r["read_s"] - r["read_ahead_s"] + r["fill_s"]
             # perf_counter durations against time.time() stamps
             assert in_collect <= r["t_collect"] - r["t_collect0"] + 1e-3
@@ -282,6 +295,34 @@ class TestStageRecords:
                      if k not in ("device_id", "ts_pub_ms", "t_emitted")}
             for r in recs[1:]:      # one trace a batch, shared
                 assert {k: r[k] for k in first} == first
+
+    def test_one_group_a_tick_is_handed_over_as_the_collect_ends(
+            self, bus, spans_on):
+        """``place_ahead_s`` (ISSUE 35) with one group a tick: the group is
+        finished by the collect's last read, so its placement starts the
+        few moments the collect takes to close ahead of ``t_collect``, and
+        all three sinks say so."""
+        fam = {f.name: f for f in registry.families()}
+        early = fam["vep_groups_placed_early_total"]
+        before = early.value
+        records = _Fleet(bus, tags=2, clips=0).run(L + 2)
+        batches = _by_batch(records)
+        assert len(batches) == L + 2
+        for recs in batches.values():
+            r = recs[0]
+            assert r["batch"][1] == 0
+            assert r["place_ahead_s"] >= 0.0
+            assert r["place_ahead_s"] == pytest.approx(
+                max(0.0, r["t_collect"] - r["t_place_q"]))
+            assert r["t_collect0"] <= r["t_place_q"] <= r["t_place0"] \
+                <= r["t_placed"] <= r["t_place_got"]
+        # every tick that read a frame handed its one group over
+        assert early.value - before == L + 2
+        placed = {tuple(e["batch"]): e for e in spans_on.events()
+                  if e["stream"] == "engine.transfer"}
+        for key, recs in batches.items():
+            assert placed[key]["ahead_ms"] == pytest.approx(
+                recs[0]["place_ahead_s"] * 1e3, abs=1e-3)
 
     @pytest.mark.parametrize("home", ["device", "host"])
     def test_two_groups_of_one_tick_share_tick_and_differ_in_batch(

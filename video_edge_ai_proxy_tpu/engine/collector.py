@@ -47,7 +47,7 @@ import threading
 import time
 import zlib
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -1169,12 +1169,19 @@ class Collector:
         return got
 
     def collect(
-        self, device_ids: Optional[Sequence[str]] = None
+        self, device_ids: Optional[Sequence[str]] = None,
+        sink: Optional[Callable[[BatchGroup], None]] = None,
     ) -> List[BatchGroup]:
         """One tick: newest unseen frame per stream -> (model, shape)-
         grouped, bucket-padded batches (clips for video models).
         ``device_ids``: precomputed inferred set (from ``partition``);
-        None re-enumerates.
+        None re-enumerates. ``sink``, where given, is called with each
+        group the moment it is finished (its last frame read, its pad rows
+        zeroed, its buffer leased), in the order of the returned list and
+        before the next group's first frame is read: the engine starts the
+        group's placement there, beside the reads that follow. The groups,
+        their buffers and leases, ``last_trace`` and every byte count are
+        the same with and without one.
 
         Hot path: a stream whose geometry is known from a previous tick
         is planned under (model, geometry, clip_len) and served from
@@ -1196,6 +1203,13 @@ class Collector:
         max_bucket = buckets[-1]
 
         groups: List[BatchGroup] = []
+
+        def done(group: BatchGroup) -> None:
+            # the one place a finished group leaves the collector
+            groups.append(group)
+            if sink is not None:
+                sink(group)
+
         spill: List[tuple] = []             # geometry drifted mid-plan
         win_planned: set = set()
         win = self._window
@@ -1215,7 +1229,7 @@ class Collector:
                         continue   # idle; buffer ages out via epochs
                     bucket = next(b for b in self._buckets
                                   if b // self._shards >= max(counts))
-                    groups.append(self._finish_sharded(
+                    done(self._finish_sharded(
                         g["buf"], g["shape"], g["idx"], g["per"],
                         g["seg"], bucket, g["hw"],
                         src_hw=g["geom"][:2], model=g["model"]))
@@ -1236,7 +1250,7 @@ class Collector:
                     model=g["model"],
                 )
                 self._lease(group, g["shape"], g["idx"])
-                groups.append(group)
+                done(group)
 
         # (model, (h, w, c), clip_len) -> [ids]: one plan for every stream
         # whose geometry is known; the window length is the model spec's.
@@ -1260,7 +1274,7 @@ class Collector:
                 fast_plan.items(), key=lambda kv: (kv[0][2],) + kv[0][:2]):
             if self._shards > 1:
                 self._collect_fast_sharded(
-                    model, geom, clip_len, devs, buckets, groups, spill)
+                    model, geom, clip_len, devs, buckets, done, spill)
                 continue
             # a window on the device: the sample the host ships is a frame
             window = self._device_window(model, clip_len)
@@ -1299,7 +1313,7 @@ class Collector:
                     metas=metas, bucket=bucket, model=model, window=window,
                 )
                 self._lease(group, shape, bidx)
-                groups.append(group)
+                done(group)
 
         # Generic path: first sight (geometry unknown) and drift.
         first_sight: List[tuple] = []
@@ -1335,13 +1349,13 @@ class Collector:
             )
         for (model, shape, window), items in sorted(by_key.items()):
             hw = shape[:-1][-2:]    # of [H, W, C] or [clip_len, H, W, C]
-            self._collect_generic(model, hw, items, buckets, groups, window)
+            self._collect_generic(model, hw, items, buckets, done, window)
         self.last_trace, self._acc = acc, _new_trace()
         return groups
 
     def _collect_fast_sharded(self, model: str, geom: tuple, clip_len: int,
                               devs: Sequence[str], buckets: tuple,
-                              groups: List[BatchGroup],
+                              done: Callable[[BatchGroup], None],
                               spill: List[tuple]) -> None:
         """Shard-segmented fast path: one (model, geometry, clip_len)
         stream set -> pooled, bucket-padded, shard-segmented batches.
@@ -1381,13 +1395,14 @@ class Collector:
                     self._unrotate(shape)
                 continue
             bucket = next(b for b in buckets if b // S >= max(counts))
-            groups.append(self._finish_sharded(
+            done(self._finish_sharded(
                 batch, shape, bidx, per, seg_a, bucket, touched,
                 src_hw=geom[:2], model=model))
 
     def _collect_generic(self, model: str, hw: tuple,
                          items: Sequence[tuple], buckets: tuple,
-                         groups: List[BatchGroup], window: int = 0) -> None:
+                         done: Callable[[BatchGroup], None],
+                         window: int = 0) -> None:
         """Generic path (first sight, drift): whole samples, each already
         in an array of its own, into a fresh zeroed buffer at final-bucket
         spacing — no compaction needed, pad rows already zero. One shard
@@ -1415,7 +1430,7 @@ class Collector:
                     metas.append(meta)
                     rows.append(s * seg + i)
             self._note_fill(t0, batch.nbytes, fresh=True)
-            groups.append(BatchGroup(
+            done(BatchGroup(
                 src_hw=hw, device_ids=ids, frames=batch, metas=metas,
                 bucket=bucket, model=model, rows=rows if S > 1 else None,
                 window=window,

@@ -693,6 +693,12 @@ class _PrefetchStage:
     batch with a real async ``jax.device_put``. The copy of batch t+1
     runs while the tick thread dispatches batch t and the device
     computes it, instead of serializing inside the dispatch loop.
+    It is fed from two places on the tick thread: the collector's sink
+    (``InferenceEngine._early_placements``), which hands over a tick's
+    first DEPTH groups each the moment its last frame is read, so that a
+    placement runs beside the reads of the groups after it; and the
+    dispatch loop, which submits whatever the sink did not (every group,
+    on a tick without a sink).
     ``block_until_ready`` on the placed array bounds the transfer window
     AND guarantees the pooled host buffer is no longer being read when
     the handle resolves — the lease-return failure path relies on that.
@@ -1079,6 +1085,10 @@ class InferenceEngine:
             ("home",))
         self._m_window_rows = {h: win_rows.labels(h)
                                for h in ("device", "host")}
+        self._m_placed_early = obs_registry.counter(
+            "vep_groups_placed_early_total",
+            "Batch groups handed to the transfer thread by the collector, "
+            "before their tick's collection closed")
         self._m_window_restarts = obs_registry.counter(
             "vep_clip_window_restarts_total",
             "Device clip windows started anew because a read frame did not "
@@ -2727,12 +2737,24 @@ class InferenceEngine:
                 paced_s = self._pacer.wait(self._stop)
                 self._pace_s += paced_s
                 t_collect0, pc_collect0 = time.time(), time.perf_counter()
+                # A group's placement starts when its last frame is read,
+                # beside the reads of the groups after it -- where what
+                # collect() finishes is what _dispatch will place.
+                sink, handed, handles = self._early_placements(rung)
                 try:
-                    groups = self._collector.collect(device_ids=inferred)
+                    groups = self._collector.collect(
+                        device_ids=inferred, sink=sink)
                 except Exception:
                     # frames may have been read and never made a group
                     for wpool in self._window_pools.values():
                         wpool.restart(list(wpool), "collect_error")
+                    # the groups that were made hold leases, and the first
+                    # of them are on the transfer thread already
+                    if self.faults is not None:
+                        for g in handed:
+                            self.faults.ledger.note_dispatched(
+                                _group_slots(g))
+                    self._drop_groups(handed, handles, "collect_error")
                     raise
                 if paced_s and groups:
                     # frames that had to wait for the device: the signal a
@@ -2751,7 +2773,8 @@ class InferenceEngine:
                 # t_collect closes the collection: collect() itself plus,
                 # under pressure or cfg.roi, the two group transforms above.
                 tick = self._open_tick(t_tick0, t_collect0, pc_collect0)
-                batches = self._dispatch(groups, tick["t_collect"], tick)
+                batches = self._dispatch(groups, tick["t_collect"], tick,
+                                         handles)
                 if batches:
                     # the host's lead: collect() entry -> the tick's first
                     # step call, less its wait for a predecessor step
@@ -2833,6 +2856,59 @@ class InferenceEngine:
                 if elapsed < tick_s:
                     self._stop.wait(tick_s - elapsed)
             self._assemble_s += time.perf_counter() - pc_assemble0
+
+    def _early_placements(self, rung: str) -> tuple:
+        """``(sink, groups, handles)`` for one tick's ``collect()``: the
+        sink hands each group the collector finishes to the transfer
+        thread at once (what the head of ``_dispatch`` would do for it
+        after the collect), keeping the groups seen and the handles made,
+        in order. At most ``_PrefetchStage.DEPTH`` placements start ahead
+        of the dispatch, so no more batches are parked on the device than
+        ``_dispatch`` itself parks; it submits the rest.
+
+        There is a sink only where a group that ``collect()`` finished is
+        certainly the group ``_dispatch`` will place: the prefetch stage
+        exists, the ladder is at ``normal`` (else ``_shed_stale_groups``
+        may drop or rebuild groups) and there is no ROI gate (else
+        ``_roi_transform`` replaces them). Otherwise it is None and the
+        tick places after the collect."""
+        handed: List[BatchGroup] = []
+        handles: List[Optional[_Prefetched]] = []
+        if self._xfer is None or rung != "normal" or self._roi is not None:
+            return None, handed, handles
+
+        def sink(group: BatchGroup) -> None:
+            handed.append(group)
+            if len(handles) < _PrefetchStage.DEPTH:
+                handles.append(self._xfer.submit(group, self._stop))
+                self._m_placed_early.inc()
+
+        return sink, handed, handles
+
+    def _drop_groups(self, groups: Sequence[BatchGroup],
+                     handles: Sequence[Optional[_Prefetched]],
+                     reason: str) -> None:
+        """Groups whose frames were read and that will not reach the drain
+        thread: return each lease, count its slots dropped, leave the
+        frames' ``dropped`` spans. ``handles[i]``, where there is one, is
+        the placement of ``groups[i]`` on the transfer thread: its lease
+        goes back only once the handle resolves -- the copy may still be
+        reading the pooled host buffer."""
+        for i, group in enumerate(groups):
+            if i < len(handles) and handles[i] is not None:
+                # Bounded: block_until_ready in the transfer loop keeps
+                # this short.
+                handles[i].ready.wait(timeout=5.0)
+            self._collector.release(group)
+            if self.faults is not None:
+                self.faults.note_dropped(_group_slots(group), reason)
+            if tracer.enabled:
+                for did, m in zip(group.device_ids, group.metas):
+                    if tracer.sampled(m.packet):
+                        tracer.record(
+                            did, "dropped", m.packet, reason=reason,
+                            trace_id=trace_id_of(m, did),
+                        )
 
     def _open_tick(self, t_tick0: float, t_collect0: float,
                    pc_collect0: float) -> dict:
@@ -2942,7 +3018,7 @@ class InferenceEngine:
             "engine.transfer", "place", n, ts=tr["t_placed"],
             dur_ms=(tr["t_placed"] - tr["t_place0"]) * 1e3,
             queued_ms=round((tr["t_place0"] - tr["t_place_q"]) * 1e3, 3),
-            **extra)
+            ahead_ms=round(tr["place_ahead_s"] * 1e3, 3), **extra)
         tracer.record(
             "engine.drain", "drain_wake", n, ts=tr["t_deq"],
             dur_ms=(tr["t_deq"] - tr["t_submit"]) * 1e3, **extra)
@@ -3185,14 +3261,18 @@ class InferenceEngine:
         )
 
     def _dispatch(self, groups: List[BatchGroup], t_collect: float,
-                  tick: Optional[dict] = None) -> List[dict]:
+                  tick: Optional[dict] = None,
+                  handles: Sequence[Optional[_Prefetched]] = ()
+                  ) -> List[dict]:
         """Dispatch one tick's collected groups to the device; returns
         the traces of the batches handed to the drain thread.
 
         ``tick`` is the tick's trace (``_open_tick``); each device batch
         gets a copy with its own stamps added: ``batch`` = (tick, index of
         the group), ``t_place_q``/``t_place0``/``t_placed`` (handed to the
-        transfer thread, picked up, placed), ``place_wait_s`` (this
+        transfer thread, picked up, placed), ``place_ahead_s`` (how long
+        before ``t_collect`` it was handed over: 0.0 unless the collector's
+        sink did it), ``place_wait_s`` (this
         thread's blocked time on the placement, ending at
         ``t_place_got``), what each carried state stamps (a stream head:
         ``head_*`` and ``pool_s``, its plan; a device window:
@@ -3218,6 +3298,14 @@ class InferenceEngine:
         dispatch. A failure after the frames were read restarts the
         windows involved.
 
+        ``handles`` are the placements the collector's sink started while
+        the tick was still reading (``_early_placements``), for
+        ``groups[:len(handles)]`` in order; the loop submits only what is
+        missing. What must precede a step (window breaks, the fault
+        ledger's count, the carried states' plans, the wait for a
+        predecessor step) is here, before the step call; none of it has to
+        precede a placement, which only reads the leased host buffer.
+
         With cfg.prefetch the placement of group g+1 (and g+2) runs on
         the transfer thread while this thread dispatches group g and the
         device computes earlier batches — H2D accounting (ROADMAP item 5
@@ -3235,7 +3323,9 @@ class InferenceEngine:
         lease, or a persistently failing model leaks one pooled buffer
         per tick until the pool failsafe churns. Prefetched leases are
         returned only after their transfer handle resolves — the copy
-        may still be reading the pooled host buffer.
+        may still be reading the pooled host buffer (``_drop_groups``;
+        ``_run`` does the same for the groups a ``collect()`` that raised
+        had already finished).
         """
         trace_on = tracer.enabled
         if tick is None:   # called outside the tick loop (tests, smokes)
@@ -3263,7 +3353,7 @@ class InferenceEngine:
                 else:
                     rest.append(g)
             groups = rest
-        handles: List[Optional[_Prefetched]] = []
+        handles = list(handles)
 
         def _top_up(upto: int) -> None:
             while len(handles) < min(len(groups), upto):
@@ -3296,7 +3386,9 @@ class InferenceEngine:
                                 "abandoned")
                     wait_s = time.perf_counter() - t_wait
                     tr.update(t_place_q=pre.t_q, t_place0=pre.t0,
-                              t_placed=pre.t1)
+                              t_placed=pre.t1,
+                              place_ahead_s=max(
+                                  0.0, tick["t_collect"] - pre.t_q))
                     if pre.error is not None:
                         raise pre.error
                     placed = pre.placed
@@ -3309,6 +3401,7 @@ class InferenceEngine:
                                    max(0.0, pre.transfer_s - wait_s))
                 else:
                     tr["t_place_q"] = tr["t_place0"] = time.time()
+                    tr["place_ahead_s"] = 0.0
                     t_h2d = time.perf_counter()
                     placed = self._place(group.frames)
                     wait_s = h2d_s = time.perf_counter() - t_h2d
@@ -3390,25 +3483,7 @@ class InferenceEngine:
                           else "dispatch_error")
                 # read, and now never to reach their windows
                 self._restart_windows(groups[gi:], "dropped")
-                for gj in range(gi, len(groups)):
-                    if gj < len(handles) and handles[gj] is not None:
-                        # Bounded: block_until_ready in the transfer loop
-                        # keeps this short, and an unresolved handle means
-                        # the copy may still be reading the host buffer.
-                        handles[gj].ready.wait(timeout=5.0)
-                    self._collector.release(groups[gj])
-                    if self.faults is not None:
-                        self.faults.note_dropped(
-                            _group_slots(groups[gj]), reason)
-                    if trace_on:
-                        for did, m in zip(groups[gj].device_ids,
-                                          groups[gj].metas):
-                            if tracer.sampled(m.packet):
-                                tracer.record(
-                                    did, "dropped", m.packet,
-                                    reason=reason,
-                                    trace_id=trace_id_of(m, did),
-                                )
+                self._drop_groups(groups[gi:], handles[gi:], reason)
                 raise
             self.batches += 1
             self._m_batches.inc()
