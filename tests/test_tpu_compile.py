@@ -329,7 +329,7 @@ class TestXing4HeadLayers:
         import flax.linen as nn
 
         xing4, cfg, on_chip, pool, rbuf, ints = self._shapes(v5e, 8)
-        attn = xing4.MlaAttention(cfg)
+        attn = xing4.MlaAttention(cfg.mla)
         h = jax.ShapeDtypeStruct((8, t, cfg.dim), jnp.bfloat16, sharding=v5e)
         variables = jax.tree_util.tree_map(on_chip, jax.eval_shape(
             lambda: nn.meta.unbox(attn.init(
@@ -389,3 +389,87 @@ class TestXing4HeadLayers:
                     if " copy(" in line and line.split("=")[1].strip()
                     .startswith(whole)]
 
+
+
+class TestDeepseekV2HeadLayers:
+    """The third streaming head's own shapes at the published widths
+    (models/deepseek_v2.py): latent attention at 32 held heads of 128
+    (models/mla.py) and the group-limited softmax router over 160 experts
+    of which ten are held (models/transformer.py), compiled INSIDE a loop
+    as the serving step runs them."""
+
+    @pytest.mark.parametrize("t", [784, 1], ids=["prefill", "decode"])
+    def test_latent_attention_at_the_held_heads_in_a_loop(self, v5e, t):
+        import flax.linen as nn
+
+        from video_edge_ai_proxy_tpu.models import deepseek_v2, mla
+
+        cfg = deepseek_v2.DeepseekV2Config()
+        attn = mla.MlaAttention(cfg.mla)
+        b = 4
+        on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+            a.shape, a.dtype, sharding=v5e)
+        pool = on_chip(jax.eval_shape(lambda: mla.empty_latent(
+            cfg.mla, cfg.num_layers, b, cfg.max_context)))
+        rbuf = on_chip(jax.eval_shape(lambda: mla.empty_latent(
+            cfg.mla, cfg.num_layers, b, 792)))
+        ints = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e)
+        h = jax.ShapeDtypeStruct((b, t, cfg.dim), jnp.bfloat16, sharding=v5e)
+        shapes = jax.eval_shape(lambda: nn.meta.unbox(attn.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 2, cfg.dim), jnp.bfloat16),
+            mla.empty_latent(cfg.mla, 1, 1, 8)[0],
+            mla.empty_latent(cfg.mla, 1, 1, 4)[0],
+            jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
+            jnp.zeros((1,), jnp.int32), 0)))
+        # the stacks hold the held heads' slices, not the model's 128
+        assert shapes["params"]["q_b"].shape == (1536, 32 * 192)
+        assert shapes["params"]["kv_b"].shape == (512, 32 * 256)
+        assert shapes["params"]["o"].shape == (32 * 128, 5120)
+        variables = jax.tree_util.tree_map(on_chip, shapes)
+
+        def twice(variables, h, pool, rbuf, slots, pos0):
+            def body(i, carry):
+                h, rows = carry
+                y, rows = attn.apply(
+                    variables, h, pool[i], rows, slots, pos0,
+                    None if t > 1 else pos0 * 0 + 784 + i, 3328)
+                return h + y, rows
+            return jax.lax.fori_loop(0, 2, body, (h, rbuf[0]))
+
+        text = _compiled_text(twice, variables, h, pool, rbuf, ints, ints)
+        assert "while" in text
+        whole = "bf16[%d,%d,%d,%d]" % pool.shape
+        assert not [line for line in text.splitlines()
+                    if " copy(" in line and whole in line.split("=")[1][:60]]
+
+    def test_group_limited_expert_layer_in_a_loop(self, v5e):
+        import flax.linen as nn
+
+        from video_edge_ai_proxy_tpu.models import deepseek_v2
+        from video_edge_ai_proxy_tpu.models.transformer import TopKMoeMlp
+
+        cfg = deepseek_v2.DeepseekV2Config().moe
+        assert (cfg.scoring, cfg.n_group, cfg.topk_group) == ("softmax", 8, 3)
+        layer = TopKMoeMlp(cfg)
+        shapes = jax.eval_shape(lambda: nn.meta.unbox(layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((8, 5120), jnp.bfloat16))))
+        assert shapes["params"]["gate"].shape == (5120, 160)
+        assert shapes["params"]["w1"].shape == (10, 5120, 1536)
+        assert "expert_bias" not in shapes["params"]
+        variables = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            shapes)
+
+        def twice(variables, x):
+            def body(_, carry):
+                x, load, hits = carry
+                y, n, _, hit = layer.apply(variables, x,
+                                           method=TopKMoeMlp.routed)
+                return x + y, load + n, hits + hit
+            return jax.lax.fori_loop(
+                0, 2, body, (x, jnp.zeros((10,), jnp.int32),
+                             jnp.zeros((), jnp.int32)))
+
+        x = jax.ShapeDtypeStruct((3136, 5120), jnp.bfloat16, sharding=v5e)
+        text = _compiled_text(twice, variables, x)
+        assert "ragged-dot" in text and "tpu_custom_call" in text

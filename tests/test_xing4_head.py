@@ -28,7 +28,7 @@ from vbench import loader, weights  # noqa: E402
 from video_edge_ai_proxy_tpu.engine import runner  # noqa: E402
 from video_edge_ai_proxy_tpu.engine.stream_state import (  # noqa: E402
     StreamStatePool, first_context_rounds)
-from video_edge_ai_proxy_tpu.models import registry, xing4  # noqa: E402
+from video_edge_ai_proxy_tpu.models import mla, registry, xing4  # noqa: E402
 from video_edge_ai_proxy_tpu.models.transformer import (  # noqa: E402
     TopKMoeConfig, TopKMoeMlp, topk_route)
 
@@ -78,7 +78,7 @@ def test_both_attention_paths_give_the_plain_form():
     flat = weights.generate(3, m["family"], sizes)
     ref, vt = _reference()
     cfg = xing4.tiny_stream_head_config().head
-    attn = xing4.MlaAttention(cfg, dtype=jnp.float32)
+    attn = mla.MlaAttention(cfg.mla, dtype=jnp.float32)
     params = _nest(flat, "head/layer1/attn/")
     n_old, n_new, n_dec = 9, 10, 2
     t = n_old + n_new + n_dec
@@ -87,7 +87,7 @@ def test_both_attention_paths_give_the_plain_form():
                                      vt._einsum("")))
     d = cfg.row_dim             # 24 numbers a row, in a 128-wide lane tile
     rows = attn.apply(params, h[None, :n_old], jnp.arange(n_old)[None],
-                      method=xing4.MlaAttention.latent)
+                      method=mla.MlaAttention.latent)
     # the stream owns slot 1 of 3; the others hold noise that is masked
     pool = jax.random.normal(jax.random.PRNGKey(6), (3, 32, d))
     pool = pool.at[1, :n_old].set(rows[0])
@@ -122,8 +122,8 @@ def test_prefill_attends_only_the_quarters_a_context_reaches_into():
     w_uk = jax.random.normal(k[3], (r, h, dn)) * 0.3
     w_uv = jax.random.normal(k[4], (r, h, dv)) * 0.3
     slots, ctx = jnp.asarray([2, 0, 3]), jnp.asarray([7, 300, 1000])
-    got = xing4.mla_prefill_attention(q, new, w_uk, w_uv, pool, slots, ctx,
-                                      0.3, cap)
+    got = mla.mla_prefill_attention(q, new, w_uk, w_uv, pool, slots, ctx,
+                                    0.3, cap)
     for b in range(3):
         n = int(ctx[b])
         rows = jnp.concatenate([pool[slots[b], :n], new[b]], axis=0)
@@ -141,13 +141,13 @@ def test_prefill_attends_only_the_quarters_a_context_reaches_into():
 
 def test_yarn_is_the_published_blend():
     cfg = xing4.Xing4Config()
-    inv = xing4.yarn_inv_freq(cfg)
+    inv = mla.yarn_inv_freq(cfg.mla)
     plain = cfg.rope_theta ** (-np.arange(0, 64, 2) / 64)
     # the fastest pairs keep their frequency, the slowest are slowed 64 x
     np.testing.assert_allclose(inv[:8], plain[:8], rtol=1e-6)
     np.testing.assert_allclose(inv[-8:], plain[-8:] / 64, rtol=1e-6)
     assert np.all(np.diff(inv) < 0)
-    assert abs(xing4.softmax_scale(cfg) - 0.1447) < 1e-4
+    assert abs(mla.softmax_scale(cfg.mla) - 0.1447) < 1e-4
 
 
 # -- the residual's maps ------------------------------------------------------

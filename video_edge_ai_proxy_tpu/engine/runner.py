@@ -1063,6 +1063,14 @@ class InferenceEngine:
             "vep_moe_pairs_total",
             "Routed (token, expert) pairs computed by the held experts"
         ).labels()
+        self._m_moe_routed = obs_registry.counter(
+            "vep_moe_pairs_routed_total",
+            "Routed (token, expert) pairs a head's routers chose over all "
+            "experts, held here or not (heads that count them)").labels()
+        self._m_moe_group_hits = obs_registry.counter(
+            "vep_moe_group_hits_total",
+            "Tokens, a routed layer, whose kept expert groups include a "
+            "group held here (group-limited routers)").labels()
         mtp = obs_registry.counter(
             "vep_mtp_drafts_total",
             "Drafts of a stream head's prediction module, verified by the "
@@ -4151,16 +4159,25 @@ class InferenceEngine:
                                moe_load_max=int(load.max()),
                                moe_load_mean=float(load.mean()))
             self._m_moe_pairs.inc(int(load.sum()))
+        if "moe_pairs_total" in host:
+            # a head that shards its experts by group: the pairs its
+            # routers chose over ALL experts, and the tokens whose kept
+            # groups include one held here
+            routed = int(host.pop("moe_pairs_total"))
+            hits = int(host.pop("moe_group_hits"))
+            inflight.tr.update(moe_pairs_total=routed, moe_group_hits=hits)
+            self._m_moe_routed.inc(routed)
+            self._m_moe_group_hits.inc(hits)
+        if "decode_iters" in host:
+            inflight.tr["head_decode_iters"] = int(host.pop("decode_iters"))
         if "mtp_drafted" in host:
             # a head whose prediction module drafts: the drafts the batch's
-            # streams verified and accepted, the decode loop's iterations
+            # streams verified and accepted
             rows = (list(group.rows) if group.rows is not None
                     else list(range(len(group.device_ids))))
             drafted = int(host.pop("mtp_drafted")[rows].sum())
             accepted = int(host["mtp_accepted"][rows].sum())
-            inflight.tr.update(
-                mtp_drafted=drafted, mtp_accepted=accepted,
-                head_decode_iters=int(host.pop("decode_iters")))
+            inflight.tr.update(mtp_drafted=drafted, mtp_accepted=accepted)
             self._m_mtp["drafted"].inc(drafted)
             self._m_mtp["accepted"].inc(accepted)
         # submit -> outputs on the host: drain-queue wait + device + fetch

@@ -450,6 +450,10 @@ class VideoMAELfm2(nn.Module):
 
     # what ``stream_head.serve_round`` asks of a head
     @nn.nowrap
+    def empty_counts(self):
+        return jnp.zeros((len(self.cfg.head.moe.held),), jnp.int32)
+
+    @nn.nowrap
     def seed_round(self, variables, state, slots, reset):
         """The key-value pool with the instruction's keys and values as
         the first rows of every slot (read and written in place, by slot),

@@ -165,6 +165,34 @@ register(ModelSpec(
                 "a stream a round, the prediction module drafting",
 ))
 
+
+def _dsv2(tiny: bool = False):
+    """The third streaming head, imported when it is first built."""
+    from . import deepseek_v2 as dsv2
+
+    if tiny:
+        return dsv2.VideoMAEDeepseekV2(dsv2.tiny_stream_head_config(),
+                                       dtype=jnp.float32)
+    return dsv2.VideoMAEDeepseekV2(dsv2.StreamHeadConfig())
+
+
+register(ModelSpec(
+    "videomae_b_dsv2", _dsv2,
+    input_size=224, preprocess="clip", kind="stream", clip_len=8,
+    prepare=prepare_for_serving,
+    description="third streaming head (models/deepseek_v2.py): VideoMAE-B "
+                "encoder -> connector -> DeepSeek-V2 decoder at the "
+                "published widths, one chip's share of a 16-chip layer (1 "
+                "dense + 4 routed blocks, 32 of 128 heads, 10 of 160 "
+                "experts a block, an eighth of the vocabulary); latent "
+                "attention (models/mla.py) told which heads it holds, a "
+                "softmax router limited to 3 of 8 expert groups, two "
+                "shared experts; per-stream latent cache in "
+                "engine/stream_state.py and no other state; 784 tokens "
+                "prefilled and 8 decoded a stream a round, one position an "
+                "iteration",
+))
+
 # --- diagnostic gauges ----------------------------------------------------
 
 register(ModelSpec(
@@ -226,4 +254,11 @@ register(ModelSpec(
     prepare=prepare_for_serving,
     description="CPU/CI twin of videomae_b_xing4 (tests/test_xing4_head.py), "
                 "in float32 throughout",
+))
+register(ModelSpec(
+    "tiny_videomae_dsv2", lambda: _dsv2(tiny=True),
+    input_size=32, preprocess="clip", kind="stream", clip_len=4,
+    prepare=prepare_for_serving,
+    description="CPU/CI twin of videomae_b_dsv2 (tests/test_deepseek_v2_head"
+                ".py), in float32 throughout",
 ))

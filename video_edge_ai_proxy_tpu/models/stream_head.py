@@ -1,5 +1,6 @@
-"""What the streaming heads share (``models/lfm2.py``, ``models/xing4.py``):
-a decoder behind the VideoMAE encoder that keeps a state per camera.
+"""What the streaming heads share (``models/lfm2.py``, ``models/xing4.py``,
+``models/deepseek_v2.py``): a decoder behind the VideoMAE encoder that keeps
+a state per camera.
 
 The reference ships frames to external clients and has no model at all
 (`/root/reference/README.md:5-27`). Here, every round, a clip camera's
@@ -13,10 +14,14 @@ takes such a model (:func:`prepare_for_serving`) and the round itself
 (:func:`serve_round`): instruction, prefill in chunks of streams, decode,
 flush, as a pure function over a head that answers
 
+- ``empty_counts() -> load``: what its expert layers count over a round,
+  zeroed (the routed pairs each held expert took, [held] int32; a head
+  that counts more gives a pytree of them);
 - ``seed_round(variables, state, slots, reset) -> (pool, rows)``: the
   standing instruction laid into the state; ``pool`` is what the round
   reads in place by slot (the caches), ``rows`` what it carries a batch row
-  (gathered by slot: a pytree, batch axis 0);
+  (gathered by slot: a pytree, batch axis 0; ``()`` where the caches are
+  all its state);
 - ``round_buffer(rows, dtype) -> rbuf``: the round's own cache rows (a
   pytree, batch axis 1), written to the pool once, when the round is over;
 - ``prefill(variables, x, pool, rows, rbuf, slots, pos0) -> (h, rows, rbuf,
@@ -211,12 +216,13 @@ def serve_round(model, variables, clips, state, slots, pos0, reset,
             variables, x, pool, tree(cut, rows),
             tree(lambda a: cut(a, 1), rbuf), cut(slots), cut(pos0))
         return (put(h, hn), tree(put, rows, rn),
-                tree(lambda a, v: put(a, v, 1), rbuf, bn), load + m)
+                tree(lambda a, v: put(a, v, 1), rbuf, bn),
+                tree(jnp.add, load, m))
 
     h, rows, rbuf, load = jax.lax.fori_loop(
         0, b // n, prefill,
         (jnp.zeros((b, c.head.dim), model.dtype), rows, rbuf,
-         jnp.zeros((len(c.head.moe.held),), jnp.int32)))
+         model.empty_counts()))
 
     out = model.decode(variables, pool, h, rows, rbuf, slots, pos0, load)
     out["state"] = model.commit_round(

@@ -237,11 +237,22 @@ class RoutedMoeMlp(nn.Module):
 
 @dataclass(frozen=True)
 class TopKMoeConfig:
-    """A routed SwiGLU expert layer as ``lfm2_moe`` and ``xing4_0`` publish
-    it: the router scores ALL ``num_experts``; this holder computes the
-    experts in ``experts_held`` (their ids among the ``num_experts``; empty
-    = all). ``shared_mlp_dim``: the width of the shared expert every token
-    takes beside the routed ones, unweighted (0: none, and no parameter)."""
+    """A routed SwiGLU expert layer as ``lfm2_moe``, ``xing4_0`` and
+    ``deepseek_v2`` publish it: the router scores ALL ``num_experts``; this
+    holder computes the experts in ``experts_held`` (their ids among the
+    ``num_experts``; empty = all). ``shared_mlp_dim``: the width of the
+    shared expert every token takes beside the routed ones, unweighted (0:
+    none, and no parameter). Three routers (:func:`topk_route`):
+
+    - ``lfm2_moe``, ``xing4_0``: ``scoring`` sigmoid, the top-k of score +
+      learned bias over all experts, renormalised, x the scaling factor;
+    - ``deepseek_v2`` (``group_limited_greedy``): ``scoring`` softmax over
+      all experts in float32, no bias; the experts lie in ``n_group``
+      groups of consecutive ids, a group scores as its best expert, only
+      the best ``topk_group`` groups' experts can be chosen, the top-k of
+      those; weights not renormalised (``norm_topk_prob`` false), x the
+      scaling factor. The group limit needs every expert's score, on a
+      holder of ten of them too."""
     dim: int
     mlp_dim: int
     num_experts: int
@@ -251,18 +262,44 @@ class TopKMoeConfig:
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     shared_mlp_dim: int = 0
+    scoring: str = "sigmoid"            # or "softmax"
+    n_group: int = 1
+    topk_group: int = 1
 
     @property
     def held(self) -> tuple:
         return tuple(self.experts_held) or tuple(range(self.num_experts))
 
+    @property
+    def groups_held(self) -> tuple:
+        """The groups that hold at least one expert held here."""
+        per = self.num_experts // self.n_group
+        return tuple(sorted({e // per for e in self.held}))
+
+
+def kept_groups(pick: jnp.ndarray, cfg: TopKMoeConfig):
+    """[N, n_group] bool: the ``topk_group`` groups a token may choose
+    from, by each group's best ``pick`` (ties to the lower group)."""
+    n = pick.shape[0]
+    best = jnp.max(pick.reshape(n, cfg.n_group, -1), axis=-1)
+    _, top = jax.lax.top_k(best, cfg.topk_group)
+    return jnp.any(top[:, :, None] == jnp.arange(cfg.n_group)[None, None],
+                   axis=1)
+
 
 def topk_route(scores: jnp.ndarray, bias, cfg: TopKMoeConfig):
     """([N, k] expert ids, [N, k] weights) from [N, E] router scores
-    (sigmoid already applied): the top-k of ``scores + bias``, weighted by
-    the unbiased scores of the chosen, renormalised to sum 1 (over their
-    sum + 1e-20, as published), times ``routed_scaling_factor``."""
+    (sigmoid or softmax already applied): the top-k of ``scores + bias``
+    (with ``n_group`` > 1: of those in the token's :func:`kept_groups`, the
+    others' taken as 0, as published; ties to the lower id), weighted by
+    the unbiased scores of the chosen, renormalised to sum 1 where
+    ``norm_topk_prob`` (over their sum + 1e-20, as published), times
+    ``routed_scaling_factor``."""
     pick = scores + bias if bias is not None else scores
+    if cfg.n_group > 1:
+        keep = jnp.repeat(kept_groups(pick, cfg),
+                          cfg.num_experts // cfg.n_group, axis=-1)
+        pick = jnp.where(keep, pick, 0.0)
     _, sel = jax.lax.top_k(pick, cfg.top_k)
     w = jnp.take_along_axis(scores, sel, axis=-1)
     if cfg.norm_topk_prob:
@@ -288,13 +325,21 @@ class TopKMoeMlp(nn.Module):
     added here unweighted, and counted once where holders are summed.
 
     Returns ``(y [N, d], load [held])``: the pairs each held expert took.
+    :meth:`routed` returns two counts more: the pairs routed in all (N x
+    ``top_k``: ``load`` summed over every holder) and the tokens whose kept
+    groups include one that holds an expert held here (every token where
+    the router has no group limit).
     """
 
     cfg: TopKMoeConfig
     dtype: Dtype = jnp.bfloat16
 
-    @nn.compact
     def __call__(self, x: jnp.ndarray):
+        y, load, _, _ = self.routed(x)
+        return y, load
+
+    @nn.compact
+    def routed(self, x: jnp.ndarray):
         c = self.cfg
         held = c.held
         n, d = x.shape
@@ -307,10 +352,22 @@ class TopKMoeMlp(nn.Module):
         bias = self.param(
             "expert_bias", nn.initializers.zeros_init(),
             (c.num_experts,), jnp.float32) if c.use_expert_bias else None
-        scores = jax.nn.sigmoid(jnp.dot(
-            x.astype(jnp.float32), gate.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        sel, w = topk_route(scores, bias, c)                    # [N, k]
+        with jax.named_scope("moe_route"):
+            logits = jnp.dot(
+                x.astype(jnp.float32), gate.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+            if c.scoring not in ("sigmoid", "softmax"):
+                raise ValueError(f"unknown scoring {c.scoring!r}; expected "
+                                 "'sigmoid' or 'softmax'")
+            scores = (jax.nn.softmax(logits, axis=-1)
+                      if c.scoring == "softmax" else jax.nn.sigmoid(logits))
+            sel, w = topk_route(scores, bias, c)                # [N, k]
+            hits = jnp.asarray(n, jnp.int32)
+            if c.n_group > 1:
+                pick = scores + bias if bias is not None else scores
+                hits = jnp.sum(jnp.any(
+                    kept_groups(pick, c)[:, list(c.groups_held)], axis=-1),
+                    dtype=jnp.int32)
 
         # global expert id -> row of this holder's stack, h = elsewhere
         # (built on the host, and counted by comparison: inside a loop the
@@ -326,11 +383,13 @@ class TopKMoeMlp(nn.Module):
 
         w1, w3, w2 = (a.astype(self.dtype) for a in _expert_weights(
             self, c, stack=h, gated=True))
-        xs = jnp.take(x.astype(self.dtype), order // k, axis=0)  # [N*k, d]
-        up = jax.lax.ragged_dot(xs, w3, sizes)
-        act = nn.silu(jax.lax.ragged_dot(xs, w1, sizes)) * up
-        ys = jax.lax.ragged_dot(act, w2, sizes,
-                                preferred_element_type=jnp.float32)
+        with jax.named_scope("moe_experts"):
+            # [N*k, d]
+            xs = jnp.take(x.astype(self.dtype), order // k, axis=0)
+            up = jax.lax.ragged_dot(xs, w3, sizes)
+            act = nn.silu(jax.lax.ragged_dot(xs, w1, sizes)) * up
+            ys = jax.lax.ragged_dot(act, w2, sizes,
+                                    preferred_element_type=jnp.float32)
         # rows past the last group are whatever the kernel left there
         ys = jnp.where(here[:, None], ys, 0.0)
         back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(n, k, d)
@@ -348,7 +407,8 @@ class TopKMoeMlp(nn.Module):
                 xd = x.astype(self.dtype)
                 y = y + jnp.dot(nn.silu(xd @ s1) * (xd @ s3), s2,
                                 preferred_element_type=jnp.float32)
-        return y.astype(self.dtype), sizes
+        return (y.astype(self.dtype), sizes, jnp.asarray(n * k, jnp.int32),
+                hits)
 
 
 class EncoderBlock(nn.Module):

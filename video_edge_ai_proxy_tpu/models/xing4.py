@@ -15,16 +15,12 @@ the cast at load are ``models/stream_head.py``'s. What is this head's own:
   their sums + ``hc_eps``, columns over theirs); ``h = H_pre X``, ``y =
   F(RMS(h))``, ``X' = H_res X + H_postᵀ y``. Entry: the embedding repeated
   n times; exit: the sum of the streams. The maps are float32.
-- **Attention is latent (MLA)**: ``c_q = RMS(h W_qa)``, ``[q_n | q_r] = c_q
-  W_qb`` a head; ``[c_kv | k_r] = h W_kva``, ``ĉ = RMS(c_kv)``; **a
-  position's cache row is ``[ĉ | rope(k_r)]``**, 576 numbers, ``k_r``
-  shared by all heads; ``[k_n | v] = ĉ W_kvb`` a head; yarn rope on the
-  rope part only; scores scaled by ``(d_n + d_r)^-½ · mscale²``. Two paths
-  over the one cache: :func:`mla_prefill_attention` up-projects the cached
-  rows it attends to per-head keys and values (per stream, never stored);
-  :func:`mla_decode_attention` folds ``W_kvb``'s key half into the query
-  and its value half into the output and attends over the 576-wide rows as
-  they lie.
+- **Attention is latent (MLA)**, and lives in ``models/mla.py`` (shared
+  with ``models/deepseek_v2.py``; its equations, its two paths over the one
+  cache, the yarn rope, ``flush_round`` and ``seed_rows`` are written down
+  there): **a position's cache row is ``[ĉ | rope(k_r)]``**, 576 numbers,
+  ``k_r`` shared by all heads. This head holds all 32 of its heads
+  (``Xing4Config.mla``).
 - **Feed-forward**: block 0 dense SwiGLU, the others
   ``transformer.TopKMoeMlp`` with the shared expert (sigmoid router over
   all experts, top-k of score + bias, renormalised, × 2, dropless, this
@@ -45,17 +41,16 @@ the whole pool into otherwise) and ``exit`` [slots, C].
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass, field
 from typing import Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from . import stream_head
+from . import mla, stream_head
 from .common import Dtype
+from .mla import MlaAttention, MlaConfig, flush_round, seed_rows
 from .stream_head import Connector, RmsNorm, SwiGlu, _kernel, top_tokens
 from .transformer import TopKMoeConfig, TopKMoeMlp
 from .videomae import VideoMAE, VideoMAEConfig, tiny_videomae_config
@@ -97,16 +92,29 @@ class Xing4Config:
     max_context: int = 4096
 
     @property
+    def mla(self) -> MlaConfig:
+        """The attention's own sizes (``models/mla.py``): every head held."""
+        return MlaConfig(
+            dim=self.dim, num_heads=self.num_heads,
+            q_lora_rank=self.q_lora_rank, kv_lora_rank=self.kv_lora_rank,
+            qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim,
+            v_head_dim=self.v_head_dim, norm_eps=self.norm_eps,
+            rope_theta=self.rope_theta, rope_factor=self.rope_factor,
+            rope_original_max=self.rope_original_max,
+            rope_beta_fast=self.rope_beta_fast,
+            rope_beta_slow=self.rope_beta_slow,
+            rope_mscale=self.rope_mscale,
+            rope_mscale_all_dim=self.rope_mscale_all_dim)
+
+    @property
     def latent_dim(self) -> int:
-        return self.kv_lora_rank + self.qk_rope_head_dim
+        return self.mla.latent_dim
 
     @property
     def row_dim(self) -> int:
-        """A cache row's width in memory: the latent row up to a lane
-        tile (576 -> 640: the TPU pads a 576-wide minor axis to 640 anyway,
-        and with an axis it would pad the compiler keeps the whole pool in
-        a second layout beside the first)."""
-        return -(-self.latent_dim // 128) * 128
+        """A cache row's width in memory (``MlaConfig.row_dim``: 640)."""
+        return self.mla.row_dim
 
     @property
     def blocks(self) -> int:
@@ -153,278 +161,6 @@ def tiny_stream_head_config(vocab_size: int = 96) -> StreamHeadConfig:
             rope_original_max=64, max_context=160),
         instruction_ids=tuple(i % vocab_size for i in (5, 17, 3, 90)),
         decode_steps=3, prefill_chunk=2)
-
-
-# -- yarn rope -----------------------------------------------------------------
-
-def yarn_mscale(factor: float, m: float) -> float:
-    return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
-
-
-def yarn_inv_freq(cfg: Xing4Config) -> np.ndarray:
-    """[d_r / 2] inverse frequencies: extrapolated (as published) below the
-    low correction dimension, interpolated (÷ factor) above the high one,
-    blended by the linear ramp between."""
-    d = cfg.qk_rope_head_dim
-    extra = cfg.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
-    inter = extra / cfg.rope_factor
-
-    def correction(rotations):
-        return (d * math.log(cfg.rope_original_max
-                             / (rotations * 2 * math.pi))
-                / (2 * math.log(cfg.rope_theta)))
-
-    low = max(math.floor(correction(cfg.rope_beta_fast)), 0)
-    high = min(math.ceil(correction(cfg.rope_beta_slow)), d - 1)
-    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
-                   / max(high - low, 1e-3), 0.0, 1.0)
-    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
-
-
-def rope(x, pos, cfg: Xing4Config):
-    """Rotate-half yarn rope: x [B, T, (H,) d_r], pos [B, T]."""
-    ang = pos.astype(jnp.float32)[..., None] * yarn_inv_freq(cfg)
-    m = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
-         / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1) * m
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1) * m
-    if x.ndim == 4:
-        cos, sin = cos[:, :, None], sin[:, :, None]
-    x = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
-
-
-def softmax_scale(cfg: Xing4Config) -> float:
-    return ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
-            * yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2)
-
-
-# -- the two attention paths over the one latent cache ------------------------
-
-def mla_prefill_attention(q, new, w_uk, w_uv, pool, slots, ctx, scale, cap):
-    """A round's first T positions: ``q`` [B, T, H, d_n + d_r] (the rope
-    part roped) against each row's slot of the pool (``pool`` [slots, S,
-    row]: the positions before ``ctx`` [B] are the stream's context; the
-    rest is stale or unwritten, and masked; only the first ``cap`` can be
-    context when a round starts) and, causally, against the T new rows
-    themselves (``new`` [B, T, row]). The cached rows are up-projected to
-    per-head keys and values (``w_uk`` [r, H, d_n], ``w_uv`` [r, H, d_v])
-    here, one stream at a time, so one stream's [cap, H, d] keys and its
-    [H, T, cap] and [H, T, T] scores are all that is held; and of the
-    cached rows only as many quarters of ``cap`` as the stream's context
-    reaches into (a switch a stream: the de-phased fleet holds every
-    depth, and a fresh context has one quarter to attend, not four). The
-    two partial softmaxes are merged by their maxima and sums."""
-    b, t, h, _ = q.shape
-    r, dn = w_uk.shape[0], w_uk.shape[-1]
-    dr = q.shape[-1] - dn
-    causal = jnp.tril(jnp.ones((t, t), bool))[None, None]
-
-    def part(qc, rows, mask):
-        """(max, sum, unnormalised output) of one stream's softmax over
-        ``rows`` [1, S, row], the rope part shared by the heads."""
-        c_rows = rows[..., :r]
-        keys = jnp.concatenate(
-            [jnp.einsum("bsr,rhd->bshd", c_rows, w_uk),
-             jnp.broadcast_to(rows[:, :, None, r:r + dr],
-                              rows.shape[:2] + (h, dr))], axis=-1)
-        s = jnp.einsum("bthd,bshd->bhts", qc, keys).astype(
-            jnp.float32) * scale
-        s = jnp.where(mask, s, -1e30)
-        m = jnp.max(s, axis=-1)
-        e = jnp.exp(s - m[..., None])
-        o = jnp.einsum("bhts,bshd->bthd", e.astype(qc.dtype),
-                       jnp.einsum("bsr,rhd->bshd", c_rows, w_uv),
-                       preferred_element_type=jnp.float32)
-        return m, jnp.sum(e, axis=-1), o
-
-    step = -(-cap // (4 * 128)) * 128 if cap else 0
-    depths = sorted({min(cap, step * k) for k in range(1, 5)}) if cap else []
-
-    def one(args):
-        # a leading axis of one stream: the batched products below are the
-        # form the TPU compiler lays out well (models/lfm2.py)
-        qc, nw, sc, cc = (a[None] for a in args)
-        m, total, o = part(qc, nw, causal)
-        if cap:
-            def cached(depth):
-                rows = jnp.take(pool, sc, axis=0, mode="clip")[:, :depth]
-                seen = jnp.arange(depth)[None, :] < cc[:, None]
-                return part(qc, rows, seen[:, None, None])
-
-            m_a, l_a, o_a = jax.lax.switch(
-                jnp.clip(-(-cc[0] // step) - 1, 0, len(depths) - 1),
-                [lambda d=d: cached(d) for d in depths])
-            m_b, total_b, o_b = m, total, o
-            m = jnp.maximum(m_a, m_b)
-            w_a, w_b = jnp.exp(m_a - m), jnp.exp(m_b - m)
-            total = l_a * w_a + total_b * w_b
-            o = (o_a * w_a.transpose(0, 2, 1)[..., None]
-                 + o_b * w_b.transpose(0, 2, 1)[..., None])
-        o = o / total.transpose(0, 2, 1)[..., None]
-        return o.astype(qc.dtype).reshape(t, -1)
-
-    return jax.lax.map(one, (q, new, slots, ctx))
-
-
-def mla_decode_attention(q_n, q_r, w_uk, w_uv, pool, rbuf, slots, ctx, upto,
-                         scale):
-    """A few new positions a stream, in the latent space: ``q_n`` [B, T, H,
-    d_n] is carried through ``w_uk`` to the cache's own width (``q_n W_ukᵀ``
-    [r] a head), joined with ``q_r``, and attends over the 576-wide rows as
-    they lie: the pool READ IN PLACE in slot order (the queries are carried
-    to their slots and the partial results back by a one-hot product, as
-    ``models/lfm2.py`` does: gathering the rows would copy the cache), and
-    this round's own rows ``rbuf`` [B, R, r + d_r], of which query t of row
-    b sees those up to ``upto[b, t]``. The two partial softmaxes are merged
-    by their maxima and sums; the output leaves the latent space through
-    ``w_uv``."""
-    b, t, h, _ = q_n.shape
-    r = w_uk.shape[0]
-    c = pool.shape[0]
-    hi = jax.lax.Precision.HIGHEST
-    width = pool.shape[-1]          # the rows' width in memory, zero-padded
-    q = jnp.concatenate(
-        [jnp.einsum("bthd,rhd->bthr", q_n, w_uk), q_r,
-         jnp.zeros(q_r.shape[:-1] + (width - r - q_r.shape[-1],),
-                   q_r.dtype)], axis=-1)
-    q = q.reshape(b, t * h, width)
-    onehot = (slots[:, None] == jnp.arange(c)[None]).astype(jnp.float32)
-
-    def partial(query, rows, mask):
-        s = jnp.einsum("bqd,bsd->bqs", query, rows,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(mask, s, -1e30)
-        m = jnp.max(s, axis=-1)
-        e = jnp.exp(s - m[..., None])
-        # the rows whole (their rope part's output is dropped after): no
-        # slice of the cache is made
-        o = jnp.einsum("bqs,bsd->bqd", e.astype(rows.dtype), rows,
-                       preferred_element_type=jnp.float32)
-        return m, jnp.sum(e, axis=-1), o[..., :r]
-
-    # the pool's part, in slot order
-    q_slot = jnp.einsum("bc,bqd->cqd", onehot, q.astype(jnp.float32))
-    ctx_slot = jnp.einsum("bc,b->c", onehot, ctx.astype(jnp.float32),
-                          precision=hi)
-    seen = jnp.arange(pool.shape[1])[None] < ctx_slot[:, None]      # [c, S]
-    part_a = partial(q_slot.astype(q.dtype), pool, seen[:, None])
-    m_a, l_a, o_a = (jnp.einsum("bc,c...->b...", onehot, x, precision=hi)
-                     for x in part_a)
-    # this round's part, in batch order
-    new = jnp.arange(rbuf.shape[1])[None, None] <= upto[:, :, None]
-    new = jnp.repeat(new, h, axis=1)                            # [B, T*H, R]
-    m_b, l_b, o_b = partial(q, rbuf, new)
-    m = jnp.maximum(m_a, m_b)
-    w_a, w_b = jnp.exp(m_a - m), jnp.exp(m_b - m)
-    o = (o_a * w_a[..., None] + o_b * w_b[..., None]) \
-        / (l_a * w_a + l_b * w_b)[..., None]
-    o = jnp.einsum("bthr,rhd->bthd",
-                   o.astype(q_n.dtype).reshape(b, t, h, r), w_uv)
-    return o.reshape(b, t, -1)
-
-
-def write_rows(rbuf, new, at):
-    """``new`` [B, T, d] into ``rbuf`` [B, R, d] at each row's own
-    ``at[b]``, by selection (a scatter with a window a row is refused by
-    the TPU compiler inside a loop)."""
-    idx = jnp.arange(rbuf.shape[1])[None] - at[:, None]             # [B, R]
-    for t in range(new.shape[1]):
-        rbuf = jnp.where((idx == t)[..., None], new[:, t:t + 1], rbuf)
-    return rbuf
-
-
-class MlaAttention(nn.Module):
-    """Latent attention over a stream's context in the latent pool (read
-    only: ``pool`` [slots, S, r + d_r]) and this round's own rows in the
-    round buffer ``rbuf`` [B, R, r + d_r], written to the pool once, when
-    the round is over (:func:`flush_round`)."""
-    cfg: Xing4Config
-    dtype: Dtype = jnp.bfloat16
-
-    def setup(self):
-        c, d, h = self.cfg, self.cfg.dim, self.cfg.num_heads
-        qk = c.qk_nope_head_dim + c.qk_rope_head_dim
-        self.q_a = _kernel(self, "q_a", (d, c.q_lora_rank), ("embed", "qkv"))
-        self.q_norm = RmsNorm(c.norm_eps, self.dtype, name="q_norm")
-        self.q_b = _kernel(self, "q_b", (c.q_lora_rank, h * qk),
-                           ("embed", "qkv"))
-        self.kv_a = _kernel(self, "kv_a", (d, c.latent_dim),
-                            ("embed", "qkv"))
-        self.kv_norm = RmsNorm(c.norm_eps, self.dtype, name="kv_norm")
-        self.kv_b = _kernel(
-            self, "kv_b",
-            (c.kv_lora_rank, h * (c.qk_nope_head_dim + c.v_head_dim)),
-            ("embed", "qkv"))
-        self.o = _kernel(self, "o", (h * c.v_head_dim, d), ("qkv", "embed"))
-
-    def latent(self, h, pos):
-        """[B, T, C] -> the positions' cache rows [B, T, r + d_r]."""
-        c, r = self.cfg, self.cfg.kv_lora_rank
-        ckv = h @ self.kv_a.astype(self.dtype)
-        return jnp.concatenate(
-            [self.kv_norm(ckv[..., :r]),
-             rope(ckv[..., r:], pos, c).astype(self.dtype),
-             jnp.zeros(h.shape[:-1] + (c.row_dim - c.latent_dim,),
-                       self.dtype)], axis=-1)
-
-    def __call__(self, h, pool, rbuf, slots, ctx, at, cap):
-        """``at`` None: a prefill, whose T positions start the round's
-        buffer; else [B], where each row's T decode positions go."""
-        c = self.cfg
-        b, t, _ = h.shape
-        dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
-        pos = ctx[:, None] + jnp.arange(t, dtype=ctx.dtype)[None]
-        if at is not None:
-            pos = pos + at[:, None]
-        new = self.latent(h, pos).astype(rbuf.dtype)
-        q = self.q_norm(h @ self.q_a.astype(self.dtype)) \
-            @ self.q_b.astype(self.dtype)
-        q = q.reshape(b, t, c.num_heads, dn + dr)
-        q_n, q_r = q[..., :dn], rope(q[..., dn:], pos, c).astype(self.dtype)
-        q = jnp.concatenate([q_n, q_r], axis=-1)
-        w = self.kv_b.astype(self.dtype).reshape(
-            c.kv_lora_rank, c.num_heads, dn + dv)
-        w_uk, w_uv = w[..., :dn], w[..., dn:]
-        if at is None:
-            with jax.named_scope("mla_prefill"):
-                rbuf = jax.lax.dynamic_update_slice_in_dim(
-                    rbuf, new, 0, axis=1)
-                o = mla_prefill_attention(
-                    q, new, w_uk, w_uv, pool, slots, ctx, softmax_scale(c),
-                    min(cap, pool.shape[1]))
-        else:
-            with jax.named_scope("mla_decode"):
-                rbuf = write_rows(rbuf, new, at)
-                upto = at[:, None] + jnp.arange(t, dtype=at.dtype)[None]
-                o = mla_decode_attention(
-                    q_n, q_r, w_uk, w_uv, pool, rbuf, slots, ctx, upto,
-                    softmax_scale(c))
-        return o @ self.o.astype(self.dtype), rbuf
-
-
-def flush_round(pool, rbuf, slots, pos0, keep, main_blocks):
-    """The round's first ``keep`` rows (``rbuf`` [blocks, B, R, d]) into
-    each row's slot of the pool, in place: the main blocks' at ``pos0``,
-    the prediction module's a position before (its cache trails by one). A
-    loop over the rows, guarded slice updates (``models/lfm2.py``
-    ``flush_round``). A row whose slot is past the pool (a padded batch
-    row) writes nothing."""
-    blocks, _, _, d = rbuf.shape
-    last = pool.shape[1] - 1
-
-    def row(i, pool):
-        for lo, hi, back in ((0, main_blocks, 0), (main_blocks, blocks, 1)):
-            new = jax.lax.dynamic_slice(
-                rbuf, (lo, i, 0, 0), (hi - lo, 1, keep, d)).astype(pool.dtype)
-            at = (lo, jnp.minimum(slots[i], last), pos0[i] - back, 0)
-            old = jax.lax.dynamic_slice(pool, at, new.shape)
-            pool = jax.lax.dynamic_update_slice(
-                pool, jnp.where(slots[i] <= last, new, old), at)
-        return pool
-
-    return jax.lax.fori_loop(0, rbuf.shape[1], row, pool)
 
 
 # -- the residual ------------------------------------------------------------
@@ -510,7 +246,7 @@ class Xing4Block(nn.Module):
         c = self.cfg
         self.attn_hc = HyperResidual(c, name="attn_hc")
         self.attn_norm = RmsNorm(c.norm_eps, self.dtype, name="attn_norm")
-        self.attn = MlaAttention(c, self.dtype, name="attn")
+        self.attn = MlaAttention(c.mla, self.dtype, name="attn")
         self.ffn_hc = HyperResidual(c, name="ffn_hc")
         self.ffn_norm = RmsNorm(c.norm_eps, self.dtype, name="ffn_norm")
         if self.dense:
@@ -711,6 +447,10 @@ class VideoMAEXing4(nn.Module):
 
     # what ``stream_head.serve_round`` asks of a head
     @nn.nowrap
+    def empty_counts(self):
+        return jnp.zeros((len(self.cfg.head.moe.held),), jnp.int32)
+
+    @nn.nowrap
     def seed_round(self, variables, state, slots, reset):
         """The latent pool (read and written in place, by slot) with the
         instruction's rows as the first of the slots that reset (the
@@ -718,24 +458,12 @@ class VideoMAEXing4(nn.Module):
         (gathered by slot), the instruction's for a stream that resets."""
         ins = variables["instruction"]
         n_i, main = len(self.cfg.instruction_ids), self.cfg.head.num_layers
-        pool, rows = state["latent"], ins["latent"].astype(
-            state["latent"].dtype)
-
-        last = pool.shape[1] - 1
-
-        def row(i, pool):
-            # a guarded slice update a row, as the flush makes them (one
-            # update of all slots at once has the TPU compiler copy the
-            # whole pool into another layout and back): the rows that
-            # reset, whose slots hold another context's rows
-            at = (0, jnp.minimum(slots[i], last), 0, 0)
-            old = jax.lax.dynamic_slice(pool, at, rows.shape)
-            keep = (jnp.arange(n_i) < n_i - 1)[None, None, :, None] | (
-                jnp.arange(rows.shape[0]) < main)[:, None, None, None]
-            new = jnp.where(reset[i] & (slots[i] <= last) & keep, rows, old)
-            return jax.lax.dynamic_update_slice(pool, new, at)
-
-        pool = jax.lax.fori_loop(0, slots.shape[0], row, pool)
+        rows = ins["latent"]
+        # the rows that reset, whose slots hold another context's rows; the
+        # module's block holds one row fewer
+        keep = (jnp.arange(n_i) < n_i - 1)[None, None, :, None] | (
+            jnp.arange(rows.shape[0]) < main)[:, None, None, None]
+        pool = seed_rows(state["latent"], rows, slots, reset, keep)
         exit_ = jnp.take(state["exit"], slots, axis=0, mode="clip")
         return pool, jnp.where(reset[:, None],
                                ins["exit"].astype(exit_.dtype), exit_)
@@ -879,4 +607,4 @@ def empty_latent(cfg: Xing4Config, rows: int, positions: int,
                  dtype=jnp.bfloat16):
     """Zeroed latent rows [blocks, rows, positions, r + d_r]: the pool
     (rows = slots), a round buffer (rows = the batch's)."""
-    return jnp.zeros((cfg.blocks, rows, positions, cfg.row_dim), dtype)
+    return mla.empty_latent(cfg.mla, cfg.blocks, rows, positions, dtype)
