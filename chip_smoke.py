@@ -259,6 +259,76 @@ def kernels_phase(seed: int) -> None:
               f"flash T={t}: outside the stated bf16 tolerance")
 
 
+def latent_prefill_phase(seed: int) -> None:
+    """The latent attention's prefill kernel at the MLA cells' shapes (a
+    chunk of 4 streams x 32 heads x 784 new positions over up to 3,328
+    cached rows of 640 in a pool of 64 slots), compiled by Mosaic and run,
+    against the plain softmax in float32 over each stream's context and new
+    rows (what the CPU tests compare the interpreted kernel with)."""
+    import jax
+    import jax.numpy as jnp
+
+    from video_edge_ai_proxy_tpu.models import mla
+
+    b, t, h, dn, dr, dv, r, row, cap = 4, 784, 32, 128, 64, 128, 512, 640, \
+        3328
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    bf = jnp.bfloat16
+    pad = lambda a: jnp.pad(  # noqa: E731
+        a, ((0, 0), (0, 0), (0, row - r - dr))).astype(bf)
+    q = jax.random.normal(k[0], (b, t, h, dn + dr)).astype(bf)
+    new = pad(jax.random.normal(k[1], (b, t, r + dr)))
+    pool = pad(jax.random.normal(k[2], (64, 4096, r + dr)))[None]
+    w_uk = (jax.random.normal(k[3], (r, h, dn)) * r ** -0.5).astype(bf)
+    w_uv = (jax.random.normal(k[4], (r, h, dv)) * r ** -0.5).astype(bf)
+    slots = jnp.asarray([5, 17, 0, 99])         # the last: a padded row
+    ctx = jnp.asarray([32, 1616, 3200, 257])
+    scale = (dn + dr) ** -0.5
+    # what lies past a context may be anything
+    for i in range(b):
+        pool = pool.at[0, min(int(slots[i]), 63), int(ctx[i]):].set(1e30)
+    compiled = jax.jit(
+        lambda *a: mla.mla_prefill_attention(*a, scale, cap, 0)).lower(
+        q, new, w_uk, w_uv, pool, slots, ctx).compile()
+    check("tpu_custom_call" in compiled.as_text(),
+          "latent prefill: no tpu_custom_call in the compiled program")
+    got = np.asarray(compiled(q, new, w_uk, w_uv, pool, slots, ctx),
+                     np.float32)
+    check(got.shape == (b, t, h * dv) and np.isfinite(got).all(),
+          f"latent prefill: shape {got.shape} or non-finite values")
+
+    @jax.jit
+    def plain(q, rows, n):
+        """One stream in float32: ``rows`` [cap + t, row], of which the
+        cached part counts up to ``n``."""
+        f = lambda a: a.astype(jnp.float32)  # noqa: E731
+        kv = lambda w: f(jnp.einsum(  # noqa: E731
+            "sr,rhd->shd", rows[:, :r], w))     # rounded as the kernel's
+        keys = jnp.concatenate(
+            [kv(w_uk), jnp.broadcast_to(f(rows[:, None, r:r + dr]),
+                                        (cap + t, h, dr))], -1)
+        s = jnp.einsum("thd,shd->hts", f(q), keys) * scale
+        at = jnp.arange(cap + t)[None]
+        seen = jnp.where(at < cap, at < n,
+                         at - cap <= jnp.arange(t)[:, None])
+        p = jax.nn.softmax(jnp.where(seen[None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("hts,shd->thd", p, kv(w_uv)).reshape(t, -1)
+
+    with jax.default_matmul_precision("highest"):
+        want = np.stack([np.asarray(plain(
+            q[i], jnp.concatenate(
+                [jnp.where(jnp.arange(cap)[:, None] < ctx[i],
+                           pool[0, min(int(slots[i]), 63), :cap], 0),
+                 new[i]]), ctx[i])) for i in range(b)])
+    err = np.abs(got - want)
+    say(f"kernel: latent prefill attention [{b},{t},{h},{dn}+{dr}] bf16 "
+        f"over depths {ctx.tolist()} vs the plain softmax in f32: max abs "
+        f"{err.max():.4f} mean abs {err.mean():.5f} (bounds {ATTN_MAX_ABS} "
+        f"/ {ATTN_MEAN_ABS}; output rms {np.sqrt((want ** 2).mean()):.3f})")
+    check(err.max() <= ATTN_MAX_ABS and err.mean() <= ATTN_MEAN_ABS,
+          "latent prefill: outside the stated bf16 tolerance")
+
+
 def stream_head_phase(seed: int, name: str = "tiny_videomae_lfm2") -> None:
     """The ``stream`` step kind on the chip at a tiny twin's size: two
     rounds of two streams through ``build_serving_step`` and a
@@ -903,6 +973,7 @@ def main(argv=None) -> int:
         facts = four_chip_phase(args.seed)
     else:
         kernels_phase(args.seed)
+        latent_prefill_phase(args.seed)
         stream_head_phase(args.seed)
         stream_head_phase(args.seed, "tiny_videomae_xing4")
         stream_head_phase(args.seed, "tiny_videomae_dsv2")

@@ -504,6 +504,54 @@ STEP_SCOPES = ("pre_cast_scale", "pre_resize", "pre_normalize", "embed",
                "encoder_block", "head", "softmax_topk")
 
 
+def test_a_stream_batch_carries_what_its_prefill_attention_visited(bus):
+    """A stream head's batch (``tiny_videomae_xing4``: latent attention):
+    the step's count of the tiles its prefill attention visited, and of
+    what a dense pass would visit, on every record of the batch and in
+    ``vep_attn_key_blocks_total``."""
+    from video_edge_ai_proxy_tpu.models import registry as models
+
+    model = "tiny_videomae_xing4"
+    cams = ["clip0", "clip1"]
+    for cam in cams:
+        bus.create_stream(cam, F)
+    eng = InferenceEngine(
+        bus, EngineConfig(model=model, batch_buckets=(2,), tick_ms=5,
+                          stage_trace=True, ladder=False),
+        annotations=AnnotationQueue(handler=lambda b: True))
+    eng.warmup()
+    fam = {f.name: f for f in registry.families()}
+    blocks = fam["vep_attn_key_blocks_total"]
+    before = {k: blocks.labels(k).value for k in ("live", "dense")}
+    eng.start()
+    try:
+        k, deadline = 0, time.time() + 90
+        while len(eng.stage_records) < 3 * len(cams) \
+                and time.time() < deadline:
+            k += 1
+            for cam in cams:
+                _publish(bus, cam, value=k % 250)
+            time.sleep(0.05)
+    finally:
+        eng.stop()
+    recs = [r for r in eng.stage_records if "attn_blocks_dense" in r]
+    assert len(recs) >= 3 * len(cams)
+    c = models.get(model).build().cfg
+    # tiny widths: the new positions one key block and one lane tile of
+    # queries, what can be cached one key block: 2 tiles a stream an
+    # attention dense, 1 or 2 live
+    dense = 2 * c.head.num_layers * 2
+    for rec in recs:
+        assert rec["attn_blocks_dense"] == dense
+        assert dense // 2 <= rec["attn_blocks_live"] <= dense
+    batches = {tuple(r["batch"]): r for r in recs}.values()
+    # a context holds the instruction at least: its key block is visited
+    assert all(r["attn_blocks_live"] == dense for r in batches)
+    for kind in ("live", "dense"):
+        assert blocks.labels(kind).value - before[kind] == sum(
+            r[f"attn_blocks_{kind}"] for r in batches)
+
+
 @pytest.mark.parametrize("model,shape", [
     ("tiny_vit", (2, H, W, 3)), ("tiny_videomae", (2, L, H, W, 3))])
 def test_the_compiled_step_names_its_stages(model, shape):

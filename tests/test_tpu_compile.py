@@ -110,6 +110,32 @@ class TestFlashAttentionKernel:
         assert text.count("tpu_custom_call") >= 3
 
 
+class TestLatentPrefillKernel:
+    """The MLA cells' shapes: a chunk of 8 (``xing64_360p``) or 4
+    (``dsv2_64_360p``) streams x 32 heads x 784 new positions over up to
+    3,328 cached rows of 640 numbers, read from one block of a pool that
+    is handed over whole."""
+
+    @pytest.mark.parametrize("chunk,blocks", [(8, 6), (4, 5)])
+    def test_it_compiles_and_copies_no_block_of_the_pool(
+            self, v5e, monkeypatch, chunk, blocks):
+        from video_edge_ai_proxy_tpu.models import mla
+
+        _as_if_on_tpu(monkeypatch)
+        bf = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
+                               sharding=v5e)
+        i32 = jax.ShapeDtypeStruct((chunk,), jnp.int32, sharding=v5e)
+        compiled = jax.jit(
+            lambda *a: mla.mla_prefill_attention(*a, 0.07, 3328, 2)).lower(
+            bf((chunk, 784, 32, 192)), bf((chunk, 784, 640)),
+            bf((512, 32, 128)), bf((512, 32, 128)),
+            bf((blocks, 64, 4096, 640)), i32, i32).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        # a block of the pool is 335 MB: nothing of that size beside the
+        # arguments (a slice handed to the kernel would be a copy of it)
+        assert compiled.memory_analysis().temp_size_in_bytes < 300 << 20
+
+
 def _as_if_on_tpu(monkeypatch):
     """Steer the backend-keyed choices (ops/nms.py ``batched_nms``,
     models/transformer.py ``auto_attention``, each kernel's ``interpret``

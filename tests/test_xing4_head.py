@@ -109,34 +109,136 @@ def test_both_attention_paths_give_the_plain_form():
     assert not np.asarray(rbuf)[..., 24:].any()
 
 
-def test_prefill_attends_only_the_quarters_a_context_reaches_into():
-    """Three streams whose contexts end in the first, second and last
-    quarter of what can be cached: each takes its own branch of the
-    switch, and each is the plain softmax over its context and the new
-    rows."""
-    h, dn, dr, dv, r, t, cap = 2, 8, 4, 8, 16, 5, 1024
+def _prefill_case(name):
+    """(sizes, slots, ctx, what the rows past ``ctx`` hold) of one case of
+    the test below; a key block of the cache is 256 rows at ``cap`` 1024
+    (``ops/flash_attention.py`` ``latent_prefill_blocks``)."""
+    small = dict(h=2, dn=8, dr=4, dv=8, r=16, row=128, t=5, cap=1024,
+                 slots_in_pool=7, positions=1200)
+    edges = ([5, 0, 3, 6, 1, 2], [0, 1, 255, 256, 257, 1024])
+    return {
+        # contexts that end in the first, second and last quarter of what
+        # can be cached
+        "attends_only_the_quarters_a_context_reaches_into":
+            (small, [2, 0, 3], [7, 300, 1000], None),
+        # none, one, one under / at / one over a key block's edge, all
+        "edges_of_a_key_block": (small, *edges, None),
+        # what lies past a context never reaches a result
+        "rows_past_the_context_hold_1e30": (small, *edges, 1e30),
+        "rows_past_the_context_hold_nan": (small, *edges, np.nan),
+        # a padded batch row's slot is past the pool: it reads the last
+        "a_padded_row_reads_some_slot": (small, [2, 7, 99], [7, 300, 40],
+                                         None),
+        # the published widths through the interpreter
+        "published_widths": (dict(h=2, dn=128, dr=64, dv=128, r=512,
+                                  row=640, t=7, cap=256, slots_in_pool=2,
+                                  positions=300), [1, 0], [100, 256], None),
+    }[name]
+
+
+@pytest.mark.parametrize("case", [
+    "attends_only_the_quarters_a_context_reaches_into",
+    "edges_of_a_key_block", "rows_past_the_context_hold_1e30",
+    "rows_past_the_context_hold_nan", "a_padded_row_reads_some_slot",
+    "published_widths"])
+def test_prefill(case):
+    """Streams in different slots at different depths: each is the plain
+    float32 softmax over its context and, causally, the new rows."""
+    z, slots, ctx, garbage = _prefill_case(case)
+    h, dn, dr, dv, r, t, cap = (z[k] for k in
+                                ("h", "dn", "dr", "dv", "r", "t", "cap"))
+    n_s, b = z["slots_in_pool"], len(slots)
     k = jax.random.split(jax.random.PRNGKey(0), 6)
-    q = jax.random.normal(k[0], (3, t, h, dn + dr))
-    new = jax.random.normal(k[1], (3, t, 128))
-    pool = jax.random.normal(k[2], (4, 1200, 128))
-    w_uk = jax.random.normal(k[3], (r, h, dn)) * 0.3
-    w_uv = jax.random.normal(k[4], (r, h, dv)) * 0.3
-    slots, ctx = jnp.asarray([2, 0, 3]), jnp.asarray([7, 300, 1000])
-    got = mla.mla_prefill_attention(q, new, w_uk, w_uv, pool, slots, ctx,
-                                    0.3, cap)
-    for b in range(3):
-        n = int(ctx[b])
-        rows = jnp.concatenate([pool[slots[b], :n], new[b]], axis=0)
+    q = jax.random.normal(k[0], (b, t, h, dn + dr))
+    new = jax.random.normal(k[1], (b, t, z["row"]))
+    pool = jax.random.normal(k[2], (n_s, z["positions"], z["row"]))
+    w_uk = jax.random.normal(k[3], (r, h, dn)) * r ** -0.5
+    w_uv = jax.random.normal(k[4], (r, h, dv)) * r ** -0.5
+    held = [min(s, n_s - 1) for s in slots]
+    given = pool
+    if garbage is not None:
+        for s, n in zip(held, ctx):
+            given = given.at[s, n:].set(garbage)
+    got = mla.mla_prefill_attention(
+        q, new, w_uk, w_uv, given, jnp.asarray(slots), jnp.asarray(ctx),
+        0.3, cap)
+    assert got.shape == (b, t, h * dv)
+    for i, (s, n) in enumerate(zip(held, ctx)):
+        rows = jnp.concatenate([pool[s, :n], new[i]], axis=0)
         keys = jnp.concatenate(
             [jnp.einsum("sr,rhd->shd", rows[:, :r], w_uk),
              jnp.broadcast_to(rows[:, None, r:r + dr], (n + t, h, dr))], -1)
-        s = jnp.einsum("thd,shd->hts", q[b], keys) * 0.3
+        s_ = jnp.einsum("thd,shd->hts", q[i], keys) * 0.3
         mask = jnp.arange(n + t)[None] <= n + jnp.arange(t)[:, None]
-        p = jax.nn.softmax(jnp.where(mask[None], s, -jnp.inf), axis=-1)
+        p = jax.nn.softmax(jnp.where(mask[None], s_, -jnp.inf), axis=-1)
         want = jnp.einsum("hts,shd->thd", p, jnp.einsum(
             "sr,rhd->shd", rows[:, :r], w_uv)).reshape(t, -1)
-        np.testing.assert_allclose(np.asarray(got[b]), np.asarray(want),
+        np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want),
                                    atol=2e-5)
+
+
+def test_prefill_counts_the_tiles_it_visits():
+    """The step's own count against a hand count. A tile is a key block
+    against a lane tile of 128 queries. 12 new positions are one key block
+    and one lane tile; 1024 cacheable positions are four key blocks of
+    256: a context of 0 / 1 / 256 / 257 / 1024 visits 0 / 1 / 1 / 2 / 4 of
+    them, in each of 3 attentions."""
+    from video_edge_ai_proxy_tpu.ops.flash_attention import (
+        latent_prefill_blocks)
+
+    assert latent_prefill_blocks(12, 1024) == (12, 256)
+    got = mla.prefill_visits(jnp.asarray([0, 1, 256, 257, 1024]), 12, 1024,
+                             3)
+    assert int(got["attn_blocks_live"]) == 3 * ((0 + 1 + 1 + 2 + 4) + 5)
+    assert int(got["attn_blocks_dense"]) == 3 * 5 * (4 + 1)
+    # the cells' shapes: 784 queries are 7 lane tiles; 3,328 cacheable
+    # positions 13 key blocks; the new rows 7 key blocks of 112, whose
+    # first keys lie in lane tiles 0, 0, 1, 2, 3, 4, 5: they are scored
+    # against 7, 7, 6, 5, 4, 3, 2 lane tiles of queries, 34 of 49
+    assert latent_prefill_blocks(784, 3328) == (112, 256)
+    got = mla.prefill_visits(jnp.asarray([32, 1616, 3200]), 784, 3328, 1)
+    assert int(got["attn_blocks_live"]) == (1 + 7 + 13) * 7 + 3 * 34
+    assert int(got["attn_blocks_dense"]) == 3 * (13 + 7) * 7
+    # nothing cacheable (the instruction through a fresh state)
+    got = mla.prefill_visits(jnp.asarray([0]), 32, 0, 2)
+    assert (int(got["attn_blocks_live"]), int(got["attn_blocks_dense"])) \
+        == (2, 2)
+
+
+def test_a_holder_of_some_heads_gives_their_partial_sum():
+    """Told which heads it holds (the DeepSeek-V2 form), the attention is
+    those heads' share of the whole: two holders' outputs add up to the
+    output of one that holds all four, on both paths."""
+    cfg = dataclasses.replace(xing4.tiny_stream_head_config().head.mla,
+                              num_heads=4)
+    dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                  cfg.v_head_dim)
+    whole = mla.MlaAttention(cfg, dtype=jnp.float32)
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, 6, cfg.dim))
+    pool = jax.random.normal(jax.random.PRNGKey(2), (2, 3, 40, cfg.row_dim))
+    rbuf = jnp.zeros((2, 9, cfg.row_dim))
+    slots, ctx = jnp.asarray([2, 0]), jnp.asarray([17, 33])
+    params = flax.core.meta.unbox(whole.init(
+        jax.random.PRNGKey(3), h, pool, rbuf, slots, ctx, None, 40, 1))
+
+    def share(held):
+        p = dict(params["params"])
+        cols = lambda w, d: np.asarray(w).reshape(  # noqa: E731
+            w.shape[0], 4, d)[:, list(held)].reshape(w.shape[0], -1)
+        p["q_b"], p["kv_b"] = cols(p["q_b"], dn + dr), cols(p["kv_b"],
+                                                             dn + dv)
+        p["o"] = np.asarray(p["o"]).reshape(4, dv, -1)[list(held)].reshape(
+            len(held) * dv, -1)
+        return mla.MlaAttention(dataclasses.replace(cfg, heads_held=held),
+                                dtype=jnp.float32), {"params": p}
+
+    for at, cap in ((None, 40), (jnp.asarray([6, 6]), 0)):
+        want, _ = whole.apply(params, h, pool, rbuf, slots, ctx, at, cap, 1)
+        parts = [m.apply(p, h, pool, rbuf, slots, ctx, at, cap, 1)[0]
+                 for m, p in (share((1, 3)), share((0, 2)))]
+        assert float(jnp.abs(parts[0]).max()) > 1e-3
+        np.testing.assert_allclose(np.asarray(parts[0] + parts[1]),
+                                   np.asarray(want), atol=2e-5)
 
 
 def test_yarn_is_the_published_blend():

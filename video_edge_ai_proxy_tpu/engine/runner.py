@@ -1071,6 +1071,12 @@ class InferenceEngine:
             "vep_moe_group_hits_total",
             "Tokens, a routed layer, whose kept expert groups include a "
             "group held here (group-limited routers)").labels()
+        attn = obs_registry.counter(
+            "vep_attn_key_blocks_total",
+            "Tiles (a key block against 128 queries) a head's prefill "
+            "attention visited (live) and what a pass over every key it "
+            "could hold would visit (dense)", ("kind",))
+        self._m_attn_blocks = {k: attn.labels(k) for k in ("live", "dense")}
         mtp = obs_registry.counter(
             "vep_mtp_drafts_total",
             "Drafts of a stream head's prediction module, verified by the "
@@ -4168,6 +4174,13 @@ class InferenceEngine:
             inflight.tr.update(moe_pairs_total=routed, moe_group_hits=hits)
             self._m_moe_routed.inc(routed)
             self._m_moe_group_hits.inc(hits)
+        if "attn_blocks_live" in host:
+            # a head whose prefill attention skips the key blocks past a
+            # stream's context and above the causal diagonal
+            for kind in ("live", "dense"):
+                n = int(host.pop(f"attn_blocks_{kind}"))
+                inflight.tr[f"attn_blocks_{kind}"] = n
+                self._m_attn_blocks[kind].inc(n)
         if "decode_iters" in host:
             inflight.tr["head_decode_iters"] = int(host.pop("decode_iters"))
         if "mtp_drafted" in host:

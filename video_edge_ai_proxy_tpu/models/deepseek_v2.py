@@ -185,10 +185,12 @@ class DeepseekV2Block(nn.Module):
         else:
             self.moe = TopKMoeMlp(c.moe, self.dtype, name="moe")
 
-    def __call__(self, x, pool, rbuf, slots, ctx, at, cap):
+    def __call__(self, x, pool, rbuf, slots, ctx, at, cap, block):
+        """``pool`` the whole latent pool, ``block`` this block's index in
+        it; ``rbuf`` this block's own."""
         b, t, d = x.shape
         y, rbuf = self.attn(self.attn_norm(x), pool, rbuf, slots, ctx, at,
-                            cap)
+                            cap, block)
         x = x + y
         h = self.ffn_norm(x)
         if self.dense:
@@ -239,7 +241,7 @@ class DeepseekV2Stack(nn.Module):
         counts = empty_counts(self.cfg)
         x = x.astype(self.dtype)
         for i, block in enumerate(self.layers):
-            x, rows, n = block(x, pool[i], rbuf[i], slots, ctx, at, cap)
+            x, rows, n = block(x, pool, rbuf[i], slots, ctx, at, cap, i)
             rbuf = rbuf.at[i].set(rows)
             counts = counts if n is None else _add(counts, n)
         return x, rbuf, counts
@@ -338,9 +340,10 @@ class VideoMAEDeepseekV2(nn.Module):
     def _cap(self) -> int:
         """Positions that can be context when a round starts (the pool
         resets a stream whose round would pass ``max_context``), up to a
-        lane tile."""
+        lane tile, within the pool."""
         c = self.cfg
-        return -(-(c.head.max_context - c.round_positions) // 128) * 128
+        return min(-(-(c.head.max_context - c.round_positions) // 128) * 128,
+                   c.head.max_context)
 
     @nn.nowrap
     def prefill(self, variables, x, pool, rows, rbuf, slots, pos0):
@@ -376,6 +379,10 @@ class VideoMAEDeepseekV2(nn.Module):
                 "rbuf": rbuf, "moe_load": counts["held"],
                 "moe_pairs_total": counts["pairs"],
                 "moe_group_hits": counts["group_hits"],
+                # what the round's prefill attention visited, summed over
+                # its chunks and blocks
+                **mla.prefill_visits(pos0, c.visual_tokens, self._cap,
+                                     c.head.num_layers),
                 "decode_iters": jnp.asarray(c.decode_steps, jnp.int32)}
 
     @nn.nowrap

@@ -15,8 +15,9 @@ alone (no head's own config reaches it):
   mscale_all_dim``; scores scaled by ``(d_n + d_r)^-½ ·
   yarn_mscale(factor, mscale_all_dim)²``.
 - Two paths over the one cache: :func:`mla_prefill_attention` up-projects
-  the cached rows it attends to per-head keys and values (per stream,
-  never stored); :func:`mla_decode_attention` folds ``W_kvb``'s key half
+  the cached rows it attends to per-head keys and values (a key block at
+  a time inside one kernel a chunk, never in memory);
+  :func:`mla_decode_attention` folds ``W_kvb``'s key half
   into the query and its value half into the output and attends over the
   latent rows as they lie.
 - **Told which heads it holds** (``heads_held``: their ids among the
@@ -44,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.flash_attention import (
+    latent_prefill_attention, latent_prefill_visits)
 from .common import Dtype
 from .stream_head import RmsNorm, _kernel
 
@@ -131,70 +134,48 @@ def softmax_scale(cfg: MlaConfig) -> float:
 
 # -- the two attention paths over the one latent cache ------------------------
 
-def mla_prefill_attention(q, new, w_uk, w_uv, pool, slots, ctx, scale, cap):
+def mla_prefill_attention(q, new, w_uk, w_uv, pool, slots, ctx, scale, cap,
+                          block=None):
     """A round's first T positions: ``q`` [B, T, H, d_n + d_r] (the rope
     part roped) against each row's slot of the pool (``pool`` [slots, S,
-    row]: the positions before ``ctx`` [B] are the stream's context; the
-    rest is stale or unwritten, and masked; only the first ``cap`` can be
-    context when a round starts) and, causally, against the T new rows
-    themselves (``new`` [B, T, row]). The cached rows are up-projected to
-    per-head keys and values (``w_uk`` [r, H, d_n], ``w_uv`` [r, H, d_v])
-    here, one stream at a time, so one stream's [cap, H, d] keys and its
-    [H, T, cap] and [H, T, T] scores are all that is held; and of the
-    cached rows only as many quarters of ``cap`` as the stream's context
-    reaches into (a switch a stream: the de-phased fleet holds every
-    depth, and a fresh context has one quarter to attend, not four). The
-    two partial softmaxes are merged by their maxima and sums."""
+    row], or the whole [blocks, slots, S, row] with ``block`` naming this
+    attention's: the positions before ``ctx`` [B] are the stream's
+    context; the rest is stale or unwritten, and never reaches a result;
+    only the first ``cap`` can be context when a round starts) and,
+    causally, against the T new rows themselves (``new`` [B, T, row]).
+    One kernel a chunk (``ops/flash_attention.py``
+    :func:`latent_prefill_attention`): a (stream, head) a program, the
+    stream's slot read where it lies, whole rows; a key block of cached
+    rows is up-projected to that head's keys and values (``w_uk`` [r, H,
+    d_n], ``w_uv`` [r, H, d_v]) on the chip and scored under one running
+    softmax with the new rows' blocks, so no per-head key, value or score
+    is ever in memory; the blocks past ``ctx`` are not visited, and a new
+    rows' block only by the queries that can see it. The keys' rope part
+    is the rows' own tail ``rows[:, r:]``, shared by the heads: the query
+    is laid out [q_n | q_r | 0] against [k_n | that tail], one query a
+    column (the kernel holds its scores keys-major). Returns [B, T, H *
+    d_v]."""
     b, t, h, _ = q.shape
     r, dn = w_uk.shape[0], w_uk.shape[-1]
-    dr = q.shape[-1] - dn
-    causal = jnp.tril(jnp.ones((t, t), bool))[None, None]
+    tail = new.shape[-1] - r - (q.shape[-1] - dn)
+    q = jnp.pad(q, ((0, 0),) * 3 + ((0, tail),)).transpose(0, 2, 3, 1)
+    if block is None:
+        pool, block = pool[None], 0
+    o = latent_prefill_attention(
+        q, new, pool, w_uk.transpose(1, 0, 2), w_uv.transpose(1, 2, 0),
+        slots, ctx, block, scale=scale, rank=r, cap=cap)
+    return o.transpose(0, 3, 1, 2).reshape(b, t, -1)
 
-    def part(qc, rows, mask):
-        """(max, sum, unnormalised output) of one stream's softmax over
-        ``rows`` [1, S, row], the rope part shared by the heads."""
-        c_rows = rows[..., :r]
-        keys = jnp.concatenate(
-            [jnp.einsum("bsr,rhd->bshd", c_rows, w_uk),
-             jnp.broadcast_to(rows[:, :, None, r:r + dr],
-                              rows.shape[:2] + (h, dr))], axis=-1)
-        s = jnp.einsum("bthd,bshd->bhts", qc, keys).astype(
-            jnp.float32) * scale
-        s = jnp.where(mask, s, -1e30)
-        m = jnp.max(s, axis=-1)
-        e = jnp.exp(s - m[..., None])
-        o = jnp.einsum("bhts,bshd->bthd", e.astype(qc.dtype),
-                       jnp.einsum("bsr,rhd->bshd", c_rows, w_uv),
-                       preferred_element_type=jnp.float32)
-        return m, jnp.sum(e, axis=-1), o
 
-    step = -(-cap // (4 * 128)) * 128 if cap else 0
-    depths = sorted({min(cap, step * k) for k in range(1, 5)}) if cap else []
-
-    def one(args):
-        # a leading axis of one stream: the batched products below are the
-        # form the TPU compiler lays out well (models/lfm2.py)
-        qc, nw, sc, cc = (a[None] for a in args)
-        m, total, o = part(qc, nw, causal)
-        if cap:
-            def cached(depth):
-                rows = jnp.take(pool, sc, axis=0, mode="clip")[:, :depth]
-                seen = jnp.arange(depth)[None, :] < cc[:, None]
-                return part(qc, rows, seen[:, None, None])
-
-            m_a, l_a, o_a = jax.lax.switch(
-                jnp.clip(-(-cc[0] // step) - 1, 0, len(depths) - 1),
-                [lambda d=d: cached(d) for d in depths])
-            m_b, total_b, o_b = m, total, o
-            m = jnp.maximum(m_a, m_b)
-            w_a, w_b = jnp.exp(m_a - m), jnp.exp(m_b - m)
-            total = l_a * w_a + total_b * w_b
-            o = (o_a * w_a.transpose(0, 2, 1)[..., None]
-                 + o_b * w_b.transpose(0, 2, 1)[..., None])
-        o = o / total.transpose(0, 2, 1)[..., None]
-        return o.astype(qc.dtype).reshape(t, -1)
-
-    return jax.lax.map(one, (q, new, slots, ctx))
+def prefill_visits(ctx, t: int, cap: int, blocks: int):
+    """{"attn_blocks_live", "attn_blocks_dense"}: the tiles (a key block
+    against a lane tile of 128 queries) :func:`mla_prefill_attention`
+    visits in ``blocks`` attentions over a batch whose contexts hold
+    ``ctx`` [B] positions, by the kernel's own rule, and what passes over
+    all ``cap + t`` keys would visit."""
+    live, dense = latent_prefill_visits(ctx, t, cap)
+    return {"attn_blocks_live": live * blocks,
+            "attn_blocks_dense": dense * blocks}
 
 
 def mla_decode_attention(q_n, q_r, w_uk, w_uv, pool, rbuf, slots, ctx, upto,
@@ -266,9 +247,11 @@ def write_rows(rbuf, new, at):
 
 class MlaAttention(nn.Module):
     """Latent attention over a stream's context in the latent pool (read
-    only: ``pool`` [slots, S, r + d_r]) and this round's own rows in the
-    round buffer ``rbuf`` [B, R, r + d_r], written to the pool once, when
-    the round is over (:func:`flush_round`). ``q_b``, ``kv_b`` and ``o``
+    only: ``pool`` [blocks, slots, S, row], of which this attention's is
+    ``pool[block]``; a pool of one block may come as [slots, S, row]) and
+    this round's own rows in the round buffer ``rbuf`` [B, R, row],
+    written to the pool once, when the round is over
+    (:func:`flush_round`). ``q_b``, ``kv_b`` and ``o``
     hold the ``cfg.held`` heads' slices and the output is their partial
     sum (module docstring)."""
     cfg: MlaConfig
@@ -300,7 +283,7 @@ class MlaAttention(nn.Module):
              jnp.zeros(h.shape[:-1] + (c.row_dim - c.latent_dim,),
                        self.dtype)], axis=-1)
 
-    def __call__(self, h, pool, rbuf, slots, ctx, at, cap):
+    def __call__(self, h, pool, rbuf, slots, ctx, at, cap, block=None):
         """``at`` None: a prefill, whose T positions start the round's
         buffer; else [B], where each row's T decode positions go."""
         c = self.cfg
@@ -325,14 +308,15 @@ class MlaAttention(nn.Module):
                     rbuf, new, 0, axis=1)
                 o = mla_prefill_attention(
                     q, new, w_uk, w_uv, pool, slots, ctx, softmax_scale(c),
-                    min(cap, pool.shape[1]))
+                    min(cap, pool.shape[-2]), block)
         else:
             with jax.named_scope("mla_decode"):
                 rbuf = write_rows(rbuf, new, at)
                 upto = at[:, None] + jnp.arange(t, dtype=at.dtype)[None]
                 o = mla_decode_attention(
-                    q_n, q_r, w_uk, w_uv, pool, rbuf, slots, ctx, upto,
-                    softmax_scale(c))
+                    q_n, q_r, w_uk, w_uv,
+                    pool if block is None else pool[block], rbuf, slots,
+                    ctx, upto, softmax_scale(c))
         return o @ self.o.astype(self.dtype), rbuf
 
 

@@ -260,11 +260,13 @@ class Xing4Block(nn.Module):
         pre, _, _ = self.attn_hc(x)
         return self.attn.latent(self.attn_norm(hc_read(pre, x)), pos)
 
-    def __call__(self, x, pool, rbuf, slots, ctx, at, cap):
+    def __call__(self, x, pool, rbuf, slots, ctx, at, cap, block):
+        """``pool`` the whole latent pool, ``block`` this block's index in
+        it; ``rbuf`` this block's own."""
         b, t = x.shape[1:3]
         pre, post, res = self.attn_hc(x)
         y, rbuf = self.attn(self.attn_norm(hc_read(pre, x)), pool, rbuf,
-                            slots, ctx, at, cap)
+                            slots, ctx, at, cap, block)
         x = hc_write(res, post, x, y)
         pre, post, res = self.ffn_hc(x)
         h = self.ffn_norm(hc_read(pre, x))
@@ -330,7 +332,7 @@ class Xing4Stack(nn.Module):
         load = jnp.zeros((len(self.cfg.moe.held),), jnp.int32)
         x = self._enter(x)
         for i, block in enumerate(self.layers):
-            x, rows, n = block(x, pool[i], rbuf[i], slots, ctx, at, cap)
+            x, rows, n = block(x, pool, rbuf[i], slots, ctx, at, cap, i)
             rbuf, load = rbuf.at[i].set(rows), load + n
         return self._exit(x), rbuf, load
 
@@ -353,8 +355,8 @@ class Xing4Stack(nn.Module):
         """The module on T decode positions: (its exit states [B, T, C],
         rbuf, load)."""
         i = self.cfg.num_layers
-        x, rows, n = self.mtp_block(self._mtp_input(h, e), pool[i], rbuf[i],
-                                    slots, ctx, at, 0)
+        x, rows, n = self.mtp_block(self._mtp_input(h, e), pool, rbuf[i],
+                                    slots, ctx, at, 0, i)
         return self._exit(x), rbuf.at[i].set(rows), n
 
 
@@ -479,9 +481,10 @@ class VideoMAEXing4(nn.Module):
     def _cap(self) -> int:
         """Positions that can be context when a round starts (the pool
         resets a stream whose round would pass ``max_context``), up to a
-        lane tile."""
+        lane tile, within the pool."""
         c = self.cfg
-        return -(-(c.head.max_context - c.round_positions) // 128) * 128
+        return min(-(-(c.head.max_context - c.round_positions) // 128) * 128,
+                   c.head.max_context)
 
     @nn.nowrap
     def prefill(self, variables, x, pool, exit_, rbuf, slots, pos0):
@@ -592,6 +595,11 @@ class VideoMAEXing4(nn.Module):
                 "rbuf": s["rbuf"], "moe_load": s["load"],
                 "mtp_drafted": s["drafted"], "mtp_accepted": s["accepted"],
                 "decode_iters": s["iters"],
+                # what the round's prefill attention visited, summed over
+                # its chunks and blocks (the module's block prefills rows
+                # alone)
+                **mla.prefill_visits(pos0, n_v, self._cap,
+                                     c.head.num_layers),
                 "draft_ids": first[1], "draft_probs": first[2]}
 
     @nn.nowrap

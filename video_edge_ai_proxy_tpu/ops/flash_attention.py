@@ -15,6 +15,12 @@ denominator / accumulator, so the result is exact dense attention.
 Drop-in `attn_fn` for `models/transformer.Encoder` ([B, T, H, D] in/out,
 non-causal, like `default_attention`). The XLA twin used off-TPU is the
 same math via `interpret=True`.
+
+Beside it, :func:`latent_prefill_attention`: the same running softmax over
+a latent cache (`models/mla.py`): a stream's cached rows read from its slot
+of the pool as they lie, up-projected to one head's keys and values a key
+block at a time inside the kernel, the stream's depth a prefetched scalar
+that decides how many key blocks a program visits.
 """
 
 from __future__ import annotations
@@ -27,8 +33,24 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
+
+
+def _softmax_block(m, l, logits, keys=-1):
+    """One key block's scores into a running softmax's statistics, all
+    float32: ``m``, ``l`` the running maximum and sum, ``logits`` the
+    block's scores (masked ones at ``_NEG``), ``keys`` their key axis (-1,
+    queries down the rows: m, l [bq, 1]; 0, keys down the rows: m, l [1,
+    bq], so that the reductions run down the sublanes and the statistics
+    lie along the lanes). Returns (m, l, alpha, p): the statistics after
+    the block, the factor the accumulator so far shrinks by, the block's
+    weights to add ``p v`` with."""
+    m_new = jnp.maximum(m, logits.max(axis=keys, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(logits - m_new)
+    return m_new, l * alpha + p.sum(axis=keys, keepdims=True), alpha, p
 
 
 def _key_mask_logits(logits, base, block, true_t):
@@ -48,12 +70,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     tp = k_ref.shape[1]
     scale = d ** -0.5
 
-    m0 = jnp.full((bq,), _NEG, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
+    m0 = jnp.full((bq, 1), _NEG, jnp.float32)
+    l0 = jnp.zeros((bq, 1), jnp.float32)
     a0 = jnp.zeros((bq, d), jnp.float32)
 
     def body(i, carry):
-        m, l, acc = carry
         k_blk = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
         v_blk = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
         logits = jax.lax.dot_general(
@@ -61,20 +82,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
             preferred_element_type=jnp.float32,
         ) * scale                                   # [bq, bk]
         logits = _key_mask_logits(logits, i * block_k, block_k, true_t)
-        m_new = jnp.maximum(m, logits.max(axis=-1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(logits - m_new[:, None])
-        l_new = l * alpha + p.sum(axis=-1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
+        m, l, acc = carry
+        m, l, alpha, p = _softmax_block(m, l, logits)
+        return m, l, acc * alpha + jax.lax.dot_general(
             p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc_new
+            preferred_element_type=jnp.float32)
 
     m, l, acc = lax.fori_loop(0, tp // block_k, body, (m0, l0, a0))
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[0] = (m + jnp.log(l_safe))[:, None]
+    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+    lse_ref[0] = m + jnp.log(l_safe)
 
 
 @functools.partial(
@@ -303,3 +320,207 @@ def flash_attention(
     block_q = max(8, -(-min(block_q, max(8, t)) // 8) * 8)
     block_k = max(8, -(-min(block_k, max(8, t)) // 8) * 8)
     return _flash(block_q, block_k, interpret, q, k, v)
+
+
+# -- prefill over a latent cache (models/mla.py) ------------------------------
+
+_LANES = 128
+
+
+def _block(n: int, limit: int) -> int:
+    """The largest divisor of ``n`` up to ``limit`` that is a whole number
+    of lane tiles (128), else of sublane tiles (16: a bfloat16 tile's
+    rows), else ``n`` itself as one block."""
+    for tile in (_LANES, 16):
+        for d in range(min(limit, n) // tile * tile, 0, -tile):
+            if n % d == 0:
+                return d
+    return n
+
+
+def latent_prefill_blocks(t: int, cap: int) -> tuple:
+    """(block_t, block_k) from the shapes alone: the key blocks of the T
+    new positions' rows and of the ``cap`` cached positions."""
+    return _block(t, _LANES), _block(cap, 2 * _LANES) if cap else 0
+
+
+def _first_query(k0: int) -> int:
+    """The first query a program scores against new-row keys from ``k0``
+    on: the queries before ``k0`` do not see them, and a block of queries
+    starts at a lane tile."""
+    return k0 // _LANES * _LANES
+
+
+def latent_prefill_visits(ctx, t: int, cap: int):
+    """(live, dense): the tiles (a key block against a lane tile of 128
+    queries) the kernel visits for streams whose contexts hold ``ctx`` [B]
+    positions, summed over them, and what a pass over all ``cap + t`` keys
+    would visit. The kernel's own rule: a cached key block is visited,
+    against every query, if it starts before ``ctx``; a new rows' key block
+    against the queries from the lane tile that holds its first key on."""
+    block_t, block_k = latent_prefill_blocks(t, cap)
+    columns = -(-t // _LANES)
+    new = sum(-(-(t - _first_query(k0)) // _LANES)
+              for k0 in range(0, t, block_t))
+    cached = -(-jnp.clip(ctx, 0, cap) // block_k) if cap else 0 * ctx
+    live = jnp.sum(cached * columns + new).astype(jnp.int32)
+    blocks = (cap // block_k if cap else 0) + t // block_t
+    return live, jnp.asarray(ctx.shape[0] * blocks * columns, jnp.int32)
+
+
+def _latent_prefill_kernel(slots_ref, ctx_ref, block_ref, q_ref, new_ref,
+                           *refs, scale: float, rank: int, block_t: int,
+                           block_k: int):
+    """One (stream, head), the scores keys-major ([keys, queries]: the
+    softmax's reductions run down the sublanes and its statistics lie along
+    the lanes). q [d_nope + tail, T] (``tail`` = row - rank: the rope part,
+    zero past it); new [T, row] the round's own rows; pool [cap, row] the
+    stream's slot (absent where nothing can be cached); wk [rank, d_nope]
+    and wv [d_v, rank] the head's up-projection; o [d_v, T]; m, l [1, T]
+    and acc [d_v, T] the running softmax, float32; s, p [block, T] a key
+    block's scores and weights."""
+    del slots_ref, block_ref            # the index maps read them
+    pool_ref = refs[0] if len(refs) == 9 else None
+    wk_ref, wv_ref, o_ref, m_ref, l_ref, acc_ref, s_ref, p_ref = refs[-8:]
+    ctx = ctx_ref[pl.program_id(0)]
+    t = q_ref.shape[1]
+    wk, wv = wk_ref[...], wv_ref[...]
+    m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def attend(rows, seen=None, k0=None):
+        """Latent rows [bk, row] into the running softmax: up-projected to
+        this head's keys [bk, d_nope + tail] (the rope part is every
+        head's alike) and values [d_v, bk], scored against all queries in
+        one product, weighted in another; between the two, the softmax a
+        lane tile of queries at a time, so that the chain from a score to
+        its weight stays in registers. ``seen`` [bk, 1]: the rows that
+        count (cached rows; None = all); ``k0``: the first row's place
+        among the new rows, which a query sees causally, and only the
+        queries from its lane tile on are scored."""
+        bk = rows.shape[0]
+        q0 = 0 if k0 is None else _first_query(k0)
+        latent = rows[:, :rank]
+        keys = jnp.concatenate(
+            [jnp.dot(latent, wk, preferred_element_type=jnp.float32).astype(
+                rows.dtype), rows[:, rank:]], axis=1)
+        values = lax.dot_general(
+            wv, latent, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(rows.dtype)
+        s_ref[:bk, q0:] = jnp.dot(keys, q_ref[:, q0:],
+                                  preferred_element_type=jnp.float32)
+        for c0 in range(q0, t, _LANES):
+            at = slice(c0, min(c0 + _LANES, t))
+            s = s_ref[:bk, at] * scale
+            if seen is not None:
+                s = jnp.where(seen, s, _NEG)
+            if k0 is not None and c0 < k0 + bk - 1:     # crosses the diagonal
+                s = jnp.where(
+                    k0 + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                    <= c0 + lax.broadcasted_iota(jnp.int32, s.shape, 1),
+                    s, _NEG)
+            m_ref[:, at], l_ref[:, at], alpha, p = _softmax_block(
+                m_ref[:, at], l_ref[:, at], s, keys=0)
+            p_ref[:bk, at] = p.astype(p_ref.dtype)
+            acc_ref[:, at] = acc_ref[:, at] * alpha
+        acc_ref[:, q0:] += jnp.dot(values, p_ref[:bk, q0:],
+                                   preferred_element_type=jnp.float32)
+
+    if pool_ref is not None:
+        # the context: whole blocks unmasked, the block that straddles
+        # ``ctx`` masked (and its rows past ``ctx`` zeroed: they may hold
+        # anything, and 0 x NaN is NaN in the value product), the rest
+        # never touched
+        whole = ctx // block_k
+        lax.fori_loop(
+            0, whole, lambda j, _: attend(pool_ref[pl.ds(
+                pl.multiple_of(j * block_k, block_k), block_k), :]), None)
+
+        @pl.when(ctx % block_k != 0)
+        def _():
+            base = pl.multiple_of(whole * block_k, block_k)
+            seen = base + lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0) < ctx
+            attend(jnp.where(seen, pool_ref[pl.ds(base, block_k), :], 0),
+                   seen=seen)
+
+    for k0 in range(0, t, block_t):
+        attend(new_ref[k0:k0 + block_t, :], k0=k0)
+    o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("scale", "rank", "cap", "interpret"),
+)
+def latent_prefill_attention(q, new, pool, w_k, w_v, slots, ctx, block, *,
+                             scale: float, rank: int, cap: int,
+                             interpret: Optional[bool] = None):
+    """Causal attention of T new positions a stream over its context in a
+    latent cache and over themselves, the scores never leaving the chip.
+
+    ``q`` [B, H, d_nope + tail, T]: a head's queries, one a column, the
+    last ``tail`` = row - rank numbers of each against the rows' own tail
+    (the shared rope key, zero where the row is padding); ``new`` [B, T,
+    row] the new positions' rows; ``pool`` [blocks, slots, S, row] the
+    whole cache, of which this attention's is ``pool[block]`` (the kernel
+    is handed the whole and reads its slots where they lie: a slice handed
+    to a kernel is a copy of the block; ``block`` is an operand, so a
+    stack's attentions share one trace and one compiled kernel) and stream
+    b's context is the
+    first ``ctx[b]`` (at most ``cap``) rows of slot ``slots[b]`` (clipped
+    into the pool: a padded row reads some slot, finitely) while the rest
+    may hold anything; ``w_k`` [H, rank, d_nope] and ``w_v`` [H, d_v, rank]
+    each head's up-projection of a row's first ``rank`` numbers to its
+    keys' first ``d_nope`` and to its values. Returns [B, H, d_v, T].
+
+    Grid (stream, head); a program reads its stream's slot as it lies
+    (whole rows, the first ``cap``: fetched once a stream, the block index
+    does not change with the head) and visits the key blocks that start
+    before ``ctx[b]``, then the new rows' blocks, each against the queries
+    that can see it. Operands enter both products in their own dtype;
+    scores, maximum, sum and accumulator are float32; the output is cast
+    once."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, h, dq, t = q.shape
+    row, dn, dv = new.shape[-1], w_k.shape[-1], w_v.shape[1]
+    bt, bk = latent_prefill_blocks(t, cap)
+    kernel = functools.partial(
+        _latent_prefill_kernel, scale=scale, rank=rank, block_t=bt,
+        block_k=bk)
+    last = pool.shape[1] - 1
+    in_specs = [
+        pl.BlockSpec((None, None, dq, t), lambda i, j, s, c, k: (i, j, 0, 0)),
+        pl.BlockSpec((None, t, row), lambda i, j, s, c, k: (i, 0, 0)),
+        pl.BlockSpec((None, None, cap, row),
+                     lambda i, j, s, c, k: (k[0], jnp.clip(s[i], 0, last), 0,
+                                            0)),
+        pl.BlockSpec((None, rank, dn), lambda i, j, s, c, k: (j, 0, 0)),
+        pl.BlockSpec((None, dv, rank), lambda i, j, s, c, k: (j, 0, 0)),
+    ]
+    args = [q, new, pool, w_k, w_v]
+    if not cap:
+        del in_specs[2], args[2]        # nothing can be cached: no pool
+    blocks = dq * t + t * row + cap * row + rank * (dn + dv) + dv * t
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, h), in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, None, dv, t),
+                                   lambda i, j, s, c, k: (i, j, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((1, t), jnp.float32),
+                            pltpu.VMEM((1, t), jnp.float32),
+                            pltpu.VMEM((dv, t), jnp.float32),
+                            pltpu.VMEM((max(bt, bk), t), jnp.float32),
+                            pltpu.VMEM((max(bt, bk), t), q.dtype)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv, t), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # every block twice (the pipeline's two buffers), and room for
+            # the running softmax and a tile's temporaries
+            vmem_limit_bytes=2 * blocks * q.dtype.itemsize + (32 << 20)),
+        interpret=interpret,
+    )(slots.astype(jnp.int32), jnp.clip(ctx, 0, cap).astype(jnp.int32),
+      jnp.asarray(block, jnp.int32).reshape(1), *args)
