@@ -44,6 +44,7 @@ from ..bus.interface import FrameBus, FrameMeta
 from ..obs import registry as obs_registry, tracer
 from ..obs.spans import trace_id_of
 from ..obs.perf import PerfTracker
+from ..obs import stages
 from ..obs.prof import Profiler
 from ..obs.slo import SLOEngine, default_slos
 from ..obs.watch import Watchdog
@@ -266,7 +267,10 @@ def window_write(window, frames_u8, idx, pos):
     whose windows are all still filling: its frames are written and
     nothing is computed (``InferenceEngine._dispatch``). The caller
     donates ``window``: same shape and dtype out, rewritten in place."""
-    return window.at[idx, pos].set(frames_u8, mode="drop")
+    import jax
+
+    with jax.named_scope("window_write"):
+        return window.at[idx, pos].set(frames_u8, mode="drop")
 
 
 def _windowed(step):
@@ -289,20 +293,21 @@ def _windowed(step):
         slots, clip_len = window.shape[:2]
         frame = window.shape[2:]
         window = window_write(window, frames_u8, idx, pos)
-        order = (pos[:, None] + 1 + jnp.arange(clip_len)) % clip_len
-        src = idx[:, None] * clip_len + order      # a padded row is clipped
-        src = jnp.minimum(src, slots * clip_len - 1).reshape(-1)
-        flat = window.reshape((slots * clip_len,) + frame)
-        zero = (0,) * len(frame)
+        with jax.named_scope("window_copy"):
+            order = (pos[:, None] + 1 + jnp.arange(clip_len)) % clip_len
+            src = idx[:, None] * clip_len + order  # a padded row is clipped
+            src = jnp.minimum(src, slots * clip_len - 1).reshape(-1)
+            flat = window.reshape((slots * clip_len,) + frame)
+            zero = (0,) * len(frame)
 
-        def copy(i, clips):
-            one = jax.lax.dynamic_slice(flat, (src[i],) + zero,
-                                        (1,) + frame)
-            return jax.lax.dynamic_update_slice(clips, one, (i,) + zero)
+            def copy(i, clips):
+                one = jax.lax.dynamic_slice(flat, (src[i],) + zero,
+                                            (1,) + frame)
+                return jax.lax.dynamic_update_slice(clips, one, (i,) + zero)
 
-        clips = jax.lax.fori_loop(
-            0, src.shape[0], copy,
-            jnp.zeros((src.shape[0],) + frame, window.dtype))
+            clips = jax.lax.fori_loop(
+                0, src.shape[0], copy,
+                jnp.zeros((src.shape[0],) + frame, window.dtype))
         out = dict(step(variables, clips.reshape(
             (idx.shape[0], clip_len) + frame), *rest))
         out["window"] = window
@@ -573,7 +578,7 @@ class _TimedStep:
     """
 
     __slots__ = ("_jit", "_aot", "_perf", "_model", "_src_hw", "_bucket",
-                 "_on_success", "_on_compiled")
+                 "_on_success", "_on_compiled", "program", "__weakref__")
 
     def __init__(self, jit_fn, perf: PerfTracker, model: str,
                  src_hw: tuple, bucket: int, on_first_success=None,
@@ -584,6 +589,9 @@ class _TimedStep:
         self._model = model
         self._src_hw = src_hw
         self._bucket = bucket
+        # The program's id: what a batch trace names (``_dispatch``) and
+        # obs/stages.py keys the program's stage map by.
+        self.program = f"{model}/{src_hw[0]}x{src_hw[1]}/{bucket}"
         # Fired once, after the first call that compiled AND executed
         # without raising — the AOT manifest record hook. Keyed on
         # success so a program whose compile reliably fails is never
@@ -618,6 +626,8 @@ class _TimedStep:
                 cb, self._on_compiled = self._on_compiled, None
                 cb(compiled)
             self._aot = compiled
+            # one dict entry: the map itself is built when asked for
+            stages.register(self.program, self)
         if self._aot is not False:
             try:
                 return self._aot(variables, *args)
@@ -671,7 +681,7 @@ class _Prefetched:
     """Handle for one batch placement in flight on the transfer thread."""
 
     __slots__ = ("group", "ready", "placed", "error", "transfer_s",
-                 "overlapped_s", "t_q", "t0", "t1")
+                 "overlapped_s", "t_q", "t0", "t_put", "t1")
 
     def __init__(self, group: BatchGroup):
         self.group = group
@@ -681,9 +691,10 @@ class _Prefetched:
         self.transfer_s = 0.0
         self.overlapped_s = 0.0   # transfer wall time with >=1 batch in flight
         # wall stamps for the batch trace: handed to the stage, picked up
-        # by the transfer thread, block_until_ready returned
+        # by the transfer thread, the placement call returned (the bytes
+        # may still be crossing), block_until_ready returned
         self.t_q = time.time()
-        self.t0 = self.t1 = 0.0
+        self.t0 = self.t_put = self.t1 = 0.0
 
 
 class _PrefetchStage:
@@ -777,6 +788,7 @@ class _PrefetchStage:
             t0 = time.perf_counter()
             try:
                 placed = self._place(pre.group.frames)
+                pre.t_put = time.time()
                 if hasattr(placed, "block_until_ready"):
                     placed.block_until_ready()
                 pre.placed = placed
@@ -2000,6 +2012,16 @@ class InferenceEngine:
             for q, _ in self._subscribers:
                 q.put(None)
             self._subscribers.clear()
+        if self._cfg.stage_trace:
+            # The stage maps (obs/stages.py) of the programs the stage
+            # records name, left behind as plain dicts: whoever reads the
+            # records reads them after this engine, and its executables,
+            # are gone (benchmark/run.py needs the HBM back first).
+            try:
+                stages.built({r["program"] for r in self.stage_records
+                              if r.get("program")})
+            except Exception:
+                log.exception("stage maps not built")
 
     # -- output-quality plane (obs/quality.py) --
 
@@ -2432,8 +2454,13 @@ class InferenceEngine:
                 if not write_only:
                     return self._step(src_hw, bucket, model, window=slots)
                 write = self._window_writer(src_hw, bucket, model, slots)
-                return lambda variables, frames, window, idx, pos: {
-                    ClipWindowPool.key: write(window, frames, idx, pos)}
+
+                def alone(variables, frames, window, idx, pos):
+                    return {ClipWindowPool.key: write(window, frames, idx,
+                                                      pos)}
+
+                alone.program = getattr(write, "program", None)
+                return alone
 
             pool = self._window_pools[model] = ClipWindowPool(
                 spec.clip_len, self._buckets or (1,),
@@ -2661,7 +2688,6 @@ class InferenceEngine:
         self._assemble_s = self._pace_s = 0.0
         while not self._stop.is_set():
             t0 = time.monotonic()
-            t_tick0 = time.time()
             # The loop must outlive any single bad batch: a dead engine
             # thread would leave subscribers blocked forever (same
             # log-and-keep-going stance as the reference's worker loops,
@@ -2786,7 +2812,7 @@ class InferenceEngine:
                     groups = self._roi_transform(groups)
                 # t_collect closes the collection: collect() itself plus,
                 # under pressure or cfg.roi, the two group transforms above.
-                tick = self._open_tick(t_tick0, t_collect0, pc_collect0)
+                tick = self._open_tick(t_collect0, pc_collect0)
                 batches = self._dispatch(groups, tick["t_collect"], tick,
                                          handles)
                 if batches:
@@ -2924,8 +2950,7 @@ class InferenceEngine:
                             trace_id=trace_id_of(m, did),
                         )
 
-    def _open_tick(self, t_tick0: float, t_collect0: float,
-                   pc_collect0: float) -> dict:
+    def _open_tick(self, t_collect0: float, pc_collect0: float) -> dict:
         """The tick's trace, measured once, on the tick thread, right after
         the collection: tick number, wall stamps, and the collector's
         phases and byte counts of this collect() (``Collector.last_trace``).
@@ -2934,7 +2959,7 @@ class InferenceEngine:
         tick = dict(self._collector.last_trace)
         in_collect = tick["read_s"] - tick["read_ahead_s"] + tick["fill_s"]
         tick.update(
-            tick=self.ticks, t_tick0=t_tick0,
+            tick=self.ticks,
             # end of the previous tick's dispatch -> collect() entry, less
             # the assembly window (its reads are in read_s, its waiting is
             # idle) and the paced wait, stamped apart: tail of the last
@@ -3019,7 +3044,8 @@ class InferenceEngine:
                     dur_ms=b["state_wait_s"] * 1e3, **extra)
             tracer.record(
                 "engine.tick", "step_call", n, ts=b["t_step1"],
-                dur_ms=b["step_call_s"] * 1e3, **extra)
+                dur_ms=b["step_call_s"] * 1e3, program=b["program"],
+                **extra)
 
     @staticmethod
     def _trace_batch(tr: dict) -> None:
@@ -3032,6 +3058,7 @@ class InferenceEngine:
             "engine.transfer", "place", n, ts=tr["t_placed"],
             dur_ms=(tr["t_placed"] - tr["t_place0"]) * 1e3,
             queued_ms=round((tr["t_place0"] - tr["t_place_q"]) * 1e3, 3),
+            put_call_ms=round(tr["put_call_s"] * 1e3, 3),
             ahead_ms=round(tr["place_ahead_s"] * 1e3, 3), **extra)
         tracer.record(
             "engine.drain", "drain_wake", n, ts=tr["t_deq"],
@@ -3284,15 +3311,20 @@ class InferenceEngine:
         ``tick`` is the tick's trace (``_open_tick``); each device batch
         gets a copy with its own stamps added: ``batch`` = (tick, index of
         the group), ``t_place_q``/``t_place0``/``t_placed`` (handed to the
-        transfer thread, picked up, placed), ``place_ahead_s`` (how long
-        before ``t_collect`` it was handed over: 0.0 unless the collector's
-        sink did it), ``place_wait_s`` (this
+        transfer thread, picked up, placed), ``put_call_s`` (of
+        ``t_place0 -> t_placed``, the part inside the placement call
+        itself; the rest is the wait for the bytes), ``place_ahead_s`` (how
+        long before ``t_collect`` it was handed over: 0.0 unless the
+        collector's sink did it), ``place_wait_s`` (this
         thread's blocked time on the placement, ending at
         ``t_place_got``), what each carried state stamps (a stream head:
         ``head_*`` and ``pool_s``, its plan; a device window:
         ``window_rows``, rows of this batch written into their streams'
         windows), ``state_wait_s`` (blocked on the predecessor step, whose
-        state this one takes), ``t_step0``/``t_step1`` and ``step_call_s``
+        state this one takes), ``program`` (the id of the program the step
+        call runs, ``"{model}/{H}x{W}/{bucket}"``: what pairs the batch's
+        device event with that program's stage map, obs/stages.py),
+        ``t_step0``/``t_step1`` and ``step_call_s``
         (around the step call; a compile shows here), ``t_submit`` and
         ``window_restarts`` (device windows started anew since the last
         batch's trace). The drain thread adds ``t_deq``, ``t_drain0``,
@@ -3401,6 +3433,7 @@ class InferenceEngine:
                     wait_s = time.perf_counter() - t_wait
                     tr.update(t_place_q=pre.t_q, t_place0=pre.t0,
                               t_placed=pre.t1,
+                              put_call_s=max(0.0, pre.t_put - pre.t0),
                               place_ahead_s=max(
                                   0.0, tick["t_collect"] - pre.t_q))
                     if pre.error is not None:
@@ -3420,6 +3453,7 @@ class InferenceEngine:
                     placed = self._place(group.frames)
                     wait_s = h2d_s = time.perf_counter() - t_h2d
                     tr["t_placed"] = time.time()
+                    tr["put_call_s"] = h2d_s    # the call is all of it
                     hidden_s = 0.0
                 tr["place_wait_s"] = wait_s
                 tr["t_place_got"] = time.time()
@@ -3462,6 +3496,7 @@ class InferenceEngine:
                     if carry.wait is not None:
                         carry.wait()
                         tr["state_wait_s"] = time.perf_counter() - pc_wait0
+                tr["program"] = getattr(step, "program", None)
                 tr["t_step0"], pc_step0 = time.time(), time.perf_counter()
                 try:
                     ran = step(variables, placed,

@@ -286,38 +286,42 @@ class MlaAttention(nn.Module):
     def __call__(self, h, pool, rbuf, slots, ctx, at, cap, block=None):
         """``at`` None: a prefill, whose T positions start the round's
         buffer; else [B], where each row's T decode positions go."""
-        c = self.cfg
-        b, t, _ = h.shape
-        heads = len(c.held)
-        dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
-        pos = ctx[:, None] + jnp.arange(t, dtype=ctx.dtype)[None]
-        if at is not None:
-            pos = pos + at[:, None]
-        new = self.latent(h, pos).astype(rbuf.dtype)
-        q = self.q_norm(h @ self.q_a.astype(self.dtype)) \
-            @ self.q_b.astype(self.dtype)
-        q = q.reshape(b, t, heads, dn + dr)
-        q_n, q_r = q[..., :dn], rope(q[..., dn:], pos, c).astype(self.dtype)
-        q = jnp.concatenate([q_n, q_r], axis=-1)
-        w = self.kv_b.astype(self.dtype).reshape(
-            c.kv_lora_rank, heads, dn + dv)
-        w_uk, w_uv = w[..., :dn], w[..., dn:]
-        if at is None:
-            with jax.named_scope("mla_prefill"):
-                rbuf = jax.lax.dynamic_update_slice_in_dim(
-                    rbuf, new, 0, axis=1)
-                o = mla_prefill_attention(
-                    q, new, w_uk, w_uv, pool, slots, ctx, softmax_scale(c),
-                    min(cap, pool.shape[-2]), block)
-        else:
-            with jax.named_scope("mla_decode"):
-                rbuf = write_rows(rbuf, new, at)
-                upto = at[:, None] + jnp.arange(t, dtype=at.dtype)[None]
-                o = mla_decode_attention(
-                    q_n, q_r, w_uk, w_uv,
-                    pool if block is None else pool[block], rbuf, slots,
-                    ctx, upto, softmax_scale(c))
-        return o @ self.o.astype(self.dtype), rbuf
+        # (the whole layer is ``head_attn``, as the first head's: the
+        # projections and the rotation lie outside the two inner scopes)
+        with jax.named_scope("head_attn"):
+            c = self.cfg
+            b, t, _ = h.shape
+            heads = len(c.held)
+            dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+            pos = ctx[:, None] + jnp.arange(t, dtype=ctx.dtype)[None]
+            if at is not None:
+                pos = pos + at[:, None]
+            new = self.latent(h, pos).astype(rbuf.dtype)
+            q = self.q_norm(h @ self.q_a.astype(self.dtype)) \
+                @ self.q_b.astype(self.dtype)
+            q = q.reshape(b, t, heads, dn + dr)
+            q_n = q[..., :dn]
+            q_r = rope(q[..., dn:], pos, c).astype(self.dtype)
+            q = jnp.concatenate([q_n, q_r], axis=-1)
+            w = self.kv_b.astype(self.dtype).reshape(
+                c.kv_lora_rank, heads, dn + dv)
+            w_uk, w_uv = w[..., :dn], w[..., dn:]
+            if at is None:
+                with jax.named_scope("mla_prefill"):
+                    rbuf = jax.lax.dynamic_update_slice_in_dim(
+                        rbuf, new, 0, axis=1)
+                    o = mla_prefill_attention(
+                        q, new, w_uk, w_uv, pool, slots, ctx,
+                        softmax_scale(c), min(cap, pool.shape[-2]), block)
+            else:
+                with jax.named_scope("mla_decode"):
+                    rbuf = write_rows(rbuf, new, at)
+                    upto = at[:, None] + jnp.arange(t, dtype=at.dtype)[None]
+                    o = mla_decode_attention(
+                        q_n, q_r, w_uk, w_uv,
+                        pool if block is None else pool[block], rbuf, slots,
+                        ctx, upto, softmax_scale(c))
+            return o @ self.o.astype(self.dtype), rbuf
 
 
 # -- the cache: a pool of slots, a round's buffer -----------------------------

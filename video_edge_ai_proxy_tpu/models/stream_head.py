@@ -165,9 +165,11 @@ def prepare_for_serving(model, variables):
 def top_tokens(logits):
     """(greedy id [B], top ids [B, 5], their probabilities) of float32
     logits [B, vocabulary]."""
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, min(TOP_K_TOKENS, logits.shape[-1]))
-    return top_i[:, 0].astype(jnp.int32), top_i.astype(jnp.int32), top_p
+    with jax.named_scope("head_sample"):
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_i = jax.lax.top_k(
+            probs, min(TOP_K_TOKENS, logits.shape[-1]))
+        return top_i[:, 0].astype(jnp.int32), top_i.astype(jnp.int32), top_p
 
 
 def serve_round(model, variables, clips, state, slots, pos0, reset,
@@ -195,7 +197,8 @@ def serve_round(model, variables, clips, state, slots, pos0, reset,
     # Every context starts with the standing instruction, so its cache rows
     # are the first of EVERY slot (written anew each round: a few MB), and
     # a stream that resets takes the rest of its state.
-    pool, rows = model.seed_round(variables, state, slots, reset)
+    with jax.named_scope("head_seed"):
+        pool, rows = model.seed_round(variables, state, slots, reset)
 
     # Preprocess, encoder, connector and visual prefill, streams in chunks
     # (the whole batch at once would hold the encoder's [B, 12, V, V]
@@ -219,12 +222,16 @@ def serve_round(model, variables, clips, state, slots, pos0, reset,
                 tree(lambda a, v: put(a, v, 1), rbuf, bn),
                 tree(jnp.add, load, m))
 
-    h, rows, rbuf, load = jax.lax.fori_loop(
-        0, b // n, prefill,
-        (jnp.zeros((b, c.head.dim), model.dtype), rows, rbuf,
-         model.empty_counts()))
+    # (the encoder inside the loop keeps ``embed`` / ``encoder_block``:
+    # obs/stages.py tells prefill from decode by the outer name)
+    with jax.named_scope("head_prefill"):
+        h, rows, rbuf, load = jax.lax.fori_loop(
+            0, b // n, prefill,
+            (jnp.zeros((b, c.head.dim), model.dtype), rows, rbuf,
+             model.empty_counts()))
 
     out = model.decode(variables, pool, h, rows, rbuf, slots, pos0, load)
-    out["state"] = model.commit_round(
-        state, pool, out.pop("rows"), out.pop("rbuf"), slots, pos0)
+    with jax.named_scope("head_flush"):
+        out["state"] = model.commit_round(
+            state, pool, out.pop("rows"), out.pop("rbuf"), slots, pos0)
     return out

@@ -120,7 +120,7 @@ class VideoMAE(nn.Module):
     def __call__(self, clips: jnp.ndarray, train: bool = False) -> jnp.ndarray:
         """Fine-tune / inference path: [B, T, H, W, 3] -> [B, num_classes]."""
         x = self.features(clips, train)
-        with jax.named_scope("head"):
+        with jax.named_scope("cls_head"):
             return self.head(jnp.mean(x.astype(jnp.float32), axis=1))
 
     def encode_visible(self, clips: jnp.ndarray, keep_mask: jnp.ndarray,

@@ -78,6 +78,6 @@ class ViT(nn.Module):
         x = Encoder(c.encoder, self.dtype, self.attn_fn, name="encoder")(
             x, deterministic=not train
         )
-        with jax.named_scope("head"):
+        with jax.named_scope("cls_head"):
             return nn.Dense(
                 c.num_classes, dtype=jnp.float32, name="classifier")(x[:, 0])

@@ -22,6 +22,10 @@ code alike. Modules:
   ``/api/v1/profile`` + gRPC admin mirror, or fired automatically when an
   SLO episode opens / the degradation ladder escalates) written as
   self-contained bundles into a byte-bounded retention ring.
+- :mod:`stages` — device time by stage from the programs' own
+  ``jax.named_scope`` names: the stage map of a compiled step (built only
+  when asked for), the one reducer behind a bundle's ``stages.json`` and
+  the benchmark's per-stage metrics.
 - :mod:`quality` — output-quality observability: per-stream black /
   frozen / flatline verdict state machines fed by device-computed frame
   statistics, detection drift scores vs committed baselines, and the

@@ -12,7 +12,9 @@ module is the single capture path behind three surfaces:
   duration-bounded ``jax.profiler`` trace written into a self-contained
   artifact *bundle*: device trace + the lineage-span window that
   overlapped the capture + a perf/SLO/health snapshot + manifest.json
-  linking them.
+  linking them, and ``stages.json``: the trace reduced to device ms a run
+  by the serving programs' own scopes (obs/stages.py; per program id, as
+  the ``step_call`` spans of the window name it).
 - **Trigger-driven**: the engine polls :meth:`Profiler.poll` off its tick
   (engine/runner.py ``_watch_tick``) with the SLO episode total and the
   degradation-ladder rung; when an episode opens or the ladder escalates,
@@ -54,7 +56,7 @@ import threading
 import time
 from typing import Callable, List, Optional
 
-from . import metrics
+from . import metrics, stages
 
 log = logging.getLogger("vep.obs.prof")
 
@@ -65,6 +67,7 @@ MANIFEST = "manifest.json"
 SPANS = "spans.json"
 SNAPSHOT = "snapshot.json"
 JOURNAL = "journal.json"
+STAGES = "stages.json"
 DEVICE_DIR = "device"
 
 # Span-window slack: spans stamped up to this long after stop_trace still
@@ -105,6 +108,40 @@ def find_device_trace(bundle_dir: str) -> Optional[str]:
                 best = best or os.path.relpath(
                     os.path.join(dirpath, name), bundle_dir)
     return best
+
+
+def find_xplane(bundle_dir: str) -> Optional[str]:
+    """The profiler's raw ``.xplane.pb`` under a bundle
+    (``device/plugins/profile/<run>/<host>.xplane.pb``): what
+    ``stages.json`` is reduced from. A path relative to ``bundle_dir``, or
+    None (a capture that failed, a stub tracer)."""
+    root = os.path.join(bundle_dir, DEVICE_DIR)
+    for dirpath, _dirnames, filenames in os.walk(root):
+        for name in sorted(filenames):
+            if name.endswith(".xplane.pb"):
+                return os.path.relpath(os.path.join(dirpath, name),
+                                       bundle_dir)
+    return None
+
+
+def _write_stages(bundle: str, t0_wall: float, span_events: List[dict]
+                  ) -> tuple:
+    """Device time by stage (obs/stages.py) of the bundle's own trace,
+    paired with the programs its ``step_call`` spans name, as
+    ``stages.json``. Returns (file name | None, why there is none)."""
+    xplane = find_xplane(bundle)
+    if xplane is None:
+        return None, "the capture wrote no .xplane.pb"
+    try:
+        content, why = stages.bundle_stages(
+            os.path.join(bundle, xplane), t0_wall, span_events)
+    except Exception as exc:  # noqa: BLE001 — bundle best-effort
+        log.error("prof stage reduction failed: %s", exc)
+        return None, f"{type(exc).__name__}: {exc}"
+    if content is not None:
+        with open(os.path.join(bundle, STAGES), "w") as f:
+            json.dump(content, f, indent=1)
+    return (None if content is None else STAGES), why
 
 
 class Profiler:
@@ -326,6 +363,8 @@ class Profiler:
         with open(os.path.join(bundle, SNAPSHOT), "w") as f:
             json.dump(snap, f, default=str)
 
+        stages_file, no_stages = _write_stages(bundle, t0_wall, span_events)
+
         manifest = {
             "bundle": name,
             "path": bundle,
@@ -340,6 +379,10 @@ class Profiler:
             "journal": JOURNAL,
             "journal_events": len(journal_events),
             "snapshot": SNAPSHOT,
+            # device ms a run by scope path, per program (obs/stages.py);
+            # None with the reason where there is nothing to reduce
+            "stages": stages_file,
+            "stages_missing": no_stages,
             "slo_episode": context.get("slo_episode"),
             "context": context,
             "error": error,
