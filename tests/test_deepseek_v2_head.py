@@ -199,11 +199,11 @@ def test_the_expert_shares_sum_to_the_uncut_routed_layer():
         held = (first, first + 1)
         layer = TopKMoeMlp(TopKMoeConfig(experts_held=held, **MOE),
                            dtype=jnp.float32)
-        y, load, pairs, hit = layer.apply(
+        y, counts = layer.apply(
             _expert_share(full, held), x, method=TopKMoeMlp.routed)
-        assert int(pairs) == 40 * 3
-        total, local = total + y, local + int(load.sum())
-        hits.append(int(hit))
+        assert int(counts["pairs"]) == 40 * 3
+        total, local = total + y, local + int(counts["held"].sum())
+        hits.append(int(counts["group_hits"]))
     assert local == 40 * 3
     assert hits[0::2] == hits[1::2]         # the two halves of a group
     assert sum(hits[0::2]) == 40 * 2        # two kept groups a token
@@ -288,29 +288,33 @@ def test_the_layers_counts_are_a_numpy_count():
     cfg = TopKMoeConfig(experts_held=(4, 5), **MOE)     # half of group 1
     assert cfg.groups_held == (1,)
     layer = TopKMoeMlp(cfg, dtype=jnp.float32)
-    _, load, pairs, hits = layer.apply(_expert_share(full, (4, 5)), x,
-                                       method=TopKMoeMlp.routed)
+    _, counts = layer.apply(_expert_share(full, (4, 5)), x,
+                            method=TopKMoeMlp.routed)
     s = np.asarray(jax.nn.softmax(x @ full["gate"], axis=-1), np.float64)
     best = s.reshape(300, 4, 4).max(-1)
     kept = np.argsort(-best, axis=-1, kind="stable")[:, :2]
     masked = np.where(np.repeat(
         (kept[:, :, None] == np.arange(4)).any(1), 4, axis=-1), s, 0)
     chosen = np.argsort(-masked, axis=-1, kind="stable")[:, :3]
-    assert int(pairs) == 300 * 3
-    assert int(hits) == int((kept == 1).any(-1).sum())
-    assert load.tolist() == [int((chosen == e).sum()) for e in (4, 5)]
+    assert int(counts["pairs"]) == 300 * 3
+    assert int(counts["group_hits"]) == int((kept == 1).any(-1).sum())
+    assert counts["held"].tolist() == [int((chosen == e).sum())
+                                       for e in (4, 5)]
+    # 300 tokens are no multiple of 8: the one rung of every pair
+    assert int(counts["rows"]) == 300 * 3
     # a holder with experts of two groups is hit by either
     both = TopKMoeConfig(experts_held=(3, 4), **MOE)
     assert both.groups_held == (0, 1)
-    _, _, _, hits2 = TopKMoeMlp(both, dtype=jnp.float32).apply(
+    _, counts2 = TopKMoeMlp(both, dtype=jnp.float32).apply(
         _expert_share(full, (3, 4)), x, method=TopKMoeMlp.routed)
-    assert int(hits2) == int(((kept == 0) | (kept == 1)).any(-1).sum())
+    assert int(counts2["group_hits"]) == int(
+        ((kept == 0) | (kept == 1)).any(-1).sum())
     # and a router without groups counts every token
     free = TopKMoeConfig(**dict(MOE, n_group=1, topk_group=1),
                          experts_held=(4, 5))
-    _, _, pairs3, hits3 = TopKMoeMlp(free, dtype=jnp.float32).apply(
+    _, counts3 = TopKMoeMlp(free, dtype=jnp.float32).apply(
         _expert_share(full, (4, 5)), x, method=TopKMoeMlp.routed)
-    assert (int(pairs3), int(hits3)) == (900, 300)
+    assert (int(counts3["pairs"]), int(counts3["group_hits"])) == (900, 300)
 
 
 # -- (a) the whole stack through the pool against one full forward ----------
@@ -530,6 +534,10 @@ def test_engine_serves_the_head_and_its_counters_reach_trace_and_metrics(
             assert rec["moe_pairs_total"] == tokens * c.head.top_k
             assert 0 <= rec["moe_group_hits"] <= tokens
             assert rec["moe_pairs_local"] <= rec["moe_pairs_total"]
+            # the twin's calls are under a rung's few tiles: every pair
+            # is moved, and the rows counted are the pairs routed
+            assert rec["moe_pair_rows"] == rec["moe_pairs_routed"] \
+                == rec["moe_pairs_total"]
             assert rec["head_decode_iters"] == c.decode_steps
             assert "mtp_drafted" not in rec
         batches = {tuple(r["batch"]): r for r in recs}.values()

@@ -82,7 +82,7 @@ def test_layers_match_the_reference(layer_types, dense):
     stack = lfm2.Lfm2Stack(cfg, dtype=jnp.float32)
     conv, pool = lfm2.empty_state(cfg, 1, 0, jnp.float32)
     conv = jnp.zeros((1, max(cfg.conv_layers, 1)) + conv.shape[2:])
-    got, _, _, load = stack.apply(
+    got, _, _, counts = stack.apply(
         _nest(flat, "head/"), x[None], conv, pool,
         lfm2.round_buffer(cfg, 1, t, jnp.float32), jnp.arange(1),
         jnp.zeros((1,), jnp.int32))
@@ -90,7 +90,7 @@ def test_layers_match_the_reference(layer_types, dense):
                                rtol=2e-4, atol=2e-4)
     if not dense:
         # every token's top-2 of 8 experts, of which 4 are held
-        assert 0 < int(load.sum()) <= t * 2 * (len(layer_types))
+        assert 0 < int(counts["held"].sum()) <= t * 2 * (len(layer_types))
 
 
 # -- the expert layer: shares and droplessness ------------------------------
@@ -138,13 +138,13 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     parts, loads = [], []
     for s in range(4):
         held = range(4 * s, 4 * s + 4)
-        y, load = _moe(held).apply(_share(full, held), x)
+        y, counts = _moe(held).apply(_share(full, held), x)
         parts.append(y)
-        loads.append(int(load.sum()))
+        loads.append(int(counts["held"].sum()))
     np.testing.assert_allclose(np.asarray(sum(parts)), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
     assert sum(loads) == 37 * 4           # every pair computed exactly once
-    whole, load = _moe(range(16)).apply(_share(full, range(16)), x)
+    whole, _ = _moe(range(16)).apply(_share(full, range(16)), x)
     np.testing.assert_allclose(np.asarray(whole), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
     # an unheld holder's part is not a stand-in for anything
@@ -159,8 +159,8 @@ def test_no_token_is_dropped_when_all_choose_one_expert():
     full["expert_bias"] = full["expert_bias"].at[2].set(50.0).at[9].set(40.0)
     x = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
     held = (0, 1, 2, 3)
-    y, load = _moe(held, top_k=2).apply(_share(full, held), x)
-    assert load.tolist() == [0, 0, 64, 0]
+    y, counts = _moe(held, top_k=2).apply(_share(full, held), x)
+    assert counts["held"].tolist() == [0, 0, 64, 0]
     s = jax.nn.sigmoid(x @ full["gate"])
     w2 = s[:, 2] / (s[:, 2] + s[:, 9])
     e2 = (jax.nn.silu(x @ full["w1"][2]) * (x @ full["w3"][2])) @ full["w2"][2]
@@ -472,6 +472,9 @@ def test_engine_serves_the_head_one_result_a_read(monkeypatch, home):
         assert rec["head_prefill_tokens"] == 3 * c.visual_tokens
         assert rec["head_decode_steps"] == c.decode_steps
         assert rec["pool_s"] >= 0 and rec["moe_pairs_local"] > 0
+        # the twin's calls have one rung: every routed pair is moved
+        assert rec["moe_pair_rows"] == rec["moe_pairs_routed"] \
+            >= rec["moe_pairs_local"]
         # the wait for the predecessor step is a phase of its own, before
         # the step call
         assert rec["state_wait_s"] >= 0

@@ -56,6 +56,18 @@ def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _computation(text, name):
+    """The instruction lines of computation ``name`` (``%region_3.1``) of
+    an optimised HLO text (not those of the computations it calls)."""
+    lines, inside = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and ") -> " in line:
+            inside = line.split(" ", 1)[0] == name
+        elif inside and line.startswith("  ") and " = " in line:
+            lines.append(line.strip().removeprefix("ROOT "))
+    return lines
+
+
 class TestNmsKernel:
     """K=256 is the serving ``max_candidates``; 16 and 64 are the batch
     buckets the detector serves at (vmapped over the batch)."""
@@ -262,7 +274,7 @@ class TestStreamHeadLayers:
             def body(_, carry):
                 x, load = carry
                 y, n = layer.apply(variables, x)
-                return x + y, load + n
+                return x + y, load + n["held"]
             return jax.lax.fori_loop(
                 0, 2, body, (x, jnp.zeros((16,), jnp.int32)))
 
@@ -468,11 +480,12 @@ class TestDeepseekV2HeadLayers:
         assert not [line for line in text.splitlines()
                     if " copy(" in line and whole in line.split("=")[1][:60]]
 
-    def test_group_limited_expert_layer_in_a_loop(self, v5e):
+    def test_group_limited_expert_layer_in_a_loop(self, v5e, monkeypatch):
         import flax.linen as nn
 
         from video_edge_ai_proxy_tpu.models import deepseek_v2
-        from video_edge_ai_proxy_tpu.models.transformer import TopKMoeMlp
+        from video_edge_ai_proxy_tpu.models.transformer import (
+            TopKMoeMlp, pair_rungs)
 
         cfg = deepseek_v2.DeepseekV2Config().moe
         assert (cfg.scoring, cfg.n_group, cfg.topk_group) == ("softmax", 8, 3)
@@ -489,13 +502,67 @@ class TestDeepseekV2HeadLayers:
         def twice(variables, x):
             def body(_, carry):
                 x, load, hits = carry
-                y, n, _, hit = layer.apply(variables, x,
-                                           method=TopKMoeMlp.routed)
-                return x + y, load + n, hits + hit
+                y, n = layer.apply(variables, x, method=TopKMoeMlp.routed)
+                return x + y, load + n["held"], hits + n["group_hits"]
             return jax.lax.fori_loop(
                 0, 2, body, (x, jnp.zeros((10,), jnp.int32),
                              jnp.zeros((), jnp.int32)))
 
         x = jax.ShapeDtypeStruct((3136, 5120), jnp.bfloat16, sharding=v5e)
+        # the way back's kernel, not its interpreted twin
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         text = _compiled_text(twice, variables, x)
         assert "ragged-dot" in text and "tpu_custom_call" in text
+        # the capacity rungs of the cell's chunk (4 streams x 784 tokens x
+        # top-6): one branch a rung, chosen in the loop's body, each
+        # gathering its own row count of tokens
+        rungs = pair_rungs(cfg, 3136)
+        assert rungs == (18816, 9472, 4736, 2432)
+        assert " conditional(" in text
+        for rows in rungs:
+            assert "bf16[%d,5120]" % rows in text, rows
+        # no rung below the top makes an [N x k, d] or [N, k, d] float32
+        # array: the weighted sum over k is a kernel that reads the rows
+        # there are
+        cond = next(line for line in text.splitlines()
+                    if " conditional(" in line)
+        branches = cond.split("branch_computations={", 1)[1].split("}")[0]
+        for i, name in enumerate(branches.split(", ")):
+            lines = _computation(text, name)
+            assert [line for line in lines
+                    if line.startswith("%combine_rows")], name
+            made = [line.split(" = ", 1)[1][:20] for line in lines]
+            assert i == 0 or not [m for m in made if m.startswith(
+                ("f32[3136,6,5120]", "f32[18816,5120]"))], name
+
+    def test_expert_layer_at_decode_has_one_rung(self, v5e, monkeypatch):
+        """Decode's 64 tokens (one position a stream, top-6: 384 pairs)
+        are under a rung's few tiles: the layer is the one body of all
+        pairs, with no conditional, and its way back is the kernel, as in
+        prefill: no [N, k, d] float32 array is made."""
+        import flax.linen as nn
+
+        from video_edge_ai_proxy_tpu.models import deepseek_v2
+        from video_edge_ai_proxy_tpu.models.transformer import (
+            TopKMoeMlp, pair_rungs)
+
+        cfg = deepseek_v2.DeepseekV2Config().moe
+        assert pair_rungs(cfg, 64) == (384,)
+        layer = TopKMoeMlp(cfg)
+        variables = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            jax.eval_shape(lambda: nn.meta.unbox(layer.init(
+                jax.random.PRNGKey(0), jnp.zeros((8, 5120), jnp.bfloat16)))))
+
+        def steps(variables, x):
+            def body(_, x):
+                return x + layer.apply(variables, x)[0]
+            return jax.lax.fori_loop(0, 2, body, x)
+
+        x = jax.ShapeDtypeStruct((64, 5120), jnp.bfloat16, sharding=v5e)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        text = _compiled_text(steps, variables, x)
+        assert "ragged-dot" in text and " conditional(" not in text
+        assert [line for line in text.splitlines()
+                if line.lstrip().startswith("%combine_rows")]
+        assert "f32[64,6,5120]" not in text

@@ -326,8 +326,8 @@ def test_the_four_shares_add_up_to_the_uncut_reference_layer():
             dim=32, mlp_dim=24, num_experts=16, top_k=4,
             experts_held=tuple(held), routed_scaling_factor=2.0,
             shared_mlp_dim=24), dtype=jnp.float32)
-        y, load = layer.apply(_share(full, held), x)
-        total, pairs = total + y, pairs + int(load.sum())
+        y, counts = layer.apply(_share(full, held), x)
+        total, pairs = total + y, pairs + int(counts["held"].sum())
     assert pairs == 40 * 4
     np.testing.assert_allclose(np.asarray(total - 3 * shared),
                                np.asarray(want), rtol=2e-4, atol=2e-5)
@@ -520,6 +520,9 @@ def test_self_drafting_is_plain_greedy_with_both_outcomes():
         assert np.all(dr <= c.decode_steps - acc + 1)
         assert np.all(dr >= c.decode_steps - acc)
         assert int(out["decode_iters"]) == dr.max()
+        # the twin's calls have one rung: every routed pair is moved
+        moved, routed = np.asarray(out["moe_pair_rows"]).tolist()
+        assert moved == routed >= int(np.asarray(out["moe_load"]).sum()) > 0
         accepted, drafted = accepted + int(acc.sum()), drafted + int(dr.sum())
         state = out["state"]
     assert 0 < accepted < drafted

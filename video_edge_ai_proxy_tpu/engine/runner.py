@@ -4200,6 +4200,11 @@ class InferenceEngine:
                                moe_load_max=int(load.max()),
                                moe_load_mean=float(load.mean()))
             self._m_moe_pairs.inc(int(load.sum()))
+        if "moe_pair_rows" in host:
+            # the rows its expert layers moved (the capacity rung each
+            # call's held-pair count chose) and the pairs they were routed
+            moved, routed = (int(v) for v in host.pop("moe_pair_rows"))
+            inflight.tr.update(moe_pair_rows=moved, moe_pairs_routed=routed)
         if "moe_pairs_total" in host:
             # a head that shards its experts by group: the pairs its
             # routers chose over ALL experts, and the tokens whose kept

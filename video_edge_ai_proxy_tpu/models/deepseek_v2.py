@@ -54,8 +54,9 @@ import jax.numpy as jnp
 from . import mla, stream_head
 from .common import Dtype
 from .mla import MlaAttention, MlaConfig, flush_round, seed_rows
-from .stream_head import Connector, RmsNorm, SwiGlu, top_tokens
-from .transformer import TopKMoeConfig, TopKMoeMlp
+from .stream_head import (Connector, RmsNorm, SwiGlu, add_counts,
+                          moe_outputs, top_tokens)
+from .transformer import TopKMoeConfig, TopKMoeMlp, zero_counts
 from .videomae import VideoMAE, VideoMAEConfig, tiny_videomae_config
 
 
@@ -155,19 +156,6 @@ def tiny_stream_head_config(vocab_size: int = 96) -> StreamHeadConfig:
         decode_steps=3, prefill_chunk=2)
 
 
-def empty_counts(cfg: DeepseekV2Config):
-    """What the expert layers count over a round, zeroed: ``held`` [held]
-    the routed pairs each held expert took, ``pairs`` the pairs routed in
-    all, ``group_hits`` the tokens whose kept groups include a held one."""
-    return {"held": jnp.zeros((len(cfg.moe.held),), jnp.int32),
-            "pairs": jnp.zeros((), jnp.int32),
-            "group_hits": jnp.zeros((), jnp.int32)}
-
-
-def _add(a, b):
-    return jax.tree_util.tree_map(jnp.add, a, b)
-
-
 class DeepseekV2Block(nn.Module):
     """Latent attention, then the dense or the routed feed-forward, each
     added to the plain residual after its own pre-norm."""
@@ -196,9 +184,8 @@ class DeepseekV2Block(nn.Module):
         if self.dense:
             return x + self.mlp(h), rbuf, None
         with jax.named_scope("head_moe"):
-            y, held, pairs, hits = self.moe.routed(h.reshape(b * t, d))
-        return x + y.reshape(b, t, d), rbuf, {
-            "held": held, "pairs": pairs, "group_hits": hits}
+            y, n = self.moe.routed(h.reshape(b * t, d))
+        return x + y.reshape(b, t, d), rbuf, n
 
 
 class DeepseekV2Stack(nn.Module):
@@ -236,14 +223,14 @@ class DeepseekV2Stack(nn.Module):
         [B] positions (the round's start); pool [blocks, slots, S, d],
         read only, of which row b owns slot ``slots[b]``; rbuf [blocks, B,
         R, d] the round's own. Returns (exit states [B, T, C], rbuf,
-        counts), the counts (:func:`empty_counts`) summed over the
-        blocks."""
-        counts = empty_counts(self.cfg)
+        counts), the expert layers' counts (``transformer.zero_counts``)
+        summed over the blocks."""
+        counts = zero_counts(self.cfg.moe)
         x = x.astype(self.dtype)
         for i, block in enumerate(self.layers):
             x, rows, n = block(x, pool, rbuf[i], slots, ctx, at, cap, i)
             rbuf = rbuf.at[i].set(rows)
-            counts = counts if n is None else _add(counts, n)
+            counts = counts if n is None else add_counts(counts, n)
         return x, rbuf, counts
 
 
@@ -322,7 +309,7 @@ class VideoMAEDeepseekV2(nn.Module):
     # what ``stream_head.serve_round`` asks of a head
     @nn.nowrap
     def empty_counts(self):
-        return empty_counts(self.cfg.head)
+        return zero_counts(self.cfg.head.moe)
 
     @nn.nowrap
     def seed_round(self, variables, state, slots, reset):
@@ -369,14 +356,14 @@ class VideoMAEDeepseekV2(nn.Module):
                     apply(VideoMAEDeepseekV2.embed, tok)[:, None], pool,
                     rbuf, slots, pos0,
                     jnp.full(pos0.shape, c.visual_tokens, pos0.dtype) + r)
-            return (h[:, 0], rbuf, _add(counts, m)), (tok, top_i, top_p)
+            return (h[:, 0], rbuf, add_counts(counts, m)), (tok, top_i, top_p)
 
         (_, rbuf, counts), (toks, top_i, top_p) = jax.lax.scan(
             step, (h, rbuf, counts),
             jnp.arange(c.decode_steps, dtype=pos0.dtype))
         return {"tokens": toks.T, "top_ids": top_i.transpose(1, 0, 2),
                 "top_probs": top_p.transpose(1, 0, 2), "rows": rows,
-                "rbuf": rbuf, "moe_load": counts["held"],
+                "rbuf": rbuf, **moe_outputs(counts),
                 "moe_pairs_total": counts["pairs"],
                 "moe_group_hits": counts["group_hits"],
                 # what the round's prefill attention visited, summed over

@@ -43,8 +43,9 @@ import jax.numpy as jnp
 from . import stream_head
 from .common import Dtype
 from .stream_head import (Connector, RmsNorm, SwiGlu, _kernel,  # noqa: F401
-                          cast_for_serving, prepare_for_serving, top_tokens)
-from .transformer import TopKMoeConfig, TopKMoeMlp
+                          add_counts, cast_for_serving, moe_outputs,
+                          prepare_for_serving, top_tokens)
+from .transformer import TopKMoeConfig, TopKMoeMlp, zero_counts
 from .videomae import VideoMAE, VideoMAEConfig, tiny_videomae_config
 
 
@@ -359,13 +360,13 @@ class Lfm2Stack(nn.Module):
         [attention layers, slots, KV, hd, S], read only, of which row b
         owns slot ``slots[b]``; rbuf = (keys, values), each [attention
         layers, B, KV, hd, R], the round's own. Returns (h, conv, rbuf,
-        load) with ``load`` [held] the routed pairs each held expert took,
-        summed over the layers."""
+        counts), the expert layers' counts (``transformer.zero_counts``)
+        summed."""
         c = self.cfg
         b, t, d = x.shape
         convs, ci, ai = [], 0, 0
         rk, rv = rbuf
-        load = jnp.zeros((len(c.moe.held),), jnp.int32)
+        counts = zero_counts(c.moe)
         x = x.astype(self.dtype)
         for i, kind in enumerate(c.layer_types):
             h = self.n1[i](x)
@@ -386,10 +387,10 @@ class Lfm2Stack(nn.Module):
                 with jax.named_scope("head_moe"):
                     y, n = self.ffns[i](h.reshape(b * t, d))
                 x = x + y.reshape(b, t, d)
-                load = load + n
+                counts = add_counts(counts, n)
         if convs:
             conv = jnp.stack(convs, axis=1).astype(conv.dtype)
-        return x, conv, (rk, rv), load
+        return x, conv, (rk, rv), counts
 
 
 class VideoMAELfm2(nn.Module):
@@ -451,7 +452,7 @@ class VideoMAELfm2(nn.Module):
     # what ``stream_head.serve_round`` asks of a head
     @nn.nowrap
     def empty_counts(self):
-        return jnp.zeros((len(self.cfg.head.moe.held),), jnp.int32)
+        return zero_counts(self.cfg.head.moe)
 
     @nn.nowrap
     def seed_round(self, variables, state, slots, reset):
@@ -476,19 +477,19 @@ class VideoMAELfm2(nn.Module):
 
     @nn.nowrap
     def prefill(self, variables, x, kv, conv, rbuf, slots, pos0):
-        h, conv, rbuf, load = self.apply(
+        h, conv, rbuf, counts = self.apply(
             variables, x, conv, kv, rbuf, slots, pos0,
             method=VideoMAELfm2.forward)
-        return h[:, -1], conv, rbuf, load
+        return h[:, -1], conv, rbuf, counts
 
     @nn.nowrap
-    def decode(self, variables, kv, h, conv, rbuf, slots, pos0, load):
+    def decode(self, variables, kv, h, conv, rbuf, slots, pos0, counts):
         """D steps that each commit one position a stream."""
         c = self.cfg
         apply = lambda method, *a: self.apply(variables, *a, method=method)  # noqa: E731
 
         def step(carry, r):
-            h, conv, rbuf, load = carry
+            h, conv, rbuf, counts = carry
             with jax.named_scope("head_decode"):
                 tok, top_i, top_p = top_tokens(
                     apply(VideoMAELfm2.logits, h))
@@ -496,14 +497,15 @@ class VideoMAELfm2(nn.Module):
                     VideoMAELfm2.forward,
                     apply(VideoMAELfm2.embed, tok)[:, None],
                     conv, kv, rbuf, slots, pos0, c.visual_tokens + r)
-            return (h[:, 0], conv, rbuf, load + m), (tok, top_i, top_p)
+            return ((h[:, 0], conv, rbuf, add_counts(counts, m)),
+                    (tok, top_i, top_p))
 
-        (_, conv, rbuf, load), (toks, top_i, top_p) = jax.lax.scan(
-            step, (h, conv, rbuf, load),
+        (_, conv, rbuf, counts), (toks, top_i, top_p) = jax.lax.scan(
+            step, (h, conv, rbuf, counts),
             jnp.arange(c.decode_steps, dtype=pos0.dtype))
         return {"tokens": toks.T, "top_ids": top_i.transpose(1, 0, 2),
                 "top_probs": top_p.transpose(1, 0, 2), "rows": conv,
-                "rbuf": rbuf, "moe_load": load}
+                "rbuf": rbuf, **moe_outputs(counts)}
 
     @nn.nowrap
     def commit_round(self, state, kv, conv, rbuf, slots, pos0):

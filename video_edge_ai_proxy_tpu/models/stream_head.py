@@ -14,9 +14,8 @@ takes such a model (:func:`prepare_for_serving`) and the round itself
 (:func:`serve_round`): instruction, prefill in chunks of streams, decode,
 flush, as a pure function over a head that answers
 
-- ``empty_counts() -> load``: what its expert layers count over a round,
-  zeroed (the routed pairs each held expert took, [held] int32; a head
-  that counts more gives a pytree of them);
+- ``empty_counts() -> counts``: what its expert layers count over a
+  round, zeroed (``transformer.zero_counts``, a pytree);
 - ``seed_round(variables, state, slots, reset) -> (pool, rows)``: the
   standing instruction laid into the state; ``pool`` is what the round
   reads in place by slot (the caches), ``rows`` what it carries a batch row
@@ -25,11 +24,12 @@ flush, as a pure function over a head that answers
 - ``round_buffer(rows, dtype) -> rbuf``: the round's own cache rows (a
   pytree, batch axis 1), written to the pool once, when the round is over;
 - ``prefill(variables, x, pool, rows, rbuf, slots, pos0) -> (h, rows, rbuf,
-  load)``: a chunk's visual tokens ``x`` [n, V, d]; ``h`` [n, d] what the
+  counts)``: a chunk's visual tokens ``x`` [n, V, d]; ``h`` [n, d] what the
   first token is read from;
-- ``decode(variables, pool, h, rows, rbuf, slots, pos0, load) -> dict``:
+- ``decode(variables, pool, h, rows, rbuf, slots, pos0, counts) -> dict``:
   ``tokens``, ``top_ids``, ``top_probs`` (and whatever else the head
-  reports), with ``rows``, ``rbuf``, ``moe_load`` as the round left them;
+  reports), with ``rows``, ``rbuf`` as the round left them and the
+  counts as :func:`moe_outputs` reports them;
 - ``commit_round(state, pool, rows, rbuf, slots, pos0) -> state``.
 """
 
@@ -162,6 +162,20 @@ def prepare_for_serving(model, variables):
         model.instruction_state)(variables)}
 
 
+def add_counts(a, b):
+    """Two sums of the expert layers' counts (``transformer.zero_counts``)
+    added."""
+    return jax.tree_util.tree_map(jnp.add, a, b)
+
+
+def moe_outputs(counts) -> dict:
+    """What a round reports of its expert layers' summed counts:
+    ``moe_load`` [held] the pairs each held expert took, ``moe_pair_rows``
+    [rows moved, pairs routed]."""
+    return {"moe_load": counts["held"],
+            "moe_pair_rows": jnp.stack([counts["rows"], counts["pairs"]])}
+
+
 def top_tokens(logits):
     """(greedy id [B], top ids [B, 5], their probabilities) of float32
     logits [B, vocabulary]."""
@@ -187,8 +201,8 @@ def serve_round(model, variables, clips, state, slots, pos0, reset,
     Returns a dict: ``tokens`` [B, D] the greedy ids, ``top_ids`` /
     ``top_probs`` [B, D, 5] of each token's distribution, ``state`` the
     pool after the round (visual tokens and all D decoded tokens
-    committed), ``moe_load`` [held] routed pairs a held expert took
-    (prefill and decode), and what else the head's ``decode`` reports.
+    committed), :func:`moe_outputs` of the expert layers' counts (prefill
+    and decode), and what else the head's ``decode`` reports.
     """
     c = model.cfg
     b = clips.shape[0]
@@ -208,7 +222,7 @@ def serve_round(model, variables, clips, state, slots, pos0, reset,
     rbuf = model.round_buffer(b, jax.tree_util.tree_leaves(pool)[0].dtype)
 
     def prefill(i, carry):
-        h, rows, rbuf, load = carry
+        h, rows, rbuf, counts = carry
         cut = lambda a, ax=0: jax.lax.dynamic_slice_in_dim(  # noqa: E731
             a, i * n, n, axis=ax)
         put = lambda a, v, ax=0: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731
@@ -220,17 +234,17 @@ def serve_round(model, variables, clips, state, slots, pos0, reset,
             tree(lambda a: cut(a, 1), rbuf), cut(slots), cut(pos0))
         return (put(h, hn), tree(put, rows, rn),
                 tree(lambda a, v: put(a, v, 1), rbuf, bn),
-                tree(jnp.add, load, m))
+                add_counts(counts, m))
 
     # (the encoder inside the loop keeps ``embed`` / ``encoder_block``:
     # obs/stages.py tells prefill from decode by the outer name)
     with jax.named_scope("head_prefill"):
-        h, rows, rbuf, load = jax.lax.fori_loop(
+        h, rows, rbuf, counts = jax.lax.fori_loop(
             0, b // n, prefill,
             (jnp.zeros((b, c.head.dim), model.dtype), rows, rbuf,
              model.empty_counts()))
 
-    out = model.decode(variables, pool, h, rows, rbuf, slots, pos0, load)
+    out = model.decode(variables, pool, h, rows, rbuf, slots, pos0, counts)
     with jax.named_scope("head_flush"):
         out["state"] = model.commit_round(
             state, pool, out.pop("rows"), out.pop("rbuf"), slots, pos0)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.combine_rows import combine_block, combine_rows
 from .common import Dtype
 
 # attn_fn(q, k, v) -> out, all [B, T, H, D]
@@ -307,6 +308,43 @@ def topk_route(scores: jnp.ndarray, bias, cfg: TopKMoeConfig):
     return sel, w * cfg.routed_scaling_factor
 
 
+# the grouped products' row tile, and the fewest rows a rung below the top
+# may have: a few tiles, so that decode's calls (256-512 pairs) keep one
+# body and the scan over decode steps no conditional
+_ROW_TILE = 128
+_LEAST_RUNG = 8 * _ROW_TILE
+
+
+def pair_rungs(cfg: TopKMoeConfig, n: int) -> tuple:
+    """The row counts :class:`TopKMoeMlp` may size its per-pair arrays by
+    in a call on ``n`` tokens, largest first, from shapes alone: ``n x
+    top_k`` (every routed pair, so no routing can pass the top rung and the
+    layer stays dropless), then halves of the rung before, each up to a
+    row tile, for as long as a half holds twice the pairs an even router
+    sends the held experts (``n x top_k x held / num_experts``) and has at
+    least ``_LEAST_RUNG`` rows. One rung where the call is small (decode),
+    every expert is held here, or the way back's kernel does not take the
+    shape (``ops/combine_rows.py`` ``combine_block``)."""
+    top = n * cfg.top_k
+    if not combine_block(n, cfg.dim):
+        return (top,)
+    floor = max(2 * top * len(cfg.held) / cfg.num_experts, _LEAST_RUNG)
+    rungs = [top]
+    while True:
+        half = -(-rungs[-1] // (2 * _ROW_TILE)) * _ROW_TILE
+        if half < floor:
+            return tuple(rungs)
+        rungs.append(half)
+
+
+def zero_counts(cfg: TopKMoeConfig) -> dict:
+    """What :class:`TopKMoeMlp` counts of a call, zeroed: the start of a
+    head's sum over its layers and steps."""
+    zero = jnp.zeros((), jnp.int32)
+    return {"held": jnp.zeros((len(cfg.held),), jnp.int32), "rows": zero,
+            "pairs": zero, "group_hits": zero}
+
+
 class TopKMoeMlp(nn.Module):
     """Dropless top-k routed SwiGLU experts, told which experts it holds.
 
@@ -316,27 +354,32 @@ class TopKMoeMlp(nn.Module):
     is held here are sorted by expert and run through three grouped matmuls
     (``jax.lax.ragged_dot``: XLA's own grouped-matmul lowering on TPU, a
     masked dense product elsewhere), whatever the load of each expert: no
-    capacity, no dropped token, all tokens to one expert included. Pairs
-    whose expert lives on another holder add nothing here: the caller sums
-    the holders' partial outputs (on one chip of a deployment, nothing
-    stands in for the others). The stacks hold ``len(experts_held)``
-    experts and carry the "expert" logical axis (ep sharding). A shared
-    expert (``shared_mlp_dim``) is every holder's alike: its output is
-    added here unweighted, and counted once where holders are summed.
+    capacity, no dropped token, all tokens to one expert included. The
+    per-pair arrays are as long as the smallest rung of :func:`pair_rungs`
+    that holds the held pairs, chosen in the step from their count
+    (``jax.lax.switch``, one copy of the body a rung; the top rung is every
+    routed pair). Pairs whose expert lives on another holder add nothing
+    here: the caller sums the holders' partial outputs (on one chip of a
+    deployment, nothing stands in for the others). The stacks hold
+    ``len(experts_held)`` experts and carry the "expert" logical axis (ep
+    sharding). A shared expert (``shared_mlp_dim``) is every holder's
+    alike: its output is added here unweighted, and counted once where
+    holders are summed.
 
-    Returns ``(y [N, d], load [held])``: the pairs each held expert took.
-    :meth:`routed` returns two counts more: the pairs routed in all (N x
-    ``top_k``: ``load`` summed over every holder) and the tokens whose kept
-    groups include one that holds an expert held here (every token where
-    the router has no group limit).
+    Returns ``(y [N, d], counts)``, the counts (:func:`zero_counts`)
+    ``held`` [held] the pairs each held expert took, ``rows`` the rows
+    the per-pair arrays had (the rung run), ``pairs`` the pairs routed in
+    all (N x ``top_k``: ``held`` summed over every holder) and
+    ``group_hits`` the tokens whose kept groups include one that holds an
+    expert held here (every token where the router has no group limit).
+    :meth:`routed` is the same call.
     """
 
     cfg: TopKMoeConfig
     dtype: Dtype = jnp.bfloat16
 
     def __call__(self, x: jnp.ndarray):
-        y, load, _, _ = self.routed(x)
-        return y, load
+        return self.routed(x)
 
     @nn.compact
     def routed(self, x: jnp.ndarray):
@@ -379,21 +422,42 @@ class TopKMoeMlp(nn.Module):
         order = jnp.argsort(key, stable=True)                   # local first
         sizes = jnp.sum(key[:, None] == jnp.arange(h, dtype=jnp.int32)[None],
                         axis=0, dtype=jnp.int32)
-        here = jnp.arange(n * k) < jnp.sum(sizes)               # sorted rows
+        count = jnp.sum(sizes)
 
         w1, w3, w2 = (a.astype(self.dtype) for a in _expert_weights(
             self, c, stack=h, gated=True))
-        with jax.named_scope("moe_experts"):
-            # [N*k, d]
-            xs = jnp.take(x.astype(self.dtype), order // k, axis=0)
-            up = jax.lax.ragged_dot(xs, w3, sizes)
-            act = nn.silu(jax.lax.ragged_dot(xs, w1, sizes)) * up
-            ys = jax.lax.ragged_dot(act, w2, sizes,
-                                    preferred_element_type=jnp.float32)
-        # rows past the last group are whatever the kernel left there
-        ys = jnp.where(here[:, None], ys, 0.0)
-        back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(n, k, d)
-        y = jnp.einsum("nkd,nk->nd", back, w.astype(jnp.float32))
+        # The held pairs sort first, so the first ``count`` rows of
+        # ``order`` are all that is computed: each rung moves its C rows,
+        # the smallest that holds them is run. A pair's row lies where it
+        # sorted; one held elsewhere sorted past ``count``, and the way
+        # back (a kernel: XLA would write every pair's row out and read it
+        # back) reads only the rows under it.
+        rungs = pair_rungs(c, n)
+        src = jnp.argsort(order).reshape(n, k)
+
+        def rung(rows):
+            def moved(x, order, src, sizes, count, w, w1, w3, w2):
+                with jax.named_scope("moe_experts"):
+                    xs = jnp.take(x, order[:rows] // k, axis=0, mode="clip")
+                    up = jax.lax.ragged_dot(xs, w3, sizes)
+                    act = nn.silu(jax.lax.ragged_dot(xs, w1, sizes)) * up
+                    ys = jax.lax.ragged_dot(
+                        act, w2, sizes, preferred_element_type=jnp.float32)
+                if combine_block(n, d):
+                    return combine_rows(ys, src, w, count)
+                # a shape the kernel does not take has the one rung of
+                # every pair; rows past the last group are whatever the
+                # grouped products left there
+                ys = jnp.where((jnp.arange(rows) < count)[:, None], ys, 0.0)
+                return jnp.einsum("nkd,nk->nd", jnp.take(ys, src, axis=0), w)
+            return moved
+
+        at = jnp.sum(count <= jnp.asarray(rungs[1:], jnp.int32),
+                     dtype=jnp.int32)           # the smallest rung that holds
+        operands = (x.astype(self.dtype), order, src, sizes, count,
+                    w.astype(jnp.float32), w1, w3, w2)
+        y = (rung(rungs[0])(*operands) if len(rungs) == 1 else
+             jax.lax.switch(at, [rung(r) for r in rungs], *operands))
         if c.shared_mlp_dim:
             m = c.shared_mlp_dim
             s1, s3, s2 = (self.param(
@@ -407,8 +471,9 @@ class TopKMoeMlp(nn.Module):
                 xd = x.astype(self.dtype)
                 y = y + jnp.dot(nn.silu(xd @ s1) * (xd @ s3), s2,
                                 preferred_element_type=jnp.float32)
-        return (y.astype(self.dtype), sizes, jnp.asarray(n * k, jnp.int32),
-                hits)
+        return y.astype(self.dtype), {
+            "held": sizes, "rows": jnp.asarray(rungs, jnp.int32)[at],
+            "pairs": jnp.asarray(n * k, jnp.int32), "group_hits": hits}
 
 
 class EncoderBlock(nn.Module):

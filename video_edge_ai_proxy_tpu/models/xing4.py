@@ -51,8 +51,9 @@ import jax.numpy as jnp
 from . import mla, stream_head
 from .common import Dtype
 from .mla import MlaAttention, MlaConfig, flush_round, seed_rows
-from .stream_head import Connector, RmsNorm, SwiGlu, _kernel, top_tokens
-from .transformer import TopKMoeConfig, TopKMoeMlp
+from .stream_head import (Connector, RmsNorm, SwiGlu, _kernel, add_counts,
+                          moe_outputs, top_tokens)
+from .transformer import TopKMoeConfig, TopKMoeMlp, zero_counts
 from .videomae import VideoMAE, VideoMAEConfig, tiny_videomae_config
 
 
@@ -271,10 +272,9 @@ class Xing4Block(nn.Module):
         pre, post, res = self.ffn_hc(x)
         h = self.ffn_norm(hc_read(pre, x))
         if self.dense:
-            y, n = self.mlp(h), 0
-        else:
-            with jax.named_scope("head_moe"):
-                y, n = self.moe(h.reshape(b * t, -1))
+            return hc_write(res, post, x, self.mlp(h)), rbuf, None
+        with jax.named_scope("head_moe"):
+            y, n = self.moe(h.reshape(b * t, -1))
         return hc_write(res, post, x, y), rbuf, n
 
 
@@ -327,14 +327,15 @@ class Xing4Stack(nn.Module):
         [B] positions (the round's start); pool [blocks, slots, S, d],
         read only, of which row b owns slot ``slots[b]``; rbuf [blocks, B,
         R, d] the round's own. Returns (exit states [B, T, C], rbuf,
-        load) with ``load`` [held] the routed pairs each held expert took,
+        counts), the expert layers' counts (``transformer.zero_counts``)
         summed over the blocks."""
-        load = jnp.zeros((len(self.cfg.moe.held),), jnp.int32)
+        counts = zero_counts(self.cfg.moe)
         x = self._enter(x)
         for i, block in enumerate(self.layers):
             x, rows, n = block(x, pool, rbuf[i], slots, ctx, at, cap, i)
-            rbuf, load = rbuf.at[i].set(rows), load + n
-        return self._exit(x), rbuf, load
+            rbuf = rbuf.at[i].set(rows)
+            counts = counts if n is None else add_counts(counts, n)
+        return self._exit(x), rbuf, counts
 
     def _mtp_input(self, h, e):
         u = jnp.concatenate([self.mtp_h_norm(h), self.mtp_e_norm(e)], -1)
@@ -353,7 +354,7 @@ class Xing4Stack(nn.Module):
 
     def mtp(self, h, e, pool, rbuf, slots, ctx, at):
         """The module on T decode positions: (its exit states [B, T, C],
-        rbuf, load)."""
+        rbuf, counts)."""
         i = self.cfg.num_layers
         x, rows, n = self.mtp_block(self._mtp_input(h, e), pool, rbuf[i],
                                     slots, ctx, at, 0, i)
@@ -450,7 +451,7 @@ class VideoMAEXing4(nn.Module):
     # what ``stream_head.serve_round`` asks of a head
     @nn.nowrap
     def empty_counts(self):
-        return jnp.zeros((len(self.cfg.head.moe.held),), jnp.int32)
+        return zero_counts(self.cfg.head.moe)
 
     @nn.nowrap
     def seed_round(self, variables, state, slots, reset):
@@ -489,17 +490,17 @@ class VideoMAEXing4(nn.Module):
     @nn.nowrap
     def prefill(self, variables, x, pool, exit_, rbuf, slots, pos0):
         apply = lambda method, *a: self.apply(variables, *a, method=method)  # noqa: E731
-        h, rbuf, load = apply(VideoMAEXing4.forward, x, pool, rbuf, slots,
-                              pos0, None, self._cap)
+        h, rbuf, counts = apply(VideoMAEXing4.forward, x, pool, rbuf, slots,
+                                pos0, None, self._cap)
         # the module's row j pairs the exit state at j with the input at
         # j + 1: the stream's last position before this round comes first
         before = jnp.concatenate(
             [exit_[:, None].astype(h.dtype), h[:, :-1]], axis=1)
         rbuf = apply(VideoMAEXing4.mtp_rows, before, x, rbuf, pos0 - 1)
-        return h[:, -1], h[:, -1], rbuf, load
+        return h[:, -1], h[:, -1], rbuf, counts
 
     @nn.nowrap
-    def decode(self, variables, pool, h, exit_, rbuf, slots, pos0, load):
+    def decode(self, variables, pool, h, exit_, rbuf, slots, pos0, counts):
         """Greedy decoding with the prediction module as a depth-1
         self-drafter. An iteration runs the main model on two positions a
         stream, the next token and the module's draft of the one after:
@@ -542,7 +543,8 @@ class VideoMAEXing4(nn.Module):
         carry = {
             "done": zero,               # tokens committed to the cache
             "tok": tok, "draft": first[0], "h": h, "rbuf": rbuf,
-            "load": load + m, "iters": jnp.zeros((), jnp.int32),
+            "counts": add_counts(counts, m),
+            "iters": jnp.zeros((), jnp.int32),
             "drafted": zero, "accepted": zero,
             "tokens": place(jnp.zeros((b, d), jnp.int32), zero, everyone,
                             tok),
@@ -579,7 +581,8 @@ class VideoMAEXing4(nn.Module):
                 pick = lambda a: jnp.where(  # noqa: E731
                     two.reshape((b,) + (1,) * (a.ndim - 2)), a[:, 1], a[:, 0])
                 return {
-                    **out, "rbuf": rbuf, "load": s["load"] + m + mm,
+                    **out, "rbuf": rbuf,
+                    "counts": add_counts(add_counts(s["counts"], m), mm),
                     "iters": s["iters"] + 1,
                     "done": jnp.where(live, done + 1 + two, done),
                     "tok": keep(jnp.where(two, t2, t1), s["tok"]),
@@ -592,7 +595,7 @@ class VideoMAEXing4(nn.Module):
                                carry)
         return {"tokens": s["tokens"], "top_ids": s["top_ids"],
                 "top_probs": s["top_probs"], "rows": s["h"],
-                "rbuf": s["rbuf"], "moe_load": s["load"],
+                "rbuf": s["rbuf"], **moe_outputs(s["counts"]),
                 "mtp_drafted": s["drafted"], "mtp_accepted": s["accepted"],
                 "decode_iters": s["iters"],
                 # what the round's prefill attention visited, summed over
