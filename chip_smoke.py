@@ -336,7 +336,9 @@ def stream_head_phase(seed: int, name: str = "tiny_videomae_lfm2") -> None:
     the program), the second continuing the first's state. Every head:
     LFM2's conv state and key-value cache, Xing4's latent cache, whose
     decode loop the prediction module drafts for, DeepSeek-V2's latent
-    cache alone, whose router counts the pairs it routed in all."""
+    cache alone, whose router counts the pairs it routed in all,
+    Falcon-H1's key-value cache beside its conv state and its float32
+    state-space state, which each round writes over its slot's."""
     import jax
     import jax.numpy as jnp
 
@@ -371,9 +373,18 @@ def stream_head_phase(seed: int, name: str = "tiny_videomae_lfm2") -> None:
         # a head whose router is limited to a few expert groups may send its
         # two held experts nothing in a round: it counts the pairs routed in
         # all, and those are what has to be there
-        check(int(host.get("moe_pairs_total", host["moe_load"].sum())) > 0,
-              f"stream head round {r}: no routed pair"
-              + ("" if "moe_pairs_total" in host else " on the held experts"))
+        if "moe_load" in host:
+            check(int(host.get("moe_pairs_total",
+                               host["moe_load"].sum())) > 0,
+                  f"stream head round {r}: no routed pair"
+                  + ("" if "moe_pairs_total" in host
+                     else " on the held experts"))
+        if "ssm" in pool.state:
+            ssm = np.asarray(pool.state["ssm"])[plan["idx"][:2]]
+            check(ssm.dtype == np.float32 and np.isfinite(ssm).all()
+                  and np.abs(ssm).max() > 0,
+                  f"stream head round {r}: the state-space state was not "
+                  "written")
         if "mtp_drafted" in host:
             check(1 <= int(host["decode_iters"]) <= c.decode_steps
                   and (host["mtp_accepted"] <= host["mtp_drafted"]).all()
@@ -391,7 +402,8 @@ def stream_head_phase(seed: int, name: str = "tiny_videomae_lfm2") -> None:
     say(f"stream head {name}: 2 rounds x 2 streams through the state pool "
         f"({held} B of {sorted(pool.state)} on "
         f"{sorted(str(d) for d in pool.state['tokens'].devices())}"
-        f"), {int(host['moe_load'].sum())} routed pairs on the held experts "
+        + (f"), {int(host['moe_load'].sum())} routed pairs on the held "
+           "experts " if "moe_load" in host else "), no experts ")
         + (f"of {int(host['moe_pairs_total'])} "
            if "moe_pairs_total" in host else "")
         + f"in the last round, tokens {host['tokens'][0].tolist()}"
@@ -977,6 +989,7 @@ def main(argv=None) -> int:
         stream_head_phase(args.seed)
         stream_head_phase(args.seed, "tiny_videomae_xing4")
         stream_head_phase(args.seed, "tiny_videomae_dsv2")
+        stream_head_phase(args.seed, "tiny_videomae_b_falcon_h1")
         facts = server_phase(args.seed)
     counts = cache.snapshot()
     say(f"cache: {counts} in "

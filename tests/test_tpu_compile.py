@@ -566,3 +566,87 @@ class TestDeepseekV2HeadLayers:
         assert [line for line in text.splitlines()
                 if line.lstrip().startswith("%combine_rows")]
         assert "f32[64,6,5120]" not in text
+
+
+class TestFalconH1HeadLayers:
+    """The fourth streaming head at the published widths
+    (models/falcon_h1.py): the Mamba-2 mixer of one chip's share (16 heads
+    of 128 channels, one group, a 256-wide float32 state a head) with its
+    chunked scan (ops/ssd.py) in the prefill and its one-position step in
+    decode, compiled INSIDE a loop as the serving step runs them; and the
+    cell's whole step, which has to fit the chip beside the benchmark's
+    float32 draw."""
+
+    @pytest.mark.parametrize("t", [784, 1], ids=["prefill", "decode"])
+    def test_mixer_in_a_loop(self, v5e, t):
+        import flax.linen as nn
+
+        from video_edge_ai_proxy_tpu.models import falcon_h1
+
+        cfg = falcon_h1.FalconH1Config()
+        mixer = falcon_h1.Mamba2Mixer(cfg)
+        b = 8
+        state = (b, cfg.mamba_heads, cfg.mamba_head_dim, cfg.d_state)
+        shapes = jax.eval_shape(lambda: nn.meta.unbox(mixer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 2, cfg.dim), jnp.bfloat16),
+            jnp.zeros((1, cfg.d_conv - 1, cfg.conv_dim), jnp.bfloat16),
+            jnp.zeros((1,) + state[1:], jnp.float32))))
+        assert shapes["params"]["in_proj"].shape == (5120, 4624)
+        variables = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            shapes)
+
+        def twice(variables, n, conv, ssm):
+            def body(_, carry):
+                n, conv, ssm = carry
+                m, conv, ssm = mixer.apply(variables, n, conv, ssm)
+                return n + m, conv.astype(jnp.bfloat16), ssm
+            return jax.lax.fori_loop(0, 2, body, (n, conv, ssm))
+
+        text = _compiled_text(
+            twice, variables,
+            jax.ShapeDtypeStruct((b, t, cfg.dim), jnp.bfloat16, sharding=v5e),
+            jax.ShapeDtypeStruct((b, cfg.d_conv - 1, cfg.conv_dim),
+                                 jnp.bfloat16, sharding=v5e),
+            jax.ShapeDtypeStruct(state, jnp.float32, sharding=v5e))
+        assert "while" in text
+        assert "f32[%d,%d,%d,%d]" % state in text
+
+    @pytest.mark.slow
+    def test_the_cells_step_fits_the_chip(self, v5e, monkeypatch):
+        """``falconh1_64_360p``'s step (64 clips of 360p, the pool of 64
+        streams donated) compiled for a described v5e: the state comes back
+        aliased, and the step's arguments and temporaries, beside the
+        float32 draw that the benchmark keeps for its reference, stay
+        under the chip's 16 GB. ~50 s of compile, hence ``slow``."""
+        from video_edge_ai_proxy_tpu.engine.runner import build_serving_step
+        from video_edge_ai_proxy_tpu.models import registry
+
+        _as_if_on_tpu(monkeypatch)
+        spec = registry.get("videomae_b_falcon_h1")
+        model = spec.build()
+        on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+            a.shape, a.dtype, sharding=v5e)
+        drawn = jax.eval_shape(
+            lambda: spec.init_params(jax.random.PRNGKey(0))[1])
+        variables = jax.tree.map(on_chip, jax.eval_shape(
+            lambda v: spec.prepare(model, v), drawn))
+        b, c = 64, model.cfg
+        state = dict(jax.eval_shape(lambda: model.empty_state(b))[0],
+                     tokens=jax.ShapeDtypeStruct(
+                         (b, c.max_rounds * c.decode_steps), jnp.int32))
+        state = jax.tree.map(on_chip, state)
+        ints = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e)
+        compiled = jax.jit(build_serving_step(model, spec),
+                           donate_argnums=(2,)).lower(
+            variables, jax.ShapeDtypeStruct((b, 8, 360, 640, 3), jnp.uint8,
+                                            sharding=v5e),
+            state, ints, ints,
+            jax.ShapeDtypeStruct((b,), bool, sharding=v5e), ints).compile()
+        mem = compiled.memory_analysis()
+        held = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves(state))
+        assert mem.alias_size_in_bytes >= held
+        draw = 4 * sum(a.size for a in jax.tree_util.tree_leaves(drawn))
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            + draw < 16e9

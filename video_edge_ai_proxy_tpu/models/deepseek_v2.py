@@ -238,6 +238,8 @@ class VideoMAEDeepseekV2(nn.Module):
     """VideoMAE encoder (no classifier) -> connector -> DeepSeek-V2 head."""
     cfg: StreamHeadConfig
     dtype: Dtype = jnp.bfloat16
+    # the kind of state the round buffer is flushed into
+    cache_kind = "latent"
 
     def setup(self):
         c = self.cfg

@@ -13,7 +13,8 @@ by ``layer_types``: ``conv``: ``B, C, x = split(in_proj(h), 3)``,
 ``u = B * x``, ``y = out_proj(C * causal_conv1d(u))`` (depthwise, kernel
 ``conv_L_cache``, no bias), whose state is the last ``conv_L_cache - 1``
 positions of ``u``; ``full_attention``: grouped-query attention, q and k
-RMS-normed per head, rotary positions (rotate-half), causal.
+RMS-normed per head, rotary positions (rotate-half), causal
+(``models/gqa.py``, shared with ``models/falcon_h1.py``).
 Feed-forward: the first ``num_dense_layers`` dense SwiGLU, the rest
 ``transformer.TopKMoeMlp`` (sigmoid router over all experts, top-k of
 score + bias, renormalised, dropless, this chip's ``experts_held``).
@@ -34,14 +35,15 @@ a head (:class:`VideoMAELfm2`'s ``seed_round`` ... ``commit_round``).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Tuple
+from typing import ClassVar, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from . import stream_head
+from . import gqa, stream_head
 from .common import Dtype
+from .gqa import CachedAttention, flush_round
 from .stream_head import (Connector, RmsNorm, SwiGlu, _kernel,  # noqa: F401
                           add_counts, cast_for_serving, moe_outputs,
                           prepare_for_serving, top_tokens)
@@ -72,6 +74,9 @@ class Lfm2Config:
     norm_eps: float = 1e-5
     rope_theta: float = 1e6
     max_context: int = 4096
+    # what the shared attention asks beyond the sizes (gqa.GqaConfig)
+    qk_norm: ClassVar[bool] = True
+    key_multiplier: ClassVar[float] = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -148,163 +153,6 @@ class ShortConv(nn.Module):
             y = sum(full[:, j:j + t] * w_conv[j].astype(self.dtype)
                     for j in range(taps))
             return (cc * y) @ w_out.astype(self.dtype), full[:, t:]
-
-
-def rope(x, pos, theta: float):
-    """Rotate-half rotary embedding: x [B, T, H, hd], pos [B, T]."""
-    hd = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = pos.astype(jnp.float32)[..., None] * inv             # [B, T, hd/2]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, :, None]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None]
-    x = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
-
-
-def prefill_attention(q, pool_k, pool_v, new_k, new_v, slots, pos0):
-    """A round's first T positions: q [B, T, H, hd] against each row's slot
-    of the pool (``pool_k`` / ``pool_v`` [slots, KV, hd, S]: the positions
-    before ``pos0`` [B] are the stream's context; the rest is stale or
-    unwritten, and masked) and, causally, against the T new keys and values
-    themselves (``new_k`` / ``new_v`` [B, KV, hd, T]). One stream at a
-    time, so the [T, S + T] scores of one are all that is held."""
-    b, t, h, hd = q.shape
-    kv = new_k.shape[1]
-    g = h // kv
-    causal = jnp.tril(jnp.ones((t, t), bool))
-
-    def one(args):
-        # a leading axis of one stream: the batched products below are the
-        # form the TPU compiler lays out well (written without the axis,
-        # this loop took twice as long on the chip)
-        qc, sc, pc, kn, vn = (a[None] for a in args)
-        kc = jnp.concatenate(
-            [jnp.take(pool_k, sc, axis=0, mode="clip"), kn], axis=-1)
-        vc = jnp.concatenate(
-            [jnp.take(pool_v, sc, axis=0, mode="clip"), vn], axis=-1)
-        s = jnp.einsum("btkgd,bkds->bkgts", qc.reshape(1, t, kv, g, hd),
-                       kc).astype(jnp.float32) * hd ** -0.5
-        old = jnp.arange(pool_k.shape[-1])[None, :] < pc[:, None]   # [1, S]
-        mask = jnp.concatenate(
-            [jnp.broadcast_to(old[:, None], (1, t, old.shape[1])),
-             causal[None]], axis=-1)
-        s = jnp.where(mask[:, None, None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(qc.dtype)
-        return jnp.einsum("bkgts,bkds->btkgd", p, vc).reshape(t, h * hd)
-
-    return jax.lax.map(one, (q, slots, pos0, new_k, new_v))
-
-
-def decode_attention(q, pool_k, pool_v, round_k, round_v, slots, pos0, r):
-    """One new position a stream: q [B, H, hd] against the pool, read IN
-    PLACE in slot order (the queries are carried to their slots and the
-    partial results back by a one-hot product: [B, slots] against a few
-    KB a row, where gathering the rows would copy the cache), and against
-    this round's own keys and values ``round_k`` / ``round_v`` [B, KV, hd,
-    R], of which the first ``r + 1`` are written. The two partial softmaxes
-    are merged by their maxima and sums."""
-    b, h, hd = q.shape
-    c, kv = pool_k.shape[0], pool_k.shape[1]
-    g = h // kv
-    scale = hd ** -0.5
-    qg = q.reshape(b, kv, g, hd)
-    onehot = (slots[:, None] == jnp.arange(c)[None]).astype(jnp.float32)
-
-    def partial(query, keys, values, mask):
-        s = jnp.einsum("bkgd,bkds->bkgs", query, keys).astype(
-            jnp.float32) * scale
-        s = jnp.where(mask[:, None, None], s, -1e30)
-        m = jnp.max(s, axis=-1)
-        e = jnp.exp(s - m[..., None])
-        o = jnp.einsum("bkgs,bkds->bkgd", e.astype(values.dtype), values)
-        return m, jnp.sum(e, axis=-1), o.astype(jnp.float32)
-
-    # the pool's part, in slot order
-    q_slot = jnp.einsum("bc,bkgd->ckgd", onehot, qg.astype(jnp.float32))
-    pos_slot = jnp.einsum("bc,b->c", onehot, pos0.astype(jnp.float32))
-    old = jnp.arange(pool_k.shape[-1])[None] < pos_slot[:, None]   # [c, S]
-    m_a, l_a, o_a = partial(q_slot.astype(q.dtype), pool_k, pool_v, old)
-    m_a, l_a, o_a = (jnp.einsum("bc,c...->b...", onehot, x)
-                     for x in (m_a, l_a, o_a))
-    # this round's part, in batch order
-    new = jnp.arange(round_k.shape[-1])[None] <= r
-    m_b, l_b, o_b = partial(qg, round_k, round_v,
-                            jnp.broadcast_to(new, (b, new.shape[1])))
-    m = jnp.maximum(m_a, m_b)
-    w_a, w_b = jnp.exp(m_a - m), jnp.exp(m_b - m)
-    o = (o_a * w_a[..., None] + o_b * w_b[..., None]) \
-        / (l_a * w_a + l_b * w_b)[..., None]
-    return o.astype(q.dtype).reshape(b, h * hd)
-
-
-class CachedAttention(nn.Module):
-    """GQA with per-head RMS-normed q and k and rotary positions, over a
-    stream's context in the key-value pool (read only: ``pool`` = (keys,
-    values), each [attention layers, slots, KV, hd, S], positions
-    minor-most, the layout the TPU compiler gives a cache that decode
-    steps read) and over this round's own positions, kept in a round
-    buffer ``rbuf`` = (keys, values), each [B, KV, hd, R], and written to
-    the pool once, when the round is over (:func:`flush_round`): inside
-    the round's loops the pool is not written, so it is never copied."""
-    cfg: Lfm2Config
-    layer: int = 0
-    dtype: Dtype = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, h, pool, rbuf, slots, pos0, r0):
-        c, d, hd = self.cfg, self.cfg.dim, self.cfg.head_dim
-        b, t, _ = h.shape
-        kvd = c.num_kv_heads * hd
-        wq = _kernel(self, "q_proj", (d, d), ("embed", "qkv"))
-        wk = _kernel(self, "k_proj", (d, kvd), ("embed", "qkv"))
-        wv = _kernel(self, "v_proj", (d, kvd), ("embed", "qkv"))
-        wo = _kernel(self, "out_proj", (d, d), ("qkv", "embed"))
-        with jax.named_scope("head_attn"):
-            pos = pos0[:, None] + r0 + jnp.arange(t, dtype=pos0.dtype)[None]
-            q = (h @ wq.astype(self.dtype)).reshape(b, t, c.num_heads, hd)
-            k = (h @ wk.astype(self.dtype)).reshape(b, t, c.num_kv_heads, hd)
-            v = (h @ wv.astype(self.dtype)).reshape(b, t, c.num_kv_heads, hd)
-            q = rope(RmsNorm(c.norm_eps, jnp.float32, name="q_norm")(q),
-                     pos, c.rope_theta).astype(self.dtype)
-            k = rope(RmsNorm(c.norm_eps, jnp.float32, name="k_norm")(k),
-                     pos, c.rope_theta).astype(self.dtype)
-            k, v = (a.transpose(0, 2, 3, 1).astype(rbuf[0].dtype)
-                    for a in (k, v))                    # [B, KV, hd, T]
-            rk = jax.lax.dynamic_update_slice_in_dim(rbuf[0], k, r0, axis=3)
-            rv = jax.lax.dynamic_update_slice_in_dim(rbuf[1], v, r0, axis=3)
-            keys, values = pool[0][self.layer], pool[1][self.layer]
-            if t == 1:
-                o = decode_attention(q[:, 0], keys, values, rk, rv, slots,
-                                     pos0, r0)[:, None]
-            else:
-                # a prefill starts its round: the T new positions are all
-                # of the round that is written
-                o = prefill_attention(q, keys, values, k, v, slots, pos0)
-            return o @ wo.astype(self.dtype), (rk, rv)
-
-
-def flush_round(pool, rbuf, slots, pos0):
-    """The round's keys and values (``rbuf`` = (keys, values), each
-    [attention layers, B, KV, hd, R]) into each row's slot of the pool at
-    its ``pos0``, in place: a loop over the rows, one guarded slice update
-    a row (all layers at once; a batched scatter with this window is
-    refused by the TPU compiler). A row whose slot is past the pool (a
-    padded batch row) writes nothing."""
-    last = pool[0].shape[1] - 1
-
-    def row(i, pool):
-        out = []
-        for dst, src in zip(pool, rbuf):
-            new = jax.lax.dynamic_slice_in_dim(src, i, 1, axis=1).astype(
-                dst.dtype)
-            at = (0, jnp.minimum(slots[i], last), 0, 0, pos0[i])
-            old = jax.lax.dynamic_slice(dst, at, new.shape)
-            out.append(jax.lax.dynamic_update_slice(
-                dst, jnp.where(slots[i] <= last, new, old), at))
-        return tuple(out)
-
-    return jax.lax.fori_loop(0, rbuf[0].shape[1], row, tuple(pool))
 
 
 class Lfm2Stack(nn.Module):
@@ -397,6 +245,8 @@ class VideoMAELfm2(nn.Module):
     """VideoMAE encoder (no classifier) -> connector -> LFM2 head."""
     cfg: StreamHeadConfig
     dtype: Dtype = jnp.bfloat16
+    # the kind of state the round buffer is flushed into
+    cache_kind = "kv"
 
     def setup(self):
         c = self.cfg
@@ -533,8 +383,7 @@ class VideoMAELfm2(nn.Module):
 def round_buffer(cfg: Lfm2Config, rows: int, positions: int,
                  dtype=jnp.bfloat16):
     """Zeroed (keys, values) of one round of ``rows`` streams."""
-    return tuple(jnp.zeros((cfg.attn_layers, rows, cfg.num_kv_heads,
-                            cfg.head_dim, positions), dtype) for _ in "kv")
+    return gqa.round_buffer(cfg, cfg.attn_layers, rows, positions, dtype)
 
 
 def empty_state(cfg: Lfm2Config, slots: int, context=None,
@@ -544,6 +393,4 @@ def empty_state(cfg: Lfm2Config, slots: int, context=None,
     context = cfg.max_context if context is None else context
     conv = jnp.zeros((slots, cfg.conv_layers, cfg.conv_l_cache - 1, cfg.dim),
                      dtype)
-    kv = tuple(jnp.zeros((cfg.attn_layers, slots, cfg.num_kv_heads,
-                          cfg.head_dim, context), dtype) for _ in "kv")
-    return conv, kv
+    return conv, gqa.empty_pool(cfg, cfg.attn_layers, slots, context, dtype)

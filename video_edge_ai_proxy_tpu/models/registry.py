@@ -193,6 +193,33 @@ register(ModelSpec(
                 "iteration",
 ))
 
+
+def _falcon_h1(tiny: bool = False):
+    """The fourth streaming head, imported when it is first built."""
+    from . import falcon_h1
+
+    if tiny:
+        return falcon_h1.VideoMAEFalconH1(falcon_h1.tiny_stream_head_config(),
+                                          dtype=jnp.float32)
+    return falcon_h1.VideoMAEFalconH1(falcon_h1.StreamHeadConfig())
+
+
+register(ModelSpec(
+    "videomae_b_falcon_h1", _falcon_h1,
+    input_size=224, preprocess="clip", kind="stream", clip_len=8,
+    prepare=prepare_for_serving,
+    description="fourth streaming head (models/falcon_h1.py): VideoMAE-B "
+                "encoder -> connector -> Falcon-H1-34B decoder at the "
+                "published widths, one chip's share of a two-chip layer (4 "
+                "of 72 layers, 10 of 20 attention heads, one of two mixer "
+                "groups, half the SwiGLU columns, an eighth of the "
+                "vocabulary); every layer a Mamba-2 mixer (ops/ssd.py, the "
+                "chunked scan) beside GQA attention (models/gqa.py); per-"
+                "stream key-value cache, conv state and float32 state-space "
+                "state in engine/stream_state.py, the last overwritten every "
+                "round; 784 tokens prefilled and 8 decoded a stream a round",
+))
+
 # --- diagnostic gauges ----------------------------------------------------
 
 register(ModelSpec(
@@ -261,4 +288,12 @@ register(ModelSpec(
     prepare=prepare_for_serving,
     description="CPU/CI twin of videomae_b_dsv2 (tests/test_deepseek_v2_head"
                 ".py), in float32 throughout",
+))
+register(ModelSpec(
+    "tiny_videomae_b_falcon_h1", lambda: _falcon_h1(tiny=True),
+    input_size=40, preprocess="clip", kind="stream", clip_len=4,
+    prepare=prepare_for_serving,
+    description="CPU/CI twin of videomae_b_falcon_h1 (tests/test_falcon_h1_"
+                "head.py), in float32 throughout: two mixer groups, a chunk "
+                "of 8 under 50 visual tokens a round",
 ))

@@ -1,6 +1,6 @@
 """What the streaming heads share (``models/lfm2.py``, ``models/xing4.py``,
-``models/deepseek_v2.py``): a decoder behind the VideoMAE encoder that keeps
-a state per camera.
+``models/deepseek_v2.py``, ``models/falcon_h1.py``): a decoder behind the
+VideoMAE encoder that keeps a state per camera.
 
 The reference ships frames to external clients and has no model at all
 (`/root/reference/README.md:5-27`). Here, every round, a clip camera's
@@ -14,15 +14,20 @@ takes such a model (:func:`prepare_for_serving`) and the round itself
 (:func:`serve_round`): instruction, prefill in chunks of streams, decode,
 flush, as a pure function over a head that answers
 
+- ``cache_kind``: the kind of state (a key of ``empty_state``) its round
+  buffer is flushed into: the buffer takes that kind's dtype, whatever
+  the dtypes of the other kinds;
 - ``empty_counts() -> counts``: what its expert layers count over a
-  round, zeroed (``transformer.zero_counts``, a pytree);
+  round, zeroed (``transformer.zero_counts``, a pytree; ``{}`` for a head
+  without experts);
 - ``seed_round(variables, state, slots, reset) -> (pool, rows)``: the
   standing instruction laid into the state; ``pool`` is what the round
   reads in place by slot (the caches), ``rows`` what it carries a batch row
   (gathered by slot: a pytree, batch axis 0; ``()`` where the caches are
   all its state);
 - ``round_buffer(rows, dtype) -> rbuf``: the round's own cache rows (a
-  pytree, batch axis 1), written to the pool once, when the round is over;
+  pytree, batch axis 1, in the dtype of ``cache_kind``), written to the
+  pool once, when the round is over;
 - ``prefill(variables, x, pool, rows, rbuf, slots, pos0) -> (h, rows, rbuf,
   counts)``: a chunk's visual tokens ``x`` [n, V, d]; ``h`` [n, d] what the
   first token is read from;
@@ -171,7 +176,9 @@ def add_counts(a, b):
 def moe_outputs(counts) -> dict:
     """What a round reports of its expert layers' summed counts:
     ``moe_load`` [held] the pairs each held expert took, ``moe_pair_rows``
-    [rows moved, pairs routed]."""
+    [rows moved, pairs routed]; nothing for a head without experts."""
+    if not counts:
+        return {}
     return {"moe_load": counts["held"],
             "moe_pair_rows": jnp.stack([counts["rows"], counts["pairs"]])}
 
@@ -219,7 +226,8 @@ def serve_round(model, variables, clips, state, slots, pos0, reset,
     # scores and the dense layer's activations). The pool is only read;
     # the round's cache rows go to the round buffer.
     n = c.prefill_chunk if b % c.prefill_chunk == 0 else b
-    rbuf = model.round_buffer(b, jax.tree_util.tree_leaves(pool)[0].dtype)
+    rbuf = model.round_buffer(b, jax.tree_util.tree_leaves(
+        state[model.cache_kind])[0].dtype)
 
     def prefill(i, carry):
         h, rows, rbuf, counts = carry

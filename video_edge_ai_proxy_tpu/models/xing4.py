@@ -365,6 +365,8 @@ class VideoMAEXing4(nn.Module):
     """VideoMAE encoder (no classifier) -> connector -> Xing4 head."""
     cfg: StreamHeadConfig
     dtype: Dtype = jnp.bfloat16
+    # the kind of state the round buffer is flushed into
+    cache_kind = "latent"
 
     def setup(self):
         c = self.cfg

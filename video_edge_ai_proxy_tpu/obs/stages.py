@@ -59,9 +59,12 @@ SCOPES = (
     # models/stream_head.py: the round of a stream head
     "head_seed", "head_prefill", "head_connector", "head_dense_mlp",
     "head_decode", "head_sample", "head_flush",
-    # models/lfm2.py, mla.py, xing4.py, deepseek_v2.py: a head's layers
+    # models/lfm2.py, gqa.py, mla.py, xing4.py, deepseek_v2.py,
+    # falcon_h1.py: a head's layers
     "head_conv", "head_attn", "mla_prefill", "mla_decode", "mhc_maps",
-    "head_moe", "head_lm", "mtp_draft",
+    "head_moe", "head_ssm", "head_lm", "mtp_draft",
+    # ops/ssd.py: the state-space scan inside a mixer
+    "ssm_scan",
     # models/transformer.py: the expert layer
     "moe_route", "moe_experts", "moe_shared",
 )
