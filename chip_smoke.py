@@ -7,12 +7,13 @@ the first failure ends the run with a non-zero exit and no result line:
    chip_smoke.py`` fails, it does not shrink). Prints versions, device kind
    and count, ``bytes_limit``, and the compile-cache directory in use
    (``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``).
-2. **Kernels** — the two Pallas kernels of the serving path on the chip,
+2. **Kernels** — the Pallas kernels of the serving path on the chip,
    each against its plain XLA twin on the same seeded input, and each
    compiled program checked for its ``tpu_custom_call``: NMS at K=256 alone
    and vmapped over 16 rows (keep masks equal exactly); flash attention at
    the ``videomae_b_long`` shape [1,6272,12,64] and at T=784, bf16, against
-   ``default_attention`` within a stated tolerance.
+   ``default_attention`` within a stated tolerance; the latent and the
+   grouped-query prefill kernels against the plain softmax in float32.
 3. **Server** — ``Server(cfg, enable_engine=True)`` in this process, the path
    ``python -m video_edge_ai_proxy_tpu.serve.server --engine`` takes:
    yolov8n at its registry widths, every other ``EngineConfig`` field at its
@@ -327,6 +328,78 @@ def latent_prefill_phase(seed: int) -> None:
         f"/ {ATTN_MEAN_ABS}; output rms {np.sqrt((want ** 2).mean()):.3f})")
     check(err.max() <= ATTN_MAX_ABS and err.mean() <= ATTN_MEAN_ABS,
           "latent prefill: outside the stated bf16 tolerance")
+
+
+def gqa_prefill_phase(seed: int) -> None:
+    """The grouped-query attention's prefill kernel at both of its heads'
+    cell shapes (a chunk of 4 streams x 784 new positions over up to 3,328
+    cached positions of a pool of 64 slots: LFM2's 32 query heads on 8
+    key-value heads of 64, Falcon-H1's 10 on 2 of 128), compiled by Mosaic
+    and run, against the plain softmax in float32 over each stream's
+    context and new positions (what the CPU tests compare the interpreted
+    kernel with)."""
+    import jax
+    import jax.numpy as jnp
+
+    from video_edge_ai_proxy_tpu.ops.flash_attention import (
+        gqa_prefill_attention)
+
+    b, t, cap, layers = 4, 784, 3328, 2
+    for h, kv, hd in ((32, 8, 64), (10, 2, 128)):
+        g = h // kv
+        k = jax.random.split(jax.random.PRNGKey(seed), 5)
+        bf = jnp.bfloat16
+        q = jax.random.normal(k[0], (b, t, h, hd)).astype(bf)
+        new_k, new_v = (jax.random.normal(x, (b, kv, hd, t)).astype(bf)
+                        for x in k[1:3])
+        pool = tuple(jax.random.normal(x, (layers, 64, kv, hd, 4096)).astype(
+            bf) for x in k[3:])
+        slots = jnp.asarray([5, 17, 0, 99])     # the last: a padded row
+        ctx = jnp.asarray([32, 1616, 3200, 257])
+        # what lies past a context may be anything
+        for i in range(b):
+            pool = tuple(p.at[1, min(int(slots[i]), 63), :, :,
+                              int(ctx[i]):].set(jnp.nan) for p in pool)
+        args = (q, new_k, new_v, *pool, 1, slots, ctx)
+        compiled = jax.jit(
+            lambda *a: gqa_prefill_attention(*a, cap=cap)).lower(
+            *args).compile()
+        check("tpu_custom_call" in compiled.as_text(),
+              "gqa prefill: no tpu_custom_call in the compiled program")
+        got = np.asarray(compiled(*args), np.float32)
+        check(got.shape == (b, t, h * hd) and np.isfinite(got).all(),
+              f"gqa prefill: shape {got.shape} or non-finite values")
+
+        @jax.jit
+        def plain(q, keys, values, n):
+            """One stream in float32: ``keys`` / ``values`` [KV, hd, cap +
+            t], of which the cached part counts up to ``n``."""
+            f = lambda a: a.astype(jnp.float32)  # noqa: E731
+            s = jnp.einsum("tkgd,kds->kgts", f(q).reshape(t, kv, g, hd),
+                           f(keys)) * hd ** -0.5
+            at = jnp.arange(cap + t)[None]
+            seen = jnp.where(at < cap, at < n,
+                             at - cap <= jnp.arange(t)[:, None])
+            p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+            return jnp.einsum("kgts,kds->tkgd", p, f(values)).reshape(t, -1)
+
+        def cache(p, i):
+            row = p[1, min(int(slots[i]), 63), :, :, :cap]
+            return jnp.where(jnp.arange(cap) < ctx[i], row, 0)
+
+        with jax.default_matmul_precision("highest"):
+            want = np.stack([np.asarray(plain(
+                q[i], jnp.concatenate([cache(pool[0], i), new_k[i]], -1),
+                jnp.concatenate([cache(pool[1], i), new_v[i]], -1), ctx[i]))
+                for i in range(b)])
+        err = np.abs(got - want)
+        say(f"kernel: gqa prefill attention [{b},{t},{h}/{kv},{hd}] bf16 "
+            f"over depths {ctx.tolist()} vs the plain softmax in f32: max "
+            f"abs {err.max():.4f} mean abs {err.mean():.5f} (bounds "
+            f"{ATTN_MAX_ABS} / {ATTN_MEAN_ABS}; output rms "
+            f"{np.sqrt((want ** 2).mean()):.3f})")
+        check(err.max() <= ATTN_MAX_ABS and err.mean() <= ATTN_MEAN_ABS,
+              "gqa prefill: outside the stated bf16 tolerance")
 
 
 def stream_head_phase(seed: int, name: str = "tiny_videomae_lfm2") -> None:
@@ -986,6 +1059,7 @@ def main(argv=None) -> int:
     else:
         kernels_phase(args.seed)
         latent_prefill_phase(args.seed)
+        gqa_prefill_phase(args.seed)
         stream_head_phase(args.seed)
         stream_head_phase(args.seed, "tiny_videomae_xing4")
         stream_head_phase(args.seed, "tiny_videomae_dsv2")

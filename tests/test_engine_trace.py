@@ -506,14 +506,12 @@ STEP_SCOPES = ("pre_cast_scale", "pre_resize", "pre_normalize", "embed",
                "encoder_block", "cls_head", "softmax_topk")
 
 
-def test_a_stream_batch_carries_what_its_prefill_attention_visited(bus):
-    """A stream head's batch (``tiny_videomae_xing4``: latent attention):
-    the step's count of the tiles its prefill attention visited, and of
-    what a dense pass would visit, on every record of the batch and in
-    ``vep_attn_key_blocks_total``."""
-    from video_edge_ai_proxy_tpu.models import registry as models
-
-    model = "tiny_videomae_xing4"
+def _visited(bus, model, layers):
+    """Serve ``model``'s tiny twin to two clip cameras for three batches
+    each and check what its prefill attention visited: the step's count of
+    the tiles it visited, and of what a dense pass would visit, on every
+    record of the batch and in ``vep_attn_key_blocks_total``. ``layers``:
+    the head's attention layers."""
     cams = ["clip0", "clip1"]
     for cam in cams:
         bus.create_stream(cam, F)
@@ -538,11 +536,10 @@ def test_a_stream_batch_carries_what_its_prefill_attention_visited(bus):
         eng.stop()
     recs = [r for r in eng.stage_records if "attn_blocks_dense" in r]
     assert len(recs) >= 3 * len(cams)
-    c = models.get(model).build().cfg
     # tiny widths: the new positions one key block and one lane tile of
     # queries, what can be cached one key block: 2 tiles a stream an
     # attention dense, 1 or 2 live
-    dense = 2 * c.head.num_layers * 2
+    dense = 2 * layers * 2
     for rec in recs:
         assert rec["attn_blocks_dense"] == dense
         assert dense // 2 <= rec["attn_blocks_live"] <= dense
@@ -552,6 +549,23 @@ def test_a_stream_batch_carries_what_its_prefill_attention_visited(bus):
     for kind in ("live", "dense"):
         assert blocks.labels(kind).value - before[kind] == sum(
             r[f"attn_blocks_{kind}"] for r in batches)
+
+
+def test_a_stream_batch_carries_what_its_prefill_attention_visited(bus):
+    """A stream head's batch (``tiny_videomae_xing4``: latent attention)."""
+    from video_edge_ai_proxy_tpu.models import registry as models
+
+    model = "tiny_videomae_xing4"
+    _visited(bus, model, models.get(model).build().cfg.head.num_layers)
+
+
+def test_a_grouped_query_heads_batch_carries_what_it_visited(bus):
+    """A grouped-query head's batch (``tiny_videomae_lfm2``: the kernel of
+    ``models/gqa.py``) carries the same fields, by the same rule."""
+    from video_edge_ai_proxy_tpu.models import registry as models
+
+    model = "tiny_videomae_lfm2"
+    _visited(bus, model, models.get(model).build().cfg.head.attn_layers)
 
 
 @pytest.mark.parametrize("model,shape", [

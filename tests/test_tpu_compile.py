@@ -284,18 +284,22 @@ class TestStreamHeadLayers:
         assert "ragged-dot" in text and "tpu_custom_call" in text
 
     @pytest.mark.parametrize("t", [784, 1], ids=["prefill", "decode"])
-    def test_cached_attention_in_a_loop(self, v5e, t):
+    def test_cached_attention_in_a_loop(self, v5e, monkeypatch, t):
+        """A prefill's attention is the Pallas kernel, inside the loop, and
+        reads the pool where it lies: its temporaries stay under one
+        layer's pool, so no copy of one is made."""
         import flax.linen as nn
 
         from video_edge_ai_proxy_tpu.models import lfm2
 
+        _as_if_on_tpu(monkeypatch)
         cfg = lfm2.Lfm2Config()
         attn = lfm2.CachedAttention(cfg)
-        b, r = 8, 792
+        b, r, slots = 8, 792, 64
         on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
             a.shape, a.dtype, sharding=v5e)
         pool = jax.tree_util.tree_map(
-            on_chip, jax.eval_shape(lambda: lfm2.empty_state(cfg, b)[1]))
+            on_chip, jax.eval_shape(lambda: lfm2.empty_state(cfg, slots)[1]))
         rbuf = tuple(on_chip(a) for a in jax.eval_shape(
             lambda: tuple(a[0] for a in lfm2.round_buffer(cfg, b, r))))
         h = jax.ShapeDtypeStruct((b, t, cfg.dim), jnp.bfloat16, sharding=v5e)
@@ -316,8 +320,18 @@ class TestStreamHeadLayers:
                 return h + y, rbuf
             return jax.lax.fori_loop(0, 2, body, (h, rbuf))
 
-        assert "while" in _compiled_text(
-            twice, variables, h, pool, rbuf, ints, ints)
+        compiled = jax.jit(twice).lower(
+            variables, h, pool, rbuf, ints, ints).compile()
+        text = compiled.as_text()
+        assert "while" in text
+        if t > 1:
+            loop = next(line for line in text.splitlines()
+                        if " while(" in line)
+            body = loop.split("body=", 1)[1].split(",")[0].split()[0]
+            assert any("tpu_custom_call" in line
+                       for line in _computation(text, body))
+            layer = pool[0].size // pool[0].shape[0] * 2
+            assert compiled.memory_analysis().temp_size_in_bytes < layer
 
     def test_flush_writes_the_donated_pool_in_place(self, v5e):
         """The round's keys and values go into the pool by a loop of slice

@@ -10,6 +10,12 @@ round's own positions; ``W_o``. The query width is ``num_heads x
 head_dim``, which need not be the model width (a chip that holds some of
 the heads holds their slices of ``W_q`` and ``W_o``).
 
+Two paths over the one pool: a prefill's T positions are one Pallas kernel
+a chunk (``ops/flash_attention.py`` :func:`gqa_prefill_attention`: a
+program a stream and the query heads of one key-value head, the stream's
+slot read where it lies, the key blocks past its context not visited);
+a decode step's one position is :func:`decode_attention`.
+
 A configuration is anything with the fields of :class:`GqaConfig`
 (``Lfm2Config`` has them). What differs between the heads is named there:
 the per-head norm, the key scale and the heads held.
@@ -23,6 +29,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.flash_attention import gqa_prefill_attention, gqa_prefill_visits
 from .common import Dtype
 from .stream_head import RmsNorm, _kernel
 
@@ -51,39 +58,23 @@ def rope(x, pos, theta: float):
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
-def prefill_attention(q, pool_k, pool_v, new_k, new_v, slots, pos0):
-    """A round's first T positions: q [B, T, H, hd] against each row's slot
-    of the pool (``pool_k`` / ``pool_v`` [slots, KV, hd, S]: the positions
-    before ``pos0`` [B] are the stream's context; the rest is stale or
-    unwritten, and masked) and, causally, against the T new keys and values
-    themselves (``new_k`` / ``new_v`` [B, KV, hd, T]). One stream at a
-    time, so the [T, S + T] scores of one are all that is held. Returns
-    [B, T, H x hd]."""
-    b, t, h, hd = q.shape
-    kv = new_k.shape[1]
-    g = h // kv
-    causal = jnp.tril(jnp.ones((t, t), bool))
+def prefill_cap(context: int, rows: int) -> int:
+    """Positions that can be context when a round of ``rows`` positions
+    starts in a pool of ``context`` (the pool resets a stream whose round
+    would pass it), up to a lane tile, within the pool."""
+    return max(min(-(-(context - rows) // 128) * 128, context), 0)
 
-    def one(args):
-        # a leading axis of one stream: the batched products below are the
-        # form the TPU compiler lays out well (written without the axis,
-        # this loop took twice as long on the chip)
-        qc, sc, pc, kn, vn = (a[None] for a in args)
-        kc = jnp.concatenate(
-            [jnp.take(pool_k, sc, axis=0, mode="clip"), kn], axis=-1)
-        vc = jnp.concatenate(
-            [jnp.take(pool_v, sc, axis=0, mode="clip"), vn], axis=-1)
-        s = jnp.einsum("btkgd,bkds->bkgts", qc.reshape(1, t, kv, g, hd),
-                       kc).astype(jnp.float32) * hd ** -0.5
-        old = jnp.arange(pool_k.shape[-1])[None, :] < pc[:, None]   # [1, S]
-        mask = jnp.concatenate(
-            [jnp.broadcast_to(old[:, None], (1, t, old.shape[1])),
-             causal[None]], axis=-1)
-        s = jnp.where(mask[:, None, None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(qc.dtype)
-        return jnp.einsum("bkgts,bkds->btkgd", p, vc).reshape(t, h * hd)
 
-    return jax.lax.map(one, (q, slots, pos0, new_k, new_v))
+def prefill_visits(pos0, t: int, g: int, cap: int, layers: int):
+    """{"attn_blocks_live", "attn_blocks_dense"}: the tiles (a key block
+    against a lane tile of 128 query columns) the prefill's kernel visits
+    in ``layers`` attentions over a batch whose contexts hold ``pos0`` [B]
+    positions, by the kernel's own rule, and what passes over all ``cap``
+    cacheable and T new positions would visit (a key-value head's; every
+    head's alike)."""
+    live, dense = gqa_prefill_visits(pos0, t, g, cap)
+    return {"attn_blocks_live": live * layers,
+            "attn_blocks_dense": dense * layers}
 
 
 def decode_attention(q, pool_k, pool_v, round_k, round_v, slots, pos0, r):
@@ -167,14 +158,16 @@ class CachedAttention(nn.Module):
                     for a in (k, v))                    # [B, KV, hd, T]
             rk = jax.lax.dynamic_update_slice_in_dim(rbuf[0], k, r0, axis=3)
             rv = jax.lax.dynamic_update_slice_in_dim(rbuf[1], v, r0, axis=3)
-            keys, values = pool[0][self.layer], pool[1][self.layer]
             if t == 1:
-                o = decode_attention(q[:, 0], keys, values, rk, rv, slots,
+                o = decode_attention(q[:, 0], pool[0][self.layer],
+                                     pool[1][self.layer], rk, rv, slots,
                                      pos0, r0)[:, None]
             else:
                 # a prefill starts its round: the T new positions are all
                 # of the round that is written
-                o = prefill_attention(q, keys, values, k, v, slots, pos0)
+                o = gqa_prefill_attention(
+                    q, k, v, *pool, self.layer, slots, pos0,
+                    cap=prefill_cap(pool[0].shape[-1], rbuf[0].shape[-1]))
             return o @ wo.astype(self.dtype), (rk, rv)
 
 

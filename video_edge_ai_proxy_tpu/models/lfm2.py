@@ -353,9 +353,16 @@ class VideoMAELfm2(nn.Module):
         (_, conv, rbuf, counts), (toks, top_i, top_p) = jax.lax.scan(
             step, (h, conv, rbuf, counts),
             jnp.arange(c.decode_steps, dtype=pos0.dtype))
+        hc = c.head
         return {"tokens": toks.T, "top_ids": top_i.transpose(1, 0, 2),
                 "top_probs": top_p.transpose(1, 0, 2), "rows": conv,
-                "rbuf": rbuf, **moe_outputs(counts)}
+                "rbuf": rbuf, **moe_outputs(counts),
+                # what the round's prefill attention visited, summed over
+                # its chunks and layers
+                **gqa.prefill_visits(
+                    pos0, c.visual_tokens, hc.num_heads // hc.num_kv_heads,
+                    gqa.prefill_cap(kv[0].shape[-1], c.round_positions),
+                    hc.attn_layers)}
 
     @nn.nowrap
     def commit_round(self, state, kv, conv, rbuf, slots, pos0):

@@ -20,7 +20,11 @@ Beside it, :func:`latent_prefill_attention`: the same running softmax over
 a latent cache (`models/mla.py`): a stream's cached rows read from its slot
 of the pool as they lie, up-projected to one head's keys and values a key
 block at a time inside the kernel, the stream's depth a prefetched scalar
-that decides how many key blocks a program visits.
+that decides how many key blocks a program visits. And
+:func:`gqa_prefill_attention`, the same over a grouped-query key-value
+cache (`models/gqa.py`): a program holds the query heads of one key-value
+head, a lane tile of their queries at a time, and reads the stream's keys
+and values where they lie in the pool.
 """
 
 from __future__ import annotations
@@ -524,3 +528,186 @@ def latent_prefill_attention(q, new, pool, w_k, w_v, slots, ctx, block, *,
         interpret=interpret,
     )(slots.astype(jnp.int32), jnp.clip(ctx, 0, cap).astype(jnp.int32),
       jnp.asarray(block, jnp.int32).reshape(1), *args)
+
+
+# -- prefill over a grouped-query key-value cache (models/gqa.py) -------------
+
+def gqa_prefill_blocks(t: int, g: int, cap: int) -> tuple:
+    """(block_t, block_k, columns) from the shapes alone: the key blocks of
+    the T new positions (a lane tile: they are padded to a whole number of
+    them) and of the ``cap`` cached positions (a lane tile where that
+    divides ``cap``, else ``cap`` itself, one block), and the query columns
+    of a group of ``g`` heads, T x g up to a lane tile. (Key blocks of 256
+    read 7% slower at both heads' widths on a v5e, and tiles of fewer
+    columns than the group's all 20–90% slower.)"""
+    block_k = _LANES if cap % _LANES == 0 else cap
+    return _LANES, block_k, -(-t * g // _LANES) * _LANES
+
+
+def gqa_prefill_visits(ctx, t: int, g: int, cap: int):
+    """(live, dense): the tiles (a key block against a lane tile of 128
+    query columns) :func:`gqa_prefill_attention` visits for one key-value
+    head of streams whose contexts hold ``ctx`` [B] positions, summed over
+    them, and what a pass over all ``cap`` cacheable positions and all new
+    ones would visit. The kernel's own rule: a cached key block is visited
+    if it starts before the context's end, and every new positions' key
+    block is; each against all the group's query columns."""
+    block_t, block_k, columns = gqa_prefill_blocks(t, g, cap)
+    tiles, blocks = columns // _LANES, -(-t // block_t)
+    cached = -(-jnp.clip(ctx, 0, cap) // block_k) if cap else 0 * ctx
+    live = jnp.sum((cached + blocks) * tiles).astype(jnp.int32)
+    dense = (cap // block_k if cap else 0) + blocks
+    return live, jnp.asarray(ctx.shape[0] * dense * tiles, jnp.int32)
+
+
+def _gqa_prefill_kernel(layer_ref, slots_ref, ctx_ref, q_ref, k_ref, v_ref,
+                        *refs, scale: float, groups: int, block_t: int,
+                        block_k: int):
+    """One stream and the query heads of one key-value head. q [hd,
+    columns]: the group's queries, one a column, position-major (column c
+    is position c // g of head c % g); k, v [hd, T'] the new positions'
+    keys and values, T' a whole number of key blocks (the padding is zero);
+    pool_k, pool_v [hd, cap] the stream's slot, positions minor as the pool
+    lays them (absent where nothing can be cached); o [hd, columns]; m, l
+    [1, columns] and acc [hd, columns] the running softmax, float32.
+
+    A key block at a time (a ``fori_loop`` over the blocks of each kind),
+    its keys turned once to [keys, hd] and scored against all the group's
+    columns in one product, keys-major ([keys, columns]: the reductions run
+    down the sublanes and the statistics lie along the lanes), weighted in
+    another. The kernel traces as three block bodies, whatever T and the
+    depth."""
+    del layer_ref, slots_ref            # the index maps read them
+    pool = refs[:2] if len(refs) == 6 else None
+    o_ref, m_ref, l_ref, acc_ref = refs[-4:]
+    ctx = ctx_ref[pl.program_id(0)]
+    m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def attend(keys, values, seen=None, k0=None):
+        """A key block (keys, values [hd, bk]) into the running softmax.
+        ``seen`` [bk, 1]: the keys that count (None = all); ``k0``: the
+        block's first new position, from which a query sees causally."""
+        s = jnp.dot(keys.T, q_ref[...],
+                    preferred_element_type=jnp.float32) * scale
+        if seen is not None:
+            s = jnp.where(seen, s, _NEG)
+        if k0 is not None:
+            s = jnp.where(
+                (k0 + lax.broadcasted_iota(jnp.int32, s.shape, 0)) * groups
+                <= lax.broadcasted_iota(jnp.int32, s.shape, 1), s, _NEG)
+        m_ref[...], l_ref[...], alpha, p = _softmax_block(
+            m_ref[...], l_ref[...], s, keys=0)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            values, p.astype(values.dtype),
+            preferred_element_type=jnp.float32)
+
+    if pool is not None:
+        # the context: whole blocks unmasked, the block that straddles
+        # ``ctx`` masked (its keys and values past ``ctx`` zeroed: they may
+        # hold anything, and 0 x NaN is NaN), the rest never touched
+        pool_k, pool_v = pool
+
+        def at(j):
+            if pool_k.shape[1] == block_k:
+                return slice(None)
+            return pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+
+        def cached(j, _):
+            attend(pool_k[:, at(j)], pool_v[:, at(j)])
+
+        def straddles(j, _):
+            lanes = j * block_k + lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1) < ctx
+            attend(jnp.where(lanes, pool_k[:, at(j)], 0),
+                   jnp.where(lanes, pool_v[:, at(j)], 0),
+                   seen=j * block_k + lax.broadcasted_iota(
+                       jnp.int32, (block_k, 1), 0) < ctx)
+
+        whole = ctx // block_k
+        lax.fori_loop(0, whole, cached, None)
+        lax.fori_loop(whole, -(-ctx // block_k), straddles, None)
+
+    # the new positions, causally
+    def new(j, _):
+        k0 = pl.multiple_of(j * block_t, block_t)
+        attend(k_ref[:, pl.ds(k0, block_t)], v_ref[:, pl.ds(k0, block_t)],
+               k0=k0)
+
+    lax.fori_loop(0, k_ref.shape[1] // block_t, new, None)
+    o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("cap", "interpret"))
+def gqa_prefill_attention(q, new_k, new_v, pool_k, pool_v, layer, slots, ctx,
+                          *, cap: int, interpret: Optional[bool] = None):
+    """Causal grouped-query attention of T new positions a stream over its
+    context in a key-value cache and over themselves, the scores never
+    leaving the chip.
+
+    ``q`` [B, T, H, hd] the queries (query head h reads key-value head h //
+    g, g = H / KV); ``new_k`` / ``new_v`` [B, KV, hd, T] the new positions'
+    keys and values; ``pool_k`` / ``pool_v`` [layers, slots, KV, hd, S] the
+    whole cache, of which this attention's is layer ``layer`` (an operand,
+    prefetched with ``slots`` and ``ctx``: a stack's attentions share one
+    trace and one compiled kernel; the whole is handed over because a slice
+    handed to a kernel is a copy of it). Stream b's context is the first
+    ``ctx[b]`` (at most ``cap``) positions of slot ``slots[b]`` (clipped
+    into the pool: a padded row reads some slot, finitely) and the rest may
+    hold anything. Softmax at ``hd^-1/2``. Returns [B, T, H x hd].
+
+    Grid (stream, key-value head): a program holds the group's g query
+    heads, all T x g of their queries at once, so a key block is fetched
+    and scored once for the group, and reads the first ``cap`` positions of
+    its slot as they lie; the key blocks past ``ctx[b]`` are not visited
+    (:func:`gqa_prefill_visits` counts the visits). Operands enter both
+    products in their own dtype; scores, maximum, sum and accumulator are
+    float32; the output is cast once."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, t, h, hd = q.shape
+    kv = new_k.shape[1]
+    g = h // kv
+    bt, bk, columns = gqa_prefill_blocks(t, g, cap)
+    # the group's queries, one a column, position-major; the new positions
+    # up to a whole key block (zero: never seen by a query, and finite)
+    qc = q.reshape(b, t, kv, g, hd).transpose(0, 2, 4, 1, 3).reshape(
+        b, kv, hd, t * g)
+    qc = jnp.pad(qc, ((0, 0),) * 3 + ((0, columns - t * g),))
+    tp = -(-t // bt) * bt
+    new_k, new_v = (jnp.pad(a, ((0, 0),) * 3 + ((0, tp - t),))
+                    for a in (new_k, new_v))
+    kernel = functools.partial(_gqa_prefill_kernel, scale=hd ** -0.5,
+                               groups=g, block_t=bt, block_k=bk)
+    last = pool_k.shape[1] - 1
+    rows = pl.BlockSpec((None, None, hd, columns),
+                        lambda i, j, l, s, c: (i, j, 0, 0))
+    new = pl.BlockSpec((None, None, hd, tp), lambda i, j, l, s, c: (i, j, 0, 0))
+    slot = pl.BlockSpec((None, None, None, hd, cap),
+                        lambda i, j, l, s, c: (l[0], jnp.clip(s[i], 0, last),
+                                               j, 0, 0))
+    in_specs, args = [rows, new, new], [qc, new_k, new_v]
+    if cap:
+        in_specs += [slot, slot]
+        args += [pool_k, pool_v]
+    blocks = 2 * hd * columns + 2 * hd * tp + 2 * hd * cap
+    o = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, kv), in_specs=in_specs,
+            out_specs=rows,
+            scratch_shapes=[pltpu.VMEM((1, columns), jnp.float32),
+                            pltpu.VMEM((1, columns), jnp.float32),
+                            pltpu.VMEM((hd, columns), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, kv, hd, columns), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # every block twice (the pipeline's two buffers), and room for
+            # the running softmax and a key block's scores and weights
+            vmem_limit_bytes=2 * blocks * q.dtype.itemsize + (32 << 20)),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
+      jnp.clip(ctx, 0, cap).astype(jnp.int32), *args)
+    o = o[..., :t * g].reshape(b, kv, hd, t, g)
+    return o.transpose(0, 3, 1, 4, 2).reshape(b, t, h * hd)
