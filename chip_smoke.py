@@ -411,7 +411,9 @@ def stream_head_phase(seed: int, name: str = "tiny_videomae_lfm2") -> None:
     decode loop the prediction module drafts for, DeepSeek-V2's latent
     cache alone, whose router counts the pairs it routed in all,
     Falcon-H1's key-value cache beside its conv state and its float32
-    state-space state, which each round writes over its slot's."""
+    state-space state, which each round writes over its slot's, and
+    Nemotron-H's three kinds held by a layer's place among its kind, its
+    latent expert layers counting the pairs routed in all."""
     import jax
     import jax.numpy as jnp
 
@@ -1064,6 +1066,7 @@ def main(argv=None) -> int:
         stream_head_phase(args.seed, "tiny_videomae_xing4")
         stream_head_phase(args.seed, "tiny_videomae_dsv2")
         stream_head_phase(args.seed, "tiny_videomae_b_falcon_h1")
+        stream_head_phase(args.seed, "tiny_videomae_nemotron3_super")
         facts = server_phase(args.seed)
     counts = cache.snapshot()
     say(f"cache: {counts} in "

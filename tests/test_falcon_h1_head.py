@@ -438,6 +438,12 @@ STEPS = {
         "7a0efe8389d15f9250fd62ef49530a8c774a04b64aa7a3b680dc0c1796e52bac",
     ("tiny_videomae_dsv2", 2, 48, 64):
         "13202e6eded1a6e206fb9d24c875bcd3595fb2541f23036d400c4433cc01c101",
+    # this head's own, taken before its mixer moved to models/mamba2.py and
+    # the expert layer learned a latent and squared ReLU for the fifth head
+    ("videomae_b_falcon_h1", 64, 360, 640):
+        "d9dedcc78c145ac66c01a021429d913d2bd5e224d2819fb7c265146d46c4dea7",
+    ("tiny_videomae_b_falcon_h1", 2, 48, 64):
+        "732ec2cdbb8d3b009bda018794c5156b126cea19d89abc8b145cc9f88dd8d132",
 }
 
 

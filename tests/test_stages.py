@@ -138,6 +138,39 @@ def test_the_stage_map_finds_scopes_inside_a_loop_and_nested():
     assert module == "jit__scoped" and again == paths
 
 
+def test_the_latent_projections_are_a_stage_of_the_expert_layer():
+    """An expert layer whose experts live in a latent (models/transformer.py
+    ``TopKMoeMlp`` with ``latent_dim``), compiled inside a prefill loop:
+    its two projections map to ``head_moe`` / ``moe_latent``, beside the
+    route, the experts and the shared expert, and nothing of it is left
+    unscoped by a name the map does not declare."""
+    import jax
+    import jax.numpy as jnp
+
+    from video_edge_ai_proxy_tpu.models.transformer import (
+        TopKMoeConfig, TopKMoeMlp)
+
+    layer = TopKMoeMlp(TopKMoeConfig(
+        dim=32, mlp_dim=24, num_experts=8, top_k=2, shared_mlp_dim=40,
+        activation="relu2", latent_dim=16), dtype=jnp.float32)
+    x = jnp.ones((6, 32), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), x)
+
+    def prefill(params, x):
+        with jax.named_scope("head_prefill"):
+            def body(i, x):
+                with jax.named_scope("head_moe"):
+                    return x + layer.apply(params, x)[0]
+            return jax.lax.fori_loop(0, 2, body, x)
+
+    assert "moe_latent" in stages.SCOPES
+    paths = set(stages.stage_map(jax.jit(prefill).lower(params, x)
+                                 .compile()).values())
+    assert ("head_prefill", "head_moe", "moe_latent") in paths
+    assert {("head_prefill", "head_moe", "moe_route"),
+            ("head_prefill", "head_moe", "moe_shared")} <= paths
+
+
 def test_what_the_compiler_made_takes_a_scope_from_its_root_or_operands():
     text = """HloModule jit_f, is_scheduled=true
 

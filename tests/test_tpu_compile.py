@@ -664,3 +664,186 @@ class TestFalconH1HeadLayers:
         draw = 4 * sum(a.size for a in jax.tree_util.tree_leaves(drawn))
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             + draw < 16e9
+
+
+class TestNemotronHHeadLayers:
+    """The fifth streaming head at the published widths
+    (models/nemotron_h.py): the latent expert layer (top-22 of 512, 16
+    held, squared ReLU in a 1,024-wide latent) at the cell's prefill chunk
+    (4 streams: at 8 the way back's two prefetched per-pair vectors,
+    137,984 entries each, pass the 1 MiB of scalar memory) and at decode,
+    the unrotated attention whose 8 query heads read one key-value head,
+    and the mixer at this head's sizes (32 heads of 64 in two groups, a
+    128-wide state), each compiled INSIDE a loop as the serving step runs
+    them; and the cell's whole step, which has to fit the chip beside the
+    benchmark's float32 draw."""
+
+    def test_latent_expert_layer_in_a_loop(self, v5e, monkeypatch):
+        import flax.linen as nn
+
+        from video_edge_ai_proxy_tpu.models import nemotron_h
+        from video_edge_ai_proxy_tpu.models.transformer import (
+            TopKMoeMlp, pair_rungs)
+
+        cfg = nemotron_h.NemotronHConfig().moe
+        layer = TopKMoeMlp(cfg)
+        shapes = jax.eval_shape(lambda: nn.meta.unbox(layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((8, 4096), jnp.bfloat16))))
+        p = shapes["params"]
+        assert p["w1"].shape == (16, 1024, 2688) and "w3" not in p
+        assert p["fc1_latent_proj"].shape == (4096, 1024)
+        assert p["shared_w1"].shape == (4096, 5376) and "shared_w3" not in p
+        variables = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            shapes)
+
+        def twice(variables, x):
+            def body(_, carry):
+                x, load = carry
+                y, n = layer.apply(variables, x, method=TopKMoeMlp.routed)
+                return x + y, load + n["held"]
+            return jax.lax.fori_loop(
+                0, 2, body, (x, jnp.zeros((16,), jnp.int32)))
+
+        # the way back's kernel, not its interpreted twin
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        x = jax.ShapeDtypeStruct((3136, 4096), jnp.bfloat16, sharding=v5e)
+        text = _compiled_text(twice, variables, x)
+        assert "ragged-dot" in text and " conditional(" in text
+        # every rung gathers its rows of the LATENT, not of the input
+        rungs = pair_rungs(cfg, 3136)
+        for rows in rungs:
+            assert "bf16[%d,1024]" % rows in text, rows
+            assert "bf16[%d,4096]" % rows not in text, rows
+        cond = next(line for line in text.splitlines()
+                    if " conditional(" in line)
+        branches = cond.split("branch_computations={", 1)[1].split("}")[0]
+        for name in branches.split(", "):
+            assert [line for line in _computation(text, name)
+                    if line.startswith("%combine_rows")], name
+        # decode's 64 tokens: one rung, no conditional
+        assert pair_rungs(cfg, 64) == (1408,)
+        x = jax.ShapeDtypeStruct((64, 4096), jnp.bfloat16, sharding=v5e)
+        text = _compiled_text(twice, variables, x)
+        assert "ragged-dot" in text and " conditional(" not in text
+
+    @pytest.mark.parametrize("t", [784, 1], ids=["prefill", "decode"])
+    def test_unrotated_attention_of_eight_heads_on_one(self, v5e,
+                                                       monkeypatch, t):
+        """A prefill's attention is the Pallas kernel with the 8 query
+        heads of the one key-value head held as its columns, inside the
+        loop, reading the pool where it lies."""
+        import flax.linen as nn
+
+        from video_edge_ai_proxy_tpu.models import gqa, nemotron_h
+
+        _as_if_on_tpu(monkeypatch)
+        cfg = nemotron_h.NemotronHConfig().attn
+        assert (cfg.num_heads, cfg.num_kv_heads, cfg.rotary) == (8, 1, False)
+        attn = gqa.CachedAttention(cfg)
+        b, r, slots = 8, 792, 64
+        on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+            a.shape, a.dtype, sharding=v5e)
+        pool = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+            lambda: gqa.empty_pool(cfg, 1, slots, 4096)))
+        rbuf = tuple(on_chip(a) for a in jax.eval_shape(
+            lambda: tuple(a[0] for a in gqa.round_buffer(cfg, 1, b, r))))
+        h = jax.ShapeDtypeStruct((b, t, 4096), jnp.bfloat16, sharding=v5e)
+        ints = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e)
+        variables = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+            lambda: nn.meta.unbox(attn.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 1, 4096), jnp.bfloat16),
+                gqa.empty_pool(cfg, 1, 1, 8),
+                tuple(a[0] for a in gqa.round_buffer(cfg, 1, 1, 4)),
+                jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32), 0))))
+
+        def twice(variables, h, pool, rbuf, slots, pos0):
+            def body(i, carry):
+                h, rbuf = carry
+                y, rbuf = attn.apply(variables, h, pool, rbuf, slots, pos0,
+                                     0 if t > 1 else 784 + i)
+                return h + y, rbuf
+            return jax.lax.fori_loop(0, 2, body, (h, rbuf))
+
+        compiled = jax.jit(twice).lower(
+            variables, h, pool, rbuf, ints, ints).compile()
+        text = compiled.as_text()
+        assert "while" in text
+        if t > 1:
+            assert "tpu_custom_call" in text
+            layer = pool[0].size // pool[0].shape[0] * 2
+            assert compiled.memory_analysis().temp_size_in_bytes < layer
+
+    @pytest.mark.parametrize("t", [784, 1], ids=["prefill", "decode"])
+    def test_mixer_in_a_loop(self, v5e, t):
+        import flax.linen as nn
+
+        from video_edge_ai_proxy_tpu.models import mamba2, nemotron_h
+
+        cfg = nemotron_h.NemotronHConfig().mixer
+        mixer = mamba2.Mamba2Mixer(cfg)
+        b = 8
+        state = (b, cfg.mamba_heads, cfg.mamba_head_dim, cfg.d_state)
+        shapes = jax.eval_shape(lambda: nn.meta.unbox(mixer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 2, cfg.dim), jnp.bfloat16),
+            jnp.zeros((1, cfg.d_conv - 1, cfg.conv_dim), jnp.bfloat16),
+            jnp.zeros((1,) + state[1:], jnp.float32))))
+        assert shapes["params"]["in_proj"].shape == (4096, 4640)
+        variables = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            shapes)
+
+        def twice(variables, n, conv, ssm):
+            def body(_, carry):
+                n, conv, ssm = carry
+                m, conv, ssm = mixer.apply(variables, n, conv, ssm)
+                return n + m, conv.astype(jnp.bfloat16), ssm
+            return jax.lax.fori_loop(0, 2, body, (n, conv, ssm))
+
+        text = _compiled_text(
+            twice, variables,
+            jax.ShapeDtypeStruct((b, t, cfg.dim), jnp.bfloat16, sharding=v5e),
+            jax.ShapeDtypeStruct((b, cfg.d_conv - 1, cfg.conv_dim),
+                                 jnp.bfloat16, sharding=v5e),
+            jax.ShapeDtypeStruct(state, jnp.float32, sharding=v5e))
+        assert "while" in text
+        assert "f32[%d,%d,%d,%d]" % state in text
+
+    @pytest.mark.slow
+    def test_the_cells_step_fits_the_chip(self, v5e, monkeypatch):
+        """``nemotron3s_64_360p``'s step (64 clips of 360p, the pool of 64
+        streams donated) compiled for a described v5e: the state comes back
+        aliased, and the step's arguments and temporaries, beside the
+        float32 draw that the benchmark keeps for its reference, stay
+        under the chip's 16 GB."""
+        from video_edge_ai_proxy_tpu.engine.runner import build_serving_step
+        from video_edge_ai_proxy_tpu.models import registry
+
+        _as_if_on_tpu(monkeypatch)
+        spec = registry.get("videomae_b_nemotron3_super")
+        model = spec.build()
+        on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+            a.shape, a.dtype, sharding=v5e)
+        drawn = jax.eval_shape(
+            lambda: spec.init_params(jax.random.PRNGKey(0))[1])
+        variables = jax.tree.map(on_chip, jax.eval_shape(
+            lambda v: spec.prepare(model, v), drawn))
+        b, c = 64, model.cfg
+        state = dict(jax.eval_shape(lambda: model.empty_state(b))[0],
+                     tokens=jax.ShapeDtypeStruct(
+                         (b, c.max_rounds * c.decode_steps), jnp.int32))
+        state = jax.tree.map(on_chip, state)
+        ints = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e)
+        compiled = jax.jit(build_serving_step(model, spec),
+                           donate_argnums=(2,)).lower(
+            variables, jax.ShapeDtypeStruct((b, 8, 360, 640, 3), jnp.uint8,
+                                            sharding=v5e),
+            state, ints, ints,
+            jax.ShapeDtypeStruct((b,), bool, sharding=v5e), ints).compile()
+        mem = compiled.memory_analysis()
+        held = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves(state))
+        assert mem.alias_size_in_bytes >= held
+        draw = 4 * sum(a.size for a in jax.tree_util.tree_leaves(drawn))
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            + draw < 16e9

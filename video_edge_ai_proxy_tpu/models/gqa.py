@@ -1,10 +1,13 @@
 """Grouped-query attention over a stream's key-value pool, shared by the
 streaming heads that cache keys and values (``models/lfm2.py``,
-``models/falcon_h1.py``), as ``models/mla.py`` is by the latent heads.
+``models/falcon_h1.py``, ``models/nemotron_h.py``), as ``models/mla.py``
+is by the latent heads.
 
 The attention: ``q = h W_q``, ``k = h W_k`` (times ``key_multiplier``
 where the configuration has one), ``v = h W_v``; q and k RMS-normed per
-head where ``qk_norm``; rotary positions (rotate-half) on q and k; causal
+head where ``qk_norm``; rotary positions (rotate-half) on q and k where
+``rotary`` (none where not: the attention of ``models/nemotron_h.py``
+applies no position at all, its order coming from the mixers); causal
 softmax at ``head_dim^-1/2`` over the stream's context in the pool and the
 round's own positions; ``W_o``. The query width is ``num_heads x
 head_dim``, which need not be the model width (a chip that holds some of
@@ -18,7 +21,7 @@ a decode step's one position is :func:`decode_attention`.
 
 A configuration is anything with the fields of :class:`GqaConfig`
 (``Lfm2Config`` has them). What differs between the heads is named there:
-the per-head norm, the key scale and the heads held.
+the per-head norm, the key scale, the rotation and the heads held.
 """
 
 from __future__ import annotations
@@ -44,6 +47,7 @@ class GqaConfig:
     rope_theta: float
     qk_norm: bool = True
     key_multiplier: float = 1.0
+    rotary: bool = True
 
 
 def rope(x, pos, theta: float):
@@ -120,9 +124,9 @@ def decode_attention(q, pool_k, pool_v, round_k, round_v, slots, pos0, r):
 
 
 class CachedAttention(nn.Module):
-    """GQA with rotary positions (and, where ``qk_norm``, per-head
-    RMS-normed q and k), over a stream's context in the key-value pool
-    (read only: ``pool`` = (keys, values), each [attention layers, slots,
+    """GQA with rotary positions where ``rotary`` (and, where ``qk_norm``,
+    per-head RMS-normed q and k), over a stream's context in the key-value
+    pool (read only: ``pool`` = (keys, values), each [attention layers, slots,
     KV, hd, S], positions minor-most, the layout the TPU compiler gives a
     cache that decode steps read) and over this round's own positions, kept
     in a round buffer ``rbuf`` = (keys, values), each [B, KV, hd, R], and
@@ -152,8 +156,10 @@ class CachedAttention(nn.Module):
             norm = (lambda x, name: RmsNorm(  # noqa: E731
                 c.norm_eps, jnp.float32, name=name)(x)) if c.qk_norm \
                 else (lambda x, name: x)
-            q = rope(norm(q, "q_norm"), pos, c.rope_theta).astype(self.dtype)
-            k = rope(norm(k, "k_norm"), pos, c.rope_theta).astype(self.dtype)
+            turn = (lambda x: rope(  # noqa: E731
+                x, pos, c.rope_theta)) if c.rotary else (lambda x: x)
+            q = turn(norm(q, "q_norm")).astype(self.dtype)
+            k = turn(norm(k, "k_norm")).astype(self.dtype)
             k, v = (a.transpose(0, 2, 3, 1).astype(rbuf[0].dtype)
                     for a in (k, v))                    # [B, KV, hd, T]
             rk = jax.lax.dynamic_update_slice_in_dim(rbuf[0], k, r0, axis=3)

@@ -77,6 +77,7 @@ class Lfm2Config:
     # what the shared attention asks beyond the sizes (gqa.GqaConfig)
     qk_norm: ClassVar[bool] = True
     key_multiplier: ClassVar[float] = 1.0
+    rotary: ClassVar[bool] = True
 
     @property
     def head_dim(self) -> int:

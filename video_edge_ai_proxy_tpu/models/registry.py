@@ -220,6 +220,36 @@ register(ModelSpec(
                 "round; 784 tokens prefilled and 8 decoded a stream a round",
 ))
 
+def _nemotron_h(tiny: bool = False):
+    """The fifth streaming head, imported when it is first built."""
+    from . import nemotron_h
+
+    if tiny:
+        return nemotron_h.VideoMAENemotronH(
+            nemotron_h.tiny_stream_head_config(), dtype=jnp.float32)
+    return nemotron_h.VideoMAENemotronH(nemotron_h.StreamHeadConfig())
+
+
+register(ModelSpec(
+    "videomae_b_nemotron3_super", _nemotron_h,
+    input_size=224, preprocess="clip", kind="stream", clip_len=8,
+    prepare=prepare_for_serving,
+    description="fifth streaming head (models/nemotron_h.py): VideoMAE-B "
+                "encoder -> connector -> Nemotron-3-Super-120B-A12B decoder "
+                "at the published widths, one chip's share of a 32-chip "
+                "deployment (published layers 0-10 of 88, MEMEMEM*EME: five "
+                "Mamba-2 mixers, five latent expert layers, one attention "
+                "layer, each alone on the residual; 32 of 128 mixer heads, "
+                "8 of 32 query heads, 16 of 512 experts, an eighth of the "
+                "vocabulary); the expert layer routes top-22 over 512 and "
+                "runs its experts in a 1,024-wide latent with squared ReLU "
+                "beside a full-width shared expert; the attention has no "
+                "rotation; per-stream key-value cache, conv state and "
+                "float32 state-space state in engine/stream_state.py, each "
+                "indexed by a layer's place among its kind; 784 tokens "
+                "prefilled and 8 decoded a stream a round",
+))
+
 # --- diagnostic gauges ----------------------------------------------------
 
 register(ModelSpec(
@@ -296,4 +326,13 @@ register(ModelSpec(
     description="CPU/CI twin of videomae_b_falcon_h1 (tests/test_falcon_h1_"
                 "head.py), in float32 throughout: two mixer groups, a chunk "
                 "of 8 under 50 visual tokens a round",
+))
+register(ModelSpec(
+    "tiny_videomae_nemotron3_super", lambda: _nemotron_h(tiny=True),
+    input_size=40, preprocess="clip", kind="stream", clip_len=4,
+    prepare=prepare_for_serving,
+    description="CPU/CI twin of videomae_b_nemotron3_super (tests/test_"
+                "nemotron_h_head.py), in float32 throughout: every layer "
+                "kind, two mixer groups, 4 of 16 experts held and top-4, a "
+                "latent narrower than the width",
 ))

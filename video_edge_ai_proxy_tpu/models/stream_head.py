@@ -1,6 +1,6 @@
 """What the streaming heads share (``models/lfm2.py``, ``models/xing4.py``,
-``models/deepseek_v2.py``, ``models/falcon_h1.py``): a decoder behind the
-VideoMAE encoder that keeps a state per camera.
+``models/deepseek_v2.py``, ``models/falcon_h1.py``, ``models/nemotron_h.py``):
+a decoder behind the VideoMAE encoder that keeps a state per camera.
 
 The reference ships frames to external clients and has no model at all
 (`/root/reference/README.md:5-27`). Here, every round, a clip camera's

@@ -119,13 +119,15 @@ class Mlp(nn.Module):
 
 
 def _expert_weights(mod: nn.Module, cfg, *, stack: int = 0,
-                    gated: bool = False):
+                    gated: bool = False, dim: int = 0):
     """The [E, d, mlp] / [E, mlp, d] expert stacks, shared by every MoE
     variant (one definition of the 'expert' logical sharding axis).
     ``stack`` is how many experts this holder keeps (default: all
     ``cfg.num_experts``); ``gated`` adds the SwiGLU up-projection ``w3``
-    beside ``w1`` and returns (w1, w3, w2)."""
+    beside ``w1`` and returns (w1, w3, w2); ``dim`` is the width the
+    experts read and write (default: ``cfg.dim``)."""
     e = stack or cfg.num_experts
+    d = dim or cfg.dim
 
     def one(name, axes, shape):
         return mod.param(
@@ -135,10 +137,10 @@ def _expert_weights(mod: nn.Module, cfg, *, stack: int = 0,
             (e,) + shape, jnp.float32,
         )
 
-    w1 = one("w1", ("embed", "mlp"), (cfg.dim, cfg.mlp_dim))
-    w2 = one("w2", ("mlp", "embed"), (cfg.mlp_dim, cfg.dim))
+    w1 = one("w1", ("embed", "mlp"), (d, cfg.mlp_dim))
+    w2 = one("w2", ("mlp", "embed"), (cfg.mlp_dim, d))
     if gated:
-        return w1, one("w3", ("embed", "mlp"), (cfg.dim, cfg.mlp_dim)), w2
+        return w1, one("w3", ("embed", "mlp"), (d, cfg.mlp_dim)), w2
     return w1, w2
 
 
@@ -238,15 +240,23 @@ class RoutedMoeMlp(nn.Module):
 
 @dataclass(frozen=True)
 class TopKMoeConfig:
-    """A routed SwiGLU expert layer as ``lfm2_moe``, ``xing4_0`` and
-    ``deepseek_v2`` publish it: the router scores ALL ``num_experts``; this
-    holder computes the experts in ``experts_held`` (their ids among the
-    ``num_experts``; empty = all). ``shared_mlp_dim``: the width of the
+    """A routed expert layer as ``lfm2_moe``, ``xing4_0``, ``deepseek_v2``
+    and ``nemotron_h`` publish it: the router scores ALL ``num_experts``;
+    this holder computes the experts in ``experts_held`` (their ids among
+    the ``num_experts``; empty = all). ``shared_mlp_dim``: the width of the
     shared expert every token takes beside the routed ones, unweighted (0:
-    none, and no parameter). Three routers (:func:`topk_route`):
+    none, and no parameter). ``activation``: ``swiglu`` (``silu(x W1) * x
+    W3``, then W2) or ``relu2`` (non-gated squared ReLU, ``relu(x W1)^2``
+    then W2), for the routed and the shared experts alike.
+    ``latent_dim``: 0, or the width of a LATENT the routed experts live in
+    (``nemotron_h``'s ``moe_latent_size``): the router and the shared
+    expert read the layer's input ``x``, the routed experts read ``x
+    W_down`` and their weighted sum goes back to the model width through
+    ``W_up``. Three routers (:func:`topk_route`):
 
-    - ``lfm2_moe``, ``xing4_0``: ``scoring`` sigmoid, the top-k of score +
-      learned bias over all experts, renormalised, x the scaling factor;
+    - ``lfm2_moe``, ``xing4_0``, ``nemotron_h`` (one group): ``scoring``
+      sigmoid, the top-k of score + learned bias over all experts,
+      renormalised, x the scaling factor;
     - ``deepseek_v2`` (``group_limited_greedy``): ``scoring`` softmax over
       all experts in float32, no bias; the experts lie in ``n_group``
       groups of consecutive ids, a group scores as its best expert, only
@@ -266,6 +276,13 @@ class TopKMoeConfig:
     scoring: str = "sigmoid"            # or "softmax"
     n_group: int = 1
     topk_group: int = 1
+    activation: str = "swiglu"          # or "relu2"
+    latent_dim: int = 0
+
+    @property
+    def expert_dim(self) -> int:
+        """The width the routed experts read and write."""
+        return self.latent_dim or self.dim
 
     @property
     def held(self) -> tuple:
@@ -326,7 +343,7 @@ def pair_rungs(cfg: TopKMoeConfig, n: int) -> tuple:
     every expert is held here, or the way back's kernel does not take the
     shape (``ops/combine_rows.py`` ``combine_block``)."""
     top = n * cfg.top_k
-    if not combine_block(n, cfg.dim):
+    if not combine_block(n, cfg.expert_dim, cfg.top_k):
         return (top,)
     floor = max(2 * top * len(cfg.held) / cfg.num_experts, _LEAST_RUNG)
     rungs = [top]
@@ -346,13 +363,14 @@ def zero_counts(cfg: TopKMoeConfig) -> dict:
 
 
 class TopKMoeMlp(nn.Module):
-    """Dropless top-k routed SwiGLU experts, told which experts it holds.
+    """Dropless top-k routed experts, told which experts it holds.
 
     The sibling of :class:`RoutedMoeMlp` (top-1, static capacity, drops)
     for serving a published router: every token's ``top_k`` experts are
     chosen over all ``num_experts``; the (token, expert) pairs whose expert
-    is held here are sorted by expert and run through three grouped matmuls
-    (``jax.lax.ragged_dot``: XLA's own grouped-matmul lowering on TPU, a
+    is held here are sorted by expert and run through the grouped matmuls
+    of the activation (three for SwiGLU, two for squared ReLU;
+    ``jax.lax.ragged_dot``: XLA's own grouped-matmul lowering on TPU, a
     masked dense product elsewhere), whatever the load of each expert: no
     capacity, no dropped token, all tokens to one expert included. The
     per-pair arrays are as long as the smallest rung of :func:`pair_rungs`
@@ -364,7 +382,10 @@ class TopKMoeMlp(nn.Module):
     ``len(experts_held)`` experts and carry the "expert" logical axis (ep
     sharding). A shared expert (``shared_mlp_dim``) is every holder's
     alike: its output is added here unweighted, and counted once where
-    holders are summed.
+    holders are summed. With a ``latent_dim``, the pairs gather rows of the
+    latent ``x W_down``, the per-pair arrays and the way back are at the
+    latent width, and ``W_up`` takes the weighted sum back (both
+    projections under ``moe_latent``, every holder's alike).
 
     Returns ``(y [N, d], counts)``, the counts (:func:`zero_counts`)
     ``held`` [held] the pairs each held expert took, ``rows`` the rows
@@ -424,8 +445,12 @@ class TopKMoeMlp(nn.Module):
                         axis=0, dtype=jnp.int32)
         count = jnp.sum(sizes)
 
-        w1, w3, w2 = (a.astype(self.dtype) for a in _expert_weights(
-            self, c, stack=h, gated=True))
+        if c.activation not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown activation {c.activation!r}; "
+                             "expected 'swiglu' or 'relu2'")
+        gated, de = c.activation == "swiglu", c.expert_dim
+        ws = tuple(a.astype(self.dtype) for a in _expert_weights(
+            self, c, stack=h, gated=gated, dim=de))
         # The held pairs sort first, so the first ``count`` rows of
         # ``order`` are all that is computed: each rung moves its C rows,
         # the smallest that holds them is run. A pair's row lies where it
@@ -436,14 +461,20 @@ class TopKMoeMlp(nn.Module):
         src = jnp.argsort(order).reshape(n, k)
 
         def rung(rows):
-            def moved(x, order, src, sizes, count, w, w1, w3, w2):
+            def moved(x, order, src, sizes, count, w, *ws):
                 with jax.named_scope("moe_experts"):
                     xs = jnp.take(x, order[:rows] // k, axis=0, mode="clip")
-                    up = jax.lax.ragged_dot(xs, w3, sizes)
-                    act = nn.silu(jax.lax.ragged_dot(xs, w1, sizes)) * up
+                    if gated:
+                        w1, w3, w2 = ws
+                        up = jax.lax.ragged_dot(xs, w3, sizes)
+                        act = nn.silu(jax.lax.ragged_dot(xs, w1, sizes)) * up
+                    else:
+                        w1, w2 = ws
+                        act = jnp.square(nn.relu(
+                            jax.lax.ragged_dot(xs, w1, sizes)))
                     ys = jax.lax.ragged_dot(
                         act, w2, sizes, preferred_element_type=jnp.float32)
-                if combine_block(n, d):
+                if combine_block(n, de, k):
                     return combine_rows(ys, src, w, count)
                 # a shape the kernel does not take has the one rung of
                 # every pair; rows past the last group are whatever the
@@ -454,23 +485,39 @@ class TopKMoeMlp(nn.Module):
 
         at = jnp.sum(count <= jnp.asarray(rungs[1:], jnp.int32),
                      dtype=jnp.int32)           # the smallest rung that holds
-        operands = (x.astype(self.dtype), order, src, sizes, count,
-                    w.astype(jnp.float32), w1, w3, w2)
-        y = (rung(rungs[0])(*operands) if len(rungs) == 1 else
-             jax.lax.switch(at, [rung(r) for r in rungs], *operands))
-        if c.shared_mlp_dim:
-            m = c.shared_mlp_dim
-            s1, s3, s2 = (self.param(
+        if c.latent_dim:
+            w_down, w_up = (self.param(
                 name, nn.with_logical_partitioning(
                     nn.initializers.xavier_uniform(), axes), shape,
                 jnp.float32).astype(self.dtype) for name, shape, axes in (
-                    ("shared_w1", (d, m), ("embed", "mlp")),
-                    ("shared_w3", (d, m), ("embed", "mlp")),
-                    ("shared_w2", (m, d), ("mlp", "embed"))))
+                    ("fc1_latent_proj", (d, de), ("embed", "mlp")),
+                    ("fc2_latent_proj", (de, d), ("mlp", "embed"))))
+            with jax.named_scope("moe_latent"):
+                u = x.astype(self.dtype) @ w_down
+        else:
+            u = x.astype(self.dtype)
+        operands = (u, order, src, sizes, count, w.astype(jnp.float32)) + ws
+        y = (rung(rungs[0])(*operands) if len(rungs) == 1 else
+             jax.lax.switch(at, [rung(r) for r in rungs], *operands))
+        if c.latent_dim:
+            with jax.named_scope("moe_latent"):
+                y = jnp.dot(y.astype(self.dtype), w_up,
+                            preferred_element_type=jnp.float32)
+        if c.shared_mlp_dim:
+            m = c.shared_mlp_dim
+            shared = (("shared_w1", (d, m), ("embed", "mlp")),
+                      ("shared_w3", (d, m), ("embed", "mlp")),
+                      ("shared_w2", (m, d), ("mlp", "embed")))
+            s1, *s3, s2 = (self.param(
+                name, nn.with_logical_partitioning(
+                    nn.initializers.xavier_uniform(), axes), shape,
+                jnp.float32).astype(self.dtype) for name, shape, axes in (
+                    shared if gated else shared[::2]))
             with jax.named_scope("moe_shared"):
                 xd = x.astype(self.dtype)
-                y = y + jnp.dot(nn.silu(xd @ s1) * (xd @ s3), s2,
-                                preferred_element_type=jnp.float32)
+                a = (nn.silu(xd @ s1) * (xd @ s3[0]) if gated
+                     else jnp.square(nn.relu(xd @ s1)))
+                y = y + jnp.dot(a, s2, preferred_element_type=jnp.float32)
         return y.astype(self.dtype), {
             "held": sizes, "rows": jnp.asarray(rungs, jnp.int32)[at],
             "pairs": jnp.asarray(n * k, jnp.int32), "group_hits": hits}

@@ -60,13 +60,14 @@ SCOPES = (
     "head_seed", "head_prefill", "head_connector", "head_dense_mlp",
     "head_decode", "head_sample", "head_flush",
     # models/lfm2.py, gqa.py, mla.py, xing4.py, deepseek_v2.py,
-    # falcon_h1.py: a head's layers
+    # falcon_h1.py, mamba2.py, nemotron_h.py: a head's layers
     "head_conv", "head_attn", "mla_prefill", "mla_decode", "mhc_maps",
     "head_moe", "head_ssm", "head_lm", "mtp_draft",
     # ops/ssd.py: the state-space scan inside a mixer
     "ssm_scan",
-    # models/transformer.py: the expert layer
-    "moe_route", "moe_experts", "moe_shared",
+    # models/transformer.py: the expert layer (``moe_latent``: the two
+    # projections of an expert layer whose experts live in a latent)
+    "moe_route", "moe_latent", "moe_experts", "moe_shared",
 )
 _SCOPE_SET = frozenset(SCOPES)
 

@@ -27,15 +27,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
+# DMA semaphores a program may hold: a v5e core's semaphore memory is 2 KiB
+# of 4-byte flags, and the kernel holds one a fetched row, two blocks' worth
+_SEMAPHORES = 512
 
 
-def combine_block(n: int, d: int) -> int:
-    """Tokens a program owns where the output is [n, d]: the largest of
-    32, 16, 8 that divides ``n``; 0 where none does or ``d`` is no
-    multiple of 128, and the kernel does not take the shape."""
+def combine_block(n: int, d: int, k: int) -> int:
+    """Tokens a program owns where the output is [n, d] and a token has
+    ``k`` rows: the largest of 32, 16, 8 that divides ``n`` and whose two
+    blocks of ``k`` rows a token stay within the semaphores a program may
+    hold; 0 where none does or ``d`` is no multiple of 128, and the kernel
+    does not take the shape."""
     if d % _LANES:
         return 0
-    return next((b for b in (32, 16, 8) if n % b == 0), 0)
+    return next((b for b in (32, 16, 8)
+                 if n % b == 0 and 2 * b * k <= _SEMAPHORES), 0)
 
 
 def _kernel(count_ref, src_ref, w_ref, rows_hbm, y_ref, got, sems, *,
@@ -91,7 +97,7 @@ def combine_rows(rows, src, w, count, *, interpret: Optional[bool] = None):
         interpret = jax.default_backend() != "tpu"
     n, k = src.shape
     c, d = rows.shape
-    tb = combine_block(n, d)
+    tb = combine_block(n, d, k)
     tiles = d // _LANES
     row = (tiles, _LANES)
     out = pl.pallas_call(
