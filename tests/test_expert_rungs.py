@@ -208,27 +208,54 @@ def test_the_ladder_comes_from_shapes_alone(cfg, n, rungs):
     assert all(r % 128 == 0 for r in rungs[1:])
 
 
-@pytest.mark.parametrize("n,k,count", [(64, 6, 150), (48, 4, 0),
-                                       (32, 4, 128)],
-                         ids=["some-held", "none-held", "all-held"])
-def test_the_way_back_adds_only_the_rows_under_count(n, k, count):
-    """``ops/combine_rows.py`` (interpreted off the chip): each token's
-    rows under ``count``, weighted, in the order of its pairs; the rows
-    past ``count`` hold NaN and are never read."""
-    from video_edge_ai_proxy_tpu.ops.combine_rows import combine_rows
+_WAY_BACK = {   # n, k, d, rows C, count, lanes of a slice (None: the rule's)
+    "some-held": (64, 6, 256, 160, 150, None),
+    "none-held": (48, 4, 256, 16, 0, None),
+    "all-held": (32, 4, 256, 128, 128, None),
+    "top-22": (64, 22, 256, 1408, 300, None),
+    "three-slices": (40, 4, 384, 96, 70, 128),
+    "count-inside-a-block": (16, 24, 2048, 384, 200, None),
+    "one-token-several-rows": (24, 8, 128, 64, 40, None),
+}
 
+
+@pytest.mark.parametrize("case", sorted(_WAY_BACK))
+def test_the_way_back_adds_only_the_rows_under_count(case, monkeypatch):
+    """``ops/combine_rows.py`` (interpreted off the chip): each held row
+    under ``count``, weighted, added to its token, in the order the rows
+    lie; the rows past ``count`` hold NaN and are never added. The rows lie
+    as the layer sorts them: a pair's token is its slot // k, the slots in
+    a seeded order. Against a NumPy loop over the rows, at top-22, with the
+    output in three slices of 128 lanes (the VMEM budget set to what one
+    slice and its row blocks take), with ``count`` inside the second of
+    three row blocks, and with every row of one token held."""
+    from video_edge_ai_proxy_tpu.ops import combine_rows as way_back
+
+    n, k, d, c, count, lanes = _WAY_BACK[case]
+    if lanes:
+        monkeypatch.setattr(way_back, "_VMEM_BUDGET", 4 * n * lanes
+                            + way_back._blocks_bytes(lanes))
+        way_back.combine_rows.clear_cache()
+    lanes = lanes or d
+    assert way_back.combine_slice(n, d) == lanes
+    if case == "count-inside-a-block":
+        assert way_back._block_rows(c, lanes) == 128 < count < 256 < c
     rng = np.random.default_rng(n + k)
-    c, d = max(count, 8) + 8, 256
+    slots = rng.permutation(n * k)
+    if case == "one-token-several-rows":
+        mine = np.isin(slots, np.arange(5 * k, 6 * k))
+        slots = np.concatenate([slots[mine], slots[~mine]])
+        slots[:count] = rng.permutation(slots[:count])
+    tok = (slots[:c] // k).astype(np.int32)
     rows = rng.standard_normal((c, d)).astype(np.float32)
     rows[count:] = np.nan
-    src = rng.permutation(n * k)[:n * k].reshape(n, k).astype(np.int32)
-    w = rng.standard_normal((n, k)).astype(np.float32)
-    got = np.asarray(combine_rows(jnp.asarray(rows), jnp.asarray(src),
-                                  jnp.asarray(w), count))
+    w = rng.standard_normal(c).astype(np.float32)
+    got = np.asarray(way_back.combine_rows(
+        jnp.asarray(rows), jnp.asarray(tok), jnp.asarray(w), count, n))
     want = np.zeros((n, d), np.float32)
-    for t in range(n):
-        for j in range(k):
-            if src[t, j] < count:
-                want[t] += w[t, j] * rows[src[t, j]]
-    assert np.isfinite(got).all()
+    for r in range(count):
+        want[tok[r]] += w[r] * rows[r]
+    if case == "one-token-several-rows":
+        assert (tok[:count] == 5).sum() == k
+    assert got.shape == (n, d) and np.isfinite(got).all()
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)

@@ -426,18 +426,17 @@ def test_the_published_sizes_are_the_chips_share():
 # sha256 of the lowered (StableHLO) text of each head's serving step, taken
 # before the attention moved out of models/lfm2.py and the round buffer's
 # dtype was taken from the cache's kind: the moves change no program. The
-# first head's two were taken anew when its prefill attention became one
-# kernel a chunk (ops/flash_attention.py gqa_prefill_attention); the latent
-# heads' are still the ones taken before, which that change left alone
+# expert heads' were taken anew when the expert layer's way back came to
+# walk only the held rows (ops/combine_rows.py, under ``moe_combine``)
 STEPS = {
     ("videomae_b_lfm2", 64, 360, 640):
-        "c8555f543bbab0636b8789fab02b2763a88edfebe8a604bd214e1aa3f4efe669",
+        "ea8e322cc3a92d180fad45e1d0126a5e792220b3309377e8a7dbd0c2cc22cb83",
     ("tiny_videomae_lfm2", 2, 48, 64):
-        "2bbcae2890bd64955db6448536ba3446d89630628ef5ff8705c40af5b86aa416",
+        "6d9f73dd571d1a3ade8ba535965b10cdb0fb18735a29b27c5bdc8cdf77fee872",
     ("tiny_videomae_xing4", 2, 48, 64):
-        "7a0efe8389d15f9250fd62ef49530a8c774a04b64aa7a3b680dc0c1796e52bac",
+        "f22be9a1ccc27aa3b49f6e4709df14e93c685bbf658cf3a8db4aeaddd1da9210",
     ("tiny_videomae_dsv2", 2, 48, 64):
-        "13202e6eded1a6e206fb9d24c875bcd3595fb2541f23036d400c4433cc01c101",
+        "3078f3d5bf7b2a2ea8f0616492bf76fd0af59a8b242b161005eb5fbb1b66b800",
     # this head's own, taken before its mixer moved to models/mamba2.py and
     # the expert layer learned a latent and squared ReLU for the fifth head
     ("videomae_b_falcon_h1", 64, 360, 640):

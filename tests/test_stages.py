@@ -171,6 +171,43 @@ def test_the_latent_projections_are_a_stage_of_the_expert_layer():
             ("head_prefill", "head_moe", "moe_shared")} <= paths
 
 
+@pytest.mark.parametrize("n,dim", [(8, 128), (6, 32)],
+                         ids=["kernel", "xla"])
+def test_the_way_back_is_a_stage_of_the_expert_layer(n, dim):
+    """The expert layer's way back from its sorted held rows to the tokens
+    (models/transformer.py ``TopKMoeMlp``), compiled inside a prefill
+    loop, maps to ``head_moe`` / ``moe_combine``: the kernel of
+    ops/combine_rows.py (interpreted here) where it takes the shape, XLA's
+    weighted sum where it does not; the grouped products beside it keep
+    ``moe_experts``."""
+    import jax
+    import jax.numpy as jnp
+
+    from video_edge_ai_proxy_tpu.models.transformer import (
+        TopKMoeConfig, TopKMoeMlp)
+    from video_edge_ai_proxy_tpu.ops.combine_rows import combine_slice
+
+    assert bool(combine_slice(n, dim)) == (n == 8)
+    layer = TopKMoeMlp(TopKMoeConfig(
+        dim=dim, mlp_dim=24, num_experts=8, top_k=2, experts_held=(0, 1, 2)),
+        dtype=jnp.float32)
+    x = jnp.ones((n, dim), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), x)
+
+    def prefill(params, x):
+        with jax.named_scope("head_prefill"):
+            def body(i, x):
+                with jax.named_scope("head_moe"):
+                    return x + layer.apply(params, x)[0]
+            return jax.lax.fori_loop(0, 2, body, x)
+
+    assert "moe_combine" in stages.SCOPES
+    paths = set(stages.stage_map(jax.jit(prefill).lower(params, x)
+                                 .compile()).values())
+    assert {("head_prefill", "head_moe", "moe_combine"),
+            ("head_prefill", "head_moe", "moe_experts")} <= paths
+
+
 def test_what_the_compiler_made_takes_a_scope_from_its_root_or_operands():
     text = """HloModule jit_f, is_scheduled=true
 

@@ -727,6 +727,58 @@ class TestNemotronHHeadLayers:
         text = _compiled_text(twice, variables, x)
         assert "ragged-dot" in text and " conditional(" not in text
 
+    def test_latent_expert_layer_sorts_once_and_walks_the_held_rows(
+            self, v5e, monkeypatch):
+        """The layer at the cell's chunk (3,136 tokens, top-22: 68,992
+        pairs a call): one sort of the N x k keys (the held pairs first;
+        the way back needs no inverse permutation), and in every rung's
+        branch the way back is the kernel, which the stage map gives
+        ``head_moe`` / ``moe_combine``, reading the grouped products'
+        [C, 1,024] float32 rows as they lie (no [C, 8, 128] copy of them);
+        no rung below the top makes an [N x k, 1,024] float32 array."""
+        import flax.linen as nn
+
+        from video_edge_ai_proxy_tpu.models import nemotron_h
+        from video_edge_ai_proxy_tpu.models.transformer import (
+            TopKMoeMlp, pair_rungs)
+        from video_edge_ai_proxy_tpu.obs import stages
+
+        cfg = nemotron_h.NemotronHConfig().moe
+        assert (cfg.top_k, cfg.expert_dim) == (22, 1024)
+        layer = TopKMoeMlp(cfg)
+        variables = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            jax.eval_shape(lambda: nn.meta.unbox(layer.init(
+                jax.random.PRNGKey(0), jnp.zeros((8, 4096), jnp.bfloat16)))))
+
+        def call(variables, x):
+            with jax.named_scope("head_moe"):
+                return layer.apply(variables, x, method=TopKMoeMlp.routed)
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = jax.jit(call).lower(variables, jax.ShapeDtypeStruct(
+            (3136, 4096), jnp.bfloat16, sharding=v5e)).compile()
+        text = compiled.as_text()
+        pairs = 3136 * 22
+        assert len([line for line in text.splitlines() if " sort(" in line
+                    and "s32[%d]" % pairs in line.split(" sort(")[0]]) == 1
+        rungs = pair_rungs(cfg, 3136)
+        assert rungs == (68992, 34560, 17280, 8704, 4352)
+        kernels = {k: p for k, p in stages.stage_map(compiled).items()
+                   if k.startswith("%combine_rows")}
+        assert len(kernels) == len(rungs)
+        assert set(kernels.values()) == {("head_moe", "moe_combine")}
+        for rows in rungs:
+            assert "f32[%d,8,128]" % rows not in text, rows
+        cond = next(line for line in text.splitlines()
+                    if " conditional(" in line)
+        branches = cond.split("branch_computations={", 1)[1].split("}")[0]
+        for i, name in enumerate(branches.split(", ")):
+            made = [line.split(" = ", 1)[1][:20]
+                    for line in _computation(text, name)]
+            assert i == 0 or not [m for m in made if m.startswith(
+                "f32[%d,1024]" % pairs)], name
+
     @pytest.mark.parametrize("t", [784, 1], ids=["prefill", "decode"])
     def test_unrotated_attention_of_eight_heads_on_one(self, v5e,
                                                        monkeypatch, t):

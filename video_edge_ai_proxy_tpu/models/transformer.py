@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.combine_rows import combine_block, combine_rows
+from ..ops.combine_rows import combine_rows, combine_slice
 from .common import Dtype
 
 # attn_fn(q, k, v) -> out, all [B, T, H, D]
@@ -341,9 +341,9 @@ def pair_rungs(cfg: TopKMoeConfig, n: int) -> tuple:
     sends the held experts (``n x top_k x held / num_experts``) and has at
     least ``_LEAST_RUNG`` rows. One rung where the call is small (decode),
     every expert is held here, or the way back's kernel does not take the
-    shape (``ops/combine_rows.py`` ``combine_block``)."""
+    shape (``ops/combine_rows.py`` ``combine_slice``)."""
     top = n * cfg.top_k
-    if not combine_block(n, cfg.expert_dim, cfg.top_k):
+    if not combine_slice(n, cfg.expert_dim):
         return (top,)
     floor = max(2 * top * len(cfg.held) / cfg.num_experts, _LEAST_RUNG)
     rungs = [top]
@@ -453,17 +453,17 @@ class TopKMoeMlp(nn.Module):
             self, c, stack=h, gated=gated, dim=de))
         # The held pairs sort first, so the first ``count`` rows of
         # ``order`` are all that is computed: each rung moves its C rows,
-        # the smallest that holds them is run. A pair's row lies where it
-        # sorted; one held elsewhere sorted past ``count``, and the way
-        # back (a kernel: XLA would write every pair's row out and read it
-        # back) reads only the rows under it.
+        # the smallest that holds them is run. The way back (a kernel: XLA
+        # would write every pair's row out and read it back) walks those
+        # rows where they lie and adds each to its token.
         rungs = pair_rungs(c, n)
-        src = jnp.argsort(order).reshape(n, k)
+        kernel = bool(combine_slice(n, de))
 
         def rung(rows):
-            def moved(x, order, src, sizes, count, w, *ws):
+            def moved(x, order, sizes, count, w, *ws):
                 with jax.named_scope("moe_experts"):
-                    xs = jnp.take(x, order[:rows] // k, axis=0, mode="clip")
+                    tok = order[:rows] // k
+                    xs = jnp.take(x, tok, axis=0, mode="clip")
                     if gated:
                         w1, w3, w2 = ws
                         up = jax.lax.ragged_dot(xs, w3, sizes)
@@ -474,13 +474,19 @@ class TopKMoeMlp(nn.Module):
                             jax.lax.ragged_dot(xs, w1, sizes)))
                     ys = jax.lax.ragged_dot(
                         act, w2, sizes, preferred_element_type=jnp.float32)
-                if combine_block(n, de, k):
-                    return combine_rows(ys, src, w, count)
-                # a shape the kernel does not take has the one rung of
-                # every pair; rows past the last group are whatever the
-                # grouped products left there
-                ys = jnp.where((jnp.arange(rows) < count)[:, None], ys, 0.0)
-                return jnp.einsum("nkd,nk->nd", jnp.take(ys, src, axis=0), w)
+                with jax.named_scope("moe_combine"):
+                    if kernel:
+                        wr = jnp.take(w.reshape(n * k), order[:rows],
+                                      mode="clip")
+                        return combine_rows(ys, tok, wr, count, n)
+                    # a shape the kernel does not take has the one rung of
+                    # every pair; rows past the last group are whatever the
+                    # grouped products left there
+                    ys = jnp.where((jnp.arange(rows) < count)[:, None], ys,
+                                   0.0)
+                    src = jnp.argsort(order).reshape(n, k)
+                    return jnp.einsum("nkd,nk->nd", jnp.take(ys, src, axis=0),
+                                      w)
             return moved
 
         at = jnp.sum(count <= jnp.asarray(rungs[1:], jnp.int32),
@@ -496,7 +502,7 @@ class TopKMoeMlp(nn.Module):
                 u = x.astype(self.dtype) @ w_down
         else:
             u = x.astype(self.dtype)
-        operands = (u, order, src, sizes, count, w.astype(jnp.float32)) + ws
+        operands = (u, order, sizes, count, w.astype(jnp.float32)) + ws
         y = (rung(rungs[0])(*operands) if len(rungs) == 1 else
              jax.lax.switch(at, [rung(r) for r in rungs], *operands))
         if c.latent_dim:

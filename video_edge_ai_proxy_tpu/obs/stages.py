@@ -66,8 +66,9 @@ SCOPES = (
     # ops/ssd.py: the state-space scan inside a mixer
     "ssm_scan",
     # models/transformer.py: the expert layer (``moe_latent``: the two
-    # projections of an expert layer whose experts live in a latent)
-    "moe_route", "moe_latent", "moe_experts", "moe_shared",
+    # projections of an expert layer whose experts live in a latent;
+    # ``moe_combine``: the way back from the sorted held rows to the tokens)
+    "moe_route", "moe_latent", "moe_experts", "moe_combine", "moe_shared",
 )
 _SCOPE_SET = frozenset(SCOPES)
 
