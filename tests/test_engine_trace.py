@@ -506,12 +506,11 @@ STEP_SCOPES = ("pre_cast_scale", "pre_resize", "pre_normalize", "embed",
                "encoder_block", "cls_head", "softmax_topk")
 
 
-def _visited(bus, model, layers):
+def _served(bus, model, part):
     """Serve ``model``'s tiny twin to two clip cameras for three batches
-    each and check what its prefill attention visited: the step's count of
-    the tiles it visited, and of what a dense pass would visit, on every
-    record of the batch and in ``vep_attn_key_blocks_total``. ``layers``:
-    the head's attention layers."""
+    each. Returns the stage records that carry ``{part}_blocks_dense``, one
+    record a batch of them, and what ``vep_{part}_key_blocks_total`` rose
+    by ({kind: n})."""
     cams = ["clip0", "clip1"]
     for cam in cams:
         bus.create_stream(cam, F)
@@ -521,7 +520,7 @@ def _visited(bus, model, layers):
         annotations=AnnotationQueue(handler=lambda b: True))
     eng.warmup()
     fam = {f.name: f for f in registry.families()}
-    blocks = fam["vep_attn_key_blocks_total"]
+    blocks = fam[f"vep_{part}_key_blocks_total"]
     before = {k: blocks.labels(k).value for k in ("live", "dense")}
     eng.start()
     try:
@@ -534,8 +533,19 @@ def _visited(bus, model, layers):
             time.sleep(0.05)
     finally:
         eng.stop()
-    recs = [r for r in eng.stage_records if "attn_blocks_dense" in r]
+    recs = [r for r in eng.stage_records if f"{part}_blocks_dense" in r]
     assert len(recs) >= 3 * len(cams)
+    batches = {tuple(r["batch"]): r for r in recs}.values()
+    return recs, batches, {k: blocks.labels(k).value - before[k]
+                           for k in ("live", "dense")}
+
+
+def _visited(bus, model, layers):
+    """Check what ``model``'s prefill attention visited: the step's count
+    of the tiles it visited, and of what a dense pass would visit, on every
+    record of the batch and in ``vep_attn_key_blocks_total``. ``layers``:
+    the head's attention layers."""
+    recs, batches, rose = _served(bus, model, "attn")
     # tiny widths: the new positions one key block and one lane tile of
     # queries, what can be cached one key block: 2 tiles a stream an
     # attention dense, 1 or 2 live
@@ -543,12 +553,10 @@ def _visited(bus, model, layers):
     for rec in recs:
         assert rec["attn_blocks_dense"] == dense
         assert dense // 2 <= rec["attn_blocks_live"] <= dense
-    batches = {tuple(r["batch"]): r for r in recs}.values()
     # a context holds the instruction at least: its key block is visited
     assert all(r["attn_blocks_live"] == dense for r in batches)
     for kind in ("live", "dense"):
-        assert blocks.labels(kind).value - before[kind] == sum(
-            r[f"attn_blocks_{kind}"] for r in batches)
+        assert rose[kind] == sum(r[f"attn_blocks_{kind}"] for r in batches)
 
 
 def test_a_stream_batch_carries_what_its_prefill_attention_visited(bus):
@@ -557,6 +565,28 @@ def test_a_stream_batch_carries_what_its_prefill_attention_visited(bus):
 
     model = "tiny_videomae_xing4"
     _visited(bus, model, models.get(model).build().cfg.head.num_layers)
+
+
+def test_a_latent_heads_batch_carries_what_its_decode_attention_fetched(
+        bus):
+    """``tiny_videomae_dsv2``: its decode attention's count of the key
+    blocks it fetched from the pool and of what a dense pass would fetch,
+    on every record of the batch and in ``vep_decode_key_blocks_total``.
+    Tiny widths: the pool's 160 positions are one key block, which holds
+    all 128 cacheable ones: a stream fetches it in each of the 3 decode
+    iterations' 3 blocks, as a dense pass would (every context holds the
+    instruction)."""
+    from video_edge_ai_proxy_tpu.models import registry as models
+
+    model = "tiny_videomae_dsv2"
+    c = models.get(model).build().cfg
+    recs, batches, rose = _served(bus, model, "decode")
+    dense = 2 * c.decode_steps * c.head.num_layers
+    for rec in recs:
+        assert rec["decode_blocks_dense"] == dense
+        assert rec["decode_blocks_live"] == dense
+    for kind in ("live", "dense"):
+        assert rose[kind] == sum(r[f"decode_blocks_{kind}"] for r in batches)
 
 
 def test_a_grouped_query_heads_batch_carries_what_it_visited(bus):

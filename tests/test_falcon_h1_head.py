@@ -427,16 +427,18 @@ def test_the_published_sizes_are_the_chips_share():
 # before the attention moved out of models/lfm2.py and the round buffer's
 # dtype was taken from the cache's kind: the moves change no program. The
 # expert heads' were taken anew when the expert layer's way back came to
-# walk only the held rows (ops/combine_rows.py, under ``moe_combine``)
+# walk only the held rows (ops/combine_rows.py, under ``moe_combine``), and
+# the latent heads' when their decode attention came to read the pool where
+# it lies (ops/flash_attention.py ``latent_decode_attention``)
 STEPS = {
     ("videomae_b_lfm2", 64, 360, 640):
         "ea8e322cc3a92d180fad45e1d0126a5e792220b3309377e8a7dbd0c2cc22cb83",
     ("tiny_videomae_lfm2", 2, 48, 64):
         "6d9f73dd571d1a3ade8ba535965b10cdb0fb18735a29b27c5bdc8cdf77fee872",
     ("tiny_videomae_xing4", 2, 48, 64):
-        "f22be9a1ccc27aa3b49f6e4709df14e93c685bbf658cf3a8db4aeaddd1da9210",
+        "1ea7752b8179de645bea02ef507d9c04235551b6735eda4fe4ee78cde1106aaa",
     ("tiny_videomae_dsv2", 2, 48, 64):
-        "3078f3d5bf7b2a2ea8f0616492bf76fd0af59a8b242b161005eb5fbb1b66b800",
+        "7f691f3cd9d950220caaaffdf501cdb7876a338eb1323c1ccdbff762c4a52675",
     # this head's own, taken before its mixer moved to models/mamba2.py and
     # the expert layer learned a latent and squared ReLU for the fifth head
     ("videomae_b_falcon_h1", 64, 360, 640):

@@ -1088,7 +1088,14 @@ class InferenceEngine:
             "Tiles (a key block against 128 queries) a head's prefill "
             "attention visited (live) and what a pass over every key it "
             "could hold would visit (dense)", ("kind",))
-        self._m_attn_blocks = {k: attn.labels(k) for k in ("live", "dense")}
+        decode = obs_registry.counter(
+            "vep_decode_key_blocks_total",
+            "Key blocks a head's decode attention fetched from its cache "
+            "(live) and what a pass over every cacheable position would "
+            "fetch (dense)", ("kind",))
+        self._m_key_blocks = {
+            part: {k: c.labels(k) for k in ("live", "dense")}
+            for part, c in (("attn", attn), ("decode", decode))}
         mtp = obs_registry.counter(
             "vep_mtp_drafts_total",
             "Drafts of a stream head's prediction module, verified by the "
@@ -4214,13 +4221,15 @@ class InferenceEngine:
             inflight.tr.update(moe_pairs_total=routed, moe_group_hits=hits)
             self._m_moe_routed.inc(routed)
             self._m_moe_group_hits.inc(hits)
-        if "attn_blocks_live" in host:
+        for part in ("attn", "decode"):
             # a head whose prefill attention skips the key blocks past a
-            # stream's context and above the causal diagonal
-            for kind in ("live", "dense"):
-                n = int(host.pop(f"attn_blocks_{kind}"))
-                inflight.tr[f"attn_blocks_{kind}"] = n
-                self._m_attn_blocks[kind].inc(n)
+            # stream's context and above the causal diagonal; one whose
+            # decode attention fetches only those its context reaches
+            if f"{part}_blocks_live" in host:
+                for kind in ("live", "dense"):
+                    n = int(host.pop(f"{part}_blocks_{kind}"))
+                    inflight.tr[f"{part}_blocks_{kind}"] = n
+                    self._m_key_blocks[part][kind].inc(n)
         if "decode_iters" in host:
             inflight.tr["head_decode_iters"] = int(host.pop("decode_iters"))
         if "mtp_drafted" in host:

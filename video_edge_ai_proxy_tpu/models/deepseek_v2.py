@@ -372,6 +372,10 @@ class VideoMAEDeepseekV2(nn.Module):
                 # its chunks and blocks
                 **mla.prefill_visits(pos0, c.visual_tokens, self._cap,
                                      c.head.num_layers),
+                # and what its decode attention fetched, over its
+                # iterations and blocks
+                **mla.decode_visits(pool, slots, pos0, self._cap,
+                                    c.decode_steps * c.head.num_layers),
                 "decode_iters": jnp.asarray(c.decode_steps, jnp.int32)}
 
     @nn.nowrap

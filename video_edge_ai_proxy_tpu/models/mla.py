@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.flash_attention import (
+    latent_decode_attention, latent_decode_depths, latent_decode_visits,
     latent_prefill_attention, latent_prefill_visits)
 from .common import Dtype
 from .stream_head import RmsNorm, _kernel
@@ -179,53 +180,42 @@ def prefill_visits(ctx, t: int, cap: int, blocks: int):
 
 
 def mla_decode_attention(q_n, q_r, w_uk, w_uv, pool, rbuf, slots, ctx, upto,
-                         scale):
+                         scale, block=None):
     """A few new positions a stream, in the latent space: ``q_n`` [B, T, H,
     d_n] is carried through ``w_uk`` to the cache's own width (``q_n W_ukᵀ``
     [r] a head), joined with ``q_r``, and attends over the 576-wide rows as
-    they lie: the pool READ IN PLACE in slot order (the queries are carried
-    to their slots and the partial results back by a one-hot product, as
-    ``models/lfm2.py`` does: gathering the rows would copy the cache), and
-    this round's own rows ``rbuf`` [B, R, r + d_r], of which query t of row
-    b sees those up to ``upto[b, t]``. The two partial softmaxes are merged
-    by their maxima and sums; the output leaves the latent space through
-    ``w_uv``."""
+    they lie: its context in the pool (``pool`` [slots, S, row], or the
+    whole [blocks, slots, S, row] with ``block`` naming this attention's),
+    read where it lies by one kernel (``ops/flash_attention.py``
+    :func:`latent_decode_attention`: a stream a program, the key blocks its
+    context reaches and no others), and this round's own rows ``rbuf`` [B,
+    R, r + d_r], of which query t of row b sees those up to ``upto[b, t]``.
+    The two partial softmaxes are merged by their maxima and sums; the
+    output leaves the latent space through ``w_uv``."""
     b, t, h, _ = q_n.shape
     r = w_uk.shape[0]
-    c = pool.shape[0]
-    hi = jax.lax.Precision.HIGHEST
     width = pool.shape[-1]          # the rows' width in memory, zero-padded
     q = jnp.concatenate(
         [jnp.einsum("bthd,rhd->bthr", q_n, w_uk), q_r,
          jnp.zeros(q_r.shape[:-1] + (width - r - q_r.shape[-1],),
                    q_r.dtype)], axis=-1)
     q = q.reshape(b, t * h, width)
-    onehot = (slots[:, None] == jnp.arange(c)[None]).astype(jnp.float32)
-
-    def partial(query, rows, mask):
-        s = jnp.einsum("bqd,bsd->bqs", query, rows,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(mask, s, -1e30)
-        m = jnp.max(s, axis=-1)
-        e = jnp.exp(s - m[..., None])
-        # the rows whole (their rope part's output is dropped after): no
-        # slice of the cache is made
-        o = jnp.einsum("bqs,bsd->bqd", e.astype(rows.dtype), rows,
-                       preferred_element_type=jnp.float32)
-        return m, jnp.sum(e, axis=-1), o[..., :r]
-
-    # the pool's part, in slot order
-    q_slot = jnp.einsum("bc,bqd->cqd", onehot, q.astype(jnp.float32))
-    ctx_slot = jnp.einsum("bc,b->c", onehot, ctx.astype(jnp.float32),
-                          precision=hi)
-    seen = jnp.arange(pool.shape[1])[None] < ctx_slot[:, None]      # [c, S]
-    part_a = partial(q_slot.astype(q.dtype), pool, seen[:, None])
-    m_a, l_a, o_a = (jnp.einsum("bc,c...->b...", onehot, x, precision=hi)
-                     for x in part_a)
-    # this round's part, in batch order
+    if block is None:
+        pool, block = pool[None], 0
+    m_a, l_a, o_a = latent_decode_attention(q, pool, slots, ctx, block,
+                                            scale=scale, rank=r)
+    # this round's part
+    s = jnp.einsum("bqd,bsd->bqs", q, rbuf,
+                   preferred_element_type=jnp.float32) * scale
     new = jnp.arange(rbuf.shape[1])[None, None] <= upto[:, :, None]
-    new = jnp.repeat(new, h, axis=1)                            # [B, T*H, R]
-    m_b, l_b, o_b = partial(q, rbuf, new)
+    s = jnp.where(jnp.repeat(new, h, axis=1), s, -1e30)     # [B, T*H, R]
+    m_b = jnp.max(s, axis=-1)
+    e = jnp.exp(s - m_b[..., None])
+    l_b = jnp.sum(e, axis=-1)
+    # the rows whole (their rope part's output is dropped after): no slice
+    # of the buffer is made
+    o_b = jnp.einsum("bqs,bsd->bqd", e.astype(rbuf.dtype), rbuf,
+                     preferred_element_type=jnp.float32)[..., :r]
     m = jnp.maximum(m_a, m_b)
     w_a, w_b = jnp.exp(m_a - m), jnp.exp(m_b - m)
     o = (o_a * w_a[..., None] + o_b * w_b[..., None]) \
@@ -233,6 +223,20 @@ def mla_decode_attention(q_n, q_r, w_uk, w_uv, pool, rbuf, slots, ctx, upto,
     o = jnp.einsum("bthr,rhd->bthd",
                    o.astype(q_n.dtype).reshape(b, t, h, r), w_uv)
     return o.reshape(b, t, -1)
+
+
+def decode_visits(pool, slots, ctx, cap: int, calls):
+    """{"decode_blocks_live", "decode_blocks_dense"}: the key blocks
+    :func:`mla_decode_attention` fetches from the pool (``pool`` [blocks,
+    slots, S, row]) in ``calls`` attentions over a batch whose contexts
+    hold ``ctx`` [B] positions in slots ``slots`` [B], by the kernel's own
+    rule, and what passes over all ``cap`` cacheable positions would
+    fetch."""
+    live, dense = latent_decode_visits(
+        latent_decode_depths(ctx, slots, pool.shape[1], pool.shape[-2]),
+        cap, pool.shape[-2])
+    return {"decode_blocks_live": live * calls,
+            "decode_blocks_dense": dense * calls}
 
 
 def write_rows(rbuf, new, at):
@@ -318,9 +322,8 @@ class MlaAttention(nn.Module):
                     rbuf = write_rows(rbuf, new, at)
                     upto = at[:, None] + jnp.arange(t, dtype=at.dtype)[None]
                     o = mla_decode_attention(
-                        q_n, q_r, w_uk, w_uv,
-                        pool if block is None else pool[block], rbuf, slots,
-                        ctx, upto, softmax_scale(c))
+                        q_n, q_r, w_uk, w_uv, pool, rbuf, slots, ctx, upto,
+                        softmax_scale(c), block)
             return o @ self.o.astype(self.dtype), rbuf
 
 

@@ -595,6 +595,12 @@ class VideoMAEXing4(nn.Module):
 
         s = jax.lax.while_loop(lambda s: jnp.any(s["done"] < d), iteration,
                                carry)
+        # the main blocks attend an iteration, the module's block an
+        # iteration and once for the first draft
+        main = mla.decode_visits(pool, slots, pos0, self._cap,
+                                 c.head.num_layers * s["iters"])
+        module = mla.decode_visits(pool, slots, seen, self._cap,
+                                   s["iters"] + 1)
         return {"tokens": s["tokens"], "top_ids": s["top_ids"],
                 "top_probs": s["top_probs"], "rows": s["h"],
                 "rbuf": s["rbuf"], **moe_outputs(s["counts"]),
@@ -605,6 +611,8 @@ class VideoMAEXing4(nn.Module):
                 # alone)
                 **mla.prefill_visits(pos0, n_v, self._cap,
                                      c.head.num_layers),
+                # and what its decode attention fetched
+                **{k: main[k] + module[k] for k in main},
                 "draft_ids": first[1], "draft_probs": first[2]}
 
     @nn.nowrap

@@ -20,7 +20,10 @@ Beside it, :func:`latent_prefill_attention`: the same running softmax over
 a latent cache (`models/mla.py`): a stream's cached rows read from its slot
 of the pool as they lie, up-projected to one head's keys and values a key
 block at a time inside the kernel, the stream's depth a prefetched scalar
-that decides how many key blocks a program visits. And
+that decides how many key blocks a program visits; and
+:func:`latent_decode_attention`, a decode's few queries a stream over the
+same cache, each key block the stream's context reaches fetched once from
+where it lies, the rest never. And
 :func:`gqa_prefill_attention`, the same over a grouped-query key-value
 cache (`models/gqa.py`): a program holds the query heads of one key-value
 head, a lane tile of their queries at a time, and reads the stream's keys
@@ -711,3 +714,171 @@ def gqa_prefill_attention(q, new_k, new_v, pool_k, pool_v, layer, slots, ctx,
       jnp.clip(ctx, 0, cap).astype(jnp.int32), *args)
     o = o[..., :t * g].reshape(b, kv, hd, t, g)
     return o.transpose(0, 3, 1, 4, 2).reshape(b, t, h * hd)
+
+
+# -- decode over a latent cache (models/mla.py) -------------------------------
+
+def latent_decode_block(positions: int) -> int:
+    """The rows a key block of :func:`latent_decode_attention` holds, from
+    the pool's ``positions`` alone."""
+    return _block(positions, 2 * _LANES)
+
+
+def latent_decode_depths(ctx, slots, pool_slots: int, positions: int):
+    """The rows of its slot each stream's decode attends: its context,
+    within the pool, and none for a padded batch row (its slot is past
+    the pool's ``pool_slots``)."""
+    return jnp.where(slots < pool_slots, jnp.clip(ctx, 0, positions),
+                     0).astype(jnp.int32)
+
+
+def latent_decode_visits(depth, cap: int, positions: int):
+    """(live, dense): the key blocks :func:`latent_decode_attention` fetches
+    for streams that attend ``depth`` [B] rows of their slots
+    (:func:`latent_decode_depths`), summed over them, and what a pass over
+    all ``cap`` cacheable positions would fetch. The kernel's own rule: a
+    key block is fetched if it starts before the depth."""
+    block_k = latent_decode_block(positions)
+    live = jnp.sum(-(-jnp.clip(depth, 0, cap) // block_k)).astype(jnp.int32)
+    return live, jnp.asarray(depth.shape[0] * -(-cap // block_k), jnp.int32)
+
+
+def _latent_decode_kernel(slots_ref, depth_ref, block_ref, q_ref, pool_ref,
+                          m_ref, l_ref, o_ref, rows_ref, sems, fetched, *,
+                          scale: float, rank: int, block_k: int):
+    """One stream: q [Q, row] its queries, one a row; pool the whole cache,
+    in HBM; m, l [Q, 1] and o [Q, rank] the running softmax, float32, the
+    outputs themselves; rows [2, block_k, row] the fetched key block and
+    the next, a DMA semaphore each; ``fetched`` the key blocks the
+    programs before this one fetched (the buffer a block lands in goes by
+    it). The programs run in order, and the last block of a stream starts
+    the next stream's first: no program waits for its first block."""
+    i = pl.program_id(0)
+    count = lambda b: (depth_ref[b] + block_k - 1) // block_k  # noqa: E731
+    depth, n = depth_ref[i], count(i)
+    whole = depth // block_k
+
+    @pl.when(i == 0)
+    def _():
+        fetched[0] = 0
+
+    first = fetched[0]
+
+    def fetch(b, j, at):
+        return pltpu.make_async_copy(
+            pool_ref.at[block_ref[0], slots_ref[b],
+                        pl.ds(pl.multiple_of(j * block_k, block_k), block_k)],
+            rows_ref.at[at], sems.at[at])
+
+    @pl.when((n > 0) & ((i == 0) | (count(jnp.maximum(i - 1, 0)) == 0)))
+    def _():
+        fetch(i, 0, first % 2).start()
+
+    m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+    def attend(rows, seen=None):
+        """A key block's rows [block_k, row] into the running softmax: the
+        scores [Q, block_k] in one product, the weighted latent rows in
+        another. ``seen`` [1, block_k]: the rows that count (None = all)."""
+        s = lax.dot_general(q_ref[...], rows, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        if seen is not None:
+            s = jnp.where(seen, s, _NEG)
+        m_ref[...], l_ref[...], alpha, p = _softmax_block(
+            m_ref[...], l_ref[...], s)
+        o_ref[...] = o_ref[...] * alpha + jnp.dot(
+            p.astype(rows.dtype), rows[:, :rank],
+            preferred_element_type=jnp.float32)
+
+    def each(j, carry):
+        at = (first + j) % 2
+
+        @pl.when(j + 1 < n)
+        def _():
+            fetch(i, j + 1, 1 - at).start()
+
+        @pl.when((j + 1 == n) & (i + 1 < pl.num_programs(0)))
+        def _():
+            @pl.when(count(i + 1) > 0)
+            def _():
+                fetch(i + 1, 0, 1 - at).start()
+
+        fetch(i, j, at).wait()
+
+        @pl.when(j < whole)
+        def _():
+            attend(rows_ref[at])
+
+        # the block that straddles the depth: its rows past it zeroed
+        # (they may hold anything, and 0 x NaN is NaN in the value product)
+        @pl.when(j == whole)
+        def _():
+            base = j * block_k
+            attend(jnp.where(base + lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0) < depth, rows_ref[at], 0),
+                seen=base + lax.broadcasted_iota(
+                    jnp.int32, (1, block_k), 1) < depth)
+
+        return carry
+
+    lax.fori_loop(0, n, each, 0)
+    fetched[0] = first + n
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "rank", "interpret"))
+def latent_decode_attention(q, pool, slots, ctx, block, *, scale: float,
+                            rank: int, interpret: Optional[bool] = None):
+    """Each stream's few decode queries over its context in a latent cache,
+    read where it lies: the pool's half of a decode attention, as a
+    running softmax's statistics.
+
+    ``q`` [B, Q, row]: a stream's Q queries (positions x heads), each laid
+    out against a whole row; ``pool`` [blocks, slots, S, row] the whole
+    cache, of which this attention's is ``pool[block]`` (``block`` an
+    operand: a stack's attentions share one trace and one compiled kernel;
+    the whole is handed over because a slice handed to a kernel is a copy
+    of it); stream b's context is the first ``ctx[b]`` rows of slot
+    ``slots[b]``, and the rest may hold anything; a row whose slot is past
+    the pool (a padded batch row) has none. Returns (m, l, o): [B, Q] the
+    scores' maximum and the sum of their exponentials, [B, Q, rank] those
+    exponentials' sum of the rows' first ``rank`` numbers, all float32 (m
+    at -1e30, l and o zero, where a stream has no context).
+
+    Grid (stream,), in order: a program fetches the key blocks that start
+    before its stream's depth, one DMA a block, the next started before
+    this one is scored, and never the rest
+    (:func:`latent_decode_visits` counts the fetches). Operands enter both
+    products in their own dtype; scores, maximum, sum and the weighted sum
+    are float32."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, nq, row = q.shape
+    _, n_slots, positions, _ = pool.shape
+    block_k = latent_decode_block(positions)
+    kernel = functools.partial(_latent_decode_kernel, scale=scale, rank=rank,
+                               block_k=block_k)
+    stream = lambda i, s, c, k: (i, 0, 0)  # noqa: E731
+    m, l, o = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[pl.BlockSpec((None, nq, row), stream),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((None, nq, 1), stream),
+                       pl.BlockSpec((None, nq, 1), stream),
+                       pl.BlockSpec((None, nq, rank), stream)],
+            scratch_shapes=[pltpu.VMEM((2, block_k, row), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, nq, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((b, nq, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((b, nq, rank), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.clip(slots, 0, n_slots - 1).astype(jnp.int32),
+      latent_decode_depths(ctx, slots, n_slots, positions),
+      jnp.asarray(block, jnp.int32).reshape(1), q, pool)
+    return m[..., 0], l[..., 0], o
